@@ -34,26 +34,7 @@ from surreal_tpu.session.profile import ProfileManager
 from surreal_tpu.session.telemetry import Tracer
 from surreal_tpu.session.tracker import PeriodicTracker
 from surreal_tpu.utils import faults
-
-
-def maybe_enable_compile_cache(session_cfg) -> str | None:
-    """Resolve + enable ``session.compile_cache_dir`` (the persistent XLA
-    compile cache); returns the active absolute dir, or None when the knob
-    is unset or enabling failed. Relative paths resolve under the session
-    folder, so the default spelling ``compile_cache_dir=xla_cache`` keeps
-    the cache session-local while an absolute path shares one cache across
-    sessions (the warm-relaunch win). One function for every caller:
-    SessionHooks (all single-host drivers + multi-host rank 0) and the
-    multi-host prologue for ranks > 0, which never construct hooks.
-    ``.get`` keeps configs saved before the knob existed loadable."""
-    cache_dir = session_cfg.get("compile_cache_dir", None)
-    if not cache_dir:
-        return None
-    if not os.path.isabs(cache_dir):
-        cache_dir = os.path.join(session_cfg.folder, cache_dir)
-    from surreal_tpu.utils.compat import enable_compile_cache
-
-    return cache_dir if enable_compile_cache(cache_dir) else None
+from surreal_tpu.utils.compat import compile_cache_counts, enable_compile_cache
 
 
 class SessionHooks:
@@ -132,10 +113,9 @@ class SessionHooks:
             cfg, on_event=self.tracer.event, log=self.log,
             policy=getattr(learner, "policy", None),
         )
-        # persistent XLA compile cache: enabled before the driver's first
-        # jitted call compiles (drivers construct hooks inside run(), and
-        # tracing/compilation is lazy until the first dispatch)
-        self.compile_cache_dir = maybe_enable_compile_cache(cfg)
+        # persistent XLA compile cache (utils/compat.py decides where):
+        # on before the driver's first hot program compiles
+        self.compile_cache_dir = enable_compile_cache()
         if self.compile_cache_dir is not None:
             self.log.info(
                 "persistent compile cache at %s", self.compile_cache_dir
@@ -510,6 +490,14 @@ class SessionHooks:
         )
         self._t0 = time.time()
         self._steps0 = env_steps
+        # the device this run resolved, as JAX reports it: what
+        # chip_smoke.py (and anyone reading the folder later) checks
+        # instead of trusting that a 'tpu' config meant a TPU run
+        dev = jax.devices()[0]
+        self.tracer.event(
+            "device", platform=str(dev.platform), kind=str(dev.device_kind),
+            count=jax.device_count(),
+        )
         if self._precision_meta is not None:
             # the active precision policy: one telemetry event per run
             # (diag renders it in Performance) + the checkpoint sidecar
@@ -778,8 +766,6 @@ class SessionHooks:
         no device sync rides on this."""
         if self.compile_cache_dir is None:
             return
-        from surreal_tpu.utils.compat import compile_cache_counts
-
         self.tracer.event(
             "compile_cache", dir=self.compile_cache_dir,
             **compile_cache_counts(),

@@ -213,10 +213,9 @@ class _MultiHostSession:
         if hooks is None:
             # ranks > 0 never construct hooks, but every process compiles
             # the same programs — enable the persistent compile cache here
-            # (ranks without the folder mounted degrade to cold compiles)
-            from surreal_tpu.launch.hooks import maybe_enable_compile_cache
+            from surreal_tpu.utils.compat import enable_compile_cache
 
-            maybe_enable_compile_cache(self.config.session_config)
+            enable_compile_cache()
             from surreal_tpu.session.interrupt import InterruptSentinel
 
             rec = self.config.session_config.get("recovery", None)

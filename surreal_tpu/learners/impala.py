@@ -257,10 +257,11 @@ class IMPALALearner(SequenceActingMixin, Learner):
         )
         impl = algo.get("vtrace_impl", "xla")
         if impl == "pallas":
+            from surreal_tpu.ops import pallas_interpret
             from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
 
             return vtrace_nextobs_pallas(
-                **kw, **clips, interpret=jax.default_backend() != "tpu"
+                **kw, **clips, interpret=pallas_interpret()
             )
         if impl == "assoc":
             return vtrace_nextobs_assoc(**kw, **clips)

@@ -297,11 +297,12 @@ class PPOLearner(SequenceActingMixin, Learner):
         decay = gamma * algo.lam * (1.0 - batch["done"].astype(jnp.float32))
         gae_impl = algo.get("gae_impl", "xla")
         if gae_impl == "pallas":
+            from surreal_tpu.ops import pallas_interpret
             from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
 
             return gae_advantages_pallas_masked(
                 batch["reward"], boot_disc, decay, values, v_next,
-                interpret=jax.default_backend() != "tpu",
+                interpret=pallas_interpret(),
             )
         deltas = batch["reward"] + boot_disc * v_next - values
         if gae_impl == "assoc":

@@ -69,16 +69,50 @@ def build_config(args) -> Config:
 
 
 def _apply_backend(backend: str) -> None:
-    """``session_config.backend``: 'tpu' (default — whatever accelerator
-    jax resolves) or 'cpu' (force host CPU; the reliable override on
-    images whose site hooks pin an accelerator platform at boot). Must run
-    before first jax use."""
-    if backend == "cpu":
-        import jax
+    """``session_config.backend``, before first jax use: 'cpu' selects the
+    host CPU for this process; 'tpu' (default) selects nothing here and
+    :func:`_require_platform` holds the resolved platform to it. Also
+    turns on the persistent compile cache (utils/compat.py decides
+    where), so the process's first compile already goes through it."""
+    import jax
 
+    if backend == "cpu":
         jax.config.update("jax_platforms", "cpu")
     elif backend != "tpu":
         raise ValueError(f"session_config.backend {backend!r} not in tpu|cpu")
+    from surreal_tpu.utils.compat import enable_compile_cache
+
+    enable_compile_cache()
+
+
+def _cpu_selected() -> bool:
+    """An explicit CPU selection in this process: ``JAX_PLATFORMS=cpu``
+    (JAX reads it into ``jax_platforms`` at import) or a
+    ``jax.config.update("jax_platforms", "cpu")``, which is how the test
+    suite runs. Touches no backend."""
+    import jax
+
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
+
+
+def _require_platform(backend: str) -> None:
+    """A chip that fails to initialise must not become a CPU run that
+    exits 0: with ``backend='tpu'`` and no explicit CPU selection
+    (:func:`_cpu_selected`), a resolved platform other than 'tpu' is an
+    error. Initialises the backend — multi-host runs call it after
+    joining the distributed runtime."""
+    import jax
+
+    if backend == "tpu" and not _cpu_selected():
+        resolved = jax.default_backend()
+        if resolved != "tpu":
+            raise RuntimeError(
+                f"session_config.backend='tpu' but JAX resolved platform "
+                f"{resolved!r} ({jax.devices()[0].device_kind}): the TPU did "
+                "not initialise (is another process holding the chip?). To "
+                "run on the CPU on purpose, say so: JAX_PLATFORMS=cpu or "
+                "--set session_config.backend=cpu"
+            )
 
 
 def _validate_seed_topology(config) -> int:
@@ -174,7 +208,28 @@ def _run_local_group(args) -> int:
     command locally, wire the coordinator, forward signals, reap children.
     Rank 0 inherits this terminal; ranks > 0 log to <folder>/rank<i>.log.
     A non-zero child exit tears the whole group down (a half-dead process
-    group would deadlock the survivors' next collective)."""
+    group would deadlock the survivors' next collective).
+
+    The ranks are CPU processes, said out loud (``JAX_PLATFORMS=cpu`` or
+    ``session_config.backend=cpu`` — a simulated multi-host group, how the
+    tests use it). With the default ``backend='tpu'`` every rank would
+    initialise the TPU runtime of THIS host, and a chip belongs to one
+    process at a time: the group is refused before anything is spawned.
+    On a TPU host one process drives all local chips (``topology.mesh``);
+    real multi-host runs start one process per host themselves
+    (``topology.multihost`` / the JAX_COORDINATOR_ADDRESS contract)."""
+    if build_config(args).session_config.backend == "tpu" and not _cpu_selected():
+        print(
+            f"--local-procs {args.local_procs} would start "
+            f"{args.local_procs} processes that each initialise the TPU "
+            "runtime on this host, and a chip belongs to one process at a "
+            "time. Local ranks are CPU processes: set JAX_PLATFORMS=cpu (or "
+            "--set session_config.backend=cpu). On a TPU host run ONE "
+            "process: it drives every local chip through "
+            "session_config.topology.mesh.",
+            file=sys.stderr,
+        )
+        return 2
     # Picking the coordinator port by bind-then-close is a TOCTOU race:
     # another process can grab it before rank 0 binds. One retry with a
     # fresh port (when the group dies inside the startup window AND the
@@ -326,6 +381,7 @@ def run_train(args) -> int:
     from surreal_tpu.parallel.multihost import initialize_from_topology
 
     multihost = initialize_from_topology(config.session_config.topology)
+    _require_platform(config.session_config.backend)
     if multihost:
         algo = config.learner_config.algo.name
         env_name = config.env_config.name
@@ -477,7 +533,9 @@ def run_actor(args) -> int:
         print(f"no config.json under {args.folder!r} (launch training first)",
               file=sys.stderr)
         return 2
-    _apply_backend(config.session_config.get("backend", "tpu"))
+    backend = config.session_config.get("backend", "tpu")
+    _apply_backend(backend)
+    _require_platform(backend)
     address = _discover_param_server(args.folder, args.connect, args.wait)
 
     import jax
@@ -608,7 +666,9 @@ def run_eval(args) -> int:
         return 2
     # eval must run on the backend the session trained on; sessions saved
     # before the backend knob existed default to tpu (the old behavior)
-    _apply_backend(config.session_config.get("backend", "tpu"))
+    backend = config.session_config.get("backend", "tpu")
+    _apply_backend(backend)
+    _require_platform(backend)
     probe = make_env(config.env_config)
     learner = build_learner(config.learner_config, probe.specs)
     if hasattr(probe, "close"):
@@ -724,7 +784,7 @@ def _merge_tune_artifact(path: str, row: dict) -> None:
 
 def run_tune(args) -> int:
     """Standalone autotuner run (surreal_tpu/tune/): search this
-    workload's candidate space with device_get-fenced chained timing,
+    workload's candidate space with fenced chained timing,
     persist the winner in the per-workload tuning cache, and record a
     ``tune`` telemetry event (+ optional shared artifact). A second run on
     the same fingerprint is a PURE cache hit — zero measurements — unless
@@ -732,6 +792,7 @@ def run_tune(args) -> int:
     the cached config without paying any search cost."""
     config = build_config(args)
     _apply_backend(config.session_config.backend)
+    _require_platform(config.session_config.backend)
     from surreal_tpu.tune import resolve_tuning_cache_dir
     from surreal_tpu.tune.search import tune_workload
 
@@ -958,7 +1019,9 @@ def run_chaos(args) -> int:
     return 1 if artifact["failures"] else 0
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
+    """The CLI's parser (also how chip_smoke.py turns an argv into the
+    same config ``main`` would build)."""
     parser = argparse.ArgumentParser(prog="surreal_tpu")
     sub = parser.add_subparsers(dest="cmd", required=True)
 
@@ -1041,7 +1104,7 @@ def main(argv=None) -> int:
 
     tu = sub.add_parser("tune", help="autotune a workload's program "
                         "geometry: search scan-unroll/gae_impl/shuffle "
-                        "candidates with device_get-fenced timing and "
+                        "candidates with fenced chained timing and "
                         "persist the winner in the per-workload tuning "
                         "cache (trainers apply it via "
                         "learner_config.algo.autotune='cache'|'search')")
@@ -1151,8 +1214,11 @@ def main(argv=None) -> int:
                    help="re-run budget per failing schedule for the "
                    "greedy shrinker")
     c.set_defaults(fn=run_chaos)
+    return parser
 
-    args = parser.parse_args(argv)
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
     # the --local-procs supervisor re-issues this exact command per rank
     args.raw_argv = list(sys.argv[1:] if argv is None else argv)
     return args.fn(args)

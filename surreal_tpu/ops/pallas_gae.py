@@ -26,13 +26,10 @@ needs f32 precision (bf16 accumulation drifts); this matches what the XLA
 path computes in practice since rewards/masks arrive as f32. Callers that
 want bf16 downstream cast the outputs.
 
-Honest status vs XLA (re-measured round 3 on the real v5lite chip with a
-device_get-fenced chained loop — the round-2 numbers used
-block_until_ready, which does not wait on this backend; see bench.py's
-measurement-integrity note. [T=256, B=4096] f32: lax.scan 6.31 ms,
-associative_scan 6.46 ms, this kernel 6.18 ms per call, outputs verified
-equal on-chip): XLA already fuses the scan well, so the kernel is an
-at-parity-to-marginally-faster ALTERNATIVE, selectable per config rather
+Status vs XLA: not measured on the current code. On the TPU v5e the
+kernel compiles, and at [T=256, B=4096] its outputs are bit-equal to
+``ops.returns.gae_advantages`` (chip_smoke.py, PR 23). XLA fuses the scan
+itself, so the kernel is an ALTERNATIVE, selectable per config rather
 than the default. Runs in interpret mode off-TPU so tests cover it
 everywhere.
 """

@@ -13,16 +13,25 @@ it in-place). Selected per workload by ``algo.replay_gather='pallas'``
 (a searched autotuner dimension, tune/space.py — adopted only when
 MEASURED faster, like every kernel in the suite).
 
-Layout contract: kernels operate on 2-D [rows, features] views; the
+Layout contract: kernels operate on [rows, 1, features] views — the
 replay layer flattens each pytree leaf's trailing dims (and restores
-them after), padding features to the 128-lane width. Row contents are
-copied verbatim — any dtype whose row view reinterprets to float32 lanes
-works, and the entry points below simply require float/int leaves (the
-replay storage is float32/bfloat16 by construction).
+them after). The singleton middle dim is what the TPU compiler needs: a
+block's last two dims must be multiples of (8, 128) or equal the array's
+own, and a ``(1, 1, F)`` block over ``[capacity, 1, F]`` is the latter
+for every F (a ``(1, F)`` block over ``[capacity, F]`` is neither, and
+Mosaic refuses it). Row contents are copied verbatim, so any float/int
+leaf dtype works (the replay storage is float32/bfloat16 by
+construction).
+
+Known cost (PERF.md): XLA's own layout for a narrow ``[capacity, F]``
+ring is not the row-major ``[capacity, 1, F]`` the kernel is handed, so
+every call pays one relayout copy of the whole ring (two for the
+scatter, which copies back).
 
 Runs in interpret mode off-TPU (``interpret=True``), which is how the
 CPU suite bit-validates both kernels against ``ring_gather`` /
-``.at[idx].set`` (tests/test_precision.py).
+``.at[idx].set`` (tests/test_precision.py); tests/test_tpu_compile.py
+compiles both for a described v5e at the replay's real shapes.
 """
 
 from __future__ import annotations
@@ -34,52 +43,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_LANES = 128
-
 
 def _copy_row_kernel(idx_ref, src_ref, out_ref):
     del idx_ref  # consumed by the index maps, not the body
-    out_ref[:, :] = src_ref[:, :]
+    out_ref[...] = src_ref[...]
 
 
 def _scatter_row_kernel(idx_ref, dest_in_ref, upd_ref, dest_ref):
     del idx_ref, dest_in_ref  # index maps address the write; dest aliased
-    dest_ref[:, :] = upd_ref[:, :]
+    dest_ref[...] = upd_ref[...]
 
 
-def _pad_features(x2d: jax.Array) -> tuple[jax.Array, int]:
-    F = x2d.shape[1]
-    pad = (-F) % _LANES
-    if pad:
-        x2d = jnp.pad(x2d, ((0, 0), (0, pad)))
-    return x2d, F
+def _rows(x: jax.Array) -> jax.Array:
+    """[rows, ...] -> [rows, 1, features] (see the layout contract)."""
+    return x.reshape(x.shape[0], 1, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows_pallas(
     storage: jax.Array, idx: jax.Array, interpret: bool = False
 ) -> jax.Array:
-    """``storage[idx]`` for a 2-D+ ``storage`` ([capacity, ...]) and int
+    """``storage[idx]`` for a ``storage`` of [capacity, ...] and int
     ``idx`` ([n]): one row-block DMA per sampled index, addressed by the
     scalar-prefetched index vector. Bit-equal to ``storage[idx]``."""
-    shape = storage.shape
-    s2d = storage.reshape(shape[0], -1)
-    s2d, F = _pad_features(s2d)
-    n = idx.shape[0]
-    Fp = s2d.shape[1]
+    rows = _rows(storage)
+    n, F = idx.shape[0], rows.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, Fp), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, Fp), lambda i, idx_ref: (i, 0)),
+        in_specs=[
+            pl.BlockSpec((1, 1, F), lambda i, idx_ref: (idx_ref[i], 0, 0))
+        ],
+        out_specs=pl.BlockSpec((1, 1, F), lambda i, idx_ref: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _copy_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, Fp), storage.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, 1, F), storage.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), s2d)
-    return out[:, :F].reshape(n, *shape[1:])
+    )(idx.astype(jnp.int32), rows)
+    return out.reshape(n, *storage.shape[1:])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -87,34 +90,32 @@ def scatter_rows_pallas(
     dest: jax.Array, idx: jax.Array, updates: jax.Array,
     interpret: bool = False,
 ) -> jax.Array:
-    """``dest.at[idx].set(updates)`` for a 2-D+ ``dest`` ([capacity,
-    ...]): one row-block DMA per index, written in grid order (duplicate
+    """``dest.at[idx].set(updates)`` for a ``dest`` of [capacity, ...]:
+    one row-block DMA per index, written in grid order (duplicate
     indices resolve last-write-wins — the same contract ``.at[].set``
     documents as unspecified; the priority-refresh caller never issues
     duplicates in one batch). ``input_output_aliases`` makes the update
     in-place — the donation discipline of the fused iterations carries
     through the kernel."""
-    shape = dest.shape
-    d2d = dest.reshape(shape[0], -1)
-    d2d, F = _pad_features(d2d)
-    u2d, _ = _pad_features(updates.reshape(updates.shape[0], -1))
-    n = idx.shape[0]
-    Fp = d2d.shape[1]
+    rows = _rows(dest)
+    n, F = idx.shape[0], rows.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),  # aliased dest (unread)
-            pl.BlockSpec((1, Fp), lambda i, idx_ref: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # aliased dest (unread)
+            pl.BlockSpec((1, 1, F), lambda i, idx_ref: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Fp), lambda i, idx_ref: (idx_ref[i], 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, F), lambda i, idx_ref: (idx_ref[i], 0, 0)
+        ),
     )
     out = pl.pallas_call(
         _scatter_row_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(d2d.shape, dest.dtype),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, dest.dtype),
         # operand 1 (dest, after the scalar-prefetch idx) aliases output 0
         input_output_aliases={1: 0},
         interpret=interpret,
-    )(idx.astype(jnp.int32), d2d, u2d)
-    return out[:, :F].reshape(shape)
+    )(idx.astype(jnp.int32), rows, _rows(updates).astype(dest.dtype))
+    return out.reshape(dest.shape)

@@ -75,11 +75,13 @@ def ring_gather(state: RingState, idx: jax.Array, impl: str = "xla") -> Any:
     either way — the kernel copies rows verbatim.
     """
     if impl == "pallas":
+        from surreal_tpu.ops import pallas_interpret
         from surreal_tpu.ops.pallas_replay import gather_rows_pallas
 
-        interp = jax.default_backend() != "tpu"
         return jax.tree.map(
-            lambda buf: gather_rows_pallas(buf, idx, interpret=interp),
+            lambda buf: gather_rows_pallas(
+                buf, idx, interpret=pallas_interpret()
+            ),
             state.storage,
         )
     if impl != "xla":

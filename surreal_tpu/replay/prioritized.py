@@ -120,11 +120,11 @@ class PrioritizedReplay:
             # stratified draw can repeat a high-mass slot) resolve
             # last-write-wins in grid order — the same "some write wins"
             # contract ``.at[].set`` documents as unspecified.
+            from surreal_tpu.ops import pallas_interpret
             from surreal_tpu.ops.pallas_replay import scatter_rows_pallas
 
             priorities = scatter_rows_pallas(
-                state.priorities, idx, prio,
-                interpret=jax.default_backend() != "tpu",
+                state.priorities, idx, prio, interpret=pallas_interpret(),
             )
         else:
             priorities = state.priorities.at[idx].set(prio)

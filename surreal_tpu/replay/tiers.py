@@ -45,7 +45,9 @@ def default_gather_impl() -> str:
     sample path. ``ring_gather``'s bit-equality contract makes the
     routing invisible to the training record; ``tiers.hot.gather_impl``
     overrides it either way."""
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    from surreal_tpu.ops import pallas_interpret
+
+    return "xla" if pallas_interpret() else "pallas"
 
 
 @partial(jax.jit, static_argnames=("capacity",), donate_argnums=(0,))

@@ -15,13 +15,15 @@ Design constraints (the transfer-guard tests enforce the first):
   both host-side — recorded ONCE per program at driver startup; the live
   gauges are pure host float arithmetic over the tracer's already-recorded
   phase windows (``Tracer.last_window``). Nothing here ever touches a
-  device value.
-- ``memory_analysis()`` needs a real backend compile, which is minutes of
-  XLA on a chip and (on jax 0.4.x) is NOT shared with the jit call cache —
-  so it runs only when it is known-cheap: ``session.perf.memory_analysis
-  = 'auto'`` compiles only when the persistent compile cache is active
-  (either order, one of the two compiles is then a disk deserialize);
-  ``True``/``False`` force it.
+  device value. The TPU's client cannot cost unoptimized HLO (first chip
+  run, PR 23: every program came back "no cost model", so no ``perf/mfu``
+  had ever been emitted on a chip); there the numbers come from the
+  COMPILED program. On jax 0.9.0 that compile is not a second one: the
+  first dispatch of the same jitted function reuses it in-process
+  (measured on the CPU backend: one compile-cache miss for the pair).
+- ``memory_analysis()`` needs the compiled program too:
+  ``session.perf.memory_analysis = 'auto'`` takes it only when the
+  persistent compile cache is active; ``True``/``False`` force it.
 - Honesty over coverage: a program whose tracer phase measures MORE than
   the program itself (the host ``rollout`` phase contains env stepping)
   yields a LOWER-bound MFU contribution; programs with no phase at all
@@ -409,25 +411,34 @@ GAUGE_REGISTRY = {
     "chaos/run_ms": _g("ms", 'campaign wall-clock, all runs + shrinking.'),
 }
 
-# Public peak specs per accelerator generation: (peak FLOP/s bf16,
-# peak HBM bytes/s). Matched by substring against the jax device_kind
-# string (lowercased). Sources: public TPU spec sheets; the v5e row is
-# the same 197 TFLOP/s bench.py's MFU denominator has always used.
-PEAK_SPECS: tuple[tuple[str, float, float], ...] = (
-    ("v5 lite", 197e12, 819e9),   # TPU v5e (jax reports 'TPU v5 lite')
-    ("v5litepod", 197e12, 819e9),
-    ("v5e", 197e12, 819e9),
-    ("v5p", 459e12, 2765e9),
-    ("v6 lite", 918e12, 1640e9),  # Trillium
-    ("v6e", 918e12, 1640e9),
-    ("v4", 275e12, 1228e9),
-    ("v3", 123e12, 900e9),
-    ("v2", 45e12, 700e9),
-    # host CPU: a nominal single-core order-of-magnitude figure so test
-    # images still exercise the full gauge path; real CPU runs should
-    # override via session.perf.peak_flops / peak_membw
-    ("cpu", 1e11, 5e10),
-)
+# Published per-chip peaks, keyed by the ``device_kind`` string JAX
+# reports (the spellings of jax's own pallas tpu_info table): (peak
+# FLOP/s in bf16, peak HBM bytes/s). Source: Google Cloud TPU
+# documentation, the system-architecture page of each generation ("TPU
+# v5e": 197 TFLOP/s bf16, 819 GB/s HBM). A device that is not here has no
+# peak: a session then reports ``perf/flops_per_s`` and no utilisation
+# gauge (or the ``session.perf.peak_flops``/``peak_membw`` it was given),
+# and a bench path fails (:func:`published_peak`).
+PEAK_SPECS: dict[str, tuple[float, float]] = {
+    "TPU v2": (45e12, 700e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),   # v5e
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),  # v6e (Trillium)
+}
+
+
+def published_peak(kind: str) -> tuple[float, float]:
+    """(peak FLOP/s, peak bytes/s) of ``kind`` for a bench path, where a
+    device without a published peak is an error, never a default."""
+    if kind not in PEAK_SPECS:
+        raise RuntimeError(
+            f"no published peak for device kind {kind!r} "
+            f"(session/costs.py::PEAK_SPECS has {sorted(PEAK_SPECS)}): "
+            "a benchmark runs on a chip whose peak is known"
+        )
+    return PEAK_SPECS[kind]
 
 
 class PeakSpec:
@@ -452,9 +463,10 @@ class PeakSpec:
 
 def resolve_peak_spec(session_cfg) -> PeakSpec:
     """Peak FLOP/s + bytes/s for the active backend: the
-    ``session.perf.peak_flops``/``peak_membw`` overrides win; otherwise
-    the :data:`PEAK_SPECS` device-kind table; otherwise an 'unknown'
-    spec (costs still recorded, utilization gauges limited to
+    ``session.perf.peak_flops``/``peak_membw`` overrides win (a partial
+    override fills the other half from the table); otherwise the
+    :data:`PEAK_SPECS` row of this ``device_kind``; otherwise an
+    'unknown' spec (costs still recorded, utilization gauges limited to
     ``perf/flops_per_s``)."""
     from surreal_tpu.utils.compat import device_kind
 
@@ -462,59 +474,27 @@ def resolve_peak_spec(session_cfg) -> PeakSpec:
     perf = session_cfg.get("perf", None) if session_cfg is not None else None
     over_f = perf.get("peak_flops", None) if perf is not None else None
     over_b = perf.get("peak_membw", None) if perf is not None else None
+    t_f, t_b = PEAK_SPECS.get(kind, (None, None))
     if over_f or over_b:
-        # a partial override fills the other half from the table
-        t_f, t_b = _table_lookup(kind)
         return PeakSpec(over_f or t_f, over_b or t_b, kind, "override")
-    t_f, t_b = _table_lookup(kind)
-    if t_f is not None:
-        return PeakSpec(t_f, t_b, kind, "table")
-    return PeakSpec(None, None, kind, "unknown")
+    return PeakSpec(t_f, t_b, kind, "table" if t_f else "unknown")
 
 
-def _table_lookup(kind: str) -> tuple[float | None, float | None]:
-    lowered = (kind or "").lower()
-    for needle, flops, membw in PEAK_SPECS:
-        if needle in lowered:
-            return flops, membw
-    return None, None
-
-
-def program_costs(jitted, *args, **kwargs) -> dict | None:
-    """XLA cost model of one jitted program at these arg shapes:
-    ``{"flops", "bytes_accessed", "arithmetic_intensity"}``, or None when
-    the backend reports nothing. Host-side only — ``lower()`` traces and
-    the cost pass runs on the unoptimized HLO; no compile, no device
-    work, no transfers (safe before the first dispatch, and safe on
-    donated-arg programs: lowering consumes no buffers)."""
-    try:
-        ca = jitted.lower(*args, **kwargs).cost_analysis()
-    except Exception:
-        return None
+def _cost_dict(ca) -> dict | None:
     if isinstance(ca, (list, tuple)):  # some backends wrap per-device
         ca = ca[0] if ca else None
     if not isinstance(ca, dict) or "flops" not in ca:
         return None
     flops = float(ca["flops"])
     byts = float(ca.get("bytes accessed", 0.0))
-    out = {
+    return {
         "flops": flops,
         "bytes_accessed": byts,
         "arithmetic_intensity": (flops / byts) if byts > 0 else None,
     }
-    return out
 
 
-def program_memory(jitted, *args, **kwargs) -> dict | None:
-    """``memory_analysis()`` of the COMPILED program (argument/output/temp
-    bytes). Pays a real XLA compile — call only when that is known-cheap
-    (see the module doc); returns None on any failure."""
-    try:
-        ma = jitted.lower(*args, **kwargs).compile().memory_analysis()
-    except Exception:
-        return None
-    if ma is None:
-        return None
+def _memory_dict(ma) -> dict | None:
     out = {}
     for k in (
         "argument_size_in_bytes", "output_size_in_bytes",
@@ -524,6 +504,37 @@ def program_memory(jitted, *args, **kwargs) -> dict | None:
         if v is not None:
             out[k.replace("_in_bytes", "")] = int(v)
     return out or None
+
+
+def analyze_program(
+    jitted, *args, memory: bool = False, **kwargs
+) -> tuple[dict | None, dict | None]:
+    """(costs, memory) of one jitted program at these arg shapes, from ONE
+    lowering. ``costs`` is XLA's cost model —
+    ``{"flops", "bytes_accessed", "arithmetic_intensity"}`` — of the
+    unoptimized HLO where the backend's client can cost it (host-side
+    only: no compile, no device work, no transfers; safe before the first
+    dispatch and on donated-arg programs, since lowering consumes no
+    buffers), and of the compiled program where it cannot (the TPU).
+    ``memory`` (argument/output/temp bytes) always needs the compile and
+    is taken only when asked for. Either is None when unavailable; a
+    program that cannot be lowered or compiled here yields (None, None)
+    and fails where it is dispatched, with its own error."""
+    try:
+        lowered = jitted.lower(*args, **kwargs)
+        ca = lowered.cost_analysis()
+        compiled = lowered.compile() if (ca is None or memory) else None
+        if ca is None:
+            ca = compiled.cost_analysis()
+        ma = compiled.memory_analysis() if memory else None
+    except Exception:
+        return None, None
+    return _cost_dict(ca), _memory_dict(ma) if ma is not None else None
+
+
+def program_costs(jitted, *args, **kwargs) -> dict | None:
+    """The ``costs`` half of :func:`analyze_program` (bench.py's view)."""
+    return analyze_program(jitted, *args, **kwargs)[0]
 
 
 class CostAccountant:
@@ -603,11 +614,10 @@ class CostAccountant:
         if self.peak is None:
             # resolved on first use, not at construction: this touches
             # jax.devices(), and hooks must stay constructible pre-backend
-            try:
-                self.peak = resolve_peak_spec(self._cfg)
-            except Exception:
-                self.peak = PeakSpec(None, None, "unknown", "unknown")
-        costs = program_costs(jitted, *args, **kwargs)
+            self.peak = resolve_peak_spec(self._cfg)
+        costs, mem = analyze_program(
+            jitted, *args, memory=self._memory_analysis_ok(), **kwargs
+        )
         if costs is None:
             self._failed.add(name)
             if self._log is not None:
@@ -624,10 +634,8 @@ class CostAccountant:
         }
         if self.policy is not None:
             rec["precision"] = getattr(self.policy, "name", str(self.policy))
-        if self._memory_analysis_ok():
-            mem = program_memory(jitted, *args, **kwargs)
-            if mem is not None:
-                rec["memory"] = mem
+        if mem is not None:
+            rec["memory"] = mem
         self._programs[name] = rec
         if self._log is not None:
             self._log.info(

@@ -16,10 +16,9 @@ import os
 
 
 def resolve_tuning_cache_dir(session_cfg) -> str:
-    """Resolve ``session.tuning_cache_dir`` exactly like the compile
-    cache's knob (launch/hooks.py::maybe_enable_compile_cache): relative
-    paths live under the session folder (session-local cache), absolute
-    paths share one cache across sessions. Unset defaults to
+    """Resolve ``session.tuning_cache_dir``: relative paths live under
+    the session folder (session-local cache), absolute paths share one
+    cache across sessions. Unset defaults to
     ``<folder>/tuning_cache`` so ``algo.autotune`` works with zero extra
     config. ``.get`` keeps configs saved before the knob existed loadable.
     """

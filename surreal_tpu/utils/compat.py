@@ -1,184 +1,96 @@
-"""Version-compat shims for the jax pinned on the running image.
-
-``shard_map`` moved twice across the jax versions this repo meets: on
-0.4.x it lives in ``jax.experimental.shard_map`` and the replication
-check is spelled ``check_rep``; newer jax exports it at top level with
-the check renamed ``check_vma``. Every product call site imports the
-wrapper below (house signature = the new one) so the codebase reads
-modern while still running on the older pin.
+"""Seams over the one installed JAX (0.9.0): the names product code
+imports for ``shard_map``/``axis_size``/``device_kind``, and the one
+function that decides where the persistent XLA compile cache lives.
+Nothing here catches an exception: with one installation these calls
+either work or are a bug.
 """
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export, check_vma kwarg
-    from jax import shard_map as _shard_map
+import os
 
-    _LEGACY_SHARD_MAP = False
-except ImportError:  # jax 0.4.x: experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _LEGACY_SHARD_MAP = True
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` with the modern signature on any supported jax."""
-    if _LEGACY_SHARD_MAP:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=check_vma,
-        )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_vma=check_vma,
-    )
+from jax import shard_map  # noqa: F401 — call sites import it from here
 
 
 def axis_size(axis_name) -> int:
-    """Static size of a named mapped axis (``jax.lax.axis_size`` on new
-    jax; 0.4.x spells it ``core.axis_frame``, which returns the bare int
-    inside shard_map)."""
+    """Static size of a named mapped axis."""
     import jax
 
-    try:
-        return int(jax.lax.axis_size(axis_name))
-    except AttributeError:  # jax 0.4.x
-        from jax._src import core
-
-        frame = core.axis_frame(axis_name)
-        return int(frame if isinstance(frame, int) else frame.size)
+    return int(jax.lax.axis_size(axis_name))
 
 
 def device_kind() -> str:
-    """Device-kind string of the default backend (e.g. 'TPU v5 lite',
-    'cpu'), or 'unknown' when the backend cannot initialize — cost
-    accounting (session/costs.py) must degrade to no-peak, never raise.
-    The spelling of the kind string varies across jaxlib pins, which is
-    why the peak table matches by substring."""
-    try:
-        import jax
+    """``device_kind`` of the default backend's first device, as JAX
+    reports it (the key of session/costs.py's peak table)."""
+    import jax
 
-        return str(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
+    return str(jax.devices()[0].device_kind)
 
 
 # -- persistent XLA compile cache ---------------------------------------------
-# The flag spelling moved across jax versions (jax_compilation_cache_dir has
-# been stable, but the persistent-cache eligibility knobs appeared later and
-# the hit/miss counters live behind the private monitoring module), so the
-# enabling + counting both route through here: product code sees one call
-# that works on any supported pin and degrades to a no-op instead of raising.
+
+# Where the cache lives unless JAX_COMPILATION_CACHE_DIR places it from
+# outside: one fixed, git-ignored directory next to the package. A
+# directory that moves never hits, so it is never built from a session
+# folder, a temporary name, a pid or the time.
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 _CACHE_COUNTS = {"hits": 0, "misses": 0}
 _CACHE_LISTENER_INSTALLED = False
 
 
-def _install_cache_listener() -> None:
-    """Count compile-cache hits/misses via jax's monitoring events (the
-    pinned jax records '/jax/compilation_cache/cache_{hits,misses}').
-    Private API — failure to install just leaves the counts at zero."""
-    global _CACHE_LISTENER_INSTALLED
-    if _CACHE_LISTENER_INSTALLED:
-        return
-    try:
-        from jax._src import monitoring
-
-        def _listener(event, **kwargs):
-            if event.endswith("/cache_hits"):
-                _CACHE_COUNTS["hits"] += 1
-            elif event.endswith("/cache_misses"):
-                _CACHE_COUNTS["misses"] += 1
-
-        monitoring.register_event_listener(_listener)
-        _CACHE_LISTENER_INSTALLED = True
-    except Exception:
-        pass
+def _count_cache_event(event: str, **_kwargs) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _CACHE_COUNTS["hits"] += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _CACHE_COUNTS["misses"] += 1
 
 
 def compile_cache_active() -> bool:
-    """True when a persistent compile-cache dir is currently configured —
+    """True when compiles of this process go through a persistent cache —
     the signal session/costs.py uses to decide an extra AOT compile
-    (memory_analysis) is a disk deserialize rather than minutes of XLA."""
+    (memory_analysis) is a disk deserialize rather than a second compile."""
     import jax
 
-    try:
-        return bool(jax.config.jax_compilation_cache_dir)
-    except AttributeError:
-        return False
+    return bool(
+        jax.config.jax_enable_compilation_cache
+        and jax.config.jax_compilation_cache_dir
+    )
 
 
 def compile_cache_counts() -> dict:
-    """Process-global compile-cache hit/miss counts since the listener was
-    installed (zeros when enable_compile_cache never ran / succeeded)."""
+    """Process-global compile-cache hit/miss counts since
+    :func:`enable_compile_cache` first ran (zeros before)."""
     return dict(_CACHE_COUNTS)
 
 
-def enable_compile_cache(cache_dir: str) -> bool:
-    """Point jax's persistent XLA compile cache at ``cache_dir`` and relax
-    the eligibility thresholds so every program caches (an RL session
-    compiles a handful of LARGE programs — the fused train iteration is
-    minutes of XLA time on a real chip — so there is nothing worth
-    filtering out). Creates the directory; returns False (leaving the
-    cache off) on any failure, because a missing cache must degrade to a
-    cold compile, never kill training."""
-    import os
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compile cache and return its directory.
 
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it
+    stands and no directory is set here; where it is not, the cache lives
+    at the fixed ``.jax_cache`` of this checkout. Every program is
+    eligible (an RL session compiles a handful of large programs, and the
+    small ones are what a warm start otherwise waits for one by one).
+    Returns None, touching nothing, where JAX's own switch
+    (``jax_enable_compilation_cache`` / ``JAX_ENABLE_COMPILATION_CACHE``)
+    has the cache off — tests/conftest.py does that for the CPU suite.
+    Every entry point (CLI, SessionHooks, bench.py, chip_smoke.py) calls
+    this; may be called any number of times, before or after the
+    process's first compile."""
+    global _CACHE_LISTENER_INSTALLED
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (OSError, AttributeError, ValueError):
-        return False
-    # eligibility knobs are best-effort per pin: the dir alone enables the
-    # cache with that pin's defaults when a knob spelling is missing
-    for flag, value in (
-        ("jax_enable_compilation_cache", True),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-    ):
-        try:
-            jax.config.update(flag, value)
-        except (AttributeError, ValueError):
-            pass
-    # the pinned jax latches an is-the-cache-used decision at the FIRST
-    # compile of the process (compilation_cache._cache_checked) — and the
-    # drivers compile key-derivation programs before SessionHooks enables
-    # the cache, which would latch it off for the whole run. reset_cache()
-    # clears the latch so the dir set above actually takes effect.
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
-    _install_cache_listener()
-    return True
-
-
-def disable_compile_cache(restore_dir: str | None = None) -> None:
-    """Re-point (or disable, ``restore_dir=None``) the persistent compile
-    cache AND drop jax's latched cache object.
-
-    Restoring ``jax_compilation_cache_dir`` alone is NOT a clean undo on
-    this image's pin: the process keeps the Cache object latched at the
-    old directory, and that stale native state + a later orbax
-    restore-then-execute reproducibly SIGSEGVs the CPU backend (found by
-    ISSUE 5's kill-and-resume suite: the compile-cache plumb-through test
-    left the latch behind and every later same-process resume crashed).
-    Anything that re-points or turns off the cache mid-process — tests,
-    embedders, notebooks — must go through here; long-lived training
-    processes never need to (the cache is meant to stay live until exit).
-    """
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", restore_dir)
-    except (AttributeError, ValueError):
-        pass
-    try:
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    if not jax.config.jax_enable_compilation_cache:
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not _CACHE_LISTENER_INSTALLED:
+        jax.monitoring.register_event_listener(_count_cache_event)
+        _CACHE_LISTENER_INSTALLED = True
+    return jax.config.jax_compilation_cache_dir

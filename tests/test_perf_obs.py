@@ -73,11 +73,17 @@ def test_resolve_peak_spec_override_and_table():
     spec = resolve_peak_spec(cfg)
     assert spec.source == "override"
     assert spec.flops == 1e12 and spec.membw == 2e11
-    # no override: the device-kind table resolves (cpu on this image)
+    # no override: the table is keyed by device_kind and holds no CPU,
+    # so this image resolves to 'unknown' (no utilization gauge) ...
     spec = resolve_peak_spec(Config(perf=Config()))
-    assert spec.source in ("table", "unknown")
-    if spec.source == "table":
-        assert spec.flops and spec.flops > 0
+    assert spec.source == "unknown" and spec.flops is None
+    assert spec.device_kind == jax.devices()[0].device_kind
+    # ... and a bench path refuses it outright
+    from surreal_tpu.session.costs import PEAK_SPECS, published_peak
+
+    with pytest.raises(RuntimeError, match="no published peak"):
+        published_peak(spec.device_kind)
+    assert published_peak("TPU v5 lite") == PEAK_SPECS["TPU v5 lite"]
 
 
 # -- MFU gauge arithmetic ------------------------------------------------------
@@ -350,7 +356,11 @@ def test_diag_renders_performance_section_and_trigger_capture(tmp_path):
     folder = tmp_path / "exp"
     os.makedirs(folder)
     write_trigger(str(folder), num_iters=2)
-    state, metrics = _train_tiny(folder, total_iters=8)
+    # the CPU has no published peak: the test supplies one
+    state, metrics = _train_tiny(
+        folder, total_iters=8,
+        extra_session={"perf": Config(peak_flops=1e11, peak_membw=5e10)},
+    )
     assert "perf/mfu" in metrics and "perf/flops_per_s" in metrics
     assert 0.0 < metrics["perf/mfu"] < 1.0
     s = diag_summary(str(folder))

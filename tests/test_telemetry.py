@@ -151,6 +151,9 @@ def _session_cfg(folder, every_n_iters=2, total_iters=6):
                            console=False),
             checkpoint=Config(every_n_iters=0),
             eval=Config(every_n_iters=0),
+            # the CPU has no published peak (session/costs.py): the
+            # perf/mfu + perf/membw_util tests supply one
+            perf=Config(peak_flops=1e11, peak_membw=5e10),
         ),
     ).extend(base_config())
 

@@ -1,0 +1,160 @@
+"""Compile the main path's kernels and the fused PPO iteration for a
+TPU v5e that is described, not attached (the chip's compiler is installed
+with libtpu and needs no chip). Interpret mode cannot show what Mosaic
+refuses — a block not aligned to the tiling, too much VMEM — and these
+compiles do, at the real shapes of ``chip_smoke.py``'s phases, at no chip
+time. Nothing runs: a compile that passes says nothing about results.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+T, B = 256, 4096            # the fused PPO geometry (bench.py, chip_smoke.py)
+VT, VB = 32, 1024           # IMPALA's V-trace geometry
+BATCH = 256                 # DDPG replay batch
+# leaves of the DDPG jax:lift replay example (OffPolicyTrainer._replay_example)
+LIFT_LEAVES = {"obs": (17,), "action": (4,), "reward": ()}
+
+
+@pytest.fixture(scope="module")
+def chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one, so the next run would warn."""
+    from jax.experimental.compilation_cache.compilation_cache import reset_cache
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    reset_cache()
+
+
+def _gae(sds):
+    from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
+
+    x = sds((T, B), jnp.float32)
+    return gae_advantages_pallas_masked, (x, x, x, x, x)
+
+
+def _returns(sds):
+    from surreal_tpu.ops.pallas_returns import discounted_returns_pallas
+
+    x = sds((T, B), jnp.float32)
+    return discounted_returns_pallas, (x, x, sds((B,), jnp.float32))
+
+
+def _vtrace(sds):
+    from functools import partial
+
+    from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
+
+    x = sds((VT, VB), jnp.float32)
+    m = sds((VT, VB), jnp.bool_)
+    return partial(vtrace_nextobs_pallas, gamma=0.99), (x, x, x, x, x, m, m)
+
+
+def _gather(capacity, leaf):
+    def build(sds):
+        from surreal_tpu.ops.pallas_replay import gather_rows_pallas
+
+        return gather_rows_pallas, (
+            sds((capacity, *LIFT_LEAVES[leaf]), jnp.float32),
+            sds((BATCH,), jnp.int32),
+        )
+
+    return build
+
+
+def _scatter(capacity):
+    def build(sds):
+        from surreal_tpu.ops.pallas_replay import scatter_rows_pallas
+
+        return scatter_rows_pallas, (
+            sds((capacity,), jnp.float32),
+            sds((BATCH,), jnp.int32),
+            sds((BATCH,), jnp.float32),
+        )
+
+    return build
+
+
+def _fused_ppo(sds):
+    """Trainer's fused rollout+learn step at the headline geometry. The
+    test jits the step itself: ``Trainer._train_iter`` is built over this
+    process's (CPU) devices."""
+    from surreal_tpu.launch.rollout import init_device_carry
+    from surreal_tpu.launch.trainer import Trainer
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import base_config
+
+    cfg = Config(
+        learner_config=Config(algo=Config(name="ppo", horizon=T)),
+        env_config=Config(name="jax:lift", num_envs=B),
+        session_config=Config(folder="unused"),
+    ).extend(base_config())
+    trainer = Trainer(cfg)
+
+    def like(tree):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = jax.eval_shape(trainer.learner.init, key)
+    carry = jax.eval_shape(
+        lambda k: init_device_carry(trainer.env, k, B), key
+    )
+    step = jax.jit(trainer._device_train_iter, donate_argnums=(0, 1))
+    return step, (like(state), like(carry), like(key))
+
+
+CASES = [
+    pytest.param(_gae, True, id="gae-256x4096"),
+    pytest.param(_returns, True, id="returns-256x4096"),
+    pytest.param(_vtrace, True, id="vtrace-32x1024"),
+    *[
+        pytest.param(_gather(cap, leaf), True, id=f"gather-{leaf}-{cap}")
+        for cap in (200_000, 1_000_000)
+        for leaf in LIFT_LEAVES
+    ],
+    *[
+        pytest.param(_scatter(cap), True, id=f"scatter-{cap}")
+        for cap in (200_000, 1_000_000)
+    ],
+    pytest.param(_fused_ppo, False, id="fused-ppo-4096x256"),
+]
+
+
+@pytest.mark.parametrize("build,is_kernel", CASES)
+def test_compiles_for_v5e(chip, build, is_kernel):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    fn, args = build(sds)
+    lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
+    if is_kernel:
+        assert "tpu_custom_call" in lowered.as_text(), (
+            "lowered without a Mosaic kernel (interpret mode leaked in?)"
+        )
+    compiled = lowered.compile()  # raises what the chip's compiler raises
+    mem = compiled.memory_analysis()
+    hbm = 16 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < hbm

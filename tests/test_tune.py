@@ -150,7 +150,7 @@ def test_autotune_rejects_unknown_mode(tmp_path):
 def test_search_persists_winner_and_second_run_is_pure_hit(tmp_path):
     cfg = bundle(tmp_path, horizon=8, epochs=1)
     first = tune_workload(
-        cfg, dims=[("rollout_unroll", [1, 2])], warmup=1, throwaway=0,
+        cfg, dims=[("rollout_unroll", [1, 2])], warmup=1,
         iters=1,
     )
     assert first["cache_hit"] is False
@@ -161,7 +161,7 @@ def test_search_persists_winner_and_second_run_is_pure_hit(tmp_path):
 
     # the pure-hit contract: zero measurements the second time
     second = tune_workload(
-        cfg, dims=[("rollout_unroll", [1, 2])], warmup=1, throwaway=0,
+        cfg, dims=[("rollout_unroll", [1, 2])], warmup=1,
         iters=1,
     )
     assert second["cache_hit"] is True
@@ -204,7 +204,7 @@ def test_search_host_env_uses_learn_surface(tmp_path):
     dm_control:...` populates exactly that fingerprint)."""
     cfg = bundle(tmp_path, env="gym:CartPole-v1", horizon=8, epochs=1)
     out = tune_workload(
-        cfg, dims=[("sgd_unroll", [1, 2])], warmup=1, throwaway=0, iters=1
+        cfg, dims=[("sgd_unroll", [1, 2])], warmup=1, iters=1
     )
     assert out["cache_hit"] is False
     assert out["measure"]["surface"] == "learn"
