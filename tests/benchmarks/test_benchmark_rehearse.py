@@ -1,0 +1,107 @@
+"""Every cell walks the whole measured path at toy sizes on the CPU, in a
+child process (the command is a process of its own; the four-chip cell
+needs four virtual devices where this suite forces eight), and prints the
+contract's last line. Without ``--rehearse`` and without a TPU the command
+exits non-zero and prints no result."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from benchmarks.harness import manifest
+
+M = manifest.load_manifest()
+CELLS = [w["name"] for w in M["workloads"]]
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+
+
+@pytest.fixture(scope="module")
+def cache_dir(tmp_path_factory):
+    """A compile cache of this module's own: a cell's traced run finds the
+    programs its untraced run compiled, which halves the module's time, and
+    nothing outlives the module (the suite itself runs with the cache off)."""
+    return str(tmp_path_factory.mktemp("rehearse_jax_cache"))
+
+
+# The child pins itself to one core and then becomes the command: the suite
+# runs one worker per core and holds timing-sensitive tests, which a child
+# that spread XLA's thread pool over every core would slow.
+ON_ONE_CORE = (
+    "import os, sys; os.sched_setaffinity(0, {max(os.sched_getaffinity(0))}); "
+    "os.execv(sys.executable, [sys.executable] + sys.argv[1:])"
+)
+
+
+def run_cell(cell: str, trace: int, *extra: str, cache: str | None = None):
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    if cache is not None:
+        env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
+        env["JAX_COMPILATION_CACHE_DIR"] = cache
+    return subprocess.run(
+        [sys.executable, "-c", ON_ONE_CORE, *M["command"][1:],
+         "--workload", cell, "--seed", "7",
+         "--seconds", "1", "--trace", str(trace), *extra],
+        cwd=manifest.ROOT, env=env, capture_output=True, text=True, timeout=300,
+    )
+
+
+def last_line(proc: subprocess.CompletedProcess) -> dict:
+    lines = proc.stdout.strip().splitlines()
+    assert lines, proc.stderr[-2000:]
+    return json.loads(lines[-1])
+
+
+@pytest.mark.parametrize(
+    "cell,trace", [(c, t) for c in CELLS for t in (0, 1)],
+    ids=[f"{c}-trace{t}" for c in CELLS for t in (0, 1)],
+)
+def test_cell_rehearses(cell, trace, cache_dir):
+    proc = run_cell(cell, trace, "--rehearse", cache=cache_dir)
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    line = last_line(proc)
+    assert LINE_KEYS <= set(line)
+    assert line["correct"] is False
+    chips = next(w["chips"] for w in M["workloads"] if w["name"] == cell)
+    assert line["device"]["platform"] == "cpu"
+    assert line["device"]["count"] == chips
+    assert "memory_peak_bytes" in line["device"]
+    assert line["attempted"] >= 1 and line["failed"] == 0
+    # every check but the one that needs the chip holds at toy sizes too
+    failed = [k for k, ok in line["checks"].items() if not ok]
+    assert failed == ["device_is_tpu_in_peak_table"], line["checks"]
+    assert line["compiles_in_window"] == 0
+    kind = "per_layer" if trace else "end_to_end"
+    declared = {m["name"]: m for m in manifest.metrics_of(kind, cell)}
+    # some readers need the chip (its published peak, its allocator) and
+    # may find nothing to read here
+    required = {
+        name for name in declared
+        if not getattr(manifest.load_layer_metric(name), "CHIP_ONLY", False)
+    } if trace else set(declared)
+    assert required <= set(line["metrics"]) <= set(declared)
+    for name, got in line["metrics"].items():
+        assert got["unit"] == declared[name]["unit"]
+        assert isinstance(got["value"], float) and got["value"] == got["value"]
+    if trace:
+        assert line["device"]["busy_s"] > 0
+        assert line["device"]["window_s"] >= line["device"]["busy_s"]
+        for key in ("device_ops", "idle_gaps"):
+            assert len(line["breakdown"][key]) <= 10
+    else:
+        assert line["metrics"]["setup_s"]["value"] >= line["launch_marks_s"]["first_stamp"]
+
+
+def test_no_tpu_means_no_result():
+    proc = run_cell(CELLS[0], 0)
+    assert proc.returncode not in (0, 3)
+    assert proc.stdout.strip() == ""
+    assert "TPU" in proc.stderr
+
+
+def test_unknown_cell_is_an_error():
+    proc = run_cell("no_such_cell", 0, "--rehearse")
+    assert proc.returncode != 0 and proc.stdout.strip() == ""
