@@ -35,6 +35,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from surreal_tpu.session.telemetry import step_annotation, trace_annotation
 from surreal_tpu.utils import faults
 
 
@@ -191,18 +192,20 @@ class LoopEngine:
                 raise faults.FaultInjected(f"engine.stage kill: {f}")
         t0 = time.perf_counter()
         try:
-            m_row, stop = self.hooks.end_iteration(
-                iteration, env_steps, state_for_hooks, out.hook_key,
-                self._wrap_metrics(out.metrics), self.on_metrics,
-            )
-            if m_row is not None:
-                if out.post_metrics is not None:
-                    out.post_metrics(m_row)
-                self.hooks.tracer.event("engine", **self._event_fields())
-                self.hooks.ops.push_local(
-                    "engine", gauges=self.gauge_row(),
-                    body=self._event_fields(),
+            # the interval _boundary_ms times, on any profile's host plane
+            with trace_annotation("engine.boundary"):
+                m_row, stop = self.hooks.end_iteration(
+                    iteration, env_steps, state_for_hooks, out.hook_key,
+                    self._wrap_metrics(out.metrics), self.on_metrics,
                 )
+                if m_row is not None:
+                    if out.post_metrics is not None:
+                        out.post_metrics(m_row)
+                    self.hooks.tracer.event("engine", **self._event_fields())
+                    self.hooks.ops.push_local(
+                        "engine", gauges=self.gauge_row(),
+                        body=self._event_fields(),
+                    )
             return bool(stop)
         finally:
             dur = (time.perf_counter() - t0) * 1e3
@@ -289,33 +292,38 @@ class LoopEngine:
         self._t0 = time.perf_counter()
         try:
             while ls.env_steps < self.total:
-                if self.fire_faults:
-                    f = faults.fire("trainer.iteration")
-                    if f is not None and self.apply_fault is not None:
-                        self.apply_fault(ls, f)
-                t_step = time.perf_counter()
-                out = self.step(ls)
-                self._step_ms.append((time.perf_counter() - t_step) * 1e3)
-                if out.skip_boundary:
-                    ls.env_steps += out.steps
-                    if self.hooks is not None and self.hooks.interrupted:
-                        break
-                    continue
-                ls.iteration += 1
-                ls.env_steps += out.steps
-                if self.after_step is not None:
-                    self.after_step(ls)
-                if not self.pipelined:
-                    if self._inline_boundary(ls, out):
-                        break
-                else:
-                    if self._pipelined_boundary(ls, out):
+                # one pass = one profiler step; engine.step and
+                # engine.boundary (in _run_boundary) lie inside it
+                with step_annotation("iteration", ls.iteration):
+                    if self._pass(ls):
                         break
             return ls
         finally:
             self._flush()
             if self._executor is not None:
                 self._executor.shutdown(wait=False)
+
+    def _pass(self, ls: LoopState) -> bool:
+        """One pass of the loop: chaos, the driver step, counters, the
+        boundary. Returns whether the loop stops."""
+        if self.fire_faults:
+            f = faults.fire("trainer.iteration")
+            if f is not None and self.apply_fault is not None:
+                self.apply_fault(ls, f)
+        t_step = time.perf_counter()
+        with trace_annotation("engine.step"):
+            out = self.step(ls)
+        self._step_ms.append((time.perf_counter() - t_step) * 1e3)
+        if out.skip_boundary:
+            ls.env_steps += out.steps
+            return self.hooks is not None and self.hooks.interrupted
+        ls.iteration += 1
+        ls.env_steps += out.steps
+        if self.after_step is not None:
+            self.after_step(ls)
+        if not self.pipelined:
+            return self._inline_boundary(ls, out)
+        return self._pipelined_boundary(ls, out)
 
     def _inline_boundary(self, ls: LoopState, out: Outcome) -> bool:
         stop = False

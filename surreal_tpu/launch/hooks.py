@@ -1,6 +1,6 @@
 """Session-services hooks shared by every training driver: metrics writing,
 periodic checkpoint with keep-best, periodic eval, restore/auto-resume, and
-an optional profiler trace window.
+on-demand profiler captures with their digest.
 
 Parity map (SURVEY.md §3.4 learner loop + §2.1): the reference's learner
 main loop interleaved ``tensorplex scalars``, ``PeriodicCheckpoint.save()``
@@ -239,10 +239,14 @@ class SessionHooks:
                 self._param_server.addresses, self._pub_every.period,
             )
 
-        # on-demand profiling (session/profile.py): legacy profiler knob,
-        # trigger-file captures, and the slow-iteration auto-trigger all
-        # live behind one boundary tick
-        self.profile = ProfileManager(cfg, cfg.folder, self.tracer, self.log)
+        # on-demand profiling (session/profile.py): trigger-file captures,
+        # request() and the slow-iteration auto-trigger share one boundary
+        # tick; each capture is reduced to a digest with the op -> phase
+        # maps of the programs the cost accountant registered
+        self.profile = ProfileManager(
+            cfg, cfg.folder, self.tracer, self.log,
+            op_phases=self.costs.op_phases,
+        )
         # watchdog & incident engine (ISSUE 15): detector sweeps over each
         # merged ops snapshot, firings correlated into root-caused
         # incident records under telemetry/incidents/ (`surreal_tpu why`).
@@ -298,6 +302,9 @@ class SessionHooks:
         self._last_eval: dict[str, float] = {}
         self._last_train: dict[str, float] = {}
         self._metrics_every = PeriodicTracker(max(1, cfg.metrics.every_n_iters))
+        # (host clock, iteration) at the end of the last metrics-sync: the
+        # fenced `cadence` phase runs from there to the end of the next
+        self._sync_end: tuple[float, int] | None = None
         self._t0 = None
         self._steps0 = 0
 
@@ -552,6 +559,15 @@ class SessionHooks:
             with self.tracer.span("metrics-sync"):
                 raw = metrics() if callable(metrics) else (metrics or {})
                 m = {k: float(v) for k, v in raw.items()}
+            # fence to fence: the only span whose total is device time
+            # (perf/* divide by it; a rollback's backward step is skipped)
+            now = time.perf_counter()
+            if self._sync_end is not None and iteration > self._sync_end[1]:
+                self.tracer.add_phase(
+                    "cadence", now - self._sync_end[0],
+                    count=iteration - self._sync_end[1],
+                )
+            self._sync_end = (now, iteration)
             m["time/env_steps"] = env_steps
             m["time/env_steps_per_s"] = (env_steps - self._steps0) / max(
                 time.time() - (self._t0 or time.time()), 1e-9
@@ -691,7 +707,11 @@ class SessionHooks:
                     )
                     if self.extra_state_fn is not None:
                         self.ckpt.save_extra(iteration, self.extra_state_fn())
-        self.profile.tick(iteration)
+        # a capture starts and stops on an idle device, so that it holds
+        # whole iterations: the state is the last dispatched one's output
+        self.profile.tick(
+            iteration, fence=lambda: jax.block_until_ready(resolve_state())
+        )
         # chaos-harness visibility: mirror any faults fired since the last
         # boundary into the telemetry spine (empty list in normal runs) —
         # and into the flight recorder, whose dump freezes the snapshots

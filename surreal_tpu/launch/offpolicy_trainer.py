@@ -43,6 +43,7 @@ from surreal_tpu.learners.ddpg import ou_noise_step
 from surreal_tpu.replay import build_replay
 from surreal_tpu.session.config import Config
 from surreal_tpu.utils import faults
+from surreal_tpu.utils.phases import phase
 
 
 class OffPolicyCarry(NamedTuple):
@@ -300,22 +301,29 @@ class OffPolicyTrainer:
 
         def step(c: OffPolicyCarry, step_key):
             akey, nkey, wkey = jax.random.split(step_key, 3)
-            if explo.noise == "ou":
-                a_det, _ = self.learner.act(state, c.obs, akey, "eval_deterministic")
-                noise = ou_noise_step(
-                    c.noise, nkey, explo.ou_theta, explo.sigma, explo.ou_dt
+            with phase("collect/act"):
+                if explo.noise == "ou":
+                    a_det, _ = self.learner.act(
+                        state, c.obs, akey, "eval_deterministic"
+                    )
+                    noise = ou_noise_step(
+                        c.noise, nkey, explo.ou_theta, explo.sigma, explo.ou_dt
+                    )
+                    action = jnp.clip(a_det + noise, -1.0, 1.0)
+                else:
+                    action, _ = self.learner.act(state, c.obs, akey, "training")
+                    noise = c.noise
+                # exploration warmup: uniform-random actions until the
+                # replay holds enough diverse data (classic off-policy
+                # bootstrap fix)
+                random_action = jax.random.uniform(
+                    wkey, action.shape, action.dtype, -1.0, 1.0
                 )
-                action = jnp.clip(a_det + noise, -1.0, 1.0)
-            else:
-                action, _ = self.learner.act(state, c.obs, akey, "training")
-                noise = c.noise
-            # exploration warmup: uniform-random actions until the replay
-            # holds enough diverse data (classic off-policy bootstrap fix)
-            random_action = jax.random.uniform(
-                wkey, action.shape, action.dtype, -1.0, 1.0
-            )
-            action = jnp.where(warmup, random_action, action)
-            env_state, obs2, reward, done, info = batch_step(self.env, c.env_state, action)
+                action = jnp.where(warmup, random_action, action)
+            with phase("collect/env"):
+                env_state, obs2, reward, done, info = batch_step(
+                    self.env, c.env_state, action
+                )
             next_obs, terminated = successor_and_termination(obs2, done, info)
             ep_return = c.ep_return + reward
             ep_length = c.ep_length + 1
@@ -351,35 +359,44 @@ class OffPolicyTrainer:
         self, state, replay_state, carry, key, beta, warmup, first, axis_name=None
     ):
         rkey, ukey = jax.random.split(key)
-        carry, traj = self._rollout(state, carry, rkey, warmup)
-        chunk = {k: traj[k] for k in TRANS_KEYS}
-        n = self.algo.n_step
-        if n > 1:
-            # prepend the previous chunk's tail so the n-1 steps at every
-            # chunk boundary still become window STARTS (without this they
-            # would silently never enter replay); carry the new tail on.
-            full = jax.tree.map(
-                lambda a, b: jnp.concatenate([a, b], axis=0), carry.tail, chunk
-            )
-            carry = carry._replace(
-                tail=jax.tree.map(lambda x: x[-(n - 1):], full)
-            )
-        else:
-            full = chunk
-        trans = nstep_transitions(full, self.algo.gamma, n)
-        if n > 1:
-            # the very first chunk's prepended tail is fabricated (no
-            # previous chunk exists), so the n-1 windows starting inside it
-            # are fictitious (obs=0, action=0) — scrub them before insert.
-            trans = jax.lax.cond(
-                first,
-                lambda t: scrub_fake_prefix_windows(t, n, chunk["reward"].shape[1]),
-                lambda t: t,
-                trans,
-            )
+        with phase("collect"):
+            carry, traj = self._rollout(state, carry, rkey, warmup)
+            chunk = {k: traj[k] for k in TRANS_KEYS}
+            n = self.algo.n_step
+            if n > 1:
+                # prepend the previous chunk's tail so the n-1 steps at
+                # every chunk boundary still become window STARTS (without
+                # this they would silently never enter replay); carry the
+                # new tail on.
+                full = jax.tree.map(
+                    lambda a, b: jnp.concatenate([a, b], axis=0),
+                    carry.tail, chunk,
+                )
+                carry = carry._replace(
+                    tail=jax.tree.map(lambda x: x[-(n - 1):], full)
+                )
+            else:
+                full = chunk
+            trans = nstep_transitions(full, self.algo.gamma, n)
+            if n > 1:
+                # the very first chunk's prepended tail is fabricated (no
+                # previous chunk exists), so the n-1 windows starting
+                # inside it are fictitious (obs=0, action=0) — scrub them
+                # before insert.
+                trans = jax.lax.cond(
+                    first,
+                    lambda t: scrub_fake_prefix_windows(
+                        t, n, chunk["reward"].shape[1]
+                    ),
+                    lambda t: t,
+                    trans,
+                )
+            # obs-normalizer: fold each fresh obs exactly once per chunk
+            with phase("collect/obs_stats"):
+                state = self.learner.update_obs_stats(
+                    state, chunk["obs"], axis_name
+                )
         replay_state = self.replay.insert(replay_state, trans)
-        # obs-normalizer: fold each fresh obs exactly once per chunk
-        state = self.learner.update_obs_stats(state, chunk["obs"], axis_name)
 
         def run_updates(operand):
             state, replay_state = operand
@@ -487,15 +504,16 @@ class OffPolicyTrainer:
         # max_priority is the globally-synced value (fills/sizes are
         # lockstep-identical across shards by construction)
         metrics.update(self.replay.gauges(replay_state))
-        n_done = traj["ep_done"].sum()
-        ep_return_sum = traj["ep_return"].sum()
-        if axis_name is not None:
-            n_done = jax.lax.psum(n_done, axis_name)
-            ep_return_sum = jax.lax.psum(ep_return_sum, axis_name)
-        metrics["episode/return"] = jnp.where(
-            n_done > 0, ep_return_sum / jnp.maximum(n_done, 1), jnp.nan
-        )
-        metrics["episode/count"] = n_done.astype(jnp.float32)
+        with phase("collect/episodes"):
+            n_done = traj["ep_done"].sum()
+            ep_return_sum = traj["ep_return"].sum()
+            if axis_name is not None:
+                n_done = jax.lax.psum(n_done, axis_name)
+                ep_return_sum = jax.lax.psum(ep_return_sum, axis_name)
+            metrics["episode/return"] = jnp.where(
+                n_done > 0, ep_return_sum / jnp.maximum(n_done, 1), jnp.nan
+            )
+            metrics["episode/count"] = n_done.astype(jnp.float32)
         return state, replay_state, carry, metrics
 
     # -- main loop -----------------------------------------------------------

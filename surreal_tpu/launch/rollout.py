@@ -29,6 +29,7 @@ from surreal_tpu.envs.base import HostEnv
 from surreal_tpu.envs.jax.base import AutoReset, batch_step
 from surreal_tpu.learners.base import TRAINING, Learner
 from surreal_tpu.learners.aggregator import multistep_batch
+from surreal_tpu.utils.phases import phase
 
 
 class RolloutCarry(NamedTuple):
@@ -82,12 +83,14 @@ def device_rollout(
     def step(scan_carry, step_key):
         c, act_carry = scan_carry
         akey, skey = jax.random.split(step_key)
-        action, info, act_carry = learner.act_step(
-            state, act_carry, c.obs, akey, TRAINING
-        )
-        env_state, obs2, reward, done, step_info = batch_step(
-            env, c.env_state, action
-        )
+        with phase("collect/act"):
+            action, info, act_carry = learner.act_step(
+                state, act_carry, c.obs, akey, TRAINING
+            )
+        with phase("collect/env"):
+            env_state, obs2, reward, done, step_info = batch_step(
+                env, c.env_state, action
+            )
         next_obs, terminated = successor_and_termination(obs2, done, step_info)
         ep_return = c.ep_return + reward
         ep_length = c.ep_length + 1
@@ -113,14 +116,15 @@ def device_rollout(
         )
         return (new_c, act_carry), trans
 
-    keys = jax.random.split(key, horizon)
     # a FRESH act carry per rollout call: sequence policies' context is
     # segment-aligned (learn recomputes exactly this conditioning);
     # memoryless learners get None, which scans as an empty pytree
-    (new_carry, _), batch = jax.lax.scan(
-        step, (carry, learner.act_init(carry.obs.shape[0])), keys,
-        unroll=max(1, min(int(unroll), horizon)),
-    )
+    with phase("collect"):
+        keys = jax.random.split(key, horizon)
+        (new_carry, _), batch = jax.lax.scan(
+            step, (carry, learner.act_init(carry.obs.shape[0])), keys,
+            unroll=max(1, min(int(unroll), horizon)),
+        )
     return new_carry, batch
 
 

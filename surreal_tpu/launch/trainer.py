@@ -42,6 +42,7 @@ from surreal_tpu.launch.rollout import (
 )
 from surreal_tpu.learners import build_learner
 from surreal_tpu.utils import faults
+from surreal_tpu.utils.phases import phase
 
 
 class Trainer:
@@ -200,15 +201,16 @@ class Trainer:
             )
         }
         state, metrics = self.learner.learn(state, learn_batch, lkey, axis_name)
-        n_done = batch["ep_done"].sum()
-        ep_return_sum = batch["ep_return"].sum()
-        if axis_name is not None:
-            n_done = jax.lax.psum(n_done, axis_name)
-            ep_return_sum = jax.lax.psum(ep_return_sum, axis_name)
-        metrics["episode/return"] = jnp.where(
-            n_done > 0, ep_return_sum / jnp.maximum(n_done, 1), jnp.nan
-        )
-        metrics["episode/count"] = n_done.astype(jnp.float32)
+        with phase("collect/episodes"):
+            n_done = batch["ep_done"].sum()
+            ep_return_sum = batch["ep_return"].sum()
+            if axis_name is not None:
+                n_done = jax.lax.psum(n_done, axis_name)
+                ep_return_sum = jax.lax.psum(ep_return_sum, axis_name)
+            metrics["episode/return"] = jnp.where(
+                n_done > 0, ep_return_sum / jnp.maximum(n_done, 1), jnp.nan
+            )
+            metrics["episode/count"] = n_done.astype(jnp.float32)
         return state, carry, metrics
 
     def init_loop_state(self, env_key: jax.Array) -> RolloutCarry:

@@ -37,6 +37,7 @@ from surreal_tpu.ops.running_stats import (
     update_stats,
 )
 from surreal_tpu.session.config import Config
+from surreal_tpu.utils.phases import phase
 
 DDPG_LEARNER_CONFIG = Config(
     algo=Config(
@@ -178,6 +179,7 @@ class DDPGLearner(Learner):
         )
 
     # -- learning ------------------------------------------------------------
+    @phase("update")
     def learn(self, state: DDPGState, batch: dict, key: jax.Array, axis_name=None):
         """One SGD update on flat n-step transitions.
 

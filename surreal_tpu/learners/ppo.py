@@ -49,6 +49,7 @@ from surreal_tpu.ops.running_stats import (
     update_stats,
 )
 from surreal_tpu.session.config import Config
+from surreal_tpu.utils.phases import phase
 
 PPO_LEARNER_CONFIG = Config(
     algo=Config(
@@ -230,51 +231,53 @@ class PPOLearner(SequenceActingMixin, Learner):
         algo = self.config.algo
         T, B = batch["reward"].shape
 
-        # 1) obs-normalizer update (reference: ZFilter update then broadcast)
-        if self._use_obs_filter:
-            obs_stats = update_stats(
-                state.obs_stats, batch["obs"], axis_name=axis_name
-            )
-        else:
-            obs_stats = state.obs_stats
-        obs = self._norm_obs(obs_stats, batch["obs"])
-        next_obs = self._norm_obs(obs_stats, batch["next_obs"])
+        with phase("prepare"):
+            # 1) obs-normalizer update (reference: ZFilter update then
+            # broadcast; its dp psums are this phase's)
+            if self._use_obs_filter:
+                obs_stats = update_stats(
+                    state.obs_stats, batch["obs"], axis_name=axis_name
+                )
+            else:
+                obs_stats = state.obs_stats
+            obs = self._norm_obs(obs_stats, batch["obs"])
+            next_obs = self._norm_obs(obs_stats, batch["next_obs"])
 
-        # 2) value forward for GAE (one shared pass, or the exact two-pass
-        # form — see PPO_LEARNER_CONFIG value_bootstrap)
-        if algo.get("value_bootstrap", "exact") == "shared":
-            stack = jnp.concatenate([obs, next_obs[-1:]], axis=0)
-            v_all = self.model.apply(state.params, stack).value
-            values, v_next = v_all[:-1], v_all[1:]
-        else:
-            values = self.model.apply(state.params, obs).value
-            v_next = self.model.apply(state.params, next_obs).value
-        advantages, value_targets = self._gae(batch, values, v_next)
-        advantages = self._norm_advantages(advantages, axis_name)
+            # 2) value forward for GAE (one shared pass, or the exact
+            # two-pass form — see PPO_LEARNER_CONFIG value_bootstrap)
+            if algo.get("value_bootstrap", "exact") == "shared":
+                stack = jnp.concatenate([obs, next_obs[-1:]], axis=0)
+                v_all = self.model.apply(state.params, stack).value
+                values, v_next = v_all[:-1], v_all[1:]
+            else:
+                values = self.model.apply(state.params, obs).value
+                v_next = self.model.apply(state.params, next_obs).value
+            advantages, value_targets = self._gae(batch, values, v_next)
+            advantages = self._norm_advantages(advantages, axis_name)
 
-        # 3) flatten time x batch -> sample axis
-        N = T * B
-        flat = {
-            "obs": obs.reshape(N, *obs.shape[2:]),
-            "action": batch["action"].reshape(N, *batch["action"].shape[2:]),
-            "behavior_logp": batch["behavior_logp"].reshape(N),
-            "adv": advantages.reshape(N),
-            "target": value_targets.reshape(N),
-            "value_old": values.reshape(N),
-        }
-        if self.discrete:
-            flat["b_logits"] = batch["behavior"]["logits"].reshape(N, -1)
-        else:
-            flat["b_mean"] = batch["behavior"]["mean"].reshape(N, -1)
-            flat["b_log_std"] = batch["behavior"]["log_std"].reshape(N, -1)
+            # 3) flatten time x batch -> sample axis
+            N = T * B
+            flat = {
+                "obs": obs.reshape(N, *obs.shape[2:]),
+                "action": batch["action"].reshape(N, *batch["action"].shape[2:]),
+                "behavior_logp": batch["behavior_logp"].reshape(N),
+                "adv": advantages.reshape(N),
+                "target": value_targets.reshape(N),
+                "value_old": values.reshape(N),
+            }
+            if self.discrete:
+                flat["b_logits"] = batch["behavior"]["logits"].reshape(N, -1)
+            else:
+                flat["b_mean"] = batch["behavior"]["mean"].reshape(N, -1)
+                flat["b_log_std"] = batch["behavior"]["log_std"].reshape(N, -1)
 
-        # precision: stage the obs minibatch array in the policy's data
-        # dtype (bf16 under 'bf16'/'bf16_fp8') — the epochs x minibatch
-        # gathers then move half the bytes, at the SAME rounding point
-        # the model's compute-dtype cast would apply per read. The
-        # numerically delicate scalars (logps, advantages, targets) stay
-        # f32 under every policy.
-        flat = self.policy.cast_stage(flat, keys=("obs",))
+            # precision: stage the obs minibatch array in the policy's data
+            # dtype (bf16 under 'bf16'/'bf16_fp8') — the epochs x minibatch
+            # gathers then move half the bytes, at the SAME rounding point
+            # the model's compute-dtype cast would apply per read. The
+            # numerically delicate scalars (logps, advantages, targets)
+            # stay f32 under every policy.
+            flat = self.policy.cast_stage(flat, keys=("obs",))
 
         sgd_out = self._sgd_epochs(
             state, flat, N, algo.num_minibatches, key, axis_name
@@ -285,6 +288,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         )
 
     # -- pieces shared by the memoryless and sequence learn paths ------------
+    @phase("prepare/gae")
     def _gae(self, batch, values, v_next):
         """GAE over [T, B] arrays with the truncation-exact two-mask form
         (bootstrap discount gamma*(1-terminated) vs accumulation decay
@@ -433,9 +437,10 @@ class PPOLearner(SequenceActingMixin, Learner):
         if blocks_per_mb:
             nblocks = num_mb * blocks_per_mb
             block_len = mb_size // blocks_per_mb
-            data = jax.tree.map(
-                lambda x: x.reshape(nblocks, block_len, *x.shape[1:]), data
-            )
+            with phase("shuffle"):
+                data = jax.tree.map(
+                    lambda x: x.reshape(nblocks, block_len, *x.shape[1:]), data
+                )
             unblock = lambda x: x.reshape(
                 blocks_per_mb * block_len, *x.shape[2:]
             )
@@ -446,26 +451,32 @@ class PPOLearner(SequenceActingMixin, Learner):
 
         def mb_update(carry, mb_idx):
             params, opt_state, stopped = carry
-            mb = jax.tree.map(lambda x: unblock(x[mb_idx]), data)
-            policy_coeff = jnp.where(stopped, 0.0, 1.0)
-            # precision: the loss scale rides the carried opt_state (a
-            # traced input — scale changes never recompile); 1.0 when the
-            # policy carries no scale
-            scale = current_loss_scale(opt_state)
-            grads, aux = grad_fn(params, mb, state.kl_beta, policy_coeff, scale)
-            if axis_name is not None:
-                grads = jax.lax.pmean(grads, axis_name)
-                aux = jax.lax.pmean(aux, axis_name)
-            # after the pmean so every replica reports the merged norm;
-            # feeds the health/* diagnostics in _finalize. Divided by the
-            # loss scale (a power of two — exact) so health thresholds see
-            # the TRUE gradient magnitude; inf/nan survive the division.
-            aux["grad_norm"] = optax.global_norm(grads) / scale
-            updates, opt_state = self.tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-            stopped = jnp.logical_or(
-                stopped, aux["kl"] > algo.kl_early_stop * algo.kl_target
-            )
+            with phase("shuffle"):
+                mb = jax.tree.map(lambda x: unblock(x[mb_idx]), data)
+            with phase("sgd"):
+                policy_coeff = jnp.where(stopped, 0.0, 1.0)
+                # precision: the loss scale rides the carried opt_state (a
+                # traced input — scale changes never recompile); 1.0 when
+                # the policy carries no scale
+                scale = current_loss_scale(opt_state)
+                grads, aux = grad_fn(
+                    params, mb, state.kl_beta, policy_coeff, scale
+                )
+                if axis_name is not None:
+                    with phase("sgd/psum"):
+                        grads = jax.lax.pmean(grads, axis_name)
+                        aux = jax.lax.pmean(aux, axis_name)
+                # after the pmean so every replica reports the merged norm;
+                # feeds the health/* diagnostics in _finalize. Divided by
+                # the loss scale (a power of two — exact) so health
+                # thresholds see the TRUE gradient magnitude; inf/nan
+                # survive the division.
+                aux["grad_norm"] = optax.global_norm(grads) / scale
+                updates, opt_state = self.tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
+                stopped = jnp.logical_or(
+                    stopped, aux["kl"] > algo.kl_early_stop * algo.kl_target
+                )
             return (params, opt_state, stopped), aux
 
         # searched minibatch-scan unroll (algo.sgd_unroll, tune/space.py);
@@ -476,10 +487,11 @@ class PPOLearner(SequenceActingMixin, Learner):
         def epoch_update(carry, epoch_key):
             # truncation covers row mode on domains not divisible by
             # num_mb; block mode divides exactly by construction
-            perm = jax.random.permutation(epoch_key, perm_domain)
-            perm = perm[: idx_shape[0] * idx_shape[1]]
+            with phase("shuffle"):
+                perm = jax.random.permutation(epoch_key, perm_domain)
+                perm = perm[: idx_shape[0] * idx_shape[1]].reshape(idx_shape)
             carry, auxs = jax.lax.scan(
-                mb_update, carry, perm.reshape(idx_shape), unroll=sgd_unroll
+                mb_update, carry, perm, unroll=sgd_unroll
             )
             return carry, auxs
 
@@ -494,6 +506,7 @@ class PPOLearner(SequenceActingMixin, Learner):
             unroll=1,
         )
 
+    @phase("finalize")
     def _finalize(
         self, state, obs_stats, sgd_out, values, value_targets, advantages,
         axis_name,
@@ -572,6 +585,27 @@ class PPOLearner(SequenceActingMixin, Learner):
         algo = self.config.algo
         T, B = batch["reward"].shape
 
+        with phase("prepare"):
+            obs_stats, values, value_targets, advantages, data = (
+                self._prepare_seq(state, batch, axis_name)
+            )
+        if B // algo.num_minibatches == 0:
+            raise ValueError(
+                f"num_minibatches={algo.num_minibatches} exceeds the env "
+                f"batch width {B}: sequence minibatches are whole envs"
+            )
+        sgd_out = self._sgd_epochs(
+            state, data, B, algo.num_minibatches, key, axis_name
+        )
+        return self._finalize(
+            state, obs_stats, sgd_out, values, value_targets, advantages,
+            axis_name,
+        )
+
+    def _prepare_seq(self, state, batch, axis_name):
+        """The sequence path's ``prepare`` phase: obs filter, one
+        extended value pass, GAE, advantage norm, env-major staging."""
+        T, B = batch["reward"].shape
         if self._use_obs_filter:
             obs_stats = update_stats(
                 state.obs_stats, batch["obs"], axis_name=axis_name
@@ -614,17 +648,4 @@ class PPOLearner(SequenceActingMixin, Learner):
         # trajectory models keep uint8 pixels raw — cast_stage skips
         # non-float leaves)
         data = self.policy.cast_stage(data, keys=("obs",))
-
-        algo = self.config.algo
-        if B // algo.num_minibatches == 0:
-            raise ValueError(
-                f"num_minibatches={algo.num_minibatches} exceeds the env "
-                f"batch width {B}: sequence minibatches are whole envs"
-            )
-        sgd_out = self._sgd_epochs(
-            state, data, B, algo.num_minibatches, key, axis_name
-        )
-        return self._finalize(
-            state, obs_stats, sgd_out, values, value_targets, advantages,
-            axis_name,
-        )
+        return obs_stats, values, value_targets, advantages, data

@@ -22,6 +22,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from surreal_tpu.utils.phases import phase
+
 
 class RingState(NamedTuple):
     """Shared ring-buffer bookkeeping."""
@@ -54,15 +56,17 @@ def ring_insert(state: RingState, batch: Any, capacity: int) -> RingState:
 
     check_insert_batch(batch, state.storage, name="ring_insert")
     n = jax.tree.leaves(batch)[0].shape[0]
-    idx = (state.cursor + jnp.arange(n, dtype=jnp.int32)) % capacity
-    storage = jax.tree.map(
-        lambda buf, new: buf.at[idx].set(new.astype(buf.dtype)), state.storage, batch
-    )
-    return RingState(
-        storage=storage,
-        cursor=(state.cursor + n) % capacity,
-        size=jnp.minimum(state.size + n, capacity),
-    )
+    with phase("replay_insert"):
+        idx = (state.cursor + jnp.arange(n, dtype=jnp.int32)) % capacity
+        storage = jax.tree.map(
+            lambda buf, new: buf.at[idx].set(new.astype(buf.dtype)),
+            state.storage, batch,
+        )
+        return RingState(
+            storage=storage,
+            cursor=(state.cursor + n) % capacity,
+            size=jnp.minimum(state.size + n, capacity),
+        )
 
 
 def ring_gather(state: RingState, idx: jax.Array, impl: str = "xla") -> Any:

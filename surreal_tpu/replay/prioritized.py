@@ -28,6 +28,7 @@ from surreal_tpu.replay.base import (
     ring_insert,
     sample_age_frac,
 )
+from surreal_tpu.utils.phases import phase
 
 
 class PrioritizedState(NamedTuple):
@@ -60,12 +61,15 @@ class PrioritizedReplay:
         """New transitions enter at the current max priority (so they are
         seen at least once before their TD error takes over)."""
         n = jax.tree.leaves(batch)[0].shape[0]
-        idx = (state.ring.cursor + jnp.arange(n, dtype=jnp.int32)) % self.capacity
-        return PrioritizedState(
-            ring=ring_insert(state.ring, batch, self.capacity),
-            priorities=state.priorities.at[idx].set(state.max_priority),
-            max_priority=state.max_priority,
-        )
+        with phase("replay_insert"):
+            idx = (
+                state.ring.cursor + jnp.arange(n, dtype=jnp.int32)
+            ) % self.capacity
+            return PrioritizedState(
+                ring=ring_insert(state.ring, batch, self.capacity),
+                priorities=state.priorities.at[idx].set(state.max_priority),
+                max_priority=state.max_priority,
+            )
 
     def can_sample(self, state: PrioritizedState) -> jax.Array:
         return can_sample(state.ring.size, self.start_sample_size)
@@ -84,19 +88,25 @@ class PrioritizedReplay:
         """
         bs = batch_size or self.batch_size
         beta = self.beta0 if beta is None else beta
-        p = state.priorities**self.alpha  # empty slots are 0^alpha = 0
-        total = p.sum()
-        cdf = jnp.cumsum(p)
-        # stratified sampling: one uniform draw per equal slice of the mass
-        u = (jnp.arange(bs) + jax.random.uniform(key, (bs,))) / bs * total
-        idx = jnp.clip(jnp.searchsorted(cdf, u), 0, self.capacity - 1).astype(jnp.int32)
+        with phase("replay_sample"):
+            with phase("replay_sample/mass"):
+                p = state.priorities**self.alpha  # empty slots: 0^alpha = 0
+                total = p.sum()
+                cdf = jnp.cumsum(p)
+            with phase("replay_sample/search"):
+                # stratified sampling: one uniform draw per equal slice of
+                # the mass
+                u = (jnp.arange(bs) + jax.random.uniform(key, (bs,))) / bs * total
+                idx = jnp.clip(
+                    jnp.searchsorted(cdf, u), 0, self.capacity - 1
+                ).astype(jnp.int32)
 
-        probs = p[idx] / jnp.maximum(total, 1e-12)
-        n = jnp.maximum(state.ring.size, 1).astype(jnp.float32)
-        weights = (n * jnp.maximum(probs, 1e-12)) ** (-beta)
-        weights = weights / jnp.maximum(weights.max(), 1e-12)
-
-        batch = ring_gather(state.ring, idx, impl=self.gather_impl)
+                probs = p[idx] / jnp.maximum(total, 1e-12)
+                n = jnp.maximum(state.ring.size, 1).astype(jnp.float32)
+                weights = (n * jnp.maximum(probs, 1e-12)) ** (-beta)
+                weights = weights / jnp.maximum(weights.max(), 1e-12)
+            with phase("replay_sample/gather"):
+                batch = ring_gather(state.ring, idx, impl=self.gather_impl)
         return state, batch, {"idx": idx, "is_weights": weights}
 
     # -- telemetry gauges (device scalars; see replay/base.py) ---------------
@@ -113,23 +123,24 @@ class PrioritizedReplay:
     def update_priorities(
         self, state: PrioritizedState, idx: jax.Array, td_errors: jax.Array
     ) -> PrioritizedState:
-        prio = jnp.abs(td_errors) + self.eps
-        if self.gather_impl == "pallas":
-            # scalar-prefetch row-DMA scatter (ops/pallas_replay.py),
-            # in-place via input_output_aliases. Duplicate indices (a
-            # stratified draw can repeat a high-mass slot) resolve
-            # last-write-wins in grid order — the same "some write wins"
-            # contract ``.at[].set`` documents as unspecified.
-            from surreal_tpu.ops import pallas_interpret
-            from surreal_tpu.ops.pallas_replay import scatter_rows_pallas
+        with phase("replay_priority"):
+            prio = jnp.abs(td_errors) + self.eps
+            if self.gather_impl == "pallas":
+                # scalar-prefetch row-DMA scatter (ops/pallas_replay.py),
+                # in-place via input_output_aliases. Duplicate indices (a
+                # stratified draw can repeat a high-mass slot) resolve
+                # last-write-wins in grid order — the same "some write
+                # wins" contract ``.at[].set`` documents as unspecified.
+                from surreal_tpu.ops import pallas_interpret
+                from surreal_tpu.ops.pallas_replay import scatter_rows_pallas
 
-            priorities = scatter_rows_pallas(
-                state.priorities, idx, prio, interpret=pallas_interpret(),
+                priorities = scatter_rows_pallas(
+                    state.priorities, idx, prio, interpret=pallas_interpret(),
+                )
+            else:
+                priorities = state.priorities.at[idx].set(prio)
+            return PrioritizedState(
+                ring=state.ring,
+                priorities=priorities,
+                max_priority=jnp.maximum(state.max_priority, prio.max()),
             )
-        else:
-            priorities = state.priorities.at[idx].set(prio)
-        return PrioritizedState(
-            ring=state.ring,
-            priorities=priorities,
-            max_priority=jnp.maximum(state.max_priority, prio.max()),
-        )

@@ -17,6 +17,7 @@ from surreal_tpu.replay.base import (
     ring_insert,
     sample_age_frac,
 )
+from surreal_tpu.utils.phases import phase
 
 
 class UniformReplay:
@@ -45,8 +46,13 @@ class UniformReplay:
         """-> (state, batch, info). Uniform with replacement over the
         current fill; size is traced, so indices are ``randint % size``."""
         bs = batch_size or self.batch_size
-        idx = jax.random.randint(key, (bs,), 0, jnp.maximum(state.size, 1))
-        batch = ring_gather(state, idx, impl=self.gather_impl)
+        with phase("replay_sample"):
+            with phase("replay_sample/search"):
+                idx = jax.random.randint(
+                    key, (bs,), 0, jnp.maximum(state.size, 1)
+                )
+            with phase("replay_sample/gather"):
+                batch = ring_gather(state, idx, impl=self.gather_impl)
         return state, batch, {"idx": idx}
 
     def sample_many(
@@ -67,15 +73,22 @@ class UniformReplay:
         """
         bs = batch_size or self.batch_size
         K = keys.shape[0]
-        idx = jax.vmap(
-            lambda k: jax.random.randint(k, (bs,), 0, jnp.maximum(state.size, 1))
-        )(keys)                                     # [K, bs]
-        # one gather for all sets (impl-routed: 'pallas' turns it into
-        # K*bs scalar-prefetch row DMAs — see ring_gather)
-        flat = ring_gather(state, idx.reshape(-1), impl=self.gather_impl)
-        batches = jax.tree.map(
-            lambda x: x.reshape(K, bs, *x.shape[1:]), flat
-        )
+        with phase("replay_sample"):
+            with phase("replay_sample/search"):
+                idx = jax.vmap(
+                    lambda k: jax.random.randint(
+                        k, (bs,), 0, jnp.maximum(state.size, 1)
+                    )
+                )(keys)                                     # [K, bs]
+            # one gather for all sets (impl-routed: 'pallas' turns it into
+            # K*bs scalar-prefetch row DMAs — see ring_gather)
+            with phase("replay_sample/gather"):
+                flat = ring_gather(
+                    state, idx.reshape(-1), impl=self.gather_impl
+                )
+                batches = jax.tree.map(
+                    lambda x: x.reshape(K, bs, *x.shape[1:]), flat
+                )
         return state, batches, idx
 
     # -- telemetry gauges (device scalars; see replay/base.py) ---------------

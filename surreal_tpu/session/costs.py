@@ -24,11 +24,15 @@ Design constraints (the transfer-guard tests enforce the first):
 - ``memory_analysis()`` needs the compiled program too:
   ``session.perf.memory_analysis = 'auto'`` takes it only when the
   persistent compile cache is active; ``True``/``False`` force it.
-- Honesty over coverage: a program whose tracer phase measures MORE than
-  the program itself (the host ``rollout`` phase contains env stepping)
-  yields a LOWER-bound MFU contribution; programs with no phase at all
-  (the SEED act closure serves on its own thread) are recorded for
-  ``diag`` but excluded from the live gauges rather than guessed at.
+- Honesty over coverage: the denominator is the window's fenced
+  ``cadence`` seconds (end of one metrics sync to the end of the next:
+  device time, where a program's own span only times its dispatch), so a
+  gauge is a utilization of the wall clock; the numerator is XLA's STATIC
+  count, which takes a loop body once whatever its trip count, so it is a
+  lower bound (6.49e11 of the 5.01e12 FLOPs a fused PPO iteration
+  requires; PERF.md section 7). Programs with no phase at all (the SEED
+  act closure serves on its own thread) are recorded for ``diag`` but
+  excluded from the live gauges rather than guessed at.
 
 Gauge registry: every ``perf/*`` scalar the codebase emits MUST be listed
 in :data:`GAUGE_REGISTRY` — ``tests/test_import_hygiene.py`` lints source
@@ -74,13 +78,16 @@ def gauge_unit(name: str) -> str | None:
 GAUGE_REGISTRY = {
     "perf/mfu": _g("ratio",
         'model FLOP utilization over the metrics window: sum over '
-        'registered programs of (flops/call x calls) / (phase seconds x '
-        'peak FLOP/s). Lower bound when a phase contains non-program work.'),
+        'registered programs of (flops/call x calls) / (fenced `cadence` '
+        'seconds x peak FLOP/s). Lower bound, static count: XLA counts a '
+        'loop body once, whatever its trip count.'),
     "perf/membw_util": _g("ratio",
         'memory-bandwidth utilization over the metrics window: bytes '
-        'accessed (XLA cost model) per second / peak bytes/s.'),
+        'accessed (XLA cost model; lower bound, static count) per fenced '
+        '`cadence` second / peak bytes/s.'),
     "perf/flops_per_s": _g("flops/s",
-        'achieved model FLOP/s over the metrics window (the MFU numerator; '
+        'achieved model FLOP/s over the fenced `cadence` seconds of the '
+        'metrics window (the MFU numerator: lower bound, static count; '
         'emitted even when no peak spec is known for the device).'),
     # -- replay occupancy (replay/base.py ring gauges; device scalars) ------
     "replay/size": _g("count",
@@ -506,20 +513,21 @@ def _memory_dict(ma) -> dict | None:
     return out or None
 
 
-def analyze_program(
-    jitted, *args, memory: bool = False, **kwargs
-) -> tuple[dict | None, dict | None]:
-    """(costs, memory) of one jitted program at these arg shapes, from ONE
-    lowering. ``costs`` is XLA's cost model —
+def analyze_program(jitted, *args, memory: bool = False, **kwargs):
+    """(costs, memory, hlo_text) of one jitted program at these arg
+    shapes, from ONE lowering. ``costs`` is XLA's cost model —
     ``{"flops", "bytes_accessed", "arithmetic_intensity"}`` — of the
     unoptimized HLO where the backend's client can cost it (host-side
     only: no compile, no device work, no transfers; safe before the first
     dispatch and on donated-arg programs, since lowering consumes no
     buffers), and of the compiled program where it cannot (the TPU).
     ``memory`` (argument/output/temp bytes) always needs the compile and
-    is taken only when asked for. Either is None when unavailable; a
-    program that cannot be lowered or compiled here yields (None, None)
-    and fails where it is dispatched, with its own error."""
+    is taken only when asked for. Either is None when unavailable.
+    ``hlo_text()`` returns the COMPILED program's HLO text (its
+    instruction names are a profile's device-op names), compiling only if
+    nothing above had to, and only when called. A program that cannot be
+    lowered or compiled here yields (None, None, None) and fails where it
+    is dispatched, with its own error."""
     try:
         lowered = jitted.lower(*args, **kwargs)
         ca = lowered.cost_analysis()
@@ -528,8 +536,16 @@ def analyze_program(
             ca = compiled.cost_analysis()
         ma = compiled.memory_analysis() if memory else None
     except Exception:
-        return None, None
-    return _cost_dict(ca), _memory_dict(ma) if ma is not None else None
+        return None, None, None
+
+    # held for the session: the executable alone where there is one
+    hlo_text = (
+        compiled.as_text if compiled is not None
+        else lambda: lowered.compile().as_text()
+    )
+    return (
+        _cost_dict(ca), _memory_dict(ma) if ma is not None else None, hlo_text
+    )
 
 
 def program_costs(jitted, *args, **kwargs) -> dict | None:
@@ -570,6 +586,10 @@ class CostAccountant:
         self._on_event = on_event
         self._log = log
         self._programs: dict[str, dict] = {}
+        # name -> zero-arg source of the compiled program's HLO text, and
+        # the {instruction: phase} maps parsed from them on first demand
+        self._hlo: dict[str, Any] = {}
+        self._op_phases: dict[str, dict[str, str]] | None = None
         self._failed: set[str] = set()  # don't re-lower every iteration
         # when a backend reports no cost model (record sites in host/SEED
         # loops call record_program once per iteration, idempotently)
@@ -615,9 +635,12 @@ class CostAccountant:
             # resolved on first use, not at construction: this touches
             # jax.devices(), and hooks must stay constructible pre-backend
             self.peak = resolve_peak_spec(self._cfg)
-        costs, mem = analyze_program(
+        costs, mem, hlo_text = analyze_program(
             jitted, *args, memory=self._memory_analysis_ok(), **kwargs
         )
+        if hlo_text is not None:
+            self._hlo[name] = hlo_text
+            self._op_phases = None
         if costs is None:
             self._failed.add(name)
             if self._log is not None:
@@ -650,29 +673,53 @@ class CostAccountant:
             self._on_event("program_cost", **rec, **self.peak.to_dict())
         return rec
 
+    def op_phases(self) -> dict[str, dict[str, str]]:
+        """``{HLO module name: {instruction name: phase}}`` of every
+        registered program, parsed from its compiled HLO text on first
+        demand (the profile digest's, off the loop's thread). A program
+        whose text cannot be had is left out."""
+        if self._op_phases is None:
+            from surreal_tpu.session.profile import hlo_op_phases
+
+            maps: dict[str, dict[str, str]] = {}
+            for name, hlo_text in list(self._hlo.items()):
+                try:
+                    module, ops = hlo_op_phases(hlo_text())
+                except Exception as e:
+                    if self._log is not None:
+                        self._log.warning(
+                            "no HLO text for program %r: %s", name, e
+                        )
+                    continue
+                maps[module] = ops
+                if not ops and self._log is not None:
+                    self._log.warning(
+                        "program %r carries no phase name: the digest will "
+                        "call its ops unattributed", name,
+                    )
+            self._op_phases = maps
+        return self._op_phases
+
     def gauges(self, window: dict | None) -> dict[str, float]:
         """``perf/*`` scalars for one flushed phase window — pure host
         float arithmetic (the transfer-guard tests run this under
-        ``disallow_device_to_host``). Programs whose phase did not fire in
-        the window contribute nothing; an empty result means no registered
+        ``disallow_device_to_host``). The denominator is the window's
+        fenced ``cadence`` seconds: a window without one (the first of a
+        run) has no gauges. Programs whose phase did not fire in the
+        window contribute nothing; an empty result means no registered
         program ran."""
         if not self.enabled or not window or not self._programs:
             return {}
         flops = 0.0
         byts = 0.0
-        denom_s = 0.0
-        seen_phases: set[str] = set()
+        denom_s = float(window.get("cadence", {}).get("total_s", 0.0))
         for rec in self._programs.values():
             ph = rec.get("phase")
             if ph is None or ph not in window:
                 continue
-            st = window[ph]
-            count = float(st.get("count", 0))
+            count = float(window[ph].get("count", 0))
             flops += rec["flops"] * count * rec["calls_per_phase"]
             byts += rec["bytes_accessed"] * count * rec["calls_per_phase"]
-            if ph not in seen_phases:
-                seen_phases.add(ph)
-                denom_s += float(st.get("total_s", 0.0))
         if denom_s <= 0.0 or (flops <= 0.0 and byts <= 0.0):
             return {}
         out = {"perf/flops_per_s": flops / denom_s}

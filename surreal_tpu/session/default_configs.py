@@ -516,11 +516,6 @@ BASE_SESSION_CONFIG = Config(
         slow_iter_factor=None,
         max_auto_captures=2,  # bound auto captures per run
     ),
-    profiler=Config(
-        enabled=False,     # legacy fixed trace window (SURVEY.md §5.1);
-        start_iter=20,     # still honored — captures now land under
-        num_iters=5,       # telemetry/profiles/ with the on-demand ones
-    ),
     publish=Config(
         # live parameter publishing (reference: the learner published every
         # publish_interval and agents/evals attached to the running session,

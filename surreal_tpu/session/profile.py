@@ -1,39 +1,78 @@
-"""On-demand profiling (ISSUE 6 tentpole, piece 3): programmatic
-``jax.profiler`` windows started/stopped at iteration boundaries.
+"""On-demand profiling: ``jax.profiler`` windows started and stopped at
+iteration boundaries, and each capture reduced to a digest by the program
+itself, so that an operator gets an answer and not only a directory.
 
 Three triggers, one manager (owned by SessionHooks, ticked once per
-``end_iteration``):
+``end_iteration``), one path:
 
-- **legacy window** — the pre-existing ``session.profiler`` knob
-  (enabled/start_iter/num_iters) still works; its capture now lands under
-  ``telemetry/profiles/`` with the on-demand ones.
 - **trigger file** — ``surreal_tpu profile <folder>`` writes
   ``<folder>/profile.trigger``; the running session polls for it (stat
   throttled to once per second — the hot loop pays nothing) and captures
   a ``session.profile.num_iters`` window starting at the next iteration
   boundary, then removes the file. The file's JSON body may override
   ``num_iters``.
+- **request()** — the incident engine's programmatic spelling of the same.
 - **slow-iteration auto-trigger** — when ``session.profile.
   slow_iter_factor`` is set, an iteration whose host wall time exceeds
   factor x the iteration-time EWMA starts a capture automatically (at
   most ``max_auto_captures`` per run). Detection is pure host clock
   deltas between boundary ticks: no device syncs, transfer-guard safe.
 
+A capture starts and stops on an idle device (the tick's ``fence``): the
+loop dispatches asynchronously and runs up to a cadence ahead of the
+device, so without the two fences a window of n host iterations would
+hold whatever the device happened to be doing. With them it holds exactly
+the n iterations between the ticks. The Python tracer is off (it slows
+the loop it would observe) and host annotations are kept.
+
 Every capture directory is ``<folder>/telemetry/profiles/<tag>/`` and is
-announced as a ``profile`` telemetry event (``diag`` renders them), so a
-session folder answers "was this run ever profiled, and where is the
-trace?" offline.
+announced as a ``profile`` telemetry event carrying the **digest**
+(``diag`` renders it), reduced OFF the loop's thread with
+``jax.profiler.ProfileData``:
+
+- ``devices``, ``steps`` (iterations the device ran in the window),
+  ``window_s`` (first op start to last op end), ``busy_s`` (union of op
+  intervals), ``idle_s``, all of one device (the first by name);
+- ``phases``: per phase of ``utils/phases.py`` and for ``unattributed``,
+  the device time its ops OWN, per iteration: at every instant the
+  innermost running op owns the time, so a ``while`` keeps only what its
+  body leaves (the rule of a self time) and the phases sum to ``busy_s``
+  exactly. An op's phase is the first vocabulary name in the ``op_name``
+  path of its HLO instruction; this runtime's op events carry the
+  instruction text without metadata, so the path comes from the compiled
+  program's HLO text, which the cost accountant keeps the source of
+  (``instruction name -> op_name``, looked up under the module the op ran
+  in). A fusion takes the ``op_name`` XLA gave the fusion instruction,
+  which is its root's. Each phase lists its three largest ops;
+- ``idle_by_span``: every device idle gap charged to the innermost
+  program span that covers it on the loop's thread (``metrics-sync``,
+  ``engine.boundary``, ``engine.step``, ``iteration``, ...), or to
+  ``none``. A span is the program's if the tracer has seen its name or
+  the loop engine annotates it.
+
+A digest that fails writes ``digest_error`` and never stops training.
+The arithmetic takes plain tuples so that a test can hand-build a trace.
 """
 
 from __future__ import annotations
 
+import bisect
+import glob
 import json
 import os
+import re
+import threading
 import time
 
 from surreal_tpu.session.telemetry import PROFILES_DIR, TELEMETRY_DIR
+from surreal_tpu.utils.phases import PHASES, UNATTRIBUTED, phase_of
 
 TRIGGER_FILE = "profile.trigger"
+# the loop engine's own annotations (engine/core.py); a tracer's span
+# names join them
+ENGINE_SPANS = ("iteration", "engine.step", "engine.boundary")
+DIGEST_WAIT_S = 120.0  # close() waits this long for a digest in flight
+TOP_OPS = 3
 
 # EWMA shape for the slow-iteration detector: first _WARM_TICKS ticks only
 # seed the average (compiles + cache warmup dominate there), later ticks
@@ -55,15 +94,363 @@ def write_trigger(folder: str, num_iters: int | None = None) -> str:
     return path
 
 
+# -- the arithmetic, on plain tuples ------------------------------------------
+
+
+def owned_pieces(events):
+    """Yield ``(index, start, end)`` pieces such that every instant some
+    event covers belongs to exactly one piece: that of the innermost event
+    running then (the one started last). ``events`` are ``(start, end,
+    ...)`` tuples; a parent keeps what its children leave, and of two that
+    overlap in part the later one owns the overlap."""
+    order = sorted(
+        range(len(events)), key=lambda i: (events[i][0], -events[i][1])
+    )
+    stack: list[int] = []
+    t = 0
+
+    def advance(to):
+        nonlocal t
+        while stack:
+            top = stack[-1]
+            end = events[top][1]
+            if end <= t:
+                stack.pop()
+            elif end <= to:
+                yield top, t, end
+                t = end
+                stack.pop()
+            else:
+                if to > t:
+                    yield top, t, to
+                t = to
+                return
+        t = to
+
+    for i in order:
+        yield from advance(events[i][0])
+        stack.append(i)
+    yield from advance(float("inf"))
+
+
+def idle_gaps(events) -> list[tuple[int, int]]:
+    """The gaps between the merged ``(start, end, ...)`` intervals."""
+    out, end = [], None
+    for s, e, *_ in sorted(events):
+        if end is not None and s > end:
+            out.append((end, s))
+        end = e if end is None else max(end, e)
+    return out
+
+
+def charge_gaps(gaps, spans) -> dict[str, int]:
+    """ns of ``gaps`` per name of the innermost ``(start, end, name)``
+    span covering each instant, the rest under ``none``."""
+    pieces = [(a, b, spans[i][2]) for i, a, b in owned_pieces(spans)]
+    starts = [a for a, _, _ in pieces]
+    out: dict[str, int] = {}
+    for ga, gb in gaps:
+        covered = 0
+        i = max(bisect.bisect_right(starts, ga) - 1, 0)
+        while i < len(pieces) and pieces[i][0] < gb:
+            a, b, name = pieces[i]
+            ns = min(gb, b) - max(ga, a)
+            if ns > 0:
+                out[name] = out.get(name, 0) + ns
+                covered += ns
+            i += 1
+        if gb - ga > covered:
+            out["none"] = out.get("none", 0) + (gb - ga) - covered
+    return out
+
+
+def reduce_digest(device_ops: dict, host_spans, steps: int) -> dict:
+    """The digest's numbers from ``{device: [(start_ns, end_ns, name,
+    phase), ...]}``, the loop thread's program spans ``[(start_ns, end_ns,
+    name), ...]`` and the iterations the window holds. Phases and gaps are
+    those of the first device by name; seconds are floats, nothing is
+    rounded."""
+    device_ops = {k: v for k, v in device_ops.items() if v}
+    out = {"devices": len(device_ops), "steps": int(steps)}
+    if not device_ops:
+        return out
+    ns = 1e-9
+    per_iter_ms = 1e-6 / max(int(steps), 1)
+    events = device_ops[sorted(device_ops)[0]]
+    by_phase: dict[str, int] = {}
+    by_op: dict[tuple[str, str], int] = {}
+    for i, a, b in owned_pieces(events):
+        _, _, name, ph = events[i]
+        by_phase[ph] = by_phase.get(ph, 0) + b - a
+        by_op[ph, name] = by_op.get((ph, name), 0) + b - a
+    busy = sum(by_phase.values())
+    window = max(e for _, e, _, _ in events) - min(s for s, _, _, _ in events)
+    by_phase.setdefault(UNATTRIBUTED, 0)
+    phases = {}
+    for ph in (*PHASES, UNATTRIBUTED):
+        if ph not in by_phase:
+            continue
+        ops = sorted(
+            ((n, t) for (p, n), t in by_op.items() if p == ph),
+            key=lambda kv: -kv[1],
+        )[:TOP_OPS]
+        phases[ph] = {
+            "ms_per_iter": by_phase[ph] * per_iter_ms,
+            "share_of_busy": by_phase[ph] / busy if busy else 0.0,
+            "top_ops": [[n, t * per_iter_ms] for n, t in ops],
+        }
+    out.update(
+        window_s=window * ns,
+        busy_s=busy * ns,
+        idle_s=(window - busy) * ns,
+        busy_s_per_device=[
+            (
+                max(e for _, e, _, _ in evs) - min(s for s, _, _, _ in evs)
+                - sum(b - a for a, b in idle_gaps(evs))
+            ) * ns
+            for _, evs in sorted(device_ops.items())
+        ],
+        phases=phases,
+        idle_by_span={
+            k: v * ns
+            for k, v in charge_gaps(idle_gaps(events), list(host_spans)).items()
+        },
+    )
+    return out
+
+
+# -- from the compiled program and the capture to those tuples ----------------
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_OP = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = (.*)$")
+_HLO_OP_NAME = re.compile(r"metadata=\{[^}]*op_name=\"([^\"]*)\"")
+_HLO_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_HLO_CALLEES = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
+)
+_HLO_REF = re.compile(r"%([\w.\-]+)")
+_HLO_OPCODE = re.compile(r"[\]})]\s([a-z][\w\-]*)\(")
+_HLO_RELAYOUT = ("copy", "copy-start", "copy-done")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
+_SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
+
+
+def hlo_op_phases(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """``(module name, {instruction name: phase})`` of a compiled
+    program's HLO text, for the instructions that have a phase:
+
+    1. its own: the first vocabulary name in its ``op_name`` metadata;
+    2. a fusion without one takes its fused computation's: the root's,
+       else the most frequent among the instructions fused;
+    3. an instruction without one inside a computation that an
+       instruction with a phase calls (the body of a ``while`` XLA made of
+       a gather) takes the caller's, from the innermost caller outwards;
+    4. an instruction XLA made itself, without a ``jit(...)/`` path (a
+       relayout ``copy``, a ``convert``, the tree a ``cumsum`` expands to),
+       takes its first user's, since such an op exists to feed that user,
+       else its first operand's; so does a ``copy`` whatever its path
+       (layout assignment stamps one with the enclosing call's). Any
+       other instruction whose own path lies outside every phase stays
+       outside.
+    """
+    head = _HLO_MODULE.match(hlo_text)
+    phases: dict[str, str] = {}
+    computations: dict[str, list] = {}   # name -> [(instr, is_root)]
+    order: list[tuple[str, list[str], str | None]] = []  # instr, refs, calls
+    home: dict[str, str] = {}            # instr -> its computation
+    caller: dict[str, str] = {}          # computation -> an instr calling it
+    placed: set[str] = set()             # instrs with a path of their own
+    current = here = None
+    for line in hlo_text.splitlines():
+        if line.startswith("}"):
+            current = None
+            continue
+        comp = _HLO_COMPUTATION.match(line)
+        if comp and " = " not in line.split("(", 1)[0]:
+            here = comp.group(1)
+            current = computations.setdefault(here, [])
+            continue
+        m = _HLO_OP.match(line)
+        if not m or current is None:
+            continue
+        root, name, rest = m.groups()
+        current.append((name, bool(root)))
+        home[name] = here
+        for one, several in _HLO_CALLEES.findall(rest):
+            for callee in (one, *_HLO_REF.findall(several)):
+                if callee:
+                    caller.setdefault(callee, name)
+        named = _HLO_OP_NAME.search(rest)
+        opcode = _HLO_OPCODE.search(rest)
+        if (
+            named and named.group(1).startswith("jit(")
+            and not (opcode and opcode.group(1) in _HLO_RELAYOUT)
+        ):
+            placed.add(name)
+        if named and phase_of(named.group(1)) != UNATTRIBUTED:
+            phases[name] = phase_of(named.group(1))
+        calls = _HLO_CALLS.search(rest)
+        order.append((
+            name, _HLO_REF.findall(rest.split(", metadata=", 1)[0]),
+            calls.group(1) if calls else None,
+        ))
+    for name, _, calls in order:
+        body = computations.get(calls) if name not in phases else None
+        if body:
+            inner = [phases[n] for n, _ in body if n in phases]
+            of_root = [phases[n] for n, is_root in body if is_root and n in phases]
+            if inner:
+                phases[name] = (
+                    of_root[0] if of_root else max(set(inner), key=inner.count)
+                )
+    own = dict(phases)
+    for name, _, _ in order:
+        at = name
+        while name not in phases and home.get(at) in caller:
+            at = caller[home[at]]
+            if at in own:
+                phases[name] = own[at]
+    users: dict[str, list[str]] = {}
+    for name, refs, _ in order:
+        for ref in refs:
+            users.setdefault(ref, []).append(name)
+    for name, _, _ in reversed(order):       # users come later in the text
+        if name not in phases and name not in placed:
+            for user in users.get(name, ()):
+                if user in phases:
+                    phases[name] = phases[user]
+                    break
+    for name, refs, _ in order:              # operands come earlier
+        if name not in phases and name not in placed:
+            for ref in refs:
+                if ref in phases:
+                    phases[name] = phases[ref]
+                    break
+    return (head.group(1) if head else ""), phases
+
+
+def _instruction(event_name: str) -> tuple[str, str]:
+    """``(instruction name, name and largest result shape)`` of a device
+    op event, which this runtime names by its whole HLO instruction
+    (``%fusion.5 = bf16[64,17]{...} fusion(...)``)."""
+    if " = " not in event_name:
+        return event_name, event_name
+    op, rest = event_name.split(" = ", 1)
+    op = op.lstrip("%")
+    rest = _LAYOUT.sub("", rest)
+    result = (
+        rest[: rest.index(")") + 1] if rest.startswith("(")
+        else rest.split(" ", 1)[0]
+    )
+
+    def elements(shape: str) -> int:
+        n = 1
+        for d in shape[shape.index("[") + 1:-1].split(","):
+            n *= int(d) if d else 1
+        return n
+
+    largest = max(_SHAPE.findall(result), key=elements, default="")
+    return op, f"{op} {largest}".strip()
+
+
+def read_capture(path: str, op_phases: dict, span_names) -> tuple:
+    """``(device_ops, loop_spans, host_span_counts)`` of one
+    ``.xplane.pb``: per device plane the ``XLA Ops`` line as ``(start_ns,
+    end_ns, name, phase)``, each op's phase looked up under the module
+    (``XLA Modules`` line) it ran in; the program's spans on the loop's
+    thread (the host line with the most ``engine.step``); and how often
+    each program span appears on any host line."""
+    from jax.profiler import ProfileData
+
+    span_names = set(span_names) | set(ENGINE_SPANS)
+    device_ops: dict[str, list] = {}
+    lines: list[list] = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/device:"):
+            by_name = {line.name: line for line in plane.lines}
+            if "XLA Ops" not in by_name:
+                continue
+            modules = sorted(
+                (int(ev.start_ns), ev.name.split("(", 1)[0])
+                for ev in (
+                    by_name["XLA Modules"].events
+                    if "XLA Modules" in by_name else ()
+                )
+            )
+            starts = [s for s, _ in modules]
+            ops = []
+            for ev in by_name["XLA Ops"].events:
+                s = int(ev.start_ns)
+                instr, shown = _instruction(ev.name)
+                i = bisect.bisect_right(starts, s) - 1
+                module = modules[i][1] if i >= 0 else ""
+                ph = op_phases.get(module, {}).get(instr, UNATTRIBUTED)
+                ops.append((s, s + int(ev.duration_ns), shown, ph))
+            device_ops[plane.name] = ops
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                spans = [
+                    (int(ev.start_ns), int(ev.start_ns + ev.duration_ns), ev.name)
+                    for ev in line.events if ev.name in span_names
+                ]
+                if spans:
+                    lines.append(spans)
+    counts: dict[str, int] = {}
+    for spans in lines:
+        for _, _, name in spans:
+            counts[name] = counts.get(name, 0) + 1
+    loop = max(
+        lines, default=[],
+        key=lambda spans: (
+            sum(1 for _, _, n in spans if n == "engine.step"), len(spans)
+        ),
+    )
+    return device_ops, loop, counts
+
+
+def digest_capture(trace_dir: str, op_phases: dict, span_names,
+                   steps: int | None = None) -> dict:
+    """Reduce the one ``.xplane.pb`` a capture left under ``trace_dir``.
+    ``steps`` is the number of iterations the fenced window holds; a
+    capture cut short has none, and the ``iteration`` steps seen on the
+    host stand in."""
+    t0 = time.perf_counter()
+    found = glob.glob(
+        os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
+    )
+    if len(found) != 1:
+        raise FileNotFoundError(
+            f"{len(found)} .xplane.pb files under {trace_dir}, expected 1"
+        )
+    device_ops, loop_spans, counts = read_capture(
+        found[0], op_phases, span_names
+    )
+    if steps is None:
+        steps = counts.get("iteration", 0)
+    out = reduce_digest(device_ops, loop_spans, steps)
+    out["host_spans"] = counts
+    out["trace_bytes"] = sum(
+        os.path.getsize(os.path.join(root, f))
+        for root, _, files in os.walk(trace_dir) for f in files
+    )
+    out["digest_s"] = time.perf_counter() - t0
+    return out
+
+
 class ProfileManager:
     """Iteration-boundary profiler control. ``tick(iteration)`` is cheap
     in the steady state: one monotonic read, one EWMA update, and (at
     most once per second) one ``os.path.exists``."""
 
-    def __init__(self, session_cfg, folder: str, tracer, log):
+    def __init__(self, session_cfg, folder: str, tracer, log, op_phases=None):
         self._folder = folder
         self._tracer = tracer
         self._log = log
+        # zero-arg source of {HLO module: {instruction: phase}} for the
+        # digest (CostAccountant.op_phases); called off the loop's thread
+        self._op_phases = op_phases or dict
         prof = session_cfg.get("profile", None)
         self._trigger_enabled = (
             bool(prof.get("trigger_file", True)) if prof is not None else True
@@ -75,18 +462,11 @@ class ProfileManager:
             int(prof.get("max_auto_captures", 2)) if prof is not None else 2
         )
         self._auto_fired = 0
-        # legacy fixed window (session.profiler): folded into the same
-        # capture machinery so both paths share start/stop + telemetry
-        legacy = session_cfg.get("profiler", None)
-        self._legacy_start = None
-        self._legacy_iters = 5
-        if legacy is not None and legacy.get("enabled", False):
-            self._legacy_start = int(legacy.get("start_iter", 20))
-            self._legacy_iters = int(legacy.get("num_iters", 5))
         self._trigger_path = os.path.join(folder, TRIGGER_FILE)
         self._last_stat = 0.0
         self._pending: tuple[str, int] | None = None  # (reason, num_iters)
         self._active: dict | None = None
+        self._digest: threading.Thread | None = None
         # newest completed capture directory — the incident engine links
         # the capture it auto-requested into the incident record from here
         self.last_capture_dir: str | None = None
@@ -96,7 +476,7 @@ class ProfileManager:
         self._ticks = 0
 
     # -- capture lifecycle ---------------------------------------------------
-    def _start(self, iteration: int, reason: str, num_iters: int) -> None:
+    def _start(self, iteration: int, reason: str, num_iters: int, fence) -> None:
         tag = f"iter{iteration:08d}"
         trace_dir = os.path.join(
             self._folder, TELEMETRY_DIR, PROFILES_DIR, tag
@@ -105,7 +485,14 @@ class ProfileManager:
             os.makedirs(trace_dir, exist_ok=True)
             import jax
 
-            jax.profiler.start_trace(trace_dir)
+            if fence is not None:
+                fence()
+            # no Python tracer: it slows the host loop it would observe
+            # and fills the trace with frames no reduction reads; the
+            # host's annotations stay
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
         except Exception as e:
             # profiling must never kill training (missing profiler deps,
             # unwritable folder); record the failure instead
@@ -124,22 +511,58 @@ class ProfileManager:
             "profiler capture started (%s) -> %s", reason, trace_dir
         )
 
-    def _stop(self, iteration: int) -> None:
+    def _stop(self, iteration: int, fence=None) -> None:
         act = self._active
         self._active = None
+        fenced = False
         try:
             import jax
 
+            if fence is not None:
+                fence()
+                fenced = True
             jax.profiler.stop_trace()
         except Exception as e:
             self._log.warning("profiler stop failed: %s", e)
-        if act is not None:
-            self.last_capture_dir = act["dir"]
-            self._tracer.event(
-                "profile", dir=act["dir"], reason=act["reason"],
-                start_iter=act["start_iter"], end_iter=int(iteration),
+        if act is None:
+            return
+        self.last_capture_dir = act["dir"]
+        self._log.info("profiler capture saved -> %s", act["dir"])
+        fields = dict(
+            dir=act["dir"], reason=act["reason"],
+            start_iter=act["start_iter"], end_iter=int(iteration),
+        )
+        # between two fences the device ran exactly these iterations
+        steps = int(iteration) - act["start_iter"] if fenced else None
+        self._join_digest()
+        self._digest = threading.Thread(
+            target=self._reduce, args=(fields, steps),
+            name="profile-digest", daemon=True,
+        )
+        self._digest.start()
+
+    def _reduce(self, fields: dict, steps: int | None) -> None:
+        """The capture's digest into its ``profile`` event (the digest
+        thread's body; a failure is recorded, never raised)."""
+        try:
+            fields["digest"] = digest_capture(
+                fields["dir"], self._op_phases(),
+                getattr(self._tracer, "span_names", ()), steps,
             )
-            self._log.info("profiler capture saved -> %s", act["dir"])
+        except Exception as e:
+            self._log.warning("profile digest failed: %s", e)
+            fields["digest_error"] = f"{type(e).__name__}: {e}"
+        self._tracer.event("profile", **fields)
+
+    def _join_digest(self) -> None:
+        if self._digest is not None:
+            self._digest.join(DIGEST_WAIT_S)
+            if self._digest.is_alive():
+                self._log.warning(
+                    "profile digest still running after %.0fs: left behind",
+                    DIGEST_WAIT_S,
+                )
+            self._digest = None
 
     def request(self, reason: str, num_iters: int | None = None) -> bool:
         """Queue a capture window starting at the next boundary tick —
@@ -152,7 +575,10 @@ class ProfileManager:
         return True
 
     # -- per-iteration tick --------------------------------------------------
-    def tick(self, iteration: int) -> None:
+    def tick(self, iteration: int, fence=None) -> None:
+        """One boundary tick. ``fence`` blocks until the device has run
+        everything dispatched so far; it is called only where a capture
+        starts or stops."""
         now = time.monotonic()
         self._last_iter = int(iteration)
         # slow-iteration detector: host wall time between boundary ticks
@@ -186,19 +612,16 @@ class ProfileManager:
 
         if self._active is not None:
             if iteration >= self._active["stop_at"]:
-                self._stop(iteration)
-            return
-
-        # legacy fixed window
-        if self._legacy_start is not None and iteration >= self._legacy_start:
-            self._legacy_start = None  # one window per run
-            self._start(iteration, "profiler_knob", self._legacy_iters)
+                self._stop(iteration, fence)
+                # the fences are not an iteration's time
+                self._last_tick = time.monotonic()
             return
 
         if self._pending is not None:
             reason, n = self._pending
             self._pending = None
-            self._start(iteration, reason, n)
+            self._start(iteration, reason, n, fence)
+            self._last_tick = time.monotonic()
             return
 
         # trigger file, stat-throttled to once per second
@@ -216,10 +639,13 @@ class ProfileManager:
                     os.unlink(self._trigger_path)
                 except OSError:
                     pass
-                self._start(iteration, "trigger_file", n)
+                self._start(iteration, "trigger_file", n, fence)
+                self._last_tick = time.monotonic()
 
     def close(self) -> None:
         # a capture cut short by run end must report the iteration it
-        # actually reached, not the stop_at it never got to
+        # actually reached, not the stop_at it never got to; then wait,
+        # bounded, for the digest in flight
         if self._active is not None:
             self._stop(self._last_iter)
+        self._join_digest()

@@ -17,8 +17,15 @@ Fence discipline:
   applies backpressure, so per-window TOTALS converge to real wall time;
   the one true fence per window stays the metrics-cadence sync that
   already existed (``SessionHooks.end_iteration``'s ``float()``
-  conversion). ``span(..., block_on=pytree)`` is available for callers
-  that ARE at a fence boundary (``utils/timer.py``'s rule).
+  conversion). The ONE fenced total is the ``cadence`` phase: host time
+  from the end of one ``metrics-sync`` to the end of the next, counted in
+  iterations — device time, fence to fence (``perf/*`` divide by it).
+- every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+  (disabled tracers included), so ANY profile of the process — an
+  on-demand capture, the benchmark's trace — carries the program's spans
+  on its host plane, in the profile's own timebase beside the device
+  planes. With no profile active an annotation is a few hundred
+  nanoseconds.
 - JSONL volume is bounded by cadence, not by iteration rate: spans
   accumulate in-memory per phase and are written as ONE ``phases`` event
   per ``flush_phases`` call (the metrics cadence); only low-frequency
@@ -86,10 +93,21 @@ line, ``t`` = unix seconds):
                      serve_batch, chunk_queue_dwell, learn_dispatch —
                      emitted at the metrics cadence)
     {"type": "profile", "t": ..., "dir": "...", "reason":
-     "trigger_file|slow_iter(...)|profiler_knob", "start_iter": ...,
-     "end_iter": ...}
+     "trigger_file|slow_iter(...)|<requester>", "start_iter": ...,
+     "end_iter": ..., "digest": {"devices": D, "steps": N, "window_s":
+     ..., "busy_s": ..., "idle_s": ..., "phases": {"<phase>|unattributed":
+     {"ms_per_iter": ..., "share_of_busy": ..., "top_ops": [[name, ms],
+     ...]}}, "idle_by_span": {"<span>|none": seconds}, "host_spans":
+     {"<span>": count}, "trace_bytes": ..., "digest_s": ...}}
                     (on-demand profiler captures, session/profile.py —
-                     the trace artifact lives under dir)
+                     the trace artifact lives under dir; ``digest`` is the
+                     capture reduced by the program itself: device SELF
+                     time per phase of utils/phases.py on one device, per
+                     iteration, and every device idle gap charged to the
+                     innermost program span covering it on the loop's
+                     thread. ``digest_error`` replaces it when the
+                     reduction failed; diag's Performance section renders
+                     the newest digest)
     {"type": "param_fetch", "t": ..., "span": S, "version": V,
      "unchanged": ..., "bytes": B}
                     (parameter-service hop: span-tagged client fetches
@@ -165,6 +183,34 @@ import uuid
 from collections import deque
 from contextlib import contextmanager
 
+# jax.profiler's annotation classes, resolved once on first use: this
+# module stays importable (and diag runnable) without jax, and a span does
+# not pay an attribute walk per call
+_ANNOTATIONS: tuple | None = None
+
+
+def _annotations() -> tuple:
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+def trace_annotation(name: str):
+    """A host span named ``name`` on the host plane of any active
+    profile (``jax.profiler.TraceAnnotation``); nothing but a few hundred
+    nanoseconds when none is."""
+    return _annotations()[0](name)
+
+
+def step_annotation(name: str, step_num: int):
+    """One pass of a loop as a profiler step
+    (``jax.profiler.StepTraceAnnotation``)."""
+    return _annotations()[1](name, step_num=step_num)
+
+
 TELEMETRY_DIR = "telemetry"
 EVENTS_FILE = "events.jsonl"
 PROFILES_DIR = "profiles"  # <folder>/telemetry/profiles/<tag>/ captures
@@ -192,7 +238,9 @@ EVENT_REGISTRY = {
     "program_cost": "cost/MFU accounting (session/costs.py)",
     "precision": "active precision policy (launch/hooks.py begin_run)",
     "hops": "cross-process hop percentiles (launch/seed_trainer.py)",
-    "profile": "on-demand profiler captures (session/profile.py)",
+    "profile": "on-demand profiler captures with their digest: device "
+               "time per phase per iteration, idle by host span "
+               "(session/profile.py)",
     "param_fetch": "parameter-service fetches (distributed/param_service.py)",
     "serving_tier": "inference-fleet snapshot (distributed/fleet.py)",
     "experience_plane": "sharded experience plane (experience/plane.py)",
@@ -380,6 +428,9 @@ class Tracer:
         # the cost accountant (session/costs.py) derives the perf/* gauges
         # from it without re-reading the event log
         self.last_window: dict[str, dict] = {}
+        # every span name this tracer has seen: the profile digest tells
+        # the program's spans from the runtime's own host events by it
+        self.span_names: set[str] = set()
         if self.enabled:
             try:
                 tel_dir = os.path.join(folder, TELEMETRY_DIR)
@@ -437,33 +488,40 @@ class Tracer:
 
     # -- spans ---------------------------------------------------------------
     @contextmanager
-    def span(self, name: str, block_on=None, emit: bool = False):
-        """Time a region into the ``name`` phase accumulator.
+    def span(self, name: str, emit: bool = False):
+        """Time a region into the ``name`` phase accumulator and
+        annotate it on the host plane of any active profile (a disabled
+        tracer still annotates: ranks > 0 are profiled too).
 
-        ``block_on``: pytree of device arrays to ``jax.block_until_ready``
-        before stopping the clock (ONLY for fence-boundary callers — see
-        the module doc). ``emit=True`` additionally writes an individual
-        ``span`` event (low-frequency side-bands only).
+        ``emit=True`` additionally writes an individual ``span`` event
+        (low-frequency side-bands only).
         """
+        self.span_names.add(name)
         if not self.enabled:
-            yield
+            with trace_annotation(name):
+                yield
             return
         t0 = time.perf_counter()
         try:
-            yield
+            with trace_annotation(name):
+                yield
         finally:
-            if block_on is not None:
-                import jax
-
-                jax.block_until_ready(block_on)
             dur = time.perf_counter() - t0
-            with self._lock:
-                st = self._phases.setdefault(name, [0, 0.0, 0.0])
-                st[0] += 1
-                st[1] += dur
-                st[2] = max(st[2], dur)
+            self.add_phase(name, dur)
             if emit:
                 self.event("span", name=name, dur_s=dur)
+
+    def add_phase(self, name: str, dur_s: float, count: int = 1) -> None:
+        """Add ``dur_s`` seconds over ``count`` calls to the ``name``
+        phase accumulator: a region the caller timed itself (the fenced
+        ``cadence`` span of SessionHooks)."""
+        if not self.enabled:
+            return
+        with self._lock:
+            st = self._phases.setdefault(name, [0, 0.0, 0.0])
+            st[0] += count
+            st[1] += dur_s
+            st[2] = max(st[2], dur_s)
 
     # -- causal trace exemplars (ISSUE 14) -----------------------------------
     def next_span_id(self) -> int:
@@ -1351,7 +1409,63 @@ def _performance_lines(s: dict) -> list[str]:
                     f", iters {p.get('start_iter')}-{p.get('end_iter')}"
                     if p.get("start_iter") is not None else ""
                 )
+                + (
+                    f", digest failed: {p['digest_error']}"
+                    if p.get("digest_error") else ""
+                )
             )
+        digests = [p for p in profiles if p.get("digest")]
+        if digests:
+            lines += _digest_lines(digests[-1])
+    return lines
+
+
+def _digest_lines(profile: dict) -> list[str]:
+    """The newest capture's digest (session/profile.py): device time per
+    phase per iteration with its share of busy, then device idle time by
+    the host span that covers it."""
+    d = profile["digest"]
+    lines = [
+        "  digest of iters {a}-{b}: {n} iteration(s) on {dev} device(s), "
+        "{tr:.1f} MB trace reduced in {ds:.1f} s; host spans seen: {hs}".format(
+            a=profile.get("start_iter"), b=profile.get("end_iter"),
+            n=d.get("steps", 0), dev=d.get("devices", 0),
+            tr=d.get("trace_bytes", 0) / 1e6, ds=d.get("digest_s", 0.0),
+            hs=", ".join(
+                f"{k} x{v}" for k, v in sorted((d.get("host_spans") or {}).items())
+            ) or "none",
+        )
+    ]
+    phases = d.get("phases")
+    if not phases:
+        lines.append("    (no device plane in the capture: no phase split)")
+        return lines
+    per_iter = 1e3 / max(int(d.get("steps", 0)), 1)
+    lines.append(
+        "    window {w:.2f} ms/iter, busy {b:.2f}, idle {i:.3f}".format(
+            w=d["window_s"] * per_iter, b=d["busy_s"] * per_iter,
+            i=d["idle_s"] * per_iter,
+        )
+    )
+    lines.append(
+        f"    {'phase':<16} {'ms/iter':>10} {'% busy':>7}  largest ops (ms/iter)"
+    )
+    for name, ph in sorted(
+        phases.items(), key=lambda kv: -kv[1]["ms_per_iter"]
+    ):
+        ops = ", ".join(f"{n} {ms:.2f}" for n, ms in ph.get("top_ops", []))
+        lines.append(
+            f"    {name:<16} {ph['ms_per_iter']:>10.3f} "
+            f"{100.0 * ph['share_of_busy']:>6.1f}%  {ops}"
+        )
+    idle = d.get("idle_by_span") or {}
+    if idle:
+        lines.append(
+            "    idle by span: " + ", ".join(
+                f"{k} {v * 1e3:.3f} ms"
+                for k, v in sorted(idle.items(), key=lambda kv: -kv[1])
+            )
+        )
     return lines
 
 

@@ -8,6 +8,7 @@ either work or are a bug.
 from __future__ import annotations
 
 import os
+import re
 
 from jax import shard_map  # noqa: F401 — call sites import it from here
 
@@ -33,10 +34,10 @@ def device_kind() -> str:
 # outside: one fixed, git-ignored directory next to the package. A
 # directory that moves never hits, so it is never built from a session
 # folder, a temporary name, a pid or the time.
-_CACHE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-    ".jax_cache",
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
+_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 _CACHE_COUNTS = {"hits": 0, "misses": 0}
 _CACHE_LISTENER_INSTALLED = False
@@ -90,6 +91,20 @@ def enable_compile_cache() -> str | None:
         os.makedirs(_CACHE_DIR, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # an op's phase is read from the op_name metadata of the COMPILED
+    # program (session/profile.py); without this an executable cached
+    # before a scope was added or moved comes back with the old names.
+    # The metadata then holds the op's own source line and not the stack
+    # of its callers, with the checkout's own path taken off, so that one
+    # program traced from two call sites (the cost accountant's lowering
+    # and the first dispatch, the CLI and the benchmark) or from a second
+    # checkout is still one cache entry
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
+    jax.config.update(
+        "jax_hlo_source_file_canonicalization_regex",
+        "^" + re.escape(_CHECKOUT) + "/",
+    )
     if not _CACHE_LISTENER_INSTALLED:
         jax.monitoring.register_event_listener(_count_cache_event)
         _CACHE_LISTENER_INSTALLED = True

@@ -1,0 +1,65 @@
+"""One vocabulary of phases for the ops of the fused programs.
+
+A phase is a name a performance change claims against: the profile digest
+(``session/profile.py``) sums device time per phase, ``diag`` renders it
+and the benchmark's ``phase_*_ms`` metrics read it. The names reach the
+device's ops through ``jax.named_scope`` — metadata in each op's
+``op_name`` path, nothing at run time — so a site is always inside jitted
+code and no host clock or fence enters it.
+
+    collect          rollout scan: act, env step, episode bookkeeping
+    prepare          PPO: obs filter, value forward, GAE, advantage norm
+    shuffle          PPO: per-epoch permutation, block layout, minibatch gather
+    sgd              PPO: loss, grad, optimizer apply (dp psums: sgd/psum)
+    finalize         PPO: beta adaptation, new state, metrics
+    replay_insert    ring insert (+ fresh priorities)
+    replay_sample    index draw (mass, search) and row gather
+    replay_priority  priority scatter after an update
+    update           off-policy learner update (DDPG ``learn``)
+
+PPO's programs use the first five, DDPG's ``collect`` and the last four:
+at most eight per algorithm, so a reader can hold a split in one line.
+An op outside every scope is ``unattributed``.
+"""
+
+from __future__ import annotations
+
+PHASES = (
+    "collect", "prepare", "shuffle", "sgd", "finalize",
+    "replay_insert", "replay_sample", "replay_priority", "update",
+)
+UNATTRIBUTED = "unattributed"
+_VOCABULARY = frozenset(PHASES)
+# transforms wrap a scope's name in the op_name path: jvp(sgd),
+# transpose(jvp(sgd)), vmap(collect). ``jit(...)`` names a function, never
+# a phase.
+_WRAPPERS = ("transpose(", "jvp(", "vmap(", "remat(", "checkpoint(")
+
+
+def phase(name: str):
+    """The ``jax.named_scope`` of phase ``name``: ``"collect"`` for a
+    top-level phase, ``"collect/act"`` for a part of one. A part enters
+    only its last segment (the site sits inside its phase's scope, where
+    the path already reads ``collect/.../act``). A name whose top level is
+    outside :data:`PHASES` is refused: one vocabulary, kept here."""
+    top, _, sub = name.partition("/")
+    if top not in _VOCABULARY or "/" in sub:
+        raise ValueError(
+            f"phase {name!r} is not in the vocabulary {PHASES} "
+            "(surreal_tpu/utils/phases.py): 'top' or 'top/part'"
+        )
+    import jax  # at trace time only: the digest and the CLI read names
+
+    return jax.named_scope(sub or top)
+
+
+def phase_of(op_name: str | None) -> str:
+    """The phase an op belongs to: the first vocabulary name among the
+    segments of its ``op_name`` path (``jit(train_iter)/collect/while/
+    body/act/tanh`` -> ``collect``), :data:`UNATTRIBUTED` without one."""
+    for segment in (op_name or "").split("/"):
+        while segment.startswith(_WRAPPERS) and segment.endswith(")"):
+            segment = segment[segment.index("(") + 1:-1]
+        if segment in _VOCABULARY:
+            return segment
+    return UNATTRIBUTED

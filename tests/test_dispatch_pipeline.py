@@ -193,6 +193,9 @@ def compile_cache_on(tmp_path, monkeypatch):
         for k in (
             "jax_enable_compilation_cache", "jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
+            "jax_compilation_cache_include_metadata_in_key",
+            "jax_traceback_in_locations_limit",
+            "jax_hlo_source_file_canonicalization_regex",
         )
     }
     jax.config.update("jax_enable_compilation_cache", True)
@@ -239,6 +242,19 @@ def test_compile_cache_env_var_wins_and_jax_switch_is_honoured(
     assert enable_compile_cache() == outside
     assert jax.config.jax_compilation_cache_dir == outside
     assert not os.path.exists(compile_cache_on)
+    # the profile digest reads phases from a compiled program's op names:
+    # they are part of the key (a cached executable never comes back with
+    # stale ones), and the callers' stack is not (one entry per program)
+    assert jax.config.jax_compilation_cache_include_metadata_in_key is True
+    assert jax.config.jax_traceback_in_locations_limit == 1
+    import re
+
+    import surreal_tpu
+
+    here = os.path.abspath(surreal_tpu.__file__)
+    assert re.sub(
+        jax.config.jax_hlo_source_file_canonicalization_regex, "", here
+    ) == "surreal_tpu/__init__.py"  # a second checkout has the same keys
     # JAX's own switch off (how conftest runs the suite): nothing changes
     jax.config.update("jax_enable_compilation_cache", False)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")

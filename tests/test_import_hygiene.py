@@ -85,7 +85,7 @@ def test_no_host_clocks_or_fences_in_jitted_step_modules():
     jitted step runs ONCE at compile and lies forever, and a
     ``jax.block_until_ready`` there serializes the async pipeline.
     Wall-clock measurement — the clock and the fence that ends the timed
-    region — belongs to utils/timer.py and session/telemetry.py, at phase
+    region — belongs to session/telemetry.py and launch/hooks.py, at phase
     boundaries only. The substring scan includes call parens so
     prose mentions in docstrings stay legal; the code itself must not
     call these."""
@@ -100,7 +100,7 @@ def test_no_host_clocks_or_fences_in_jitted_step_modules():
                     bad.append(f"{path.relative_to(_REPO_ROOT)}: {banned}")
     assert not bad, (
         "host clock / fence calls inside jitted-step modules "
-        "(move timing to utils/timer.py or session/telemetry.py):\n"
+        "(move timing to session/telemetry.py, at a phase boundary):\n"
         + "\n".join(bad)
     )
 

@@ -90,8 +90,10 @@ def test_resolve_peak_spec_override_and_table():
 
 def test_mfu_gauge_arithmetic_hand_computed(tmp_path):
     """The gauge formula against a hand-computed value: one program with
-    known flops/bytes, a phase window with known count/total_s, and an
-    exact peak override -> mfu and membw_util must match exactly."""
+    known flops/bytes, a phase window with known call count and fenced
+    ``cadence`` seconds, and an exact peak override -> mfu and membw_util
+    must match exactly. The program's own span times its dispatch only and
+    is no part of the denominator."""
     cfg = Config(
         perf=Config(peak_flops=1e9, peak_membw=1e8, memory_analysis=False)
     )
@@ -104,9 +106,12 @@ def test_mfu_gauge_arithmetic_hand_computed(tmp_path):
     # substitute exact numbers so the expectation is hand-computable
     acct._programs["prog"]["flops"] = 1e6
     acct._programs["prog"]["bytes_accessed"] = 5e5
-    window = {"train_iter": {"count": 4, "total_s": 0.5, "max_ms": 200.0}}
+    window = {
+        "train_iter": {"count": 4, "total_s": 0.002, "max_ms": 0.6},
+        "cadence": {"count": 4, "total_s": 0.5, "max_ms": 500.0},
+    }
     g = acct.gauges(window)
-    # 4 calls x 1e6 flops / 0.5 s = 8e6 flops/s; peak 1e9 -> mfu 0.008
+    # 4 calls x 1e6 flops / 0.5 fenced s = 8e6 flops/s; peak 1e9 -> 0.008
     assert g["perf/flops_per_s"] == pytest.approx(8e6)
     assert g["perf/mfu"] == pytest.approx(8e6 / 1e9)
     # 4 x 5e5 bytes / 0.5 s = 4e6 B/s; peak 1e8 -> 0.04
@@ -117,7 +122,11 @@ def test_mfu_gauge_arithmetic_hand_computed(tmp_path):
     g3 = acct.gauges(window)
     assert g3["perf/mfu"] == pytest.approx(3 * g["perf/mfu"])
     # phases the program doesn't own contribute nothing
-    assert acct.gauges({"other": {"count": 1, "total_s": 1.0}}) == {}
+    cadence = {"cadence": {"count": 1, "total_s": 1.0}}
+    assert acct.gauges({"other": {"count": 1, "total_s": 1.0}, **cadence}) == {}
+    # a window without a fenced cadence span (the first of a run) has no
+    # denominator: no gauge, rather than one over the dispatch time
+    assert acct.gauges({"train_iter": window["train_iter"]}) == {}
     assert acct.gauges({}) == {}
     assert acct.gauges(None) == {}
 
@@ -129,7 +138,10 @@ def test_gauges_without_peak_spec_still_report_flops():
         "name": "p", "phase": "learn", "calls_per_phase": 1,
         "flops": 2e6, "bytes_accessed": 1e6, "arithmetic_intensity": 2.0,
     }
-    g = acct.gauges({"learn": {"count": 2, "total_s": 1.0}})
+    g = acct.gauges({
+        "learn": {"count": 2, "total_s": 0.1},
+        "cadence": {"count": 2, "total_s": 1.0},
+    })
     assert g["perf/flops_per_s"] == pytest.approx(4e6)
     assert "perf/mfu" not in g and "perf/membw_util" not in g
 
@@ -150,7 +162,10 @@ def test_every_registry_gauge_emittable():
         "name": "p", "phase": "x", "calls_per_phase": 1,
         "flops": 1e6, "bytes_accessed": 1e6, "arithmetic_intensity": 1.0,
     }
-    g = acct.gauges({"x": {"count": 1, "total_s": 1.0}})
+    g = acct.gauges({
+        "x": {"count": 1, "total_s": 1.0},
+        "cadence": {"count": 1, "total_s": 1.0},
+    })
     assert set(g) == {k for k in GAUGE_REGISTRY if k.startswith("perf/")}
 
 
@@ -445,7 +460,6 @@ def test_slow_iteration_auto_trigger(tmp_path, monkeypatch):
     cfg = Config(
         profile=Config(slow_iter_factor=3.0, num_iters=1, max_auto_captures=1,
                        trigger_file=False),
-        profiler=Config(enabled=False),
     )
     sink = Sink()
     pm = ProfileManager(cfg, str(tmp_path), sink, Log())

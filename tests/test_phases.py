@@ -1,0 +1,200 @@
+"""One vocabulary of phases (utils/phases.py) in three places: the op_name
+metadata of the compiled fused programs, the program's spans and the loop
+engine's steps on a profile's host plane, and the digest of a triggered
+capture that ``diag`` renders."""
+
+import json
+import os
+import re
+import time
+
+import jax
+import pytest
+
+from surreal_tpu.session.config import Config
+from surreal_tpu.session.default_configs import base_config
+from surreal_tpu.session.profile import hlo_op_phases, write_trigger
+from surreal_tpu.session.telemetry import Tracer, diag_report, diag_summary
+from surreal_tpu.utils.phases import PHASES, UNATTRIBUTED, phase, phase_of
+
+PPO_PHASES = ("collect", "prepare", "shuffle", "sgd", "finalize")
+DDPG_PHASES = (
+    "collect", "replay_insert", "replay_sample", "replay_priority", "update",
+)
+# what one Tracer.span may cost with no profile active, per call, on a
+# sandbox core shared with five other test workers (measured alone: 2.3 us,
+# of which the annotation is 0.4)
+SPAN_BUDGET_US = 25.0
+
+
+def _config(algo: str, folder: str, iters: int = 12) -> Config:
+    if algo == "ppo":
+        learner = Config(algo=Config(name="ppo", horizon=8))
+        steps = 8 * 8 * iters
+    else:
+        learner = Config(
+            algo=Config(name="ddpg", horizon=4, updates_per_iter=2),
+            replay=Config(kind="prioritized", capacity=256, batch_size=16,
+                          start_sample_size=16),
+        )
+        steps = 4 * 8 * iters
+    return Config(
+        learner_config=learner,
+        env_config=Config(name="jax:lift", num_envs=8),
+        session_config=Config(
+            folder=folder, total_env_steps=steps,
+            metrics=Config(every_n_iters=2, tensorboard=False, console=False),
+            checkpoint=Config(every_n_iters=0),
+            eval=Config(every_n_iters=0),
+        ),
+    ).extend(base_config())
+
+
+def _compiled_text(algo: str) -> str:
+    """The fused iteration of ``algo`` at toy sizes, compiled: its HLO text."""
+    import jax.numpy as jnp
+
+    key = jax.random.key(0)
+    if algo == "ppo":
+        from surreal_tpu.launch.trainer import Trainer
+
+        trainer = Trainer(_config("ppo", "unused"))
+        args = (trainer.learner.init(key), trainer.init_loop_state(key), key)
+    else:
+        from surreal_tpu.launch.offpolicy_trainer import OffPolicyTrainer
+
+        trainer = OffPolicyTrainer(_config("ddpg", "unused"))
+        carry, replay_state = trainer.init_loop_state(key)
+        args = (
+            trainer.learner.init(key), replay_state, carry, key,
+            jnp.float32(0.4), jnp.asarray(False), jnp.asarray(True),
+        )
+    return trainer._train_iter.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("name", ["warmup", "collect/act/inner", "", "Collect"])
+def test_a_name_outside_the_vocabulary_is_refused(name):
+    with pytest.raises(ValueError, match="vocabulary"):
+        phase(name)
+
+
+def test_vocabulary_is_small_per_algorithm():
+    assert set(PPO_PHASES) | set(DDPG_PHASES) == set(PHASES)
+    assert len(PPO_PHASES) <= 8 and len(DDPG_PHASES) <= 8
+    assert UNATTRIBUTED not in PHASES
+    with phase("collect"), phase("collect/act"):
+        pass
+
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(train_iter)/collect/while/body/closed_call/act/tanh", "collect"),
+    ("jit(f)/while/body/sgd/transpose(jvp(sgd))/dot_general", "sgd"),
+    ("jit(f)/transpose(jvp(sgd))/mul", "sgd"),
+    ("jit(f)/vmap(replay_sample)/gather", "replay_sample"),
+    ("jit(update)/add", UNATTRIBUTED),       # a function's name is no phase
+    ("jit(f)/collector/add", UNATTRIBUTED),  # whole segments only
+    ("jit(f)/replay_insert/replay_insert/scatter", "replay_insert"),
+    ("", UNATTRIBUTED),
+    (None, UNATTRIBUTED),
+])
+def test_phase_of_takes_the_first_vocabulary_segment(op_name, expected):
+    assert phase_of(op_name) == expected
+
+
+@pytest.mark.parametrize(
+    "algo,phases", [("ppo", PPO_PHASES), ("ddpg", DDPG_PHASES)]
+)
+def test_every_phase_names_ops_of_the_compiled_fused_program(algo, phases):
+    text = _compiled_text(algo)
+    seen = {phase_of(n) for n in re.findall(r'op_name="([^"]*)"', text)}
+    assert set(phases) <= seen, set(phases) - seen
+    # and nothing of the other algorithm's
+    assert seen - {UNATTRIBUTED} <= set(phases), seen
+    module, ops = hlo_op_phases(text)
+    assert module.startswith("jit_")
+    assert set(phases) <= set(ops.values())
+    # parts nest inside their phase in the op's path
+    if algo == "ppo":
+        assert re.search(r'op_name="[^"]*/collect/[^"]*/act/', text)
+        assert re.search(r'op_name="[^"]*/prepare/[^"]*gae/', text)
+    else:
+        assert re.search(r'op_name="[^"]*/replay_sample/mass/', text)
+
+
+def test_span_stays_within_its_budget_with_no_profile_active(tmp_path):
+    calls = 5000
+    for tracer in (Tracer(str(tmp_path)), Tracer(None, enabled=False)):
+        with tracer.span("warm"):
+            pass
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                with tracer.span("x"):
+                    pass
+            best = min(best, (time.perf_counter() - t0) / calls)
+        assert best * 1e6 < SPAN_BUDGET_US, best
+        assert "x" in tracer.span_names  # a disabled tracer annotates too
+        tracer.close()
+
+
+@pytest.fixture(scope="module")
+def captured(tmp_path_factory):
+    """One toy fused PPO run whose trigger file is there from the start:
+    a capture of 3 iterations, reduced on close."""
+    from surreal_tpu.launch.trainer import Trainer
+
+    folder = str(tmp_path_factory.mktemp("captured"))
+    write_trigger(folder, num_iters=3)
+    Trainer(_config("ppo", folder)).run()
+    return folder
+
+
+def test_triggered_capture_leaves_a_digest(captured):
+    profiles = diag_summary(captured)["profiles"]
+    assert len(profiles) == 1 and "digest_error" not in profiles[0]
+    event, digest = profiles[0], profiles[0]["digest"]
+    assert event["reason"] == "trigger_file"
+    # fenced at both ends: the window holds exactly the iterations between
+    assert digest["steps"] == event["end_iter"] - event["start_iter"] == 3
+    spans = digest["host_spans"]
+    # the pass that stops the capture is still open when it stops
+    assert spans["iteration"] >= 2 and spans["engine.step"] == 3
+    assert spans["engine.boundary"] >= 2 and spans["train_iter"] == 3
+    assert spans["metrics-sync"] >= 1
+    assert digest["trace_bytes"] > 0 and digest["digest_s"] > 0
+    # the CPU has no device plane: no phase split, and it says so
+    assert digest["devices"] == 0 and "phases" not in digest
+    # the event survives a round trip through the log as JSON
+    assert json.loads(json.dumps(event)) == event
+    assert not os.path.exists(os.path.join(captured, "profile.trigger"))
+
+
+def test_diag_renders_the_digest(captured):
+    report = diag_report(captured)
+    assert "digest of iters" in report and "3 iteration(s)" in report
+    assert "engine.step x3" in report and "metrics-sync" in report
+    assert "no device plane" in report
+    # the fenced span is in the phase table, beside the dispatch span
+    assert re.search(r"^\s+cadence\s+\d+", report, re.M)
+
+
+def test_diag_renders_a_device_digest(tmp_path):
+    """With a device plane (hand-built here) diag prints one line per
+    phase and the idle time by span."""
+    from surreal_tpu.session.profile import reduce_digest
+
+    ops = [(0, 600, "while", "collect"), (100, 500, "fusion.1 f32[8]", "sgd"),
+           (800, 1000, "copy.2", UNATTRIBUTED)]
+    digest = reduce_digest(
+        {"/device:TPU:0": ops}, [(550, 900, "metrics-sync")], steps=2
+    )
+    digest.update(host_spans={"iteration": 2}, trace_bytes=10, digest_s=0.1)
+    tracer = Tracer(str(tmp_path))
+    tracer.event("profile", dir="d", reason="trigger_file", start_iter=4,
+                 end_iter=6, digest=digest)
+    tracer.close()
+    report = diag_report(str(tmp_path))
+    assert re.search(r"sgd\s+0\.000\s+50\.0%\s+fusion\.1 f32\[8\]", report)
+    assert re.search(r"unattributed\s+0\.000\s+25\.0%", report)
+    assert "idle by span: metrics-sync" in report

@@ -347,14 +347,17 @@ def test_evaluator_records_video(tmp_path):
 
 @pytest.mark.slow
 def test_profiler_trace_window_writes_profile(tmp_path):
-    """SURVEY §5.1: the session-config profiler hook must capture a
-    jax.profiler trace window around the configured iterations and leave
-    the TensorBoard profile artifacts under <folder>/telemetry/profiles/
-    (the on-demand profiling layer's unified capture location —
-    session/profile.py folds the legacy window into it)."""
+    """SURVEY §5.1: a session must be able to capture a jax.profiler
+    trace window around chosen iterations and leave the TensorBoard
+    profile artifacts under <folder>/telemetry/profiles/. The trigger file
+    (`surreal_tpu profile <folder>`) asks for it; the fixed
+    `session.profiler` window went with ISSUE 25."""
     from surreal_tpu.launch.trainer import Trainer
+    from surreal_tpu.session.profile import write_trigger
 
     folder = str(tmp_path / "prof_run")
+    os.makedirs(folder)
+    write_trigger(folder, num_iters=2)
     cfg = Config(
         learner_config=Config(algo=Config(name="ppo", horizon=8)),
         env_config=Config(name="jax:cartpole", num_envs=8),
@@ -364,7 +367,6 @@ def test_profiler_trace_window_writes_profile(tmp_path):
             metrics=Config(every_n_iters=1, tensorboard=False, console=False),
             checkpoint=Config(every_n_iters=0),
             eval=Config(every_n_iters=0),
-            profiler=Config(enabled=True, start_iter=2, num_iters=2),
         ),
     ).extend(base_config())
     Trainer(cfg).run()

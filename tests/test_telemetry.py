@@ -299,16 +299,23 @@ def test_perf_gauges_add_no_syncs_beyond_metrics(tmp_path):
         assert "train_iter" in hooks.costs.programs
         hooks.begin_run(0, 0)
         steps_per_iter = trainer.horizon * trainer.num_envs
-        key, it_key, hk_key = jax.random.split(key, 3)
-        with hooks.tracer.span("train_iter"):
-            state, carry, metrics = trainer._train_iter(state, carry, it_key)
-        # the caller's one sync: host floats BEFORE the guard window
-        host_metrics_row = {k: float(v) for k, v in metrics.items()}
-        with jax.transfer_guard_device_to_host("disallow"):
-            m, _ = hooks.end_iteration(
-                1, steps_per_iter, state, hk_key, host_metrics_row, None
-            )
-        assert m is not None
+        # the gauges divide by the fenced `cadence` span, which runs from
+        # one metrics sync to the next: the first window has none yet
+        for it in (1, 2):
+            key, it_key, hk_key = jax.random.split(key, 3)
+            with hooks.tracer.span("train_iter"):
+                state, carry, metrics = trainer._train_iter(
+                    state, carry, it_key
+                )
+            # the caller's one sync: host floats BEFORE the guard window
+            host_metrics_row = {k: float(v) for k, v in metrics.items()}
+            with jax.transfer_guard_device_to_host("disallow"):
+                m, _ = hooks.end_iteration(
+                    it, it * steps_per_iter, state, hk_key,
+                    host_metrics_row, None,
+                )
+            assert m is not None
+            assert ("perf/mfu" in m) == (it == 2), sorted(m)
         assert "perf/mfu" in m and "perf/membw_util" in m, sorted(m)
         assert 0.0 < m["perf/mfu"] < 1.0
         # the ops-plane snapshot (ISSUE 13) rode the SAME guarded
