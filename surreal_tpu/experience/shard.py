@@ -129,12 +129,15 @@ class HostRing:
 
 
 class HostPrioritized(HostRing):
-    """Prioritized mirror (Schaul et al. 2016 via the repo's no-sum-tree
-    cumsum+searchsorted design). Float32 throughout like the device
-    implementation; np vs jnp reduction order makes the cdf differ by
-    ulps, so cross-implementation equivalence is *convergence within
-    tolerance*, not bit-equality (tests/test_experience.py documents the
-    budget)."""
+    """Prioritized twin on the host (Schaul et al. 2016, no sum-tree): the
+    same stratified proportional draw as the device's
+    (replay/prioritized.py), but float32 throughout and by a flat
+    cumsum+searchsorted, where the device goes in two levels (block sums,
+    then one block) and carries the block level in double-float. The two
+    add the mass up in different orders and to different precision, so the
+    cdfs differ by ulps and cross-implementation equivalence is
+    *convergence within tolerance*, not bit-equality
+    (tests/test_experience.py documents the budget)."""
 
     def __init__(self, spec, capacity, alpha=0.6, beta0=0.4, eps=1e-6):
         super().__init__(spec, capacity)
@@ -154,7 +157,8 @@ class HostPrioritized(HostRing):
         priority state (exactly what the remote contract already implies:
         an iteration's priority refresh lands as one batched frame AFTER
         its learns) — the stratifying uniforms come from one vmapped
-        draw, the cdf math is float32 numpy mirroring the device form."""
+        draw, the cdf math is a flat float32 numpy cumsum (the device's
+        draw is the two-level one; see the class docstring)."""
         jax = _jax()
         beta = np.float32(self.beta0 if beta is None else beta)
         p = self.priorities ** self.alpha

@@ -11,8 +11,10 @@ program's dataflow; under a dp mesh each device owns a shard of the buffer
 (the reference's ShardedReplay, for free, see replay/sharded.py).
 
 All buffers store flat transition dicts: {k: [capacity, ...]} with a write
-cursor and size. Insertion is vectorized (a whole [N, ...] batch lands in
-one ``dynamic_update_slice``-style scatter).
+cursor and size. Insertion is vectorized: a whole [N, ...] batch lands in
+one scatter per leaf. It is a row scatter, not a ``dynamic_update_slice``:
+on the TPU v5e it costs about 105 ns per row and leaf, 362 ms for the
+1 048 576 rows a fused DDPG iteration inserts (PERF.md section 5).
 """
 
 from __future__ import annotations

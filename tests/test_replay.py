@@ -117,6 +117,317 @@ def test_prioritized_fresh_inserts_get_max_priority():
     np.testing.assert_allclose(np.asarray(state.priorities[4:6]), float(state.max_priority))
 
 
+def _ddpg_ref():
+    """The benchmark's float64 reference of the draw (benchmarks/reference/
+    ddpg_ref.py), with the tolerances the chip run is held to."""
+    from benchmarks.harness import manifest
+
+    return manifest.load_reference("ddpg_ref")
+
+
+def _abs_normal(capacity, filled=None, seed=0):
+    rng = np.random.default_rng(seed)
+    p = np.abs(rng.standard_normal(capacity)).astype(np.float32) + 1e-6
+    p[capacity if filled is None else filled:] = 0.0
+    return p
+
+
+def _case(priorities, alpha=0.6, uniforms=None):
+    """A ring's priorities (its size is the number of non-empty slots), the
+    exponent, and uniforms to draw with in place of the key's."""
+    return dict(priorities=priorities, alpha=alpha, uniforms=uniforms)
+
+
+def _one_hot(capacity, slot, value=3.0):
+    p = np.zeros(capacity, np.float32)
+    p[slot] = value
+    return p
+
+
+def _zero_runs(capacity):
+    """Runs of empty slots that start, end and lie across block edges."""
+    p = _abs_normal(capacity, seed=5)
+    for lo, hi in ((0, 3), (100, 300), (383, 385), (512, 640), (1000, 1024)):
+        p[lo:hi] = 0.0
+    return p
+
+
+def _wide_range(capacity):
+    rng = np.random.default_rng(6)
+    return (10.0 ** rng.uniform(-6.0, 3.0, capacity)).astype(np.float32)
+
+
+def _crafted(priorities, uniforms):
+    """alpha = 1 and powers of two, so that float32 and the float64
+    reference hold the same sums and a draw can be put ON an edge."""
+    return _case(
+        np.asarray(priorities, np.float32), alpha=1.0,
+        uniforms=np.broadcast_to(np.float32(uniforms), (256,)),
+    )
+
+
+TOP = np.float32(1.0 - 2.0**-24)  # the largest uniform: u_255 is the total
+DRAW_CASES = {
+    "capacity_8": _case(_abs_normal(8)),
+    "capacity_64": _case(_abs_normal(64)),
+    "capacity_1000": _case(_abs_normal(1000)),            # padded last block
+    "capacity_5120": _case(_abs_normal(5120)),            # the rehearsal's
+    "capacity_50000": _case(_abs_normal(50000)),
+    "capacity_65536_filled_40000": _case(_abs_normal(65536, 40000)),  # the check's ring
+    "one_nonzero_slot": _case(_one_hot(1000, 517)),
+    "mass_in_last_slot_of_a_block": _case(_one_hot(1024, 383)),
+    "mass_in_last_slot_of_the_ring": _case(_one_hot(1000, 999)),
+    "zero_runs_across_block_edges": _case(_zero_runs(1024)),
+    "priorities_1e-6_to_1e3": _case(_wide_range(5120)),
+    # block 0 sums to 2^23, block 1 holds 0.7 in its first slot: the block
+    # cdf ends at fl(2^23 + 0.7) = 2^23 + 1, so the top draw's residual is
+    # 1 > 0.7 and, unclamped, falls behind the block's last slot with mass
+    "residual_rounds_past_the_block_sum": _crafted(
+        [2.0**16] * 128 + [0.7] + [0.0] * 127, [0.5] * 255 + [TOP]
+    ),
+    # u_k = k * 2^10 = cdf[k - 1]: left search gives slot k - 1, u_0 slot 0
+    "draws_on_slot_edges": _crafted([2.0**10] * 256, 0.0),
+    # u_k = k * 2^10 = block_cdf[k - 1]: block k - 1, its last slot
+    "draws_on_block_edges": _crafted([8.0] * 32768, 0.0),
+}
+
+
+@pytest.mark.parametrize("shape,axis", [((163840,), 0), ((7813,), 0), ((256, 128), 1)])
+def test_mass_cdf_is_monotone_and_flat_over_zeros(shape, axis):
+    """What the draw's left searches lean on, whatever order the backend's
+    scan adds in (the CPU's plain ``cumsum`` has neither property at the
+    first two shapes): the cdf never falls, and does not move over an entry
+    without mass, so a search cannot stop on one."""
+    from surreal_tpu.replay.prioritized import mass_cdf
+
+    rng = np.random.default_rng(3)
+    p = np.abs(rng.standard_normal(shape)).astype(np.float32)
+    p[rng.random(shape) < 0.3] = 0.0
+    p[..., shape[-1] // 2:shape[-1] // 2 + 40] = 0.0
+    p[..., -shape[-1] // 8:] = 0.0       # the empty tail of a filling ring
+    cdf = np.asarray(jax.jit(lambda x: mass_cdf(x, axis=axis))(p), np.float64)
+    step = np.diff(cdf, axis=axis)
+    assert (step >= 0).all()
+    assert (step[np.take(p, np.arange(1, shape[axis]), axis=axis) == 0] == 0).all()
+    want = np.cumsum(p.astype(np.float64), axis=axis)
+    np.testing.assert_allclose(cdf, want, rtol=0, atol=2e-6 * want.max())
+
+
+def test_double_float_sum_and_product_are_exact():
+    """The block level's arithmetic (``replay/prioritized.py``): a sum and
+    a product of two float32 come back as the rounded result and exactly
+    what the rounding dropped."""
+    from surreal_tpu.replay.prioritized import _two_prod, _two_sum
+
+    rng = np.random.default_rng(5)
+    a = (rng.standard_normal(4096) * 10.0 ** rng.uniform(-6, 6, 4096)).astype(np.float32)
+    b = (rng.standard_normal(4096) * 10.0 ** rng.uniform(-6, 6, 4096)).astype(np.float32)
+    a[:3], b[:3] = (0.0, 1.0, 3.0), (0.0, 0.0, 2.0**-24)
+    a64, b64 = a.astype(np.float64), b.astype(np.float64)
+    for op, want in ((_two_sum, a64 + b64), (_two_prod, a64 * b64)):
+        hi, lo = (np.asarray(x) for x in jax.jit(op)(a, b))
+        assert hi.dtype == lo.dtype == np.float32
+        np.testing.assert_array_equal(hi, want.astype(np.float32))
+        np.testing.assert_array_equal(hi.astype(np.float64) + lo, want)
+
+
+@pytest.mark.parametrize("n", [1, 512, 7813, 163840])
+def test_double_float_cumsum_matches_float64(n):
+    """``_dd_cumsum`` over block sums with empty runs: hi + lo is the
+    float64 cumulative sum to 2^-40 of the total, where a float32
+    ``cumsum`` stops at 2^-23; hi alone is that sum rounded."""
+    from surreal_tpu.replay.prioritized import _dd_cumsum
+
+    rng = np.random.default_rng(7)
+    x = (np.abs(rng.standard_normal((n, 128))) ** 0.6).sum(1).astype(np.float32)
+    x[n // 3:n // 3 + 100] = 0.0
+    x[::7] = 0.0
+    want = np.cumsum(x.astype(np.float64))
+    hi, lo = (np.asarray(c) for c in jax.jit(_dd_cumsum)(x))
+    assert hi.dtype == lo.dtype == np.float32
+    total = max(want[-1], 1.0)
+    assert np.abs(hi.astype(np.float64) + lo - want).max() <= 2.0**-40 * total
+    assert np.abs(hi - want).max() <= 2.0**-24 * total
+    zeros = np.zeros(n, np.float32)  # an empty ring
+    assert all((np.asarray(c) == 0).all() for c in _dd_cumsum(zeros))
+
+
+@pytest.mark.parametrize("batch_size", [64, 256])
+def test_prioritized_draw_is_the_float64_draw_on_the_checks_ring(batch_size):
+    """On the reference check's ring (40 000 rows in 65 536 slots) the total
+    mass is about 3e4 and its float32 ulp 1/500 of a slot's mass: with the
+    draw's position and the block cdf in float32, 4 of these 2048 and 13 of
+    these 8192 draws fell in the slot next to the float64 reference's, and
+    when such a draw is the batch's rarest every max-normalised IS weight
+    moves with it (`sample/is_weights` of the benchmark's check). With the
+    block level in double-float none does."""
+    ref = _ddpg_ref()
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=65536, batch_size=batch_size, start_sample_size=1,
+    ))
+    sample = jax.jit(lambda s, k: replay.sample(s, k, beta=0.4)[2]["idx"])
+    for seed in range(32):
+        priorities = _abs_normal(65536, 40000, seed=seed)
+        key = jax.random.key(100 + seed)
+        idx = sample(_prioritized_state(replay, priorities), key)
+        want, _ = ref.prioritized_draw(
+            priorities, jax.random.uniform(key, (batch_size,)), 40000,
+            replay.alpha, 0.4,
+        )
+        np.testing.assert_array_equal(np.asarray(idx), want)
+
+
+def _prioritized_state(replay, priorities):
+    state = replay.init({"x": jnp.zeros((), jnp.float32)})
+    size = int(np.count_nonzero(priorities))
+    return state._replace(
+        ring=state.ring._replace(size=jnp.asarray(size, jnp.int32)),
+        priorities=jnp.asarray(priorities),
+        max_priority=jnp.asarray(priorities.max()),
+    )
+
+
+def _check_draw(ref, priorities, size, alpha, beta, key, idx, weights):
+    """One draw against the float64 cumulative sum, by ddpg_ref's bounds."""
+    idx, weights = np.asarray(idx), np.asarray(weights)
+    uniforms = jax.random.uniform(key, idx.shape)
+    assert (np.asarray(priorities)[idx] > 0).all(), "a slot of zero mass was drawn"
+    ref_idx, ref_w = ref.prioritized_draw(priorities, uniforms, size, alpha, beta)
+    same = idx == ref_idx
+    assert 1.0 - same.mean() <= ref.MAX_INDEX_MISMATCH
+    mass_err, _ = ref.draw_mass_error(priorities, alpha, idx, uniforms)
+    assert mass_err <= ref.DRAW_MASS_TOL
+    np.testing.assert_allclose(
+        weights[same], ref_w[same], **ref.TOL["sample/is_weights"]
+    )
+    # the batch's largest weight is x / x: 1 on the CPU, one ulp under it on
+    # the TPU (PERF.md section 6)
+    assert 1.0 - 2.0**-23 <= weights.max() <= 1.0
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("case", DRAW_CASES)
+def test_prioritized_draw_matches_float64_reference(case, jit, monkeypatch):
+    """The two-level draw (block sums, then one block) lands where the flat
+    float64 cumulative sum of the benchmark's reference puts it: never on a
+    slot without mass (an empty slot, the padding, or slot 127 of a block
+    because the residual rounded past the block's sum), left-searched at
+    both levels, at capacities that are and are not multiples of the block."""
+    ref = _ddpg_ref()
+    case = DRAW_CASES[case]
+    priorities = case["priorities"]
+    if case["uniforms"] is not None:  # the replay's and the reference's
+        monkeypatch.setattr(
+            jax.random, "uniform", lambda key, shape: jnp.asarray(case["uniforms"])
+        )
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=len(priorities), batch_size=256,
+        start_sample_size=1, priority_alpha=case["alpha"],
+    ))
+    state = _prioritized_state(replay, priorities)
+    beta = 0.4
+    sample = lambda s, k: replay.sample(s, k, beta=beta)[2]
+    if jit:
+        sample = jax.jit(sample)
+    for key in jax.random.split(jax.random.key(11), 3):
+        info = sample(state, key)
+        assert info["idx"].dtype == jnp.int32
+        _check_draw(
+            ref, priorities, int(state.ring.size), replay.alpha, beta, key,
+            info["idx"], info["is_weights"],
+        )
+
+
+def test_prioritized_draw_in_scan_with_priority_updates():
+    """The fused DDPG iteration's loop: ``lax.scan`` under ``jit`` of sample
+    -> new priorities for the drawn slots -> the next sample. Every draw is
+    checked against the reference on the priorities it saw."""
+    ref = _ddpg_ref()
+    capacity, filled, steps, beta = 5120, 4000, 8, 0.5
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=capacity, batch_size=256, start_sample_size=1,
+    ))
+    state = _prioritized_state(replay, _abs_normal(capacity, filled, seed=9))
+    keys = jax.random.split(jax.random.key(12), steps)
+
+    def one_update(state, key):
+        seen = state.priorities
+        state, _, info = replay.sample(state, key, beta=beta)
+        td = jnp.abs(jnp.sin(info["idx"].astype(jnp.float32))) * 4.0
+        state = replay.update_priorities(state, info["idx"], td)
+        return state, (seen, info["idx"], info["is_weights"])
+
+    final, (seen, idx, weights) = jax.jit(
+        lambda s, ks: jax.lax.scan(one_update, s, ks)
+    )(state, keys)
+    assert not np.array_equal(np.asarray(seen[0]), np.asarray(seen[-1]))
+    assert (np.asarray(final.priorities)[filled:] == 0).all()
+    for k in range(steps):
+        _check_draw(
+            ref, np.asarray(seen[k]), filled, replay.alpha, beta, keys[k],
+            idx[k], weights[k],
+        )
+
+
+# capacity, rows of each successive insert
+INSERT_CASES = {
+    "40000_into_65536_from_slot_0": (65536, [40000]),   # the reference check's
+    "second_insert_wraps": (65536, [40000, 40000]),
+    "cursor_no_multiple_of_n": (1000, [7, 300, 300, 300, 300]),
+    "n_equals_capacity": (64, [10, 64]),
+    "n_1": (8, [1] * 11),
+    "cell_ratio_21_inserts": (5120, [256] * 21),        # n = capacity / 20
+}
+
+
+@pytest.mark.parametrize("prioritized", [False, True], ids=["ring", "prioritized"])
+@pytest.mark.parametrize("case", INSERT_CASES)
+def test_ring_insert_matches_numpy_ring(case, prioritized):
+    """``ring_insert`` and ``PrioritizedReplay.insert`` against a ring kept
+    in NumPy, row by row: rows land at ``(cursor + i) % capacity`` in
+    order, the oldest are evicted, cursor and size follow, and fresh slots
+    take the max priority of the moment. The guard for a block-write
+    replacement of the row scatter (ROADMAP S1)."""
+    from surreal_tpu.replay.base import init_ring, ring_insert
+
+    capacity, inserts = INSERT_CASES[case]
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=capacity, batch_size=4, start_sample_size=1,
+    ))
+    example = jax.tree.map(lambda x: x[0], trans(1))
+    state = replay.init(example) if prioritized else init_ring(example, capacity)
+    insert = jax.jit(
+        replay.insert if prioritized
+        else lambda s, rows: ring_insert(s, rows, capacity)
+    )
+    want = {k: np.zeros((capacity, *np.shape(v)), np.float32) for k, v in example.items()}
+    want_prio, max_prio = np.zeros(capacity, np.float32), np.float32(1.0)
+    cursor = size = base = 0
+    for n in inserts:
+        rows = trans(n, base=base)
+        state = insert(state, rows)
+        host_rows = jax.tree.map(np.asarray, rows)
+        for i in range(n):
+            slot = (cursor + i) % capacity
+            for k in want:
+                want[k][slot] = host_rows[k][i]
+            want_prio[slot] = max_prio
+        cursor, size, base = (cursor + n) % capacity, min(size + n, capacity), base + n
+        ring = state.ring if prioritized else state
+        assert (int(ring.cursor), int(ring.size)) == (cursor, size)
+        for k in want:
+            np.testing.assert_array_equal(np.asarray(ring.storage[k]), want[k])
+        if prioritized:
+            np.testing.assert_array_equal(np.asarray(state.priorities), want_prio)
+            # move the max, so that the next insert's priorities differ
+            newest = (cursor - 1) % capacity
+            state = replay.update_priorities(
+                state, jnp.asarray([newest]), jnp.asarray([max_prio + 1.5])
+            )
+            want_prio[newest] = max_prio = np.float32(state.max_priority)
+            assert max_prio > 1.5 and state.priorities[newest] == max_prio
+
+
 def test_sharded_replay_per_device_buffers():
     """Each dp shard owns an independent buffer: inserts inside shard_map
     land in per-device storage (the ShardedReplay capability)."""
@@ -159,10 +470,12 @@ def test_sharded_replay_per_device_buffers():
 
 @pytest.mark.slow
 def test_prioritized_sample_cost_at_1e6_capacity():
-    """VERDICT r1 weak #8: the cumsum+searchsorted sampler is O(capacity)
-    per call by design — measure it at config-③ scale (1e6 transitions,
-    64 updates/iter) so the trade is quantified, not assumed. The bound is
-    deliberately loose (CPU sim; TPU HBM is faster): 64 fused
+    """VERDICT r1 weak #8: the stateless sampler reads the whole priority
+    vector on every call by design (p^alpha reduced to block sums; see
+    replay/prioritized.py) — measure it at config-③ scale (1e6
+    transitions, 64 updates/iter) so the trade is quantified, not assumed.
+    The bound is a loose one for the CPU simulation and says nothing about
+    the chip, where PERF.md section 5 has the measured cost: 64 fused
     sample+update calls must stay under 2 s once compiled."""
     import time
 
