@@ -46,13 +46,14 @@ def _builtin_jax_envs():
     from surreal_tpu.envs.jax.lift import BlockLift
     from surreal_tpu.envs.jax.nut_assembly import NutAssembly
     from surreal_tpu.envs.jax.pixels import BlockLiftPixels, NutAssemblyPixels
-    from surreal_tpu.envs.jax.pong import Pong, PongSmall
+    from surreal_tpu.envs.jax.pong import Pong, Pong84, PongSmall
 
     _JAX_ENVS.setdefault("cartpole", CartPole)
     _JAX_ENVS.setdefault("pendulum", Pendulum)
     _JAX_ENVS.setdefault("lift", BlockLift)
     _JAX_ENVS.setdefault("pong", Pong)
     _JAX_ENVS.setdefault("pong16", PongSmall)
+    _JAX_ENVS.setdefault("pong84", Pong84)
     _JAX_ENVS.setdefault("nut", NutAssembly)
     _JAX_ENVS.setdefault("lift_pixels", BlockLiftPixels)
     _JAX_ENVS.setdefault("nut_pixels", NutAssemblyPixels)

@@ -203,7 +203,7 @@ def frame_renderer(env):
         import numpy as np
 
         def pong_frame(s):
-            f = np.asarray(s.prev_frame).repeat(4, axis=0).repeat(4, axis=1)
+            f = np.asarray(s.history[..., 0]).repeat(4, axis=0).repeat(4, axis=1)
             return np.stack([f] * 3, axis=-1)
 
         return pong_frame

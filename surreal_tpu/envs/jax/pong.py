@@ -19,10 +19,14 @@ Game (Atari-Pong-shaped):
   (AutoReset truncation) or when either side reaches 21
   (``info['score']`` tracks agent minus opponent).
 
-Observation: [42, 42, 2] uint8 pixels — channel 0 is the current frame
-(paddles + ball as bright blocks), channel 1 the previous frame, giving
-the CNN the motion information Atari setups get from frame-stacking
-(rendered in-env, so no host wrapper is needed on the device path).
+Observation: [res, res, stack] uint8 pixels — channel k is the frame k
+steps back (paddles + ball as bright blocks), giving the CNN the motion
+information Atari setups get from frame-stacking (rendered in-env, so no
+host wrapper is needed on the device path). ``jax:pong84`` is the
+published shape, [84, 84, 4] (Mnih et al. 2015: the input the Nature CNN
+was sized for, 3136 -> 512 at its dense layer); ``jax:pong`` is
+[42, 42, 2] and ``jax:pong16`` [16, 16, 2], the same game rendered
+smaller for runs and tests where the CNN's cost is in the way.
 """
 
 from __future__ import annotations
@@ -56,7 +60,7 @@ class PongState(NamedTuple):
     opp_y: jax.Array       # [] opponent paddle center
     agent_score: jax.Array # [] int32 points won by the agent
     opp_score: jax.Array   # [] int32 points won by the opponent
-    prev_frame: jax.Array  # [_RES, _RES] uint8
+    history: jax.Array     # [res, res, stack - 1] uint8, newest first
     key: jax.Array         # serve randomness
 
 
@@ -83,18 +87,24 @@ def _render(ball, agent_y, opp_y, res: int = _RES) -> jax.Array:
     return jnp.where(ball_px | agent_px | opp_px, 255, 0).astype(jnp.uint8)
 
 
+def _specs(res: int, stack: int) -> EnvSpecs:
+    return EnvSpecs(
+        obs=ArraySpec(shape=(res, res, stack), dtype=np.dtype(np.uint8), name="pixels"),
+        action=DiscreteSpec(shape=(), dtype=np.dtype(np.int32), name="action", n=3),
+    )
+
+
 class Pong(JaxEnv):
     max_episode_steps = 2048
     res = _RES  # render resolution; physics is resolution-independent
+    stack = 2   # frames in the observation; the state keeps stack - 1
 
-    specs = EnvSpecs(
-        obs=ArraySpec(shape=(_RES, _RES, 2), dtype=np.dtype(np.uint8), name="pixels"),
-        action=DiscreteSpec(shape=(), dtype=np.dtype(np.int32), name="action", n=3),
-    )
+    specs = _specs(res, stack)
 
     def reset(self, key: jax.Array):
         key, serve_key, side_key = jax.random.split(key, 3)
         ball, vel = _serve(serve_key, jax.random.bernoulli(side_key))
+        frame = _render(ball, 0.5, 0.5, self.res)
         state = PongState(
             ball=ball,
             vel=vel,
@@ -102,10 +112,11 @@ class Pong(JaxEnv):
             opp_y=jnp.asarray(0.5, jnp.float32),
             agent_score=jnp.zeros((), jnp.int32),
             opp_score=jnp.zeros((), jnp.int32),
-            prev_frame=_render(ball, 0.5, 0.5, self.res),
+            # before the first step every frame back is the first one
+            history=jnp.repeat(frame[..., None], self.stack - 1, axis=-1),
             key=key,
         )
-        return state, self._obs(state)
+        return state, jnp.repeat(frame[..., None], self.stack, axis=-1)
 
     def step(self, state: PongState, action: jax.Array):
         # paddles
@@ -163,6 +174,7 @@ class Pong(JaxEnv):
         vel = jnp.where(point, serve_vel, vel)
 
         frame = _render(ball, agent_y, opp_y, self.res)
+        obs = jnp.concatenate([frame[..., None], state.history], axis=-1)
         new_state = PongState(
             ball=ball,
             vel=vel,
@@ -170,28 +182,32 @@ class Pong(JaxEnv):
             opp_y=opp_y,
             agent_score=agent_score,
             opp_score=opp_score,
-            prev_frame=frame,
+            history=obs[..., :-1],
             key=key,
         )
         # like Atari Pong: game over when EITHER side reaches 21 points
         done = (agent_score >= _WIN_SCORE) | (opp_score >= _WIN_SCORE)
         info = {"score": agent_score - opp_score, "point": point}
-        obs = jnp.stack([frame, state.prev_frame], axis=-1)
         return new_state, obs, reward, done, info
-
-    @staticmethod
-    def _obs(state: PongState) -> jax.Array:
-        return jnp.stack([state.prev_frame, state.prev_frame], axis=-1)
 
 
 class PongSmall(Pong):
     """16x16 Pong (``jax:pong16``): the same court, physics, and opponent —
     resolution is render-only — at a size whose CNN forward is cheap enough
     for the CPU-sim suite to LEARN on (the in-suite pixel-learning guard,
-    round-3 VERDICT missing #5; the real-chip result stays the 42x42 env)."""
+    round-3 VERDICT missing #5; the real-chip result is ``jax:pong84``'s,
+    the published shape)."""
 
     res = 16
-    specs = EnvSpecs(
-        obs=ArraySpec(shape=(16, 16, 2), dtype=np.dtype(np.uint8), name="pixels"),
-        action=DiscreteSpec(shape=(), dtype=np.dtype(np.int32), name="action", n=3),
-    )
+    specs = _specs(res, Pong.stack)
+
+
+class Pong84(Pong):
+    """84x84x4 Pong (``jax:pong84``): the same court, physics and opponent
+    at the input the Nature CNN was published for (Mnih et al. 2015: 84 x
+    84 pixels, the last four frames), so the CNN's defaults give the
+    paper's 3136 -> 512 dense layer. What the benchmark's IMPALA cell runs."""
+
+    res = 84
+    stack = 4
+    specs = _specs(res, stack)

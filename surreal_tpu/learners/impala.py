@@ -9,6 +9,15 @@ One update per batch (no epochs/minibatches — IMPALA's design), so the
 whole learn is a single fused backward pass; V-trace is the reverse scan
 in ``ops/vtrace.py``. Shares the PPO batch contract, so the same Trainer
 and collectors drive it.
+
+Phases (``utils/phases.py``; with the rollout's ``collect`` that is four):
+``bootstrap`` is the value forward over ``next_obs`` in ``learn`` (it
+carries no gradient, so it runs outside the differentiated function);
+``vtrace`` is ``_vtrace`` inside ``loss_fn``; ``learn`` is the rest of
+``learn``: the obs filter, the forward over ``obs``, the three losses, the
+backward pass, the dp ``pmean`` (``learn/psum``), the optimizer apply, the
+new state and the metrics. A trajectory policy reads its bootstrap values
+from the one extended forward, so it has no ``bootstrap``.
 """
 
 from __future__ import annotations
@@ -34,6 +43,7 @@ from surreal_tpu.ops.precision import current_loss_scale, loss_scale_metrics
 from surreal_tpu.ops.running_stats import RunningStats, init_stats, normalize, update_stats
 from surreal_tpu.ops.vtrace import vtrace_nextobs, vtrace_nextobs_assoc
 from surreal_tpu.session.config import Config
+from surreal_tpu.utils.phases import phase
 
 IMPALA_LEARNER_CONFIG = Config(
     algo=Config(
@@ -153,12 +163,14 @@ class IMPALALearner(SequenceActingMixin, Learner):
 
         check_learn_batch(batch, self.specs, name="impala.learn")
         algo = self.config.algo
-        if self._use_obs_filter:
-            obs_stats = update_stats(state.obs_stats, batch["obs"], axis_name=axis_name)
-        else:
-            obs_stats = state.obs_stats
-        obs = self._norm_obs(obs_stats, batch["obs"])
-        next_obs = self._norm_obs(obs_stats, batch["next_obs"])
+        with phase("learn"):
+            if self._use_obs_filter:
+                obs_stats = update_stats(
+                    state.obs_stats, batch["obs"], axis_name=axis_name
+                )
+            else:
+                obs_stats = state.obs_stats
+            obs = self._norm_obs(obs_stats, batch["obs"])
 
         T = batch["reward"].shape[0]
         # precision: dynamic loss scale from the carried opt_state (1.0
@@ -166,81 +178,100 @@ class IMPALALearner(SequenceActingMixin, Learner):
         # divides the grads back down and skips overflowed steps
         loss_scale = current_loss_scale(state.opt_state)
 
-        def loss_fn(params):
-            if self.seq_policy:
-                # ONE extended [B, T+1] apply: per-position outputs
-                # conditioned causally on the segment prefix (exactly the
-                # conditioning act_step used during the rollout), with
-                # the V-trace bootstrap read from the shifted positions —
-                # same truncation-boundary caveat as PPO's _learn_seq
-                obs_bt = jnp.swapaxes(obs, 0, 1)
-                ext = jnp.concatenate([obs_bt, next_obs[-1][:, None]], axis=1)
-                out_ext = self.model.apply(params, ext)
-                out = jax.tree.map(
-                    lambda x: jnp.swapaxes(x[:, :T], 0, 1), out_ext
-                )
-                values = out.value
-                values_next = jnp.swapaxes(out_ext.value[:, 1:], 0, 1)
-            else:
-                out = self.model.apply(params, obs)
-                values = out.value
-                values_next = self.model.apply(params, next_obs).value
-            if self.discrete:
-                logp = D.categorical_logp(out.logits, batch["action"])
-                entropy = D.categorical_entropy(out.logits).mean()
-            else:
-                logp = D.diag_gauss_logp(out.mean, out.log_std, batch["action"])
-                entropy = D.diag_gauss_entropy(out.log_std).mean()
+        if self.seq_policy:
+            next_obs = self._norm_obs(obs_stats, batch["next_obs"])
+            boot_values = None
+        else:
+            # V(s'_t) enters V-trace as a constant: computed once, outside
+            # the differentiated function
+            with phase("bootstrap"):
+                boot_values = self.model.apply(
+                    state.params, self._norm_obs(obs_stats, batch["next_obs"])
+                ).value
 
-            vt = self._vtrace(
-                behaviour_logp=batch["behavior_logp"],
-                target_logp=jax.lax.stop_gradient(logp),
-                rewards=batch["reward"],
-                values=jax.lax.stop_gradient(values),
-                values_next=jax.lax.stop_gradient(values_next),
-                done=batch["done"],
-                terminated=batch["terminated"],
-            )
-            pg_loss = -(vt.pg_advantages * logp).mean()
-            v_loss = 0.5 * ((values - vt.vs) ** 2).mean()
-            total = pg_loss + algo.value_coeff * v_loss - algo.entropy_coeff * entropy
-            return total * loss_scale, {
-                "pg_loss": pg_loss,
-                "v_loss": v_loss,
-                "entropy": entropy,
-                "rho_mean": jnp.exp(
-                    jax.lax.stop_gradient(logp) - batch["behavior_logp"]
-                ).mean(),
-            }
+        def loss_fn(params):
+            with phase("learn"):
+                if self.seq_policy:
+                    # ONE extended [B, T+1] apply: per-position outputs
+                    # conditioned causally on the segment prefix (exactly the
+                    # conditioning act_step used during the rollout), with
+                    # the V-trace bootstrap read from the shifted positions —
+                    # same truncation-boundary caveat as PPO's _learn_seq
+                    obs_bt = jnp.swapaxes(obs, 0, 1)
+                    ext = jnp.concatenate([obs_bt, next_obs[-1][:, None]], axis=1)
+                    out_ext = self.model.apply(params, ext)
+                    out = jax.tree.map(
+                        lambda x: jnp.swapaxes(x[:, :T], 0, 1), out_ext
+                    )
+                    values = out.value
+                    values_next = jnp.swapaxes(out_ext.value[:, 1:], 0, 1)
+                else:
+                    out = self.model.apply(params, obs)
+                    values = out.value
+                    values_next = boot_values
+                if self.discrete:
+                    logp = D.categorical_logp(out.logits, batch["action"])
+                    entropy = D.categorical_entropy(out.logits).mean()
+                else:
+                    logp = D.diag_gauss_logp(out.mean, out.log_std, batch["action"])
+                    entropy = D.diag_gauss_entropy(out.log_std).mean()
+
+            with phase("vtrace"):
+                vt = self._vtrace(
+                    behaviour_logp=batch["behavior_logp"],
+                    target_logp=jax.lax.stop_gradient(logp),
+                    rewards=batch["reward"],
+                    values=jax.lax.stop_gradient(values),
+                    values_next=jax.lax.stop_gradient(values_next),
+                    done=batch["done"],
+                    terminated=batch["terminated"],
+                )
+            with phase("learn"):
+                pg_loss = -(vt.pg_advantages * logp).mean()
+                v_loss = 0.5 * ((values - vt.vs) ** 2).mean()
+                total = (
+                    pg_loss + algo.value_coeff * v_loss
+                    - algo.entropy_coeff * entropy
+                )
+                return total * loss_scale, {
+                    "pg_loss": pg_loss,
+                    "v_loss": v_loss,
+                    "entropy": entropy,
+                    "rho_mean": jnp.exp(
+                        jax.lax.stop_gradient(logp) - batch["behavior_logp"]
+                    ).mean(),
+                }
 
         grads, aux = jax.grad(loss_fn, has_aux=True)(state.params)
-        if axis_name is not None:
-            grads = jax.lax.pmean(grads, axis_name)
-            aux = jax.lax.pmean(aux, axis_name)
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with phase("learn"):
+            if axis_name is not None:
+                with phase("learn/psum"):
+                    grads = jax.lax.pmean(grads, axis_name)
+                    aux = jax.lax.pmean(aux, axis_name)
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
 
-        new_state = IMPALAState(
-            params=params,
-            opt_state=opt_state,
-            obs_stats=obs_stats,
-            iteration=state.iteration + 1,
-        )
-        metrics = {
-            "loss/pg": aux["pg_loss"],
-            "loss/value": aux["v_loss"],
-            "policy/entropy": aux["entropy"],
-            "policy/rho_mean": aux["rho_mean"],
-            # grads are already pmean'd, so the health scalars replicate;
-            # the norm is divided by the (power-of-two) loss scale so
-            # health thresholds see the true magnitude — inf/nan survive
-            **training_health(
-                state.params, params, optax.global_norm(grads) / loss_scale
-            ),
-            # precision: loss-scale telemetry (empty when the policy
-            # carries no scale)
-            **loss_scale_metrics(opt_state),
-        }
+            new_state = IMPALAState(
+                params=params,
+                opt_state=opt_state,
+                obs_stats=obs_stats,
+                iteration=state.iteration + 1,
+            )
+            metrics = {
+                "loss/pg": aux["pg_loss"],
+                "loss/value": aux["v_loss"],
+                "policy/entropy": aux["entropy"],
+                "policy/rho_mean": aux["rho_mean"],
+                # grads are already pmean'd, so the health scalars replicate;
+                # the norm is divided by the (power-of-two) loss scale so
+                # health thresholds see the true magnitude — inf/nan survive
+                **training_health(
+                    state.params, params, optax.global_norm(grads) / loss_scale
+                ),
+                # precision: loss-scale telemetry (empty when the policy
+                # carries no scale)
+                **loss_scale_metrics(opt_state),
+            }
         return new_state, metrics
 
     def _vtrace(self, **kw):

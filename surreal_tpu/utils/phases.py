@@ -16,9 +16,19 @@ code and no host clock or fence enters it.
     replay_sample    index draw (mass, search) and row gather
     replay_priority  priority scatter after an update
     update           off-policy learner update (DDPG ``learn``)
+    bootstrap        IMPALA: value forward over ``next_obs`` (no gradient)
+    vtrace           IMPALA: importance ratios, clips, reverse recurrence,
+                     pg advantages
+    learn            IMPALA: forward over ``obs``, the three losses, grad,
+                     optimizer apply, new state, metrics (dp pmean:
+                     learn/psum)
 
-PPO's programs use the first five, DDPG's ``collect`` and the last four:
-at most eight per algorithm, so a reader can hold a split in one line.
+    algorithm   its phases
+    PPO         collect prepare shuffle sgd finalize
+    DDPG        collect replay_insert replay_sample replay_priority update
+    IMPALA      collect bootstrap vtrace learn
+
+At most eight per algorithm, so a reader can hold a split in one line.
 An op outside every scope is ``unattributed``.
 """
 
@@ -27,6 +37,7 @@ from __future__ import annotations
 PHASES = (
     "collect", "prepare", "shuffle", "sgd", "finalize",
     "replay_insert", "replay_sample", "replay_priority", "update",
+    "bootstrap", "vtrace", "learn",
 )
 UNATTRIBUTED = "unattributed"
 _VOCABULARY = frozenset(PHASES)

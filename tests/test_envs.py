@@ -472,6 +472,61 @@ def test_pong_ball_stays_in_court_and_obs_carries_motion():
         prev = np.asarray(obs[..., 0])
 
 
+def test_pong84_is_the_published_shape_and_channel_k_is_k_steps_back():
+    """``jax:pong84``: [84, 84, 4] uint8 (Mnih et al. 2015), channel k the
+    frame k steps back; before the first step every channel is the first
+    frame."""
+    from surreal_tpu.envs.jax.pong import Pong84
+
+    wrapped = make_env(env_cfg(name="jax:pong84", num_envs=2))
+    assert is_jax_env(wrapped)
+    assert wrapped.specs.obs.shape == (84, 84, 4)
+    assert wrapped.specs.obs.dtype == np.uint8 and wrapped.specs.action.n == 3
+    env = Pong84()
+    state, obs = env.reset(jax.random.key(4))
+    assert obs.shape == (84, 84, 4) and obs.dtype == jnp.uint8
+    assert state.history.shape == (84, 84, 3)
+    frames = [np.asarray(obs[..., 0])]
+    for k in range(1, 4):
+        np.testing.assert_array_equal(np.asarray(obs[..., k]), frames[0])
+    step = jax.jit(env.step)
+    for t in range(1, 9):
+        state, obs, _, _, _ = step(state, jnp.asarray(t % 3, jnp.int32))
+        frames.append(np.asarray(obs[..., 0]))
+        for k in range(4):
+            np.testing.assert_array_equal(
+                np.asarray(obs[..., k]), frames[max(t - k, 0)]
+            )
+    # the ball moved: the stack carries motion, not four copies
+    assert (frames[-1] != frames[-4]).any()
+
+
+@pytest.mark.parametrize("name", ["jax:pong84", "jax:pong16"])
+def test_pong_renders_one_game_at_every_resolution(name):
+    """Resolution and stack depth are render-only: under the same keys and
+    actions the rewards, dones and scores equal ``jax:pong``'s step for
+    step, through points, re-serves and a time-limit reset."""
+    def play(env_name):
+        env = make_env(env_cfg(name=env_name, num_envs=4, time_limit=200))
+        state, _ = batch_reset(env, jax.random.split(jax.random.key(5), 4))
+
+        @jax.jit
+        def rollout(state, key):
+            def step(st, k):
+                actions = jax.random.randint(k, (4,), 0, 3)
+                st, _, rew, done, info = batch_step(env, st, actions)
+                return st, (rew, done, info["score"], info["truncated"])
+
+            return jax.lax.scan(step, state, jax.random.split(key, 450))[1]
+
+        return [np.asarray(x) for x in rollout(state, jax.random.key(6))]
+
+    want, got = play("jax:pong"), play(name)
+    for a, b in zip(want, got):
+        np.testing.assert_array_equal(a, b)
+    assert np.abs(want[0]).sum() > 0 and want[1].any()  # points and resets
+
+
 def test_impala_cnn_trains_on_pong():
     """Config-⑤ shape end-to-end on device: pixel obs -> NatureCNN -> IMPALA
     (V-trace) in the fused Trainer; two iterations, finite losses."""

@@ -21,6 +21,7 @@ PPO_PHASES = ("collect", "prepare", "shuffle", "sgd", "finalize")
 DDPG_PHASES = (
     "collect", "replay_insert", "replay_sample", "replay_priority", "update",
 )
+IMPALA_PHASES = ("collect", "bootstrap", "vtrace", "learn")
 # what one Tracer.span may cost with no profile active, per call, on a
 # sandbox core shared with five other test workers (measured alone: 2.3 us,
 # of which the annotation is 0.4)
@@ -28,8 +29,8 @@ SPAN_BUDGET_US = 25.0
 
 
 def _config(algo: str, folder: str, iters: int = 12) -> Config:
-    if algo == "ppo":
-        learner = Config(algo=Config(name="ppo", horizon=8))
+    if algo in ("ppo", "impala"):
+        learner = Config(algo=Config(name=algo, horizon=8))
         steps = 8 * 8 * iters
     else:
         learner = Config(
@@ -55,10 +56,10 @@ def _compiled_text(algo: str) -> str:
     import jax.numpy as jnp
 
     key = jax.random.key(0)
-    if algo == "ppo":
+    if algo in ("ppo", "impala"):
         from surreal_tpu.launch.trainer import Trainer
 
-        trainer = Trainer(_config("ppo", "unused"))
+        trainer = Trainer(_config(algo, "unused"))
         args = (trainer.learner.init(key), trainer.init_loop_state(key), key)
     else:
         from surreal_tpu.launch.offpolicy_trainer import OffPolicyTrainer
@@ -79,8 +80,8 @@ def test_a_name_outside_the_vocabulary_is_refused(name):
 
 
 def test_vocabulary_is_small_per_algorithm():
-    assert set(PPO_PHASES) | set(DDPG_PHASES) == set(PHASES)
-    assert len(PPO_PHASES) <= 8 and len(DDPG_PHASES) <= 8
+    assert set(PPO_PHASES) | set(DDPG_PHASES) | set(IMPALA_PHASES) == set(PHASES)
+    assert max(map(len, (PPO_PHASES, DDPG_PHASES, IMPALA_PHASES))) <= 8
     assert UNATTRIBUTED not in PHASES
     with phase("collect"), phase("collect/act"):
         pass
@@ -91,7 +92,11 @@ def test_vocabulary_is_small_per_algorithm():
     ("jit(f)/while/body/sgd/transpose(jvp(sgd))/dot_general", "sgd"),
     ("jit(f)/transpose(jvp(sgd))/mul", "sgd"),
     ("jit(f)/vmap(replay_sample)/gather", "replay_sample"),
+    ("jit(f)/transpose(jvp(learn))/conv_general_dilated", "learn"),
+    ("jit(f)/jvp(vtrace)/while/body/mul", "vtrace"),
+    ("jit(f)/learn/psum/psum", "learn"),
     ("jit(update)/add", UNATTRIBUTED),       # a function's name is no phase
+    ("jit(learn)/add", UNATTRIBUTED),
     ("jit(f)/collector/add", UNATTRIBUTED),  # whole segments only
     ("jit(f)/replay_insert/replay_insert/scatter", "replay_insert"),
     ("", UNATTRIBUTED),
@@ -102,7 +107,8 @@ def test_phase_of_takes_the_first_vocabulary_segment(op_name, expected):
 
 
 @pytest.mark.parametrize(
-    "algo,phases", [("ppo", PPO_PHASES), ("ddpg", DDPG_PHASES)]
+    "algo,phases",
+    [("ppo", PPO_PHASES), ("ddpg", DDPG_PHASES), ("impala", IMPALA_PHASES)],
 )
 def test_every_phase_names_ops_of_the_compiled_fused_program(algo, phases):
     text = _compiled_text(algo)
@@ -117,8 +123,17 @@ def test_every_phase_names_ops_of_the_compiled_fused_program(algo, phases):
     if algo == "ppo":
         assert re.search(r'op_name="[^"]*/collect/[^"]*/act/', text)
         assert re.search(r'op_name="[^"]*/prepare/[^"]*gae/', text)
-    else:
+    elif algo == "ddpg":
         assert re.search(r'op_name="[^"]*/replay_sample/mass/', text)
+    else:
+        # the differentiated function's scopes come back wrapped, and resolve
+        names = re.findall(r'op_name="([^"]*)"', text)
+        assert any("/jvp(learn)/" in n for n in names)
+        backward = [n for n in names if "transpose(jvp(learn))" in n]
+        assert backward and {phase_of(n) for n in backward} == {"learn"}
+        # V-trace's inputs carry no gradient: forward ops only
+        assert any("jvp(vtrace)" in n for n in names)
+        assert not any("transpose(jvp(vtrace))" in n for n in names)
 
 
 def test_span_stays_within_its_budget_with_no_profile_active(tmp_path):
