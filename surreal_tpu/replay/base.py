@@ -11,10 +11,13 @@ program's dataflow; under a dp mesh each device owns a shard of the buffer
 (the reference's ShardedReplay, for free, see replay/sharded.py).
 
 All buffers store flat transition dicts: {k: [capacity, ...]} with a write
-cursor and size. Insertion is vectorized: a whole [N, ...] batch lands in
-one scatter per leaf. It is a row scatter, not a ``dynamic_update_slice``:
-on the TPU v5e it costs about 105 ns per row and leaf, 362 ms for the
-1 048 576 rows a fused DDPG iteration inserts (PERF.md section 5).
+cursor and size. Insertion is vectorized: a whole [N, ...] batch is
+contiguous in the ring but for at most one wrap, so it lands as two
+windows of N rows per leaf (:func:`ring_write`), read, merged and written
+in place. Not a row scatter: XLA keeps a narrow ``[capacity, F]`` ring
+capacity-minor on the TPU (ops/pallas_replay.py, "Known cost"), so a
+scattered row is F single words a whole strip apart, while a window of
+rows is F contiguous strips (PERF.md section 6, PR 29).
 """
 
 from __future__ import annotations
@@ -48,20 +51,49 @@ def init_ring(example: Any, capacity: int) -> RingState:
     )
 
 
+def ring_write(buf: jax.Array, new: jax.Array, cursor: jax.Array) -> jax.Array:
+    """The ring ``buf`` of ``capacity = buf.shape[0]`` slots with the rows
+    of ``new`` at slots ``(cursor + i) % capacity``, cast to ``buf.dtype``.
+
+    ``n = new.shape[0]`` is static and at most ``capacity`` (more rows than
+    slots is refused at trace time: the first of them would be evicted by
+    the last of the same call). The last ``tail`` rows wrap to slot 0, so
+    the rows are rolled by ``tail`` and written as two windows of ``n``
+    slots, each keeping the ring's contents where the other's rows go: one
+    at the cursor, pulled back to ``capacity - n`` where the rows wrap
+    (``dynamic_update_slice`` clamps a start that runs past the end; it
+    does not wrap), and one at slot 0, which rewrites what it read where
+    nothing wraps.
+    """
+    n, capacity = new.shape[0], buf.shape[0]
+    if n > capacity:
+        raise ValueError(
+            f"ring_write: {n} rows do not fit a ring of {capacity} slots"
+        )
+    tail = jnp.maximum(cursor + n - capacity, 0)
+    rolled = jnp.roll(new.astype(buf.dtype), tail, axis=0)
+    wrapped = jax.lax.broadcasted_iota(jnp.int32, new.shape, 0) < tail
+    for start, keep in ((jnp.minimum(cursor, capacity - n), wrapped), (0, ~wrapped)):
+        old = jax.lax.dynamic_slice_in_dim(buf, start, n)
+        buf = jax.lax.dynamic_update_slice_in_dim(
+            buf, jnp.where(keep, old, rolled), start, axis=0
+        )
+    return buf
+
+
 def ring_insert(state: RingState, batch: Any, capacity: int) -> RingState:
     """Insert a [N, ...] batch at the cursor with wraparound (FIFO evict).
 
-    N is a static shape; positions are ``(cursor + arange(N)) % capacity``
-    — one scatter per leaf, fully on device.
+    N is a static shape; row ``i`` lands at ``(cursor + i) % capacity`` —
+    two window writes per leaf (:func:`ring_write`), fully on device.
     """
     from surreal_tpu.utils.asserts import check_insert_batch
 
     check_insert_batch(batch, state.storage, name="ring_insert")
     n = jax.tree.leaves(batch)[0].shape[0]
     with phase("replay_insert"):
-        idx = (state.cursor + jnp.arange(n, dtype=jnp.int32)) % capacity
         storage = jax.tree.map(
-            lambda buf, new: buf.at[idx].set(new.astype(buf.dtype)),
+            lambda buf, new: ring_write(buf, new, state.cursor),
             state.storage, batch,
         )
         return RingState(

@@ -44,6 +44,7 @@ from surreal_tpu.replay.base import (
     ring_gather,
     ring_gauges,
     ring_insert,
+    ring_write,
     sample_age_frac,
 )
 from surreal_tpu.utils.phases import phase
@@ -167,12 +168,13 @@ class PrioritizedReplay:
         seen at least once before their TD error takes over)."""
         n = jax.tree.leaves(batch)[0].shape[0]
         with phase("replay_insert"):
-            idx = (
-                state.ring.cursor + jnp.arange(n, dtype=jnp.int32)
-            ) % self.capacity
             return PrioritizedState(
                 ring=ring_insert(state.ring, batch, self.capacity),
-                priorities=state.priorities.at[idx].set(state.max_priority),
+                priorities=ring_write(
+                    state.priorities,
+                    jnp.full(n, state.max_priority),
+                    state.ring.cursor,
+                ),
                 max_priority=state.max_priority,
             )
 

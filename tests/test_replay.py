@@ -369,7 +369,7 @@ def test_prioritized_draw_in_scan_with_priority_updates():
         )
 
 
-# capacity, rows of each successive insert
+# capacity, rows of each successive insert[, the obs leaf's storage dtype]
 INSERT_CASES = {
     "40000_into_65536_from_slot_0": (65536, [40000]),   # the reference check's
     "second_insert_wraps": (65536, [40000, 40000]),
@@ -377,7 +377,46 @@ INSERT_CASES = {
     "n_equals_capacity": (64, [10, 64]),
     "n_1": (8, [1] * 11),
     "cell_ratio_21_inserts": (5120, [256] * 21),        # n = capacity / 20
+    # the edges of the two windows' arithmetic (replay/base.py::ring_write)
+    "ends_on_the_last_slot": (64, [24, 40, 5]),         # tail 0, next cursor 0
+    "wraps_by_one_row": (64, [50, 15]),
+    "wraps_by_all_but_one_row": (64, [63, 20]),
+    "more_rows_than_slots_refused": (8, [3, 9]),
+    "bfloat16_leaf_fed_float32": (1000, [300, 300, 300, 300], jnp.bfloat16),
 }
+
+
+class NumpyRing:
+    """The ring kept on the host, one row at a time: what every insert
+    path is compared with."""
+
+    def __init__(self, example, capacity):
+        self.capacity = capacity
+        self.rows = {
+            k: np.zeros((capacity, *np.shape(v)), np.asarray(v).dtype)
+            for k, v in example.items()
+        }
+        self.prio = np.zeros(capacity, np.float32)
+        self.cursor = self.size = 0
+
+    def insert(self, rows, max_prio):
+        rows = jax.tree.map(np.asarray, rows)
+        n = len(jax.tree.leaves(rows)[0])
+        for i in range(n):
+            slot = (self.cursor + i) % self.capacity
+            for k in self.rows:
+                self.rows[k][slot] = rows[k][i]   # casts as the ring does
+            self.prio[slot] = max_prio
+        self.cursor = (self.cursor + n) % self.capacity
+        self.size = min(self.size + n, self.capacity)
+
+    def assert_equals(self, ring, priorities=None):
+        assert (int(ring.cursor), int(ring.size)) == (self.cursor, self.size)
+        for k in self.rows:
+            assert ring.storage[k].dtype == self.rows[k].dtype
+            np.testing.assert_array_equal(np.asarray(ring.storage[k]), self.rows[k])
+        if priorities is not None:
+            np.testing.assert_array_equal(np.asarray(priorities), self.prio)
 
 
 @pytest.mark.parametrize("prioritized", [False, True], ids=["ring", "prioritized"])
@@ -385,47 +424,113 @@ INSERT_CASES = {
 def test_ring_insert_matches_numpy_ring(case, prioritized):
     """``ring_insert`` and ``PrioritizedReplay.insert`` against a ring kept
     in NumPy, row by row: rows land at ``(cursor + i) % capacity`` in
-    order, the oldest are evicted, cursor and size follow, and fresh slots
-    take the max priority of the moment. The guard for a block-write
-    replacement of the row scatter (ROADMAP S1)."""
+    order, cast to the storage dtype, the oldest are evicted, cursor and
+    size follow, and fresh slots take the max priority of the moment. The
+    guard of the window arithmetic of ``replay/base.py::ring_write``."""
     from surreal_tpu.replay.base import init_ring, ring_insert
 
-    capacity, inserts = INSERT_CASES[case]
+    capacity, inserts, *obs_dtype = INSERT_CASES[case]
     replay = build_replay(replay_cfg(
         "prioritized", capacity=capacity, batch_size=4, start_sample_size=1,
     ))
     example = jax.tree.map(lambda x: x[0], trans(1))
+    if obs_dtype:
+        example["obs"] = example["obs"].astype(obs_dtype[0])
     state = replay.init(example) if prioritized else init_ring(example, capacity)
     insert = jax.jit(
         replay.insert if prioritized
         else lambda s, rows: ring_insert(s, rows, capacity)
     )
-    want = {k: np.zeros((capacity, *np.shape(v)), np.float32) for k, v in example.items()}
-    want_prio, max_prio = np.zeros(capacity, np.float32), np.float32(1.0)
-    cursor = size = base = 0
+    want, max_prio, base = NumpyRing(example, capacity), np.float32(1.0), 0
     for n in inserts:
         rows = trans(n, base=base)
+        if n > capacity:
+            with pytest.raises(ValueError, match="do not fit a ring"):
+                insert(state, rows)
+            break
         state = insert(state, rows)
-        host_rows = jax.tree.map(np.asarray, rows)
-        for i in range(n):
-            slot = (cursor + i) % capacity
-            for k in want:
-                want[k][slot] = host_rows[k][i]
-            want_prio[slot] = max_prio
-        cursor, size, base = (cursor + n) % capacity, min(size + n, capacity), base + n
-        ring = state.ring if prioritized else state
-        assert (int(ring.cursor), int(ring.size)) == (cursor, size)
-        for k in want:
-            np.testing.assert_array_equal(np.asarray(ring.storage[k]), want[k])
+        want.insert(rows, max_prio)
+        base += n
+        want.assert_equals(
+            state.ring if prioritized else state,
+            state.priorities if prioritized else None,
+        )
         if prioritized:
-            np.testing.assert_array_equal(np.asarray(state.priorities), want_prio)
             # move the max, so that the next insert's priorities differ
-            newest = (cursor - 1) % capacity
+            newest = (want.cursor - 1) % capacity
             state = replay.update_priorities(
                 state, jnp.asarray([newest]), jnp.asarray([max_prio + 1.5])
             )
-            want_prio[newest] = max_prio = np.float32(state.max_priority)
+            want.prio[newest] = max_prio = np.float32(state.max_priority)
             assert max_prio > 1.5 and state.priorities[newest] == max_prio
+
+
+def _td_of(idx, step):
+    """A TD error that depends on the slot alone within a step, so that a
+    slot drawn twice is written the same priority twice."""
+    return 0.25 * ((idx + step) % 5).astype(jnp.float32) + 0.5
+
+
+@pytest.mark.parametrize("how", ["donated_jit", "scan"])
+def test_insert_sample_update_cycle_matches_numpy_ring(how):
+    """The fused iteration's order, insert -> sample -> update_priorities,
+    as three donating ``jax.jit`` calls per step (the host drivers) and
+    as the body of one ``lax.scan`` (the device driver), against the NumPy
+    ring after every step; the cursor passes the wrap twice."""
+    capacity, n, steps, bs = 200, 72, 7, 16
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=capacity, batch_size=bs, start_sample_size=1,
+    ))
+    example = jax.tree.map(lambda x: x[0], trans(1))
+    chunks = jax.tree.map(
+        lambda *xs: jnp.stack(xs), *[trans(n, base=n * t) for t in range(steps)]
+    )
+    keys = jax.random.split(jax.random.key(3), steps)
+
+    def cycle(state, xs):
+        rows, key, step = xs
+        state = replay.insert(state, rows)
+        after_insert = state
+        state, batch, info = replay.sample(state, key)
+        state = replay.update_priorities(
+            state, info["idx"], _td_of(info["idx"], step)
+        )
+        return state, (after_insert, batch, info["idx"], state)
+
+    xs = (chunks, keys, jnp.arange(steps))
+    if how == "scan":
+        _, outs = jax.jit(lambda s: jax.lax.scan(cycle, s, xs))(replay.init(example))
+        outs = [jax.tree.map(lambda x: x[t], outs) for t in range(steps)]
+    else:
+        insert = jax.jit(replay.insert, donate_argnums=(0,))
+        sample = jax.jit(replay.sample, donate_argnums=(0,))
+        update = jax.jit(replay.update_priorities, donate_argnums=(0,))
+        state, outs = replay.init(example), []
+
+        def host(tree):  # read before the next call donates it
+            return jax.tree.map(np.asarray, tree)
+
+        for t in range(steps):
+            state = insert(state, jax.tree.map(lambda x: x[t], chunks))
+            after_insert = host(state)
+            state, batch, info = sample(state, keys[t])
+            idx = info["idx"]
+            state = update(state, idx, _td_of(idx, t))
+            outs.append((after_insert, batch, idx, host(state)))
+
+    want, max_prio = NumpyRing(example, capacity), np.float32(1.0)
+    for t, (after_insert, batch, idx, after_update) in enumerate(outs):
+        want.insert(jax.tree.map(lambda x: x[t], chunks), max_prio)
+        want.assert_equals(after_insert.ring, after_insert.priorities)
+        idx = np.asarray(idx)
+        for k in want.rows:  # the drawn rows are the inserted ones
+            np.testing.assert_array_equal(np.asarray(batch[k]), want.rows[k][idx])
+        prio = np.abs(np.asarray(_td_of(idx, t))) + np.float32(replay.eps)
+        want.prio[idx] = prio
+        max_prio = max(max_prio, prio.max())
+        want.assert_equals(after_update.ring, after_update.priorities)
+        assert np.float32(after_update.max_priority) == max_prio
+    assert want.cursor == (n * steps) % capacity and want.size == capacity
 
 
 def test_sharded_replay_per_device_buffers():

@@ -158,3 +158,44 @@ def test_compiles_for_v5e(chip, build, is_kernel):
     mem = compiled.memory_analysis()
     hbm = 16 * 2**30
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < hbm
+
+
+def test_ring_insert_compiles_to_window_writes(chip):
+    """``PrioritizedReplay.insert`` at the shapes of ``ddpg_lift_per20m``
+    (1 048 576 rows into 20 971 520 slots, the five leaves of
+    ``OffPolicyTrainer._replay_example`` and the priorities, state donated):
+    windows written in place. A row scatter there takes 105 ns a row and
+    leaf on the chip (PERF_LEDGER.jsonl, PR 28: 362 of the cell's 395 ms),
+    and a copy of one obs leaf is 2 GB of temporaries."""
+    from surreal_tpu.replay import build_replay
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import BASE_LEARNER_CONFIG
+
+    capacity, n = 20 * 2**20, 2**20
+    leaves = dict(LIFT_LEAVES, next_obs=(17,), discount=())
+    replay = build_replay(
+        Config(kind="prioritized", capacity=capacity).extend(BASE_LEARNER_CONFIG.replay)
+    )
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+        )
+
+    state = jax.eval_shape(
+        replay.init, {k: jnp.zeros(shape) for k, shape in leaves.items()}
+    )
+    rows = {
+        k: jax.ShapeDtypeStruct((n, *shape), jnp.float32)
+        for k, shape in leaves.items()
+    }
+    compiled = (
+        jax.jit(replay.insert, donate_argnums=(0,))
+        .lower(on_chip(state), on_chip(rows))
+        .compile()
+    )
+    assert " scatter(" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # every output (the six ring arrays, three scalars) reuses its input
+    assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2**20
+    assert mem.temp_size_in_bytes < 0.6e9
