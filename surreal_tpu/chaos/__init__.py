@@ -7,8 +7,8 @@ schedule shrinker that reduces any failing schedule to minimal form.
 - :mod:`surreal_tpu.chaos.invariants` — the post-run oracles
 - :mod:`surreal_tpu.chaos.campaign` — N seeded real runs + shrinking
 
-CLI: ``surreal_tpu chaos <algo> <env> --seeds N``; the committed
-``CHAOS_campaign.json`` artifact is gated by ``perf_gate.gate_chaos``.
+CLI: ``surreal_tpu chaos <algo> <env> --seeds N`` (``--out`` writes the
+campaign's record where the operator asks).
 """
 
 from surreal_tpu.chaos.schedule import PROFILES, generate_schedule

@@ -620,7 +620,7 @@ class TieredSampler:
     ON DEVICE at *request* time — the jitted draw+gather dispatches
     async and overlaps the learner, so ``get_iteration`` returns already-
     resident batches with ~zero wait (the mechanism behind the hot-hit
-    ``experience/sample_wait_ms`` figure in BENCH_tiers.json). A miss —
+    ``experience/sample_wait_ms`` gauge). A miss —
     hot ring still filling — falls back to the PR-8 shard-major fan-in
     with the SAME keys, counted in ``tier/hot_misses``, never silent.
 

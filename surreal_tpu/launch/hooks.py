@@ -255,22 +255,10 @@ class SessionHooks:
         self.watchdog = None
         self.incidents = None
         if wd_cfg is None or wd_cfg.get("enabled", True):
-            import jax
-
             from surreal_tpu.session.incidents import IncidentEngine
             from surreal_tpu.session.watchdog import Watchdog
 
-            base_dir = (
-                wd_cfg.get("baseline_dir", None) if wd_cfg is not None else None
-            )
-            self.watchdog = Watchdog(
-                cfg=wd_cfg,
-                baseline_rows=Watchdog.load_baseline(base_dir)
-                if base_dir
-                else None,
-                platform=jax.default_backend(),
-                geometry=f"{jax.device_count()}x{type(jax.devices()[0]).__name__}",
-            )
+            self.watchdog = Watchdog(cfg=wd_cfg)
             self.incidents = IncidentEngine(
                 folder=cfg.folder,
                 cfg=wd_cfg,
@@ -322,7 +310,7 @@ class SessionHooks:
 
     def bind_remediation_actuators(self, **surfaces) -> None:
         """Hand the remediation engine its actuator surfaces (fleet,
-        admission, restart map, learner downshift/restore) once the
+        admission, restart map, learner group) once the
         driver has built them — no-op when remediation is off. See
         :meth:`RemediationEngine.bind_actuators`."""
         if self.remediate is not None:

@@ -763,27 +763,6 @@ class SEEDTrainer:
 
             # closed-loop remediation (ISSUE 16): hand the hooks-owned
             # engine its actuator surfaces now that every tier exists.
-            # The learner downshift rides the existing overrides path —
-            # it mutates the live algo Config (batch halved, full->mixed
-            # precision), effective at the next learner (re)build — and
-            # returns the prior values so the counter-detector can
-            # revert; None (nothing left to downshift) is counted
-            # unmapped by the engine.
-            def _learner_downshift():
-                prior = {}
-                b = int(self.algo.get("batch_size", 0) or 0)
-                if b >= 64:
-                    prior["batch_size"] = b
-                    self.algo["batch_size"] = b // 2
-                if self.algo.get("precision") == "full":
-                    prior["precision"] = "full"
-                    self.algo["precision"] = "mixed"
-                return prior or None
-
-            def _learner_restore(prior):
-                for k, v in (prior or {}).items():
-                    self.algo[k] = v
-
             hooks.bind_remediation_actuators(
                 fleet=server if hasattr(server, "scale_up") else None,
                 admission=getattr(gateway, "admission", None),
@@ -801,8 +780,6 @@ class SEEDTrainer:
                         ),
                     }.items() if v is not None
                 },
-                learner_downshift=_learner_downshift,
-                learner_restore=_learner_restore,
             )
 
             def next_chunk_from_xplane():

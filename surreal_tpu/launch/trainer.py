@@ -130,7 +130,7 @@ class Trainer:
                     # models/attention.py re-asserts this same invariant at
                     # the learn-pass shape (B>1, T>1) inside the ring's
                     # batch-tiling fallback — the two sites must not drift
-                    # (ADVICE r5 low: a mis-sized learn batch used to fall
+                    # (round-5 review: a mis-sized learn batch used to fall
                     # back to silent full replication)
                     check_dp_divisible(
                         self.num_envs // mb, dp,

@@ -253,7 +253,7 @@ _EARLY_GROUP_FAILURE = -255  # sentinel: group died inside the startup window
 # error signatures of the jax.distributed coordinator losing its port race
 # (rank 0's bind, other ranks' connect/handshake against a dead address) —
 # deterministic startup failures (bad flag, import error, config typo) match
-# none of these and must NOT respawn the group (ADVICE r5 low)
+# none of these and must NOT respawn the group (round-5 review)
 _COORDINATOR_FAILURE_RE = None  # compiled lazily (keeps module import light)
 
 
@@ -757,9 +757,9 @@ def _parse_dims(spec: str) -> list:
 
 def _merge_tune_artifact(path: str, row: dict) -> None:
     """Append/replace ``row`` (keyed by fingerprint) in the shared
-    BENCH_tune.json-style artifact, atomically — repeated `surreal_tpu
-    tune` runs against different geometries accumulate into one committed
-    record instead of clobbering each other."""
+    ``tune --out`` artifact (e.g. ``tune.json``), atomically — repeated
+    `surreal_tpu tune` runs against different geometries accumulate into
+    one record instead of clobbering each other."""
     import jax
 
     data = {"metric": "autotune_fused_iter_ms", "workloads": []}
@@ -773,8 +773,8 @@ def _merge_tune_artifact(path: str, row: dict) -> None:
     data["workloads"] = [
         w for w in data["workloads"] if w.get("key") != row.get("key")
     ] + [row]
-    # bench.py discipline: record the device actually measured — a CPU
-    # fallback must never masquerade as a chip record
+    # record the device actually measured — a CPU fallback must never
+    # masquerade as a chip record
     data["device"] = str(jax.devices()[0].device_kind)
     data["platform"] = str(jax.devices()[0].platform)
     with open(path + ".tmp", "w") as f:
@@ -975,9 +975,8 @@ def run_chaos(args) -> int:
     """Randomized chaos campaign (surreal_tpu/chaos/): N seeded
     multi-site fault schedules executed as short REAL training runs,
     every run judged by the invariant oracles, failing schedules shrunk
-    to minimal form. Exit 0 only on zero violations — the committed
-    CHAOS_campaign.json this writes is what perf_gate.gate_chaos
-    enforces."""
+    to minimal form. Exit 0 only on zero violations; ``--out`` writes
+    the campaign's record."""
     import tempfile
 
     from surreal_tpu.chaos import campaign as chaos_campaign
@@ -1133,8 +1132,8 @@ def build_parser() -> argparse.ArgumentParser:
     tu.add_argument("--force", action="store_true",
                     help="re-measure even on a cache hit")
     tu.add_argument("--out", default=None,
-                    help="merge the result into a shared BENCH_tune.json-"
-                         "style artifact (keyed by fingerprint)")
+                    help="merge the result into a shared artifact, e.g. "
+                         "tune.json (keyed by fingerprint)")
     tu.set_defaults(fn=run_tune, total_steps=None, restore_from=None,
                     workers=None)
 
@@ -1205,8 +1204,7 @@ def build_parser() -> argparse.ArgumentParser:
                    "profile i %% len(profiles); intensity ramps with "
                    "seed %% 3)")
     c.add_argument("--out", default=None,
-                   help="write the campaign artifact JSON here "
-                   "(CHAOS_campaign.json for the committed, gated copy)")
+                   help="write the campaign artifact JSON here")
     c.add_argument("--dir", default=None,
                    help="scratch dir for the runs' session folders "
                    "(default: a fresh temp dir)")

@@ -103,7 +103,7 @@ class CausalSelfAttention(nn.Module):
             # NOT silently replicate — that quiet perf cliff is exactly
             # what the Trainer-side check_dp_divisible (launch/trainer.py,
             # sp>1 branch) rejects; this assert is its model-side twin so
-            # the two sites cannot drift (ADVICE r5 low).
+            # the two sites cannot drift (round-5 review).
             ba = self.batch_axis
             if ba is not None and B % self.mesh.shape[ba] != 0:
                 if B > 1 and T > 1 and not replicate_ok:

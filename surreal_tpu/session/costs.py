@@ -234,7 +234,7 @@ GAUGE_REGISTRY = {
         '(drop_eval) — counted, never silent.'),
     "ops/watchdog_firings": _g("count",
         'detector firings across all sweeps this run (breakout, '
-        'saturation, growth, liveness, regression).'),
+        'saturation, growth, liveness).'),
     "ops/incidents_open": _g("count",
         'whether an incident is currently open (0/1 — the engine holds at '
         'most one open incident, extending it while detectors keep '
@@ -377,7 +377,7 @@ GAUGE_REGISTRY = {
         'failures (1 per latched shard).'),
     "tier/cold_bytes_per_row": _g("bytes",
         'encoded WAL bytes per transition (the quantization win vs the '
-        'raw f32 row — BENCH_tiers.json commits the ratio).'),
+        'raw f32 row; tests/test_tiers.py holds the ratio).'),
     "tier/torn_segments": _g("count",
         'torn WAL segments skipped by magic-resync on read (crash '
         'mid-append; the experience.spill chaos site drives this).'),
@@ -549,7 +549,7 @@ def analyze_program(jitted, *args, memory: bool = False, **kwargs):
 
 
 def program_costs(jitted, *args, **kwargs) -> dict | None:
-    """The ``costs`` half of :func:`analyze_program` (bench.py's view)."""
+    """The ``costs`` half of :func:`analyze_program`."""
     return analyze_program(jitted, *args, **kwargs)[0]
 
 

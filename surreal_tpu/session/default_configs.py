@@ -421,13 +421,11 @@ BASE_SESSION_CONFIG = Config(
     # session/incidents.py): detector sweeps over the merged ops snapshot
     # at the metrics cadence — EWMA/MAD breakouts on the headline
     # latencies/throughputs, queue/backpressure saturation, monotonic
-    # growth of every dropped/bad_frames counter, tier liveness from the
-    # ops plane's DEAD rendering, and online regression vs a committed
-    # BENCH baseline. Firings open root-caused incidents (one open at a
-    # time) persisted under telemetry/incidents/ and rendered by
-    # `surreal_tpu why <folder>`. Pure host arithmetic over the snapshot
-    # dict — no device->host syncs (transfer-guard tested), overhead
-    # committed <=1% of iteration time (perf_gate.gate_watchdog).
+    # growth of every dropped/bad_frames counter and tier liveness from
+    # the ops plane's DEAD rendering. Firings open root-caused incidents
+    # (one open at a time) persisted under telemetry/incidents/ and
+    # rendered by `surreal_tpu why <folder>`. Pure host arithmetic over
+    # the snapshot dict — no device->host syncs (transfer-guard tested).
     watchdog=Config(
         enabled=True,
         warmup=8,            # sweeps before breakout detectors arm
@@ -441,9 +439,6 @@ BASE_SESSION_CONFIG = Config(
         staleness_growth_windows=4,  # ... for lineage/staleness_p99
         staleness_floor=64.0,  # versions; the startup ramp toward
         # steady-state pipeline depth stays below this and never fires
-        regression_frac=0.5,     # fire when live throughput/MFU < frac*bench
-        regression_sustain=3,
-        baseline_dir=None,   # dir of BENCH_r*.json rows (None -> repo root)
         # incident engine knobs (session/incidents.py)
         close_windows=5,         # clean sweeps before incident_close
         evidence_window_s=120.0,  # fault/recovery correlation horizon
@@ -456,9 +451,8 @@ BASE_SESSION_CONFIG = Config(
     # observe — the engine maps the open incident's top-ranked cause
     # tier to ONE bounded action on an existing actuator (fleet
     # scale_up, per-tenant throttle via AdmissionController.set_quota,
-    # RespawnSchedule-backed targeted restart, learner batch/precision
-    # downshift via the config overrides path). Every action is a
-    # counted `remediation` event + an atomic
+    # RespawnSchedule-backed targeted restart, learner-group scale_up).
+    # Every action is a counted `remediation` event + an atomic
     # telemetry/actions/action-<n>.json record + evidence on its
     # incident; a counter-detector watches the triggering objective for
     # verify_windows post-action sweeps and reverts what regressed

@@ -15,8 +15,6 @@ ONE bounded action on an actuator the system already has:
                  tenant)
     DEAD tier    targeted_restart   the tier's supervise()       (irreversible)
                                     (RespawnSchedule-backed)
-    learner      learner_downshift  the config overrides path    restore the
-    (regression)                    (batch/precision)            prior values
     learner      learner_scale_up   LearnerGroup.scale_up        scale_down
     (saturated/                     (parallel/learner_group.py:  (remove the
     lagging)                        join a member, rebalance)    joined member)
@@ -100,8 +98,6 @@ class RemediationEngine:
         self._fleet = None
         self._admission = None
         self._restart: dict = {}
-        self._learner_downshift = None
-        self._learner_restore = None
         self._learner_group = None
         # bookkeeping
         self._next_id = 1
@@ -117,15 +113,12 @@ class RemediationEngine:
         self._write_ok = folder is not None
 
     def bind_actuators(self, fleet=None, admission=None, restart=None,
-                       learner_downshift=None, learner_restore=None,
                        learner_group=None) -> None:
         """Hand the engine its actuator surfaces: ``fleet`` duck-types
         ``scale_up()/scale_down()`` (InferenceFleet), ``admission``
         duck-types ``quota_of()/set_quota()`` (AdmissionController),
         ``restart`` maps tier name -> zero-arg supervise callable (the
-        RespawnSchedule-backed supervisors), the learner pair
-        implements the overrides downshift (downshift() -> revert
-        payload or None; restore(payload)), and ``learner_group``
+        RespawnSchedule-backed supervisors), and ``learner_group``
         duck-types ``scale_up() -> member_id / scale_down(member_id)``
         (the elastic LearnerGroup — ROADMAP's "scale the named tier"
         reservation for learners)."""
@@ -135,10 +128,6 @@ class RemediationEngine:
             self._admission = admission
         if restart:
             self._restart.update(restart)
-        if learner_downshift is not None:
-            self._learner_downshift = learner_downshift
-        if learner_restore is not None:
-            self._learner_restore = learner_restore
         if learner_group is not None:
             self._learner_group = learner_group
 
@@ -146,8 +135,9 @@ class RemediationEngine:
     def step(self, firings: list[dict] | None, snap: dict | None) -> None:
         """One decision sweep: verify the active actions against this
         snapshot, then map the open incident's top cause to at most one
-        new bounded action. Pure host work; every non-action outcome is
-        counted."""
+        new bounded action. ``firings``, this sweep's, are not read: the
+        open incident has folded them in and is what the decision reads
+        (ROADMAP D14). Pure host work; every non-action outcome is counted."""
         if not self.enabled:
             return
         now = time.time()
@@ -162,7 +152,7 @@ class RemediationEngine:
         if any(a["incident"] == inc["id"] for a in self._active):
             return  # an answer is already under verification — wait
         tier = str(inc["causes"][0].get("tier"))
-        plan = self._map_action(tier, inc, firings or [], snap)
+        plan = self._map_action(tier, inc, snap)
         if plan is None:
             self.unmapped += 1
             return
@@ -191,8 +181,7 @@ class RemediationEngine:
                            incident=inc["id"], reason=reason)
 
     # -- cause tier -> action plan -------------------------------------------
-    def _map_action(self, tier: str, inc: dict, firings: list[dict],
-                    snap: dict) -> dict | None:
+    def _map_action(self, tier: str, inc: dict, snap: dict) -> dict | None:
         """The action table. Returns ``{kind, detail, run, revert_info,
         reversible, objective fields...}`` or None (no bound actuator /
         no actionable target — counted unmapped by the caller)."""
@@ -229,28 +218,11 @@ class RemediationEngine:
                 "objective": "tier_dead",
                 "tier": tier,
             }
-        regression = any(
-            f.get("detector") == "regression" for f in firings
-        ) or any(
-            str(k).startswith("regression:learner")
-            for k in (inc.get("detector_counts") or {})
-        )
-        if tier == "learner" and regression and (
-            self._learner_downshift is not None
-        ):
-            return {
-                "kind": "learner_downshift",
-                "detail": "batch/precision downshift via config overrides",
-                "objective": "throughput",
-            }
-        if tier == "learner" and not regression and (
-            self._learner_group is not None
-        ):
-            # non-regression learner causes (saturation/growth/liveness
-            # naming the learner tier = it can't keep up, not that its
-            # update got slower): add a group member under the same
-            # cooldown + max-actions + counter-detection discipline;
-            # revert = remove the joined member
+        if tier == "learner" and self._learner_group is not None:
+            # a cause naming the learner tier = it can't keep up: add a
+            # group member under the same cooldown + max-actions +
+            # counter-detection discipline; revert = remove the joined
+            # member
             return {
                 "kind": "learner_scale_up",
                 "detail": "join a learner-group member "
@@ -304,13 +276,6 @@ class RemediationEngine:
             elif kind == "targeted_restart":
                 self._restart[plan["tier"]]()
                 reversible = False  # a restart cannot be un-run
-            elif kind == "learner_downshift":
-                payload = self._learner_downshift()
-                if payload is None:
-                    self.unmapped += 1  # nothing left to downshift
-                    return
-                revert_info = {"payload": payload}
-                reversible = self._learner_restore is not None
             elif kind == "learner_scale_up":
                 revert_info["member"] = int(self._learner_group.scale_up())
             else:  # pragma: no cover — _map_action emits only the above
@@ -463,8 +428,6 @@ class RemediationEngine:
                 self._fleet.scale_down()
             elif kind == "tenant_throttle":
                 self._admission.set_quota(info["tenant"], info["quota"])
-            elif kind == "learner_downshift":
-                self._learner_restore(info["payload"])
             elif kind == "learner_scale_up":
                 self._learner_group.scale_down(info.get("member"))
             else:

@@ -27,9 +27,6 @@ consumes):
   state stays below the floor and never fires).
 - **liveness** — any tier the aggregator marked DEAD (silent for 3x its
   own declared cadence).
-- **regression** — live env steps/s and MFU against the committed BENCH
-  baseline rows for the same fingerprint (``perf_gate.load_rows``): the
-  bench-time win must *stay* won during live runs.
 
 Every evaluation honors the ``watchdog.eval`` chaos site: ``drop_eval``
 skips the sweep (counted in ``ops/watchdog_dropped_evals``, never
@@ -183,8 +180,7 @@ class Watchdog:
     call :meth:`evaluate` with each merged ops snapshot; returns the
     list of firing dicts for the incident engine."""
 
-    def __init__(self, cfg=None, baseline_rows=None, platform=None,
-                 geometry=None):
+    def __init__(self, cfg=None):
         cfg = cfg or {}
         get = cfg.get if hasattr(cfg, "get") else lambda k, d=None: d
         self.enabled = bool(get("enabled", True))
@@ -215,59 +211,12 @@ class Watchdog:
         self._queue_streaks: dict[str, int] = {}
         self._counters: dict[str, _Counter] = {}
         self._counter_window = bo["window"]
-        # online regression vs the committed BENCH trail: rows from
-        # perf_gate.load_rows for THIS platform (+ geometry when the live
-        # run declares one). None/empty disarms the detector — a dev-box
-        # run at a toy geometry has no committed fingerprint to regress
-        # against.
-        self.regression_frac = float(get("regression_frac", 0.5))
-        self.regression_sustain = max(1, int(get("regression_sustain", 3)))
-        self._regression_streaks = {"throughput": 0, "mfu": 0}
-        self._baseline = self._match_baseline(
-            baseline_rows, platform, geometry
-        )
         # snapshot-to-snapshot derivations (iteration time)
         self._last_t: float | None = None
         self._last_iter: int | None = None
         self.evals = 0
         self.dropped_evals = 0
         self.firings = 0
-
-    @staticmethod
-    def _match_baseline(rows, platform, geometry) -> dict:
-        """Pick the committed headline numbers matching the live
-        fingerprint out of the ``perf_gate.load_rows`` row dicts."""
-        best: dict = {}
-        for row in rows or ():
-            if row.get("failed") or row.get("value") is None:
-                continue
-            if not str(row.get("metric", "")).startswith("env_steps_per_sec"):
-                continue
-            if platform and row.get("platform") not in (None, platform):
-                continue
-            if geometry and row.get("geometry") not in (None, geometry):
-                continue
-            if float(row["value"]) > float(best.get("throughput", 0.0)):
-                best["throughput"] = float(row["value"])
-                best["file"] = row.get("file")
-                if row.get("mfu") is not None:
-                    best["mfu"] = float(row["mfu"])
-        return best
-
-    @staticmethod
-    def load_baseline(art_dir: str):
-        """Committed BENCH rows via ``perf_gate.load_rows`` — guarded:
-        perf_gate lives at the repo root, not in the package, so an
-        installed tree without the bench trail simply disarms the
-        regression detector."""
-        try:
-            from perf_gate import load_rows
-        except ImportError:
-            return None
-        try:
-            return load_rows(art_dir)
-        except Exception:
-            return None
 
     # -- snapshot value extraction (pure dict walks) -------------------------
     @staticmethod
@@ -423,33 +372,6 @@ class Watchdog:
                     "baseline": stale - sum(recent),
                     "direction": "high",
                 })
-
-        # online regression vs the committed BENCH fingerprint
-        if self._baseline.get("throughput"):
-            for name, live_key, base in (
-                ("throughput", "time/env_steps_per_s",
-                 self._baseline.get("throughput")),
-                ("mfu", "perf/mfu", self._baseline.get("mfu")),
-            ):
-                if not base:
-                    continue
-                live = self._find_gauge(snap, live_key)
-                if live is None:
-                    continue
-                if live < self.regression_frac * float(base):
-                    self._regression_streaks[name] += 1
-                else:
-                    self._regression_streaks[name] = 0
-                if self._regression_streaks[name] >= self.regression_sustain:
-                    firings.append({
-                        "detector": "regression",
-                        "signal": name,
-                        "tier": "learner",
-                        "value": round(float(live), 4),
-                        "baseline": round(float(base), 4),
-                        "direction": "low",
-                        "bench": self._baseline.get("file"),
-                    })
 
         self.firings += len(firings)
         for f in firings:

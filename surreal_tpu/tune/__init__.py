@@ -25,8 +25,9 @@ Three layers, mirroring the compile cache's design:
 - :mod:`search` (+ :mod:`space`) — greedy coordinate descent over the
   declared candidate space (rollout-scan ``unroll``, SGD/update-loop
   ``unroll``, ``gae_impl`` incl. the pallas kernel, shuffle layout), each
-  candidate timed with bench.py's fenced chained-iteration
-  discipline through the REAL fused trainer program.
+  candidate timed over a fenced window of chained iterations (warm-up
+  calls first, ``block_until_ready`` on the last outputs) through the
+  REAL fused trainer program.
 
 Trainers consult the cache at build time via ``algo.autotune``:
 

@@ -1,6 +1,6 @@
 """Measurement-driven search over the declared candidate space.
 
-Timing discipline is bench.py's: warmup calls absorb XLA compilation, and
+Timing discipline: warmup calls absorb XLA compilation, and
 the measured window is a CHAINED loop (each iteration consumes the
 previous state) fenced by ``jax.block_until_ready`` on its last outputs.
 Candidates are timed through the REAL
@@ -369,7 +369,7 @@ def tune_workload(
             "warmup": warmup,
             "iters": iters,
             "min_gain": min_gain,
-            "timing": "fenced chained window (bench.py discipline)",
+            "timing": "fenced chained window",
         },
         "created_t": time.time(),
     }

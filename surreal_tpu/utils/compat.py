@@ -79,7 +79,7 @@ def enable_compile_cache() -> str | None:
     Returns None, touching nothing, where JAX's own switch
     (``jax_enable_compilation_cache`` / ``JAX_ENABLE_COMPILATION_CACHE``)
     has the cache off — tests/conftest.py does that for the CPU suite.
-    Every entry point (CLI, SessionHooks, bench.py, chip_smoke.py) calls
+    Every entry point (CLI, SessionHooks, chip_smoke.py) calls
     this; may be called any number of times, before or after the
     process's first compile."""
     global _CACHE_LISTENER_INSTALLED

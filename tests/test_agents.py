@@ -167,14 +167,17 @@ def test_param_client_recovers_socket_after_timeout():
         agent._client.fetch = lambda *a, **k: (_ for _ in ()).throw(TimeoutError())
         assert agent.fetch_params() is False  # stale copy kept, no raise
         agent._client.fetch = real_fetch
-        pub.publish(agent.acting_view(state))
         import time
 
-        deadline = time.time() + 5
+        # PUB/SUB drops what is published before the server's SUB has
+        # joined: repeat the snapshot, as a learner does at every cadence,
+        # until the recovered client fetches it (bounded at a minute)
+        deadline = time.time() + 60
         ok = False
         while not ok and time.time() < deadline:
-            ok = agent.fetch_params()
+            pub.publish(agent.acting_view(state))
             time.sleep(0.05)
+            ok = agent.fetch_params()
         assert ok
     finally:
         if agent is not None:

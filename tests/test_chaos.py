@@ -304,7 +304,7 @@ def test_mini_campaign_two_real_runs_zero_violations(tmp_path):
     assert len(artifact["sites_covered"]) >= 2
     _assert_clean(artifact)
     # the artifact round-trips through the committed-file writer
-    out = tmp_path / "CHAOS_campaign.json"
+    out = tmp_path / "campaign.json"
     chaos_campaign.write_artifact(str(out), artifact)
     assert json.loads(out.read_text())["kind"] == "chaos_campaign"
 

@@ -148,14 +148,20 @@ def test_sharded_param_server_routes_and_serves():
         assert sharded.address_for("eval-0") == sharded.address_for("eval-0")
         routes = {sharded.address_for(f"eval-{i}") for i in range(32)}
         assert len(routes) > 1  # load actually spreads
-        pub.publish({"w": jnp.full((2,), 9.0)})
         for addr in sharded.addresses:
             c = ParameterClient(addr, template={"w": jnp.zeros(2)})
             clients.append(c)
-            deadline = time.time() + 5
+            # PUB/SUB drops what is published before a shard's SUB has
+            # joined, and when it joins is the scheduler's business: the
+            # publisher repeats its snapshot, as a learner does at every
+            # cadence, until this shard serves it (bounded at a minute)
+            deadline = time.time() + 60
             got = None
             while got is None and time.time() < deadline:
+                pub.publish({"w": jnp.full((2,), 9.0)})
+                time.sleep(0.05)
                 got = c.fetch()
+            assert got is not None, f"shard {addr} never served a snapshot"
             np.testing.assert_allclose(np.asarray(got["w"]), 9.0)
     finally:
         for c in clients:

@@ -45,7 +45,8 @@ def _example():
     }
 
 
-def _make_plane(transport="tcp", kind="uniform", shards=1, **over):
+def _make_plane(transport="tcp", kind="uniform", shards=1, example=None,
+                **over):
     cfg = {
         "num_shards": shards, "shard_mode": "thread",
         "transport": transport, "ack_timeout_s": 1.0,
@@ -54,7 +55,7 @@ def _make_plane(transport="tcp", kind="uniform", shards=1, **over):
     }
     cfg.update(over)
     return ExperiencePlane(
-        kind=kind, example=_example(), capacity=64 * shards,
+        kind=kind, example=example or _example(), capacity=64 * shards,
         batch_size=8 * shards, start_sample_size=1, updates_per_iter=2,
         num_slots=4, max_insert_rows=16, cfg=cfg,
         base_key=jax.random.key(7), prefetch=False, device_put=False,
@@ -237,6 +238,39 @@ def test_sender_hash_routing_and_watermarks():
         assert all(len(v) == 8 for v in info["shard_idx"].values())
     finally:
         plane.close()
+
+
+def test_shm_arm_ships_control_frames_only():
+    """The shm arm's contract as the plane's own gauge counts it: rows and
+    sampled batches travel through slabs, so ``experience/wire_bytes_per_
+    step`` (bytes over the sockets, both ways, per ingested row) holds
+    control frames only — under 100 bytes however wide the row is — while
+    the tcp arm ships the rows themselves: more than a row's 1036 bytes."""
+    example = dict(_example(), obs=np.zeros((256,), np.float32))
+    row_bytes = sum(v.nbytes for v in example.values())
+    per_step = {}
+    for transport in ("shm", "tcp"):
+        plane = _make_plane(transport=transport, example=example)
+        try:
+            rng = np.random.default_rng(0)
+            for _ in range(4):
+                rows = {
+                    k: rng.normal(size=(16,) + v.shape).astype(np.float32)
+                    for k, v in example.items()
+                }
+                wm = plane.sender.send_rows(rows, np.arange(16) % 4)
+            for probe in range(2):
+                plane.sampler.fetch_batch(
+                    jax.random.fold_in(jax.random.key(42), probe), 0.0, wm
+                )
+            assert plane.sender.links[0].transport == transport
+            gauges = plane.gauges()
+            assert gauges["experience/rows"] == 64.0
+            per_step[transport] = gauges["experience/wire_bytes_per_step"]
+        finally:
+            plane.close()
+    assert 0 < per_step["shm"] < 100, per_step
+    assert per_step["tcp"] > row_bytes > per_step["shm"], per_step
 
 
 def test_shm_slabs_unlink_on_close_and_no_fd_leak():

@@ -71,6 +71,42 @@ def test_package_import_initializes_no_backend():
     assert n_modules > 30, f"walk found only {n_modules} modules"
 
 
+def test_package_imports_no_module_from_the_repo_root():
+    """The package stands alone: no module under ``surreal_tpu/`` imports
+    a top-level module that is a file (or a package) beside it at the
+    repository's root — scripts and records there are not part of what is
+    installed, and a lower layer that reaches for one (a function-level
+    ``from <root script> import ...`` under a ``try``) silently changes
+    behaviour with the directory it is started from. The AST walk sees
+    imports at every depth of nesting."""
+    import ast
+
+    beside = {p.stem for p in _REPO_ROOT.glob("*.py")} | {
+        p.parent.name for p in _REPO_ROOT.glob("*/__init__.py")
+    }
+    beside.discard(_PKG_ROOT.name)
+    assert "chip_smoke" in beside, "the walk no longer sees the root scripts"
+    bad = []
+    for path in sorted(_PKG_ROOT.rglob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text(), str(path))):
+            if isinstance(node, ast.Import):
+                names = [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom) and node.level == 0:
+                names = [node.module or ""]
+            else:
+                continue
+            for name in names:
+                if name.split(".", 1)[0] in beside:
+                    bad.append(
+                        f"{path.relative_to(_REPO_ROOT)}:{node.lineno}: "
+                        f"imports {name}"
+                    )
+    assert not bad, (
+        "modules of the package importing from the repository's root:\n"
+        + "\n".join(bad)
+    )
+
+
 _JITTED_STEP_SOURCES = (
     # packages whose modules contain (or are traced into) jitted step code
     "learners", "ops", "replay", "models", "parallel", "envs/jax",

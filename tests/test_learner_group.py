@@ -275,7 +275,7 @@ def test_membership_chaos_join_leave_crash_mid_run(tmp_path):
 # -- remediation actuator -----------------------------------------------------
 
 def test_remediation_scales_learner_group_and_reverts(tmp_path):
-    """A non-regression learner-tier cause (saturation) maps to
+    """A learner-tier cause (saturation) maps to
     learner_scale_up when a group is bound; an ineffective verdict
     (throughput fell further) reverts by removing the joined member."""
     from surreal_tpu.session.remediate import RemediationEngine, load_actions
@@ -328,7 +328,7 @@ def test_remediation_scales_learner_group_and_reverts(tmp_path):
         incidents=stub, trace_id="tr-test",
     )
     rem.bind_actuators(learner_group=group)
-    # saturation (NOT a regression firing) -> scale up the group
+    # a learner-tier cause -> scale up the group
     rem.step([{"detector": "breakout", "tier": "learner"}],
              snap(0, 2000.0))
     assert group.joined == [7]

@@ -130,7 +130,7 @@ def test_trajectory_encoder_sp_matches_single_device():
 
 
 def test_ring_batch_indivisible_learn_shape_raises():
-    """ADVICE r5 low: on a dp x sp mesh, a NON-trivial batch (B>1, T>1)
+    """Round-5 review: on a dp x sp mesh, a NON-trivial batch (B>1, T>1)
     that does not divide the batch axis must raise instead of silently
     replicating (the quiet perf cliff); the known tiny-batch callers —
     init's [1, 1, obs] dummy and the evaluator's B=1 episode — still fall

@@ -49,15 +49,12 @@ def _env(num_envs=8, name="jax:pendulum"):
 _FUSED_CACHE: dict = {}
 
 
-def _fused_iter(algo_name: str, policy: str, horizon=16, num_envs=8, **algo_kw):
-    """One rollout + learn under ``policy``; returns (state, metrics).
-    Memoized per exact config — several tests compare against the same
-    baseline arm, and each uncached call pays an XLA compile (the tier-1
-    wall-clock budget is the constraint)."""
-    cache_key = (algo_name, policy, horizon, num_envs, tuple(sorted(algo_kw.items())))
-    if cache_key in _FUSED_CACHE:
-        return _FUSED_CACHE[cache_key]
-    env = _env(num_envs)
+def _fused_program(algo_name: str, policy: str, horizon=16, num_envs=8,
+                   env_name="jax:pendulum", **algo_kw):
+    """The fused iteration (rollout + learn) under ``policy`` as a jitted
+    function and its arguments: nothing is traced until the caller runs
+    or lowers it."""
+    env = _env(num_envs, env_name)
     cfg = Config(
         algo=Config(name=algo_name, precision=policy, horizon=horizon, **algo_kw)
     )
@@ -72,7 +69,19 @@ def _fused_iter(algo_name: str, policy: str, horizon=16, num_envs=8, **algo_kw):
         lb = {k: batch[k] for k in LEARN_KEYS}
         return learner.learn(state, lb, key)
 
-    state, metrics = it(state, carry, key)
+    return it, (state, carry, key)
+
+
+def _fused_iter(algo_name: str, policy: str, horizon=16, num_envs=8, **algo_kw):
+    """One rollout + learn under ``policy``; returns (state, metrics).
+    Memoized per exact config — several tests compare against the same
+    baseline arm, and each uncached call pays an XLA compile (the tier-1
+    wall-clock budget is the constraint)."""
+    cache_key = (algo_name, policy, horizon, num_envs, tuple(sorted(algo_kw.items())))
+    if cache_key in _FUSED_CACHE:
+        return _FUSED_CACHE[cache_key]
+    it, args = _fused_program(algo_name, policy, horizon, num_envs, **algo_kw)
+    state, metrics = it(*args)
     out = (state, jax.device_get(metrics))
     _FUSED_CACHE[cache_key] = out
     return out
@@ -292,6 +301,31 @@ def test_bf16_vs_f32_fused_iteration(algo):
     for k in ("loss/pg", "loss/value"):
         np.testing.assert_allclose(m16[k], mm[k], rtol=1e-5, atol=1e-6)
     _tree_close(s16.params, sm.params, atol=1e-5)
+
+
+def test_bf16_policy_cuts_bytes_accessed_of_the_fused_iteration():
+    """What the low-precision pipeline is for, as a count a CPU can state:
+    XLA's cost model (deterministic, from the lowering alone — nothing is
+    compiled or run) reads at least 25% fewer bytes accessed for the
+    fused PPO iteration on ``jax:lift`` under ``bf16`` than under
+    ``f32``, because the rollout is staged and the minibatches are read
+    at half the width; ``mixed`` stages the same way. The FLOPs stay
+    where they were: the policy changes widths, not work."""
+    from surreal_tpu.session.costs import program_costs
+
+    cost = {}
+    for policy in ("f32", "mixed", "bf16"):
+        it, args = _fused_program(
+            "ppo", policy, horizon=64, num_envs=512, env_name="jax:lift"
+        )
+        cost[policy] = program_costs(it, *args)
+        assert cost[policy] is not None, policy
+    f32 = cost["f32"]["bytes_accessed"]
+    assert f32 > 0
+    for policy in ("mixed", "bf16"):
+        assert cost[policy]["bytes_accessed"] <= 0.75 * f32, (policy, cost)
+        assert abs(cost[policy]["flops"] / cost["f32"]["flops"] - 1) < 0.02, (
+            policy, cost)
 
 
 @pytest.mark.slow

@@ -280,37 +280,6 @@ def test_dead_tier_targeted_restart_is_irreversible(tmp_path):
     assert act["reverted"] is False and act["status"] == "done"
 
 
-def test_learner_regression_downshifts_and_restore_reverts(tmp_path):
-    """A learner-tier cause WITH a regression firing rides the config
-    overrides path: downshift() returns the prior values, and an
-    ineffective verdict (throughput fell further) hands them back to
-    restore()."""
-    applied, restored = [], []
-
-    def downshift():
-        applied.append(1)
-        return {"batch_size": 256}
-
-    stub = _StubIncidents(_incident("learner"))
-    rem = _engine(tmp_path, stub)
-    rem.bind_actuators(learner_downshift=downshift,
-                       learner_restore=restored.append)
-    # no regression firing -> unmapped, the downshift is never invoked
-    rem.step([{"detector": "breakout", "tier": "learner"}], _snap(0))
-    assert applied == [] and rem.unmapped == 1
-    rem.step([{"detector": "regression", "tier": "learner",
-               "signal": "time/env_steps_per_s"}],
-             _snap(1, steps_per_s=2000.0))
-    assert applied == [1]
-    # throughput fell FURTHER -> ineffective -> restore(prior)
-    rem.step([], _snap(2, steps_per_s=1000.0))
-    rem.step([], _snap(3, steps_per_s=900.0))
-    assert restored == [{"batch_size": 256}]
-    (act,) = load_actions(str(tmp_path))
-    assert act["kind"] == "learner_downshift"
-    assert act["verdict"] == "ineffective" and act["reverted"] is True
-
-
 # -- bounds: budget, cooldown, errors (all loud) ------------------------------
 
 def test_action_budget_exhaustion_suppresses_loudly(tmp_path):

@@ -98,7 +98,7 @@ def test_cold_codec_error_within_documented_bound():
     """Quantized cold reads: every Q8 field reconstructs within
     q8_error_bound of its per-segment [lo, hi]; f16 fields within f16
     roundoff; non-f32 fields exact. And the quantized row is >= 25%
-    smaller than the raw f32 row (the BENCH_tiers acceptance bound)."""
+    smaller than the raw f32 row (the tier's acceptance bound)."""
     rng = np.random.default_rng(3)
     rows = {
         "obs": rng.normal(size=(64, 3)).astype(np.float32),

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-T, B = 256, 4096            # the fused PPO geometry (bench.py, chip_smoke.py)
+T, B = 256, 4096            # the fused PPO geometry (chip_smoke.py)
 VT, VB = 32, 1024           # IMPALA's V-trace geometry
 BATCH = 256                 # DDPG replay batch
 # leaves of the DDPG jax:lift replay example (OffPolicyTrainer._replay_example)

@@ -258,7 +258,7 @@ def test_tune_cli_writes_cache_artifact_and_telemetry(tmp_path, capsys):
     from surreal_tpu.main.launch import main
 
     folder = str(tmp_path / "sess")
-    out = str(tmp_path / "BENCH_tune.json")
+    out = str(tmp_path / "tune.json")
     argv = [
         "tune", "ppo", "jax:pendulum", "--folder", folder,
         "--num-envs", "8",

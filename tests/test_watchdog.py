@@ -160,34 +160,6 @@ def test_staleness_growth_needs_the_floor():
     ), fired
 
 
-def test_regression_detector_vs_committed_baseline():
-    """Live throughput below ``regression_frac`` x the committed bench
-    row for the same fingerprint fires after ``regression_sustain``
-    sweeps; a mismatched-platform row disarms the detector."""
-    rows = [{"file": "BENCH_r99.json", "metric": "env_steps_per_sec_x",
-             "value": 20000.0, "platform": "cpu", "geometry": None,
-             "mfu": None, "failed": False}]
-    wd = Watchdog(cfg={"regression_sustain": 2}, baseline_rows=rows,
-                  platform="cpu")
-    # a healthy sweep above the threshold arms nothing
-    assert wd.evaluate(make_snap(0, steps_per_s=15000.0)) == []
-    # 5000 steps/s < 0.5 x 20000: fires on the SECOND sustained sweep
-    assert all(
-        f["detector"] != "regression" for f in wd.evaluate(make_snap(1))
-    )
-    fired = wd.evaluate(make_snap(2))
-    assert any(
-        f["detector"] == "regression" and f["signal"] == "throughput"
-        and f["bench"] == "BENCH_r99.json" for f in fired
-    ), fired
-    # other platform: no committed fingerprint -> disarmed
-    wd2 = Watchdog(baseline_rows=rows, platform="tpu")
-    for s in WARM:
-        assert all(
-            f["detector"] != "regression" for f in wd2.evaluate(s)
-        )
-
-
 def test_false_positive_guard_clean_run_zero_incidents(tmp_path):
     """The guard rail: 200 healthy sweeps with mild deterministic noise
     on every signal, default thresholds — zero firings, zero incidents,
