@@ -79,10 +79,9 @@ PPO_LEARNER_CONFIG = Config(
         sgd_unroll=1,         # minibatch-scan unroll inside _sgd_epochs
                               # (searched autotuner dimension — tune/space.py)
         shuffle="block",      # minibatch shuffling: 'block' permutes
-                              # contiguous blocks (the TPU-fast path —
-                              # row gathers and 1M-element permutations
-                              # were ~70% of the measured learn phase;
-                              # see _sgd_epochs) | 'row' (exact per-row
+                              # contiguous blocks, read where they lie
+                              # when long enough (_sgd_epochs has the
+                              # chip's readings) | 'row' (exact per-row
                               # reshuffles, the reference's semantics)
         # value forward for GAE: 'exact' runs a second model.apply over
         # next_obs so truncated episodes bootstrap off the TRUE pre-reset
@@ -107,9 +106,10 @@ def _block_layout(domain: int, num_mb: int, row_bytes: int) -> int:
     permutation of minibatch order; (c) SKINNY rows — row shuffling is
     only slow for 4-byte-row leaves that walk the TPU scalar unit, while
     rows past ~4 KB (pixel obs, whole-env segments) already gather as
-    efficient contiguous DMA AND block-gathering their megabyte slices
-    hits a pathological path on this backend (measured on nut_pixels:
-    fused iter 91 ms row vs 63,000 ms block)."""
+    efficient contiguous DMA, and block-gathering their megabyte slices
+    was pathological where it was tried (nut_pixels, before PR 23 and not
+    on this chip: a fused iteration of 91 ms by rows took 63,000 ms by
+    blocks; no cell of BENCHMARK.json runs fat rows)."""
     if domain % num_mb != 0 or row_bytes > 4096:
         return 0
     mb_size = domain // num_mb
@@ -117,6 +117,32 @@ def _block_layout(domain: int, num_mb: int, row_bytes: int) -> int:
     while blocks_per_mb < 64 and mb_size % (blocks_per_mb * 2) == 0:
         blocks_per_mb *= 2
     return blocks_per_mb if blocks_per_mb >= 4 else 0
+
+
+# Rows a block needs before a minibatch is read a block a trip where the
+# blocks lie (``_mb_pieces``). The fused iteration at horizon 256 on the
+# v5e, gathered -> in place, fenced ms (my chip runs, PR 31): 2048 envs,
+# so 2048 rows a block, 16.09 -> 22.92; 4096: 25.42 -> 25.80; 8192: 56.08
+# -> 39.58; 16384: 126.08 -> 63.22; 32768: 340.49 -> 118.90; 65536 (the
+# cells of BENCHMARK.json): 801.45 -> 223.77.
+_IN_PLACE_BLOCK_ROWS = 8192
+# Blocks per trip of that loop: against 1, 2 saves 1.2% of the iteration
+# at 65536 rows a block, 2.6% at 16384, 6.5% at 8192; 4 costs 17% at 65536
+# (my chip runs, PR 31).
+_BLOCK_UNROLL = 2
+
+
+def _mb_pieces(blocks_per_mb: int, block_len: int) -> int:
+    """In how many pieces ``_sgd_epochs`` consumes a minibatch of
+    ``blocks_per_mb`` permuted blocks of ``block_len`` rows: 1 gathers
+    them into one array and differentiates once; ``blocks_per_mb`` takes a
+    block a trip by a slice over the leading axis, so no minibatch is
+    built, and pays a loop trip and a forward-backward pass per block.
+    Nothing between: any piece of two blocks or more is a gather again,
+    at the same cost a row. Row mode (``blocks_per_mb`` 0) is one piece."""
+    if blocks_per_mb and block_len >= _IN_PLACE_BLOCK_ROWS:
+        return blocks_per_mb
+    return 1
 
 
 class PPOState(NamedTuple):
@@ -405,15 +431,26 @@ class PPOLearner(SequenceActingMixin, Learner):
         ``algo.shuffle`` selects how minibatches are drawn:
 
         - 'block' (default): permute CONTIGUOUS BLOCKS (up to 64 per
-          minibatch), not rows. Measured on the v5lite headline (4096
-          envs x 256 horizon): per-epoch row shuffling costs ~109 ms —
-          a 1M-element argsort permutation plus random gathers of
-          4-byte-row leaves that walk the scalar unit — while ALL
-          sixteen grad steps cost 19.6 ms; block shuffling turns the
-          gathers into long contiguous slices and shrinks the
-          permutation ~16000x. Statistically benign here: a flat-layout
-          block is a same-timestep slab of independent envs, so
-          within-block correlation is near zero.
+          minibatch), not rows: the permutation is 256 ids where rows
+          would be millions, and a flat-layout block is a same-timestep
+          slab of independent envs, so within-block correlation is near
+          zero. A block of ``_IN_PLACE_BLOCK_ROWS`` rows or more is read
+          where it lies (``_mb_pieces``): a slice over the leading axis a
+          trip, gradient and aux summed in float32 over the minibatch's
+          blocks, one optimizer step a minibatch as ever. Gathering such
+          blocks (``x[mb_idx]``) is the slow thing on this chip: at 65 536
+          envs x 256 a block is one time step of the rollout's
+          ``[T, B, ...]``, laid out time-major with the features on
+          sublanes and the envs on lanes; XLA's gather relays the whole
+          leaf so that the 256 blocks lie on sublanes, then fills the
+          minibatch one sublane row of every (8,128) tile a trip, about
+          10 GB/s of the chip's 819. ``ppo_lift_long`` read
+          ``phase_shuffle_ms`` 335.90 and ``phase_sgd_ms`` 356.44 of
+          ``fenced_iter_ms`` 803.89 gathered (PERF_LEDGER.jsonl, PR 30)
+          and 14.48 and 98.70 of 227.09 in place (my chip run, PR 31):
+          ``sgd`` falls too, its activations being ``[65536, 64]`` a
+          block where they were ``[4194304, 64]``. Shorter blocks are
+          gathered: 1024 small passes an iteration lose to it.
         - 'row': exact per-row reshuffling every epoch (the reference's
           semantics), for geometries too small/odd to block (also the
           automatic fallback when fewer than 4 blocks fit a minibatch).
@@ -441,27 +478,71 @@ class PPOLearner(SequenceActingMixin, Learner):
                 data = jax.tree.map(
                     lambda x: x.reshape(nblocks, block_len, *x.shape[1:]), data
                 )
-            unblock = lambda x: x.reshape(
-                blocks_per_mb * block_len, *x.shape[2:]
-            )
+            unblock = lambda x: x.reshape(-1, *x.shape[2:])
             perm_domain, idx_shape = nblocks, (num_mb, blocks_per_mb)
+            pieces = _mb_pieces(blocks_per_mb, block_len)
         else:
             unblock = lambda x: x
             perm_domain, idx_shape = domain, (num_mb, mb_size)
+            pieces = 1
+        in_place = pieces > 1   # then a piece is one block (_mb_pieces)
 
         def mb_update(carry, mb_idx):
             params, opt_state, stopped = carry
-            with phase("shuffle"):
-                mb = jax.tree.map(lambda x: unblock(x[mb_idx]), data)
             with phase("sgd"):
                 policy_coeff = jnp.where(stopped, 0.0, 1.0)
                 # precision: the loss scale rides the carried opt_state (a
                 # traced input — scale changes never recompile); 1.0 when
                 # the policy carries no scale
                 scale = current_loss_scale(opt_state)
-                grads, aux = grad_fn(
-                    params, mb, state.kl_beta, policy_coeff, scale
+
+            def piece_grads(idx):
+                """(grads, aux) of the rows ``idx`` names: one block id,
+                sliced where the block lies, or all of the minibatch's
+                ids, gathered."""
+                with phase("shuffle"):
+                    if in_place:
+                        mb = jax.tree.map(
+                            lambda x: jax.lax.dynamic_index_in_dim(
+                                x, idx, 0, keepdims=False
+                            ),
+                            data,
+                        )
+                    else:
+                        mb = jax.tree.map(lambda x: unblock(x[idx]), data)
+                with phase("sgd"):
+                    return grad_fn(
+                        params, mb, state.kl_beta, policy_coeff, scale
+                    )
+
+            if in_place:
+                # every reduction of _loss_fn is a mean and the blocks are
+                # equal-sized, so the minibatch's gradient and aux are the
+                # means of the blocks': summed in float32, divided once
+                out = jax.eval_shape(piece_grads, mb_idx[0])
+
+                def add_block(total, block_id):
+                    block = piece_grads(block_id)
+                    with phase("sgd"):
+                        return jax.tree.map(
+                            lambda t, x: t + x.astype(jnp.float32),
+                            total, block,
+                        ), None
+
+                total, _ = jax.lax.scan(
+                    add_block,
+                    jax.tree.map(
+                        lambda o: jnp.zeros(o.shape, jnp.float32), out
+                    ),
+                    mb_idx, unroll=_BLOCK_UNROLL,
                 )
+                with phase("sgd"):
+                    grads, aux = jax.tree.map(
+                        lambda t, o: (t / pieces).astype(o.dtype), total, out
+                    )
+            else:
+                grads, aux = piece_grads(mb_idx)
+            with phase("sgd"):
                 if axis_name is not None:
                     with phase("sgd/psum"):
                         grads = jax.lax.pmean(grads, axis_name)

@@ -9,7 +9,7 @@ code and no host clock or fence enters it.
 
     collect          rollout scan: act, env step, episode bookkeeping
     prepare          PPO: obs filter, value forward, GAE, advantage norm
-    shuffle          PPO: per-epoch permutation, block layout, minibatch gather
+    shuffle          PPO: per-epoch permutation; the minibatch gather, if one is built
     sgd              PPO: loss, grad, optimizer apply (dp psums: sgd/psum)
     finalize         PPO: beta adaptation, new state, metrics
     replay_insert    ring insert (+ fresh priorities)

@@ -530,6 +530,111 @@ def test_block_layout_selection_rules():
             assert domain % (num_mb * k) == 0
 
 
+@pytest.mark.parametrize(
+    "envs,horizon,blocks_per_mb,block_len,pieces",
+    [
+        pytest.param(65536, 256, 64, 65536, 64, id="cells-65536x256-in-place"),
+        pytest.param(8192, 256, 64, 8192, 64, id="8192x256-in-place"),
+        pytest.param(4096, 256, 64, 4096, 1, id="4096x256-gather"),
+        pytest.param(8, 16, 32, 1, 1, id="8x16-one-row-blocks-gather"),
+        pytest.param(25, 10, 0, 0, 1, id="row-mode-one-piece"),
+    ],
+)
+def test_mb_pieces_rule(envs, horizon, blocks_per_mb, block_len, pieces):
+    """How ``_sgd_epochs`` consumes a minibatch is a function of the block
+    length alone (learners/ppo.py::_mb_pieces): a block a trip, sliced
+    where it lies, at the cells' 65 536 rows a block; one gather of the
+    whole minibatch at 4096 rows and below, where 1024 small passes lose
+    to it; row mode has no blocks to read in place."""
+    from surreal_tpu.learners.ppo import _block_layout, _mb_pieces
+
+    domain, num_mb = envs * horizon, 4
+    assert _block_layout(domain, num_mb, 17 * 4) == blocks_per_mb
+    if blocks_per_mb:
+        assert domain // num_mb // blocks_per_mb == block_len
+    assert _mb_pieces(blocks_per_mb, block_len) == pieces
+
+
+@pytest.mark.parametrize("precision", ["f32", "mixed"])
+@pytest.mark.parametrize("axis_name", [None, "dp"])
+def test_in_place_blocks_match_gathered_minibatch(monkeypatch, axis_name, precision):
+    """``learn`` with each minibatch read a block a trip (pieces =
+    blocks_per_mb) against one gather of all its blocks (pieces = 1): the
+    same permutation, rows, sixteen optimizer steps and KL stop, so the
+    same new parameters, optimizer state and metrics up to the order of
+    the float32 sum over blocks (under 'mixed' also up to where a weight
+    gradient is rounded to bfloat16: once a block, not once a minibatch).
+    With ``axis_name`` it runs under shard_map on the simulated mesh, two
+    envs to a device: the psum a minibatch is the same one."""
+    import surreal_tpu.learners.ppo as ppo
+    from surreal_tpu.parallel import dp_learn, make_mesh
+
+    T, B = 32, 16 if axis_name else 8
+    learner = build_learner(
+        Config(algo=Config(name="ppo", precision=precision)), _continuous_specs()
+    )
+    state = learner.init(jax.random.key(0))
+    batch = _fake_batch(jax.random.key(1), T=T, B=B)
+    # per device 32 x 2 (dp=8) or 32 x 8 samples: 16 or 64 a minibatch
+    per_mb = ppo._block_layout(T * (2 if axis_name else B), 4, 6 * 4)
+    assert per_mb >= 16
+
+    def learn(pieces):
+        monkeypatch.setattr(
+            ppo, "_mb_pieces", lambda blocks, rows: pieces or blocks
+        )
+        if axis_name is None:
+            step = jax.jit(learner.learn)
+        else:
+            mesh = make_mesh(Config(mesh=Config({"dp": 8})))
+            step = dp_learn(learner, mesh, donate=False)
+        out = step(state, batch, jax.random.key(2))
+        text = step.lower(state, batch, jax.random.key(2)).as_text()
+        return out, text
+
+    (gathered, g_metrics), g_text = learn(1)
+    (in_place, p_metrics), p_text = learn(0)
+    # the in-place program holds one loop more (epochs, minibatches, blocks)
+    assert p_text.count("stablehlo.while") == g_text.count("stablehlo.while") + 1
+
+    assert set(p_metrics) == set(g_metrics)
+    assert float(p_metrics["policy/early_stopped"]) == float(
+        g_metrics["policy/early_stopped"]
+    )
+    if precision == "f32":
+        for k in g_metrics:
+            np.testing.assert_allclose(
+                float(p_metrics[k]), float(g_metrics[k]), err_msg=k,
+                rtol=2e-4, atol=2e-6,
+            )
+        jax.tree.map(
+            lambda a, b: np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-6),
+            (in_place.params, in_place.opt_state),
+            (gathered.params, gathered.opt_state),
+        )
+    else:
+        # 'mixed' rounds a weight or bias gradient to bfloat16 where the
+        # rows are contracted, so a float32 sum over 1-row blocks and one
+        # bfloat16 sum over a minibatch differ by percents in an element
+        # near zero, and Adam turns a flipped sign into 2 x lr a step
+        # (seen here: 12 of 4096 elements of one kernel off by up to
+        # 2.3e-3 after the 16 steps). A wrong row or a dropped block would
+        # move most elements, so the bound is on the share that moved.
+        for k in g_metrics:
+            np.testing.assert_allclose(
+                float(p_metrics[k]), float(g_metrics[k]), err_msg=k,
+                rtol=5e-3, atol=1e-3,
+            )
+        moved = np.concatenate([
+            np.abs(np.asarray(a) - np.asarray(b)).ravel() > 5e-4
+            for a, b in zip(
+                jax.tree.leaves(in_place.params), jax.tree.leaves(gathered.params)
+            )
+        ])
+        assert moved.mean() < 0.02, moved.mean()
+    assert int(in_place.iteration) == int(gathered.iteration) == 1
+
+
 def test_shuffle_block_matches_row_for_single_minibatch():
     """With one minibatch per epoch both modes train on ALL rows in one
     gradient, so block and row must produce the same update (up to f32

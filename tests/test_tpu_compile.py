@@ -35,6 +35,12 @@ def chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture
+def sds(chip):
+    """A shape on the described chip: what a compile takes for an array."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+
 @pytest.fixture(autouse=True)
 def _no_persistent_cache():
     """A compile for a described chip is written to the persistent cache
@@ -98,7 +104,7 @@ def _scatter(capacity):
     return build
 
 
-def _fused_ppo(sds):
+def _fused_ppo(sds, envs=B, learner=None):
     """Trainer's fused rollout+learn step at the headline geometry. The
     test jits the step itself: ``Trainer._train_iter`` is built over this
     process's (CPU) devices."""
@@ -108,8 +114,10 @@ def _fused_ppo(sds):
     from surreal_tpu.session.default_configs import base_config
 
     cfg = Config(
-        learner_config=Config(algo=Config(name="ppo", horizon=T)),
-        env_config=Config(name="jax:lift", num_envs=B),
+        learner_config=Config(algo=Config(name="ppo", horizon=T)).extend(
+            learner or {}
+        ),
+        env_config=Config(name="jax:lift", num_envs=envs),
         session_config=Config(folder="unused"),
     ).extend(base_config())
     trainer = Trainer(cfg)
@@ -120,7 +128,7 @@ def _fused_ppo(sds):
     key = jax.eval_shape(lambda: jax.random.key(0))
     state = jax.eval_shape(trainer.learner.init, key)
     carry = jax.eval_shape(
-        lambda k: init_device_carry(trainer.env, k, B), key
+        lambda k: init_device_carry(trainer.env, k, envs), key
     )
     step = jax.jit(trainer._device_train_iter, donate_argnums=(0, 1))
     return step, (like(state), like(carry), like(key))
@@ -144,10 +152,7 @@ CASES = [
 
 
 @pytest.mark.parametrize("build,is_kernel", CASES)
-def test_compiles_for_v5e(chip, build, is_kernel):
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
+def test_compiles_for_v5e(sds, build, is_kernel):
     fn, args = build(sds)
     lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(*args)
     if is_kernel:
@@ -199,3 +204,41 @@ def test_ring_insert_compiles_to_window_writes(chip):
     # every output (the six ring arrays, three scalars) reuses its input
     assert mem.output_size_in_bytes - mem.alias_size_in_bytes < 2**20
     assert mem.temp_size_in_bytes < 0.6e9
+
+
+def test_fused_ppo_65536x256_reads_blocks_in_place(sds):
+    """The fused PPO iteration at the geometry and overrides of
+    ``ppo_lift_long`` (65 536 envs x 256, 64-64 tanh, ``mixed``): a
+    minibatch's 64 blocks are each one time step of the rollout's own
+    ``[T, B, ...]``, read by a slice over the major axis. Gathered, XLA
+    relays the obs leaf so that the blocks lie on sublanes and fills six
+    column pieces a sublane row a trip: 336 of the cell's 804 ms
+    (PERF_LEDGER.jsonl, PR 30: ``phase_shuffle_ms``, five
+    ``dynamic-update-slice bf16[64,11008,17]`` and ``copy
+    bf16[256,65536,17]``). The phases are the profile digest's own
+    reading of the program's text."""
+    import re
+
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.profile import hlo_op_phases
+
+    cell = Config(
+        algo=Config(clip_ratio=0.2, precision="mixed"),
+        model=Config(
+            actor_hidden=[64, 64], critic_hidden=[64, 64], activation="tanh"
+        ),
+        optimizer=Config(lr=3e-4),
+    )
+    step, args = _fused_ppo(sds, envs=65536, learner=cell)
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    _, phases = hlo_op_phases(text)
+    written = [
+        name for name, phase in phases.items()
+        if phase == "shuffle" and name.startswith("dynamic-update-slice")
+    ]
+    assert not written, f"the minibatch is built again: {written}"
+    assert "mini-gather" not in text
+    layouts = set(re.findall(r"bf16\[256,65536,17\]\{[^}]*\}", text))
+    assert len(layouts) == 1, f"the obs leaf is relaid: {layouts}"
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.1e9
