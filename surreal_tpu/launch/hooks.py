@@ -245,7 +245,7 @@ class SessionHooks:
         # maps of the programs the cost accountant registered
         self.profile = ProfileManager(
             cfg, cfg.folder, self.tracer, self.log,
-            op_phases=self.costs.op_phases,
+            op_phases=self.costs.op_phases, op_parts=self.costs.op_parts,
         )
         # watchdog & incident engine (ISSUE 15): detector sweeps over each
         # merged ops snapshot, firings correlated into root-caused
@@ -547,6 +547,7 @@ class SessionHooks:
             with self.tracer.span("metrics-sync"):
                 raw = metrics() if callable(metrics) else (metrics or {})
                 m = {k: float(v) for k, v in raw.items()}
+            refuse_dropped_assignments(m)
             # fence to fence: the only span whose total is device time
             # (perf/* divide by it; a rollback's backward step is skipped)
             now = time.perf_counter()
@@ -852,6 +853,22 @@ def host_metrics(metrics, recent_returns, window: int = HOST_METRICS_WINDOW):
         return m
 
     return build
+
+
+def refuse_dropped_assignments(row: dict) -> None:
+    """A routed-expert layer promises that no token routed to a held
+    expert is dropped; its sorted buffer is a static bound under the worst
+    case (ops/moe.py), and ``moe/overflow`` counts what fell outside it in
+    the iteration the row reports. A non-zero is a broken promise, not a
+    gauge."""
+    dropped = row.get("moe/overflow", 0.0)
+    if dropped > 0.0:
+        raise RuntimeError(
+            f"moe/overflow: {dropped:.0f} assignments to held experts fell "
+            "outside the sorted buffer and were dropped; the router sends "
+            "this chip more than ops/moe.py::CAPACITY_FACTOR times its even "
+            "share"
+        )
 
 
 def training_env_config(env_config) -> Config:

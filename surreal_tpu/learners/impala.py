@@ -37,6 +37,7 @@ from surreal_tpu.learners.base import (
     training_health,
 )
 from surreal_tpu.learners.seq_policy import SequenceActingMixin, build_seq_model
+from surreal_tpu.models.attention import block_family
 from surreal_tpu.models.ppo_net import CategoricalPPOModel, PPOModel
 from surreal_tpu.ops import distributions as D
 from surreal_tpu.ops.precision import current_loss_scale, loss_scale_metrics
@@ -85,6 +86,12 @@ class IMPALALearner(SequenceActingMixin, Learner):
         enc = learner_config.model.get("encoder", None)
         self.seq_policy = bool(enc is not None and enc.get("kind") == "trajectory")
         self.requires_act_carry = self.seq_policy
+        if self.seq_policy and block_family(enc) != "preln":
+            raise ValueError(
+                "model.encoder.block='mla_moe' is wired into PPO alone: its "
+                "router-bias rule runs after each optimizer step "
+                "(learners/ppo.py); IMPALA takes the 'preln' blocks"
+            )
         # precision: model dtypes materialize from the resolved policy
         # (Learner.__init__), 'auto' knobs -> concrete per algo.precision
         model_cfg = self.policy.model_config(learner_config.model)

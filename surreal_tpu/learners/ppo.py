@@ -39,7 +39,13 @@ from surreal_tpu.learners.base import (
     training_health,
 )
 from surreal_tpu.ops.precision import current_loss_scale, loss_scale_metrics
-from surreal_tpu.learners.seq_policy import SequenceActingMixin, build_seq_model
+from surreal_tpu.learners.seq_policy import (
+    SequenceActingMixin,
+    build_seq_model,
+    family_config,
+)
+from surreal_tpu.models import latent_moe
+from surreal_tpu.models.attention import block_family
 from surreal_tpu.models.ppo_net import CategoricalPPOModel, PPOModel
 from surreal_tpu.ops import distributions as D
 from surreal_tpu.ops.running_stats import (
@@ -49,7 +55,7 @@ from surreal_tpu.ops.running_stats import (
     update_stats,
 )
 from surreal_tpu.session.config import Config
-from surreal_tpu.utils.phases import phase
+from surreal_tpu.utils.phases import part, phase
 
 PPO_LEARNER_CONFIG = Config(
     algo=Config(
@@ -163,6 +169,14 @@ class PPOLearner(SequenceActingMixin, Learner):
         enc = learner_config.model.get("encoder", None)
         self.seq_policy = bool(enc is not None and enc.get("kind") == "trajectory")
         self.requires_act_carry = self.seq_policy
+        # routed-expert blocks (model.encoder.block='mla_moe'): every apply
+        # that learns reads the router's statistics, and the selection
+        # bias moves by its own rule after each optimizer step
+        self.moe = None
+        if self.seq_policy:
+            enc_cfg = family_config(enc.to_dict())
+            if block_family(enc_cfg) == "mla_moe":
+                self.moe = enc_cfg
         # precision: model dtypes materialize from the resolved policy
         # (Learner.__init__), 'auto' knobs -> concrete per algo.precision
         model_cfg = self.policy.model_config(learner_config.model)
@@ -202,7 +216,9 @@ class PPOLearner(SequenceActingMixin, Learner):
             obs = jnp.zeros((1, 1, *self.specs.obs.shape), self.specs.obs.dtype)
         else:
             obs = jnp.zeros((1, *self.specs.obs.shape), self.specs.obs.dtype)
-        params = self.model.init(key, obs)
+        # the parameters alone: a routed-expert model's init also sows its
+        # statistics, which belong to an apply, not to the state
+        params = {"params": self.model.init(key, obs)["params"]}
         return PPOState(
             params=params,
             opt_state=self.tx.init(params),
@@ -227,6 +243,16 @@ class PPOLearner(SequenceActingMixin, Learner):
         if not self._use_obs_filter:
             return obs
         return normalize(stats, obs.astype(jnp.float32))
+
+    def _apply(self, params, obs):
+        """``(model output, router statistics)`` of one learn-side apply:
+        ``{"load": [layers, n_routed], "overflow": scalar}`` for
+        routed-expert blocks (models/latent_moe.py), else ``None``."""
+        if self.moe is None:
+            return self.model.apply(params, obs), None
+        collection = latent_moe.MOE_COLLECTION
+        out, sown = self.model.apply(params, obs, mutable=[collection])
+        return out, latent_moe.moe_stats(sown[collection])
 
     # -- acting --------------------------------------------------------------
     def act(self, state: PPOState, obs: jax.Array, key: jax.Array, mode: str = TRAINING):
@@ -380,7 +406,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         chain divides the gradients back down and skips overflowed steps.
         """
         algo = self.config.algo
-        out = self.model.apply(params, mb["obs"])
+        out, moe = self._apply(params, mb["obs"])
         if self.discrete:
             logp = D.categorical_logp(out.logits, mb["action"])
             kl = D.categorical_kl(mb["b_logits"], out.logits).mean()
@@ -414,12 +440,30 @@ class PPOLearner(SequenceActingMixin, Learner):
             policy_coeff * (pg_loss - algo.entropy_coeff * entropy)
             + algo.value_coeff * v_loss
         )
-        return total * loss_scale, {
+        aux = {
             "pg_loss": pg_loss,
             "v_loss": v_loss,
             "entropy": entropy,
             "kl": kl,
         }
+        if moe is not None:
+            aux["moe_load"] = jax.lax.stop_gradient(moe["load"])
+            aux["moe_overflow"] = jax.lax.stop_gradient(moe["overflow"])
+        return total * loss_scale, aux
+
+    @part("optimizer")
+    def _optimizer_step(self, params, opt_state, grads, aux):
+        """One optimizer step on a minibatch's gradient: ``(params,
+        opt_state)``. With routed-expert blocks the selection bias, which
+        has no gradient (Adam leaves it where it is), then moves by its own
+        rule on this step's loads (``aux["moe_load"]``)."""
+        updates, opt_state = self.tx.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
+        if self.moe is not None:
+            params = latent_moe.update_router_bias(
+                params, aux["moe_load"], float(self.moe["bias_update_speed"])
+            )
+        return params, opt_state
 
     def _sgd_epochs(self, state, data, domain, num_mb, key, axis_name):
         """epochs x minibatches as one nested lax.scan with KL early-stop.
@@ -553,8 +597,9 @@ class PPOLearner(SequenceActingMixin, Learner):
                 # thresholds see the TRUE gradient magnitude; inf/nan
                 # survive the division.
                 aux["grad_norm"] = optax.global_norm(grads) / scale
-                updates, opt_state = self.tx.update(grads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+                params, opt_state = self._optimizer_step(
+                    params, opt_state, grads, aux
+                )
                 stopped = jnp.logical_or(
                     stopped, aux["kl"] > algo.kl_early_stop * algo.kl_target
                 )
@@ -590,7 +635,7 @@ class PPOLearner(SequenceActingMixin, Learner):
     @phase("finalize")
     def _finalize(
         self, state, obs_stats, sgd_out, values, value_targets, advantages,
-        axis_name,
+        axis_name, prepare_overflow=0.0,
     ):
         """Beta adaptation + new state + the shared metrics dict."""
         algo = self.config.algo
@@ -632,6 +677,19 @@ class PPOLearner(SequenceActingMixin, Learner):
         metrics.update(
             training_health(state.params, params, auxs["grad_norm"].mean())
         )
+        if self.moe is not None:
+            # assignments over every minibatch step and routed layer
+            load = auxs["moe_load"].sum((0, 1, 2))
+            first, held = int(self.moe["first_held"]), int(self.moe["num_held"])
+            mine = load[first:first + held]
+            metrics.update({
+                "moe/held_share": mine.sum() / load.sum(),
+                "moe/load_max_over_mean": mine.max() / mine.mean(),
+                "moe/overflow": auxs["moe_overflow"].sum() + prepare_overflow,
+                "moe/bias_abs_max": jnp.stack(
+                    [jnp.abs(b).max() for b in latent_moe.router_biases(params)]
+                ).max(),
+            })
         # precision: loss-scale telemetry (device scalars riding the
         # metrics cadence); empty dict when the policy carries no scale
         metrics.update(loss_scale_metrics(opt_state))
@@ -667,7 +725,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         T, B = batch["reward"].shape
 
         with phase("prepare"):
-            obs_stats, values, value_targets, advantages, data = (
+            obs_stats, values, value_targets, advantages, data, moe = (
                 self._prepare_seq(state, batch, axis_name)
             )
         if B // algo.num_minibatches == 0:
@@ -681,11 +739,13 @@ class PPOLearner(SequenceActingMixin, Learner):
         return self._finalize(
             state, obs_stats, sgd_out, values, value_targets, advantages,
             axis_name,
+            prepare_overflow=0.0 if moe is None else moe["overflow"],
         )
 
     def _prepare_seq(self, state, batch, axis_name):
         """The sequence path's ``prepare`` phase: obs filter, one
-        extended value pass, GAE, advantage norm, env-major staging."""
+        extended value pass, GAE, advantage norm, env-major staging; last
+        the value pass's router statistics (``None`` without experts)."""
         T, B = batch["reward"].shape
         if self._use_obs_filter:
             obs_stats = update_stats(
@@ -702,7 +762,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         )
         last_next = self._norm_obs(obs_stats, batch["next_obs"][-1])
         ext = jnp.concatenate([obs_bt, last_next[:, None]], axis=1)
-        out_ext = self.model.apply(state.params, ext)   # [B, T+1, ...]
+        out_ext, moe = self._apply(state.params, ext)   # [B, T+1, ...]
         values = out_ext.value[:, :T].swapaxes(0, 1)    # [T, B]
         v_next = out_ext.value[:, 1:].swapaxes(0, 1)    # [T, B]
 
@@ -729,4 +789,4 @@ class PPOLearner(SequenceActingMixin, Learner):
         # trajectory models keep uint8 pixels raw — cast_stage skips
         # non-float leaves)
         data = self.policy.cast_stage(data, keys=("obs",))
-        return obs_stats, values, value_targets, advantages, data
+        return obs_stats, values, value_targets, advantages, data, moe

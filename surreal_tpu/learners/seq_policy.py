@@ -97,17 +97,11 @@ class SequenceActingMixin(PolicyHeadMixin):
                 ),
                 "pos": jnp.zeros((), jnp.int32),
             }
-        # K/V caches live in the policy's compute dtype — the attention
-        # math's own precision, so decode and full-segment recompute
-        # round identically (precision policy, ops/precision.py)
-        kv_dtype = jnp.dtype(self.policy.compute_dtype)
-        mk = lambda: jnp.zeros(
-            (num_envs, T, int(enc.num_heads), int(enc.head_dim)), kv_dtype
-        )
+        # the cache's form is the model's own (models/attention.py
+        # acting_cache): full keys and values for the 'preln' blocks, the
+        # latent rows alone for 'mla_moe'
         return {
-            "cache": [
-                {"k": mk(), "v": mk()} for _ in range(int(enc.num_layers))
-            ],
+            "cache": self.model.init_cache(num_envs, T),
             "pos": jnp.zeros((), jnp.int32),
         }
 
@@ -126,7 +120,7 @@ class SequenceActingMixin(PolicyHeadMixin):
             # so the wrap reset only needs the index (stale K/V rows are
             # overwritten as the new segment advances)
             cache, pos = act_carry["cache"], act_carry["pos"]
-            T = cache[0]["k"].shape[1]
+            T = jax.tree.leaves(cache)[0].shape[1]
             pos = jnp.where(pos >= T, 0, pos)
             out_t, cache = self.model.apply(
                 state.params,
@@ -161,6 +155,41 @@ class SequenceActingMixin(PolicyHeadMixin):
         return action, info, {"buf": buf, "pos": pos + 1}
 
 
+# model.encoder keys only the 'preln' blocks read (their defaults are
+# session/default_configs.py's); 'mla_moe' reads models/latent_moe.py's
+# FAMILY_DEFAULTS, which default to None there. Both read kind, block,
+# num_layers, num_heads and act_impl.
+_PRELN_KEYS = ("features", "head_dim", "max_len")
+
+
+def family_config(enc_cfg: dict) -> dict:
+    """``model.encoder`` as its block family reads it: a key of the other
+    family that was set is an error, not ignored, and 'mla_moe' gets its
+    unset keys' published values."""
+    from surreal_tpu.models import latent_moe
+    from surreal_tpu.models.attention import block_family
+    from surreal_tpu.session.default_configs import BASE_LEARNER_CONFIG
+
+    if block_family(enc_cfg) == "mla_moe":
+        unset = BASE_LEARNER_CONFIG.model.encoder
+        stray = [k for k in _PRELN_KEYS if enc_cfg.get(k, unset[k]) != unset[k]]
+        if stray:
+            raise ValueError(
+                f"model.encoder.block='mla_moe' does not read {stray} "
+                "('preln' keys; its widths are hidden_size, q_lora_rank, ...)"
+            )
+        return latent_moe.resolve(enc_cfg)
+    stray = [
+        k for k in latent_moe.FAMILY_DEFAULTS if enc_cfg.get(k) is not None
+    ]
+    if stray:
+        raise ValueError(
+            f"model.encoder.block='preln' does not read {stray}: set "
+            "model.encoder.block=mla_moe, or leave them unset"
+        )
+    return enc_cfg
+
+
 def build_seq_model(
     model_config, specs, init_log_std, mesh=None, sp_axis="sp",
     horizon=None, batch_axis=None, policy=None,
@@ -177,12 +206,23 @@ def build_seq_model(
         TrajectoryPPOModel,
     )
 
-    max_len = int(model_config.encoder.get("max_len", 4096))
-    if horizon is not None and int(horizon) + 1 > max_len:
+    from surreal_tpu.models.attention import block_family
+
+    enc_cfg = family_config(model_config.encoder.to_dict())
+    moe_block = block_family(enc_cfg) == "mla_moe"
+    max_len = int(enc_cfg.get("max_len", 4096))
+    # 'mla_moe' has no learned positions: its rotary part takes any index
+    if not moe_block and horizon is not None and int(horizon) + 1 > max_len:
         raise ValueError(
             f"algo.horizon={int(horizon)} needs model.encoder.max_len >= "
             f"{int(horizon) + 1} (the sequence learn pass extends the "
             f"segment by one bootstrap position); got max_len={max_len}"
+        )
+    if moe_block and (model_config.cnn.enabled or mesh is not None):
+        raise ValueError(
+            "model.encoder.block='mla_moe' runs flat vector obs on one "
+            "chip: no CNN stem and no sp mesh path yet (ROADMAP: a trunk "
+            "over pixels at these widths; an expert axis in parallel/mesh.py)"
         )
     cnn_cfg = None
     if model_config.cnn.enabled:
@@ -201,7 +241,6 @@ def build_seq_model(
             "model.cnn.enabled for [H, W, C] pixels); got obs shape "
             f"{specs.obs.shape}"
         )
-    enc_cfg = model_config.encoder.to_dict()
     compute_dtype = jnp.dtype(policy.compute_dtype) if policy else jnp.bfloat16
     if specs.discrete:
         return TrajectoryCategoricalPPOModel(

@@ -224,6 +224,51 @@ class TrajectoryEncoder(nn.Module):
         return (out, new_cache) if decode else out
 
 
+def block_family(encoder_cfg) -> str:
+    """``model.encoder.block``: 'preln' (this module's
+    :class:`TrajectoryEncoder`, the default) | 'mla_moe'
+    (``models/latent_moe.py``)."""
+    block = encoder_cfg.get("block", "preln") or "preln"
+    if block not in ("preln", "mla_moe"):
+        raise ValueError(f"model.encoder.block {block!r} not in preln|mla_moe")
+    return block
+
+
+def build_trunk(cfg, *, cnn_cfg, mesh, sp_axis, batch_axis, compute_dtype):
+    """The trunk ``model.encoder.block`` selects, under the name both
+    heads give it. Both take ``[B, T, obs]``, or ``[B, obs]`` with
+    ``cache`` and ``pos``."""
+    if block_family(cfg) == "mla_moe":
+        from surreal_tpu.models.latent_moe import LatentMoETrunk
+
+        return LatentMoETrunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
+    return TrajectoryEncoder(
+        features=cfg["features"], num_layers=cfg["num_layers"],
+        num_heads=cfg["num_heads"], head_dim=cfg["head_dim"],
+        max_len=int(cfg.get("max_len", 4096)),
+        cnn_cfg=cnn_cfg,
+        mesh=mesh, sp_axis=sp_axis,
+        batch_axis=batch_axis, name="trunk",
+        compute_dtype=compute_dtype,
+    )
+
+
+def acting_cache(cfg, num_envs: int, horizon: int, dtype) -> list:
+    """The cache of the incremental acting carry, one entry a layer, as
+    the trunk's decode path takes it: full keys and values for 'preln',
+    the latent rows alone for 'mla_moe'. In the compute dtype, the
+    attention math's own, so decode and the full-segment recompute round
+    alike (precision policy, ops/precision.py)."""
+    if block_family(cfg) == "mla_moe":
+        from surreal_tpu.models import latent_moe
+
+        return latent_moe.acting_cache(cfg, num_envs, horizon, dtype)
+    mk = lambda: jnp.zeros(
+        (num_envs, horizon, int(cfg["num_heads"]), int(cfg["head_dim"])), dtype
+    )
+    return [{"k": mk(), "v": mk()} for _ in range(int(cfg["num_layers"]))]
+
+
 def _obs_dtype(obs):
     """THE obs-dtype rule for trajectory models (single owner — learners
     pass obs through untouched): uint8 pixels stay uint8 into the trunk
@@ -250,20 +295,21 @@ class TrajectoryPPOModel(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16  # precision policy's compute
                                              # dtype (learners/seq_policy)
 
+    def init_cache(self, num_envs: int, horizon: int) -> list:
+        """The acting carry's cache, as this model's decode path takes it."""
+        return acting_cache(
+            self.encoder_cfg, num_envs, horizon, self.compute_dtype
+        )
+
     @nn.compact
     def __call__(self, obs_seq: jax.Array, *, cache=None, pos=None,
                  replicate_ok: bool = False):
         from surreal_tpu.models.ppo_net import PolicyOutput
 
         cfg = self.encoder_cfg
-        trunk = TrajectoryEncoder(
-            features=cfg["features"], num_layers=cfg["num_layers"],
-            num_heads=cfg["num_heads"], head_dim=cfg["head_dim"],
-            max_len=int(cfg.get("max_len", 4096)),
-            cnn_cfg=self.cnn_cfg,
-            mesh=self.mesh, sp_axis=self.sp_axis,
-            batch_axis=self.batch_axis, name="trunk",
-            compute_dtype=self.compute_dtype,
+        trunk = build_trunk(
+            cfg, cnn_cfg=self.cnn_cfg, mesh=self.mesh, sp_axis=self.sp_axis,
+            batch_axis=self.batch_axis, compute_dtype=self.compute_dtype,
         )
         if cache is not None:  # incremental acting: obs_seq is [B, obs]
             h, new_cache = trunk(_obs_dtype(obs_seq), cache=cache, pos=pos)
@@ -301,20 +347,21 @@ class TrajectoryCategoricalPPOModel(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16  # precision policy's compute
                                              # dtype (learners/seq_policy)
 
+    def init_cache(self, num_envs: int, horizon: int) -> list:
+        """The acting carry's cache, as this model's decode path takes it."""
+        return acting_cache(
+            self.encoder_cfg, num_envs, horizon, self.compute_dtype
+        )
+
     @nn.compact
     def __call__(self, obs_seq: jax.Array, *, cache=None, pos=None,
                  replicate_ok: bool = False):
         from surreal_tpu.models.ppo_net import CategoricalOutput
 
         cfg = self.encoder_cfg
-        trunk = TrajectoryEncoder(
-            features=cfg["features"], num_layers=cfg["num_layers"],
-            num_heads=cfg["num_heads"], head_dim=cfg["head_dim"],
-            max_len=int(cfg.get("max_len", 4096)),
-            cnn_cfg=self.cnn_cfg,
-            mesh=self.mesh, sp_axis=self.sp_axis,
-            batch_axis=self.batch_axis, name="trunk",
-            compute_dtype=self.compute_dtype,
+        trunk = build_trunk(
+            cfg, cnn_cfg=self.cnn_cfg, mesh=self.mesh, sp_axis=self.sp_axis,
+            batch_axis=self.batch_axis, compute_dtype=self.compute_dtype,
         )
         if cache is not None:  # incremental acting: obs_seq is [B, obs]
             h, new_cache = trunk(_obs_dtype(obs_seq), cache=cache, pos=pos)

@@ -590,6 +590,7 @@ class CostAccountant:
         # the {instruction: phase} maps parsed from them on first demand
         self._hlo: dict[str, Any] = {}
         self._op_phases: dict[str, dict[str, str]] | None = None
+        self._op_parts: dict[str, dict[str, str]] | None = None
         self._failed: set[str] = set()  # don't re-lower every iteration
         # when a backend reports no cost model (record sites in host/SEED
         # loops call record_program once per iteration, idempotently)
@@ -640,7 +641,7 @@ class CostAccountant:
         )
         if hlo_text is not None:
             self._hlo[name] = hlo_text
-            self._op_phases = None
+            self._op_phases = self._op_parts = None
         if costs is None:
             self._failed.add(name)
             if self._log is not None:
@@ -679,26 +680,40 @@ class CostAccountant:
         demand (the profile digest's, off the loop's thread). A program
         whose text cannot be had is left out."""
         if self._op_phases is None:
-            from surreal_tpu.session.profile import hlo_op_phases
+            from surreal_tpu.utils.phases import phase_of
 
-            maps: dict[str, dict[str, str]] = {}
-            for name, hlo_text in list(self._hlo.items()):
-                try:
-                    module, ops = hlo_op_phases(hlo_text())
-                except Exception as e:
-                    if self._log is not None:
-                        self._log.warning(
-                            "no HLO text for program %r: %s", name, e
-                        )
-                    continue
-                maps[module] = ops
-                if not ops and self._log is not None:
-                    self._log.warning(
-                        "program %r carries no phase name: the digest will "
-                        "call its ops unattributed", name,
-                    )
-            self._op_phases = maps
+            self._op_phases = self._op_labels(phase_of, warn_empty=True)
         return self._op_phases
+
+    def op_parts(self) -> dict[str, dict[str, str]]:
+        """The same maps by model part (``utils/phases.py`` ``PARTS``);
+        empty for a program whose model scopes none."""
+        if self._op_parts is None:
+            from surreal_tpu.utils.phases import part_of
+
+            self._op_parts = self._op_labels(part_of, warn_empty=False)
+        return self._op_parts
+
+    def _op_labels(self, label_of, warn_empty: bool) -> dict:
+        from surreal_tpu.session.profile import hlo_op_phases
+
+        maps: dict[str, dict[str, str]] = {}
+        for name, hlo_text in list(self._hlo.items()):
+            try:
+                module, ops = hlo_op_phases(hlo_text(), label_of)
+            except Exception as e:
+                if self._log is not None:
+                    self._log.warning(
+                        "no HLO text for program %r: %s", name, e
+                    )
+                continue
+            maps[module] = ops
+            if warn_empty and not ops and self._log is not None:
+                self._log.warning(
+                    "program %r carries no phase name: the digest will "
+                    "call its ops unattributed", name,
+                )
+        return maps
 
     def gauges(self, window: dict | None) -> dict[str, float]:
         """``perf/*`` scalars for one flushed phase window — pure host

@@ -67,17 +67,52 @@ BASE_LEARNER_CONFIG = Config(
             # seam as a config knob (on-policy learners: ppo AND impala,
             # device envs; ddpg fails fast rather than silently ignore it)
             kind="auto",
+            # the trajectory trunk's block family, and which keys each
+            # reads (a key of the other family that is set is an error,
+            # learners/seq_policy.py::family_config):
+            # 'preln' (default; models/attention.py): pre-LayerNorm,
+            #   learned positions, q/k/v/o of num_heads x head_dim, GELU
+            #   MLP of 4 x features; a preset for CPU tests and small
+            #   policies. Reads features, head_dim, max_len.
+            # 'mla_moe' (models/latent_moe.py): RMSNorm, latent attention
+            #   with a rotary part and a latent acting cache, SwiGLU,
+            #   sigmoid-routed experts of which this chip holds a share.
+            #   Reads the keys below that default to None (None = the
+            #   published JoyAI-LLM-Flash value, FAMILY_DEFAULTS there).
+            # Both read kind, block, num_layers, num_heads, act_impl.
+            block="preln",
             features=64,
             num_layers=2,
             num_heads=4,
             head_dim=16,
-            # trajectory acting: 'kv' (incremental decode against a K/V
-            # cache — O(T) per step) | 'padded' (re-run the full padded
-            # segment each step — O(T^2), the simple reference form)
+            # trajectory acting: 'kv' (incremental decode against the
+            # model's own cache, models/attention.py::acting_cache — O(T)
+            # per step) | 'padded' (re-run the full padded segment each
+            # step — O(T^2), the simple reference form)
             act_impl="kv",
             # pos_embed capacity; the sequence learn pass uses horizon+1
             # positions, validated at learner build (seq_policy.py)
             max_len=4096,
+            # -- 'mla_moe' only ------------------------------------------
+            hidden_size=None,
+            q_lora_rank=None,
+            kv_lora_rank=None,
+            qk_nope_head_dim=None,
+            qk_rope_head_dim=None,
+            v_head_dim=None,
+            intermediate_size=None,        # dense layers' SwiGLU width
+            moe_intermediate_size=None,    # an expert's width
+            n_routed_experts=None,         # the router's width
+            num_experts_per_tok=None,
+            n_shared_experts=None,
+            routed_scaling_factor=None,
+            first_k_dense_replace=None,    # leading dense layers
+            rope_theta=None,
+            rms_norm_eps=None,
+            # the experts this chip holds of the routed ones
+            first_held=None,
+            num_held=None,
+            bias_update_speed=None,        # router selection bias, a step
         ),
         cnn=Config(
             enabled=False,          # pixel observations -> Nature-CNN stem

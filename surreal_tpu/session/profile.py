@@ -44,6 +44,10 @@ announced as a ``profile`` telemetry event carrying the **digest**
   (``instruction name -> op_name``, looked up under the module the op ran
   in). A fusion takes the ``op_name`` XLA gave the fusion instruction,
   which is its root's. Each phase lists its three largest ops;
+- ``parts``: the same device time split a second way, by the model part
+  of ``utils/phases.py`` (``PARTS``) each op's path names, read and owned
+  by the same rules; ``unattributed`` holds what no part names (all of a
+  program whose model scopes none), so parts too sum to ``busy_s``;
 - ``idle_by_span``: every device idle gap charged to the innermost
   program span that covers it on the loop's thread (``metrics-sync``,
   ``engine.boundary``, ``engine.step``, ``iteration``, ...), or to
@@ -65,7 +69,9 @@ import threading
 import time
 
 from surreal_tpu.session.telemetry import PROFILES_DIR, TELEMETRY_DIR
-from surreal_tpu.utils.phases import PHASES, UNATTRIBUTED, phase_of
+from surreal_tpu.utils.phases import (
+    PARTS, PHASES, UNATTRIBUTED, part_of, phase_of,
+)
 
 TRIGGER_FILE = "profile.trigger"
 # the loop engine's own annotations (engine/core.py); a tracer's span
@@ -164,12 +170,37 @@ def charge_gaps(gaps, spans) -> dict[str, int]:
     return out
 
 
+def _split(owned, labels, vocabulary, busy: int, per_iter_ms: float) -> dict:
+    """``{label: {ms_per_iter, share_of_busy, top_ops}}`` of the owned
+    pieces ``[(ns, op name), ...]`` under ``labels`` (one a piece), in the
+    vocabulary's order with ``unattributed`` last and always present."""
+    by_label: dict[str, int] = {UNATTRIBUTED: 0}
+    by_op: dict[tuple[str, str], int] = {}
+    for (t, name), label in zip(owned, labels):
+        by_label[label] = by_label.get(label, 0) + t
+        by_op[label, name] = by_op.get((label, name), 0) + t
+    out = {}
+    for label in (*vocabulary, UNATTRIBUTED):
+        if label not in by_label:
+            continue
+        ops = sorted(
+            ((n, t) for (p, n), t in by_op.items() if p == label),
+            key=lambda kv: -kv[1],
+        )[:TOP_OPS]
+        out[label] = {
+            "ms_per_iter": by_label[label] * per_iter_ms,
+            "share_of_busy": by_label[label] / busy if busy else 0.0,
+            "top_ops": [[n, t * per_iter_ms] for n, t in ops],
+        }
+    return out
+
+
 def reduce_digest(device_ops: dict, host_spans, steps: int) -> dict:
     """The digest's numbers from ``{device: [(start_ns, end_ns, name,
-    phase), ...]}``, the loop thread's program spans ``[(start_ns, end_ns,
-    name), ...]`` and the iterations the window holds. Phases and gaps are
-    those of the first device by name; seconds are floats, nothing is
-    rounded."""
+    phase[, part]), ...]}``, the loop thread's program spans ``[(start_ns,
+    end_ns, name), ...]`` and the iterations the window holds. Phases,
+    parts and gaps are those of the first device by name; seconds are
+    floats, nothing is rounded."""
     device_ops = {k: v for k, v in device_ops.items() if v}
     out = {"devices": len(device_ops), "steps": int(steps)}
     if not device_ops:
@@ -177,40 +208,34 @@ def reduce_digest(device_ops: dict, host_spans, steps: int) -> dict:
     ns = 1e-9
     per_iter_ms = 1e-6 / max(int(steps), 1)
     events = device_ops[sorted(device_ops)[0]]
-    by_phase: dict[str, int] = {}
-    by_op: dict[tuple[str, str], int] = {}
-    for i, a, b in owned_pieces(events):
-        _, _, name, ph = events[i]
-        by_phase[ph] = by_phase.get(ph, 0) + b - a
-        by_op[ph, name] = by_op.get((ph, name), 0) + b - a
-    busy = sum(by_phase.values())
-    window = max(e for _, e, _, _ in events) - min(s for s, _, _, _ in events)
-    by_phase.setdefault(UNATTRIBUTED, 0)
-    phases = {}
-    for ph in (*PHASES, UNATTRIBUTED):
-        if ph not in by_phase:
-            continue
-        ops = sorted(
-            ((n, t) for (p, n), t in by_op.items() if p == ph),
-            key=lambda kv: -kv[1],
-        )[:TOP_OPS]
-        phases[ph] = {
-            "ms_per_iter": by_phase[ph] * per_iter_ms,
-            "share_of_busy": by_phase[ph] / busy if busy else 0.0,
-            "top_ops": [[n, t * per_iter_ms] for n, t in ops],
-        }
+    pieces = list(owned_pieces(events))
+    owned = [(b - a, events[i][2]) for i, a, b in pieces]
+    busy = sum(t for t, _ in owned)
+    window = max(ev[1] for ev in events) - min(ev[0] for ev in events)
+    phases = _split(
+        owned, [events[i][3] for i, _, _ in pieces], PHASES, busy, per_iter_ms
+    )
+    parts = _split(
+        owned,
+        [
+            events[i][4] if len(events[i]) > 4 else UNATTRIBUTED
+            for i, _, _ in pieces
+        ],
+        PARTS, busy, per_iter_ms,
+    )
     out.update(
         window_s=window * ns,
         busy_s=busy * ns,
         idle_s=(window - busy) * ns,
         busy_s_per_device=[
             (
-                max(e for _, e, _, _ in evs) - min(s for s, _, _, _ in evs)
+                max(ev[1] for ev in evs) - min(ev[0] for ev in evs)
                 - sum(b - a for a, b in idle_gaps(evs))
             ) * ns
             for _, evs in sorted(device_ops.items())
         ],
         phases=phases,
+        parts=parts,
         idle_by_span={
             k: v * ns
             for k, v in charge_gaps(idle_gaps(events), list(host_spans)).items()
@@ -237,9 +262,10 @@ _LAYOUT = re.compile(r"\{[^{}]*\}")
 _SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
 
 
-def hlo_op_phases(hlo_text: str) -> tuple[str, dict[str, str]]:
+def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]]:
     """``(module name, {instruction name: phase})`` of a compiled
-    program's HLO text, for the instructions that have a phase:
+    program's HLO text, for the instructions that have a phase (or, with
+    ``label_of=part_of``, a model part: the rules are one):
 
     1. its own: the first vocabulary name in its ``op_name`` metadata;
     2. a fusion without one takes its fused computation's: the root's,
@@ -289,8 +315,8 @@ def hlo_op_phases(hlo_text: str) -> tuple[str, dict[str, str]]:
             and not (opcode and opcode.group(1) in _HLO_RELAYOUT)
         ):
             placed.add(name)
-        if named and phase_of(named.group(1)) != UNATTRIBUTED:
-            phases[name] = phase_of(named.group(1))
+        if named and label_of(named.group(1)) != UNATTRIBUTED:
+            phases[name] = label_of(named.group(1))
         calls = _HLO_CALLS.search(rest)
         order.append((
             name, _HLO_REF.findall(rest.split(", metadata=", 1)[0]),
@@ -355,16 +381,18 @@ def _instruction(event_name: str) -> tuple[str, str]:
     return op, f"{op} {largest}".strip()
 
 
-def read_capture(path: str, op_phases: dict, span_names) -> tuple:
+def read_capture(path: str, op_phases: dict, span_names,
+                 op_parts: dict | None = None) -> tuple:
     """``(device_ops, loop_spans, host_span_counts)`` of one
     ``.xplane.pb``: per device plane the ``XLA Ops`` line as ``(start_ns,
-    end_ns, name, phase)``, each op's phase looked up under the module
-    (``XLA Modules`` line) it ran in; the program's spans on the loop's
+    end_ns, name, phase, part)``, each op's phase and part looked up under
+    the module (``XLA Modules`` line) it ran in; the program's spans on the loop's
     thread (the host line with the most ``engine.step``); and how often
     each program span appears on any host line."""
     from jax.profiler import ProfileData
 
     span_names = set(span_names) | set(ENGINE_SPANS)
+    op_parts = op_parts or {}
     device_ops: dict[str, list] = {}
     lines: list[list] = []
     for plane in ProfileData.from_file(path).planes:
@@ -387,7 +415,8 @@ def read_capture(path: str, op_phases: dict, span_names) -> tuple:
                 i = bisect.bisect_right(starts, s) - 1
                 module = modules[i][1] if i >= 0 else ""
                 ph = op_phases.get(module, {}).get(instr, UNATTRIBUTED)
-                ops.append((s, s + int(ev.duration_ns), shown, ph))
+                pt = op_parts.get(module, {}).get(instr, UNATTRIBUTED)
+                ops.append((s, s + int(ev.duration_ns), shown, ph, pt))
             device_ops[plane.name] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -411,7 +440,8 @@ def read_capture(path: str, op_phases: dict, span_names) -> tuple:
 
 
 def digest_capture(trace_dir: str, op_phases: dict, span_names,
-                   steps: int | None = None) -> dict:
+                   steps: int | None = None,
+                   op_parts: dict | None = None) -> dict:
     """Reduce the one ``.xplane.pb`` a capture left under ``trace_dir``.
     ``steps`` is the number of iterations the fenced window holds; a
     capture cut short has none, and the ``iteration`` steps seen on the
@@ -425,7 +455,7 @@ def digest_capture(trace_dir: str, op_phases: dict, span_names,
             f"{len(found)} .xplane.pb files under {trace_dir}, expected 1"
         )
     device_ops, loop_spans, counts = read_capture(
-        found[0], op_phases, span_names
+        found[0], op_phases, span_names, op_parts
     )
     if steps is None:
         steps = counts.get("iteration", 0)
@@ -444,13 +474,15 @@ class ProfileManager:
     in the steady state: one monotonic read, one EWMA update, and (at
     most once per second) one ``os.path.exists``."""
 
-    def __init__(self, session_cfg, folder: str, tracer, log, op_phases=None):
+    def __init__(self, session_cfg, folder: str, tracer, log, op_phases=None,
+                 op_parts=None):
         self._folder = folder
         self._tracer = tracer
         self._log = log
         # zero-arg source of {HLO module: {instruction: phase}} for the
         # digest (CostAccountant.op_phases); called off the loop's thread
         self._op_phases = op_phases or dict
+        self._op_parts = op_parts or dict   # the same, by model part
         prof = session_cfg.get("profile", None)
         self._trigger_enabled = (
             bool(prof.get("trigger_file", True)) if prof is not None else True
@@ -548,6 +580,7 @@ class ProfileManager:
             fields["digest"] = digest_capture(
                 fields["dir"], self._op_phases(),
                 getattr(self._tracer, "span_names", ()), steps,
+                op_parts=self._op_parts(),
             )
         except Exception as e:
             self._log.warning("profile digest failed: %s", e)

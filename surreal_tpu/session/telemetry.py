@@ -97,7 +97,8 @@ line, ``t`` = unix seconds):
      "end_iter": ..., "digest": {"devices": D, "steps": N, "window_s":
      ..., "busy_s": ..., "idle_s": ..., "phases": {"<phase>|unattributed":
      {"ms_per_iter": ..., "share_of_busy": ..., "top_ops": [[name, ms],
-     ...]}}, "idle_by_span": {"<span>|none": seconds}, "host_spans":
+     ...]}}, "parts": {"<part>|unattributed": {...the same}},
+     "idle_by_span": {"<span>|none": seconds}, "host_spans":
      {"<span>": count}, "trace_bytes": ..., "digest_s": ...}}
                     (on-demand profiler captures, session/profile.py —
                      the trace artifact lives under dir; ``digest`` is the
@@ -1447,17 +1448,22 @@ def _digest_lines(profile: dict) -> list[str]:
             i=d["idle_s"] * per_iter,
         )
     )
-    lines.append(
-        f"    {'phase':<16} {'ms/iter':>10} {'% busy':>7}  largest ops (ms/iter)"
-    )
-    for name, ph in sorted(
-        phases.items(), key=lambda kv: -kv[1]["ms_per_iter"]
-    ):
-        ops = ", ".join(f"{n} {ms:.2f}" for n, ms in ph.get("top_ops", []))
+    splits = [("phase", phases)]
+    parts = d.get("parts") or {}
+    if len(parts) > 1:  # a model that scopes its parts (utils/phases.py)
+        splits.append(("model part", parts))
+    for title, split in splits:
         lines.append(
-            f"    {name:<16} {ph['ms_per_iter']:>10.3f} "
-            f"{100.0 * ph['share_of_busy']:>6.1f}%  {ops}"
+            f"    {title:<16} {'ms/iter':>10} {'% busy':>7}  largest ops (ms/iter)"
         )
+        for name, ph in sorted(
+            split.items(), key=lambda kv: -kv[1]["ms_per_iter"]
+        ):
+            ops = ", ".join(f"{n} {ms:.2f}" for n, ms in ph.get("top_ops", []))
+            lines.append(
+                f"    {name:<16} {ph['ms_per_iter']:>10.3f} "
+                f"{100.0 * ph['share_of_busy']:>6.1f}%  {ops}"
+            )
     idle = d.get("idle_by_span") or {}
     if idle:
         lines.append(

@@ -30,6 +30,20 @@ code and no host clock or fence enters it.
 
 At most eight per algorithm, so a reader can hold a split in one line.
 An op outside every scope is ``unattributed``.
+
+Phases say *when* in the iteration an op runs. A second, short
+vocabulary of **parts** says *which part of the model* it belongs to, for
+a trunk large enough that this is the question (``models/latent_moe.py``).
+A part's scope sits inside whatever phase runs the model, so an op has
+one phase and at most one part, and the digest sums each on its own:
+
+    attn         latent attention: both low-rank paths, rotary part,
+                 scores, softmax, output projection (expanded or absorbed)
+    moe_route    router scores, biased top-k, weights, the sort by expert
+    moe_experts  row gather, the held experts' grouped products, the
+                 weighted combine, and the shared expert
+    dense_ffn    a dense layer's SwiGLU
+    optimizer    PPO: clip, Adam, apply, the router-bias rule
 """
 
 from __future__ import annotations
@@ -39,8 +53,10 @@ PHASES = (
     "replay_insert", "replay_sample", "replay_priority", "update",
     "bootstrap", "vtrace", "learn",
 )
+PARTS = ("attn", "moe_route", "moe_experts", "dense_ffn", "optimizer")
 UNATTRIBUTED = "unattributed"
 _VOCABULARY = frozenset(PHASES)
+_PARTS = frozenset(PARTS)
 # transforms wrap a scope's name in the op_name path: jvp(sgd),
 # transpose(jvp(sgd)), vmap(collect). ``jit(...)`` names a function, never
 # a phase.
@@ -64,13 +80,37 @@ def phase(name: str):
     return jax.named_scope(sub or top)
 
 
+def part(name: str):
+    """The ``jax.named_scope`` of model part ``name``; a name outside
+    :data:`PARTS` is refused."""
+    if name not in _PARTS:
+        raise ValueError(
+            f"part {name!r} is not in the vocabulary {PARTS} "
+            "(surreal_tpu/utils/phases.py)"
+        )
+    import jax
+
+    return jax.named_scope(name)
+
+
+def _first_segment_in(op_name: str | None, vocabulary: frozenset) -> str:
+    for segment in (op_name or "").split("/"):
+        while segment.startswith(_WRAPPERS) and segment.endswith(")"):
+            segment = segment[segment.index("(") + 1:-1]
+        if segment in vocabulary:
+            return segment
+    return UNATTRIBUTED
+
+
 def phase_of(op_name: str | None) -> str:
     """The phase an op belongs to: the first vocabulary name among the
     segments of its ``op_name`` path (``jit(train_iter)/collect/while/
     body/act/tanh`` -> ``collect``), :data:`UNATTRIBUTED` without one."""
-    for segment in (op_name or "").split("/"):
-        while segment.startswith(_WRAPPERS) and segment.endswith(")"):
-            segment = segment[segment.index("(") + 1:-1]
-        if segment in _VOCABULARY:
-            return segment
-    return UNATTRIBUTED
+    return _first_segment_in(op_name, _VOCABULARY)
+
+
+def part_of(op_name: str | None) -> str:
+    """The model part an op belongs to, by the same rule over
+    :data:`PARTS` (``jit(train_iter)/sgd/transpose(jvp(attn))/dot`` ->
+    ``attn``)."""
+    return _first_segment_in(op_name, _PARTS)

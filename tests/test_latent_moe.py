@@ -1,0 +1,395 @@
+"""The second block family of the trajectory seam (``model.encoder.block=
+'mla_moe'``: models/latent_moe.py, ops/moe.py) at toy widths on the CPU:
+the absorbed latent-cache decode against the expanded forward, the held
+experts' share of a routed layer, the ragged product's bound, the
+selection-bias rule, the acting carry, and the parts vocabulary."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from surreal_tpu.envs.base import ArraySpec, EnvSpecs
+from surreal_tpu.learners import build_learner
+from surreal_tpu.models import latent_moe
+from surreal_tpu.ops import moe
+from surreal_tpu.session.config import Config
+from surreal_tpu.session.default_configs import base_config
+
+TOY = dict(
+    kind="trajectory", block="mla_moe", num_layers=3, num_heads=2,
+    hidden_size=32, q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=4, v_head_dim=8, intermediate_size=64,
+    moe_intermediate_size=16, n_routed_experts=8, num_experts_per_tok=2,
+    num_held=2,
+)
+SPECS = EnvSpecs(
+    obs=ArraySpec(shape=(5,), dtype=np.dtype(np.float32)),
+    action=ArraySpec(shape=(2,), dtype=np.dtype(np.float32)),
+)
+
+
+def _learner(horizon=8, precision="f32", **encoder):
+    cfg = Config(
+        algo=Config(
+            name="ppo", horizon=horizon, epochs=2, num_minibatches=2,
+            precision=precision,
+        ),
+        model=Config(encoder=Config(**{**TOY, **encoder})),
+    )
+    return build_learner(cfg, SPECS)
+
+
+@pytest.mark.parametrize("precision,tol", [("f32", 2e-5), ("mixed", 6e-2)])
+def test_latent_cache_decode_equals_the_full_forward(precision, tol):
+    """Absorbed against expanded, at every position: what ``act_step``
+    produced through the ``[envs, T, kv_lora + rope]`` cache is what one
+    whole-segment apply recomputes (the importance-ratio contract)."""
+    T, B = 8, 4
+    learner = _learner(T, precision)
+    state = learner.init(jax.random.key(0))
+    obs = jax.random.normal(jax.random.key(1), (T, B, 5), jnp.float32)
+    carry = learner.act_init(B)
+    means, values = [], []
+    for t in range(T):
+        _, info, carry = learner.act_step(
+            state, carry, obs[t], jax.random.key(t), "eval_deterministic"
+        )
+        means.append(info["mean"])
+        values.append(info["value"])
+    out = learner.model.apply(
+        state.params, learner._norm_obs(state.obs_stats, obs).swapaxes(0, 1)
+    )
+    np.testing.assert_allclose(
+        jnp.stack(means, 1), out.mean, rtol=0, atol=tol
+    )
+    np.testing.assert_allclose(
+        jnp.stack(values, 1), out.value, rtol=0, atol=tol
+    )
+    assert float(jnp.abs(out.value).max()) > 0.1  # not a comparison of zeros
+
+
+@pytest.mark.parametrize("block", ["preln", "mla_moe"])
+def test_act_init_takes_its_carry_from_the_model(block):
+    T, B = 8, 3
+    if block == "mla_moe":
+        learner = _learner(T, "mixed")
+        want = [(B, T, 16 + 4)] * 3   # the latent rows, nothing per head
+    else:
+        learner = build_learner(
+            Config(
+                algo=Config(name="ppo", horizon=T),
+                model=Config(encoder=Config(
+                    kind="trajectory", features=32, num_layers=2,
+                    num_heads=2, head_dim=8,
+                )),
+            ), SPECS,
+        )
+        want = [(B, T, 2, 8)] * 4     # k and v of each layer
+    carry = learner.act_init(B)
+    cache = learner.model.init_cache(B, T)
+    got = [x.shape for x in jax.tree.leaves(carry["cache"])]
+    assert got == want == [x.shape for x in jax.tree.leaves(cache)]
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(carry["cache"]))
+    assert int(carry["pos"]) == 0
+
+
+@pytest.mark.parametrize("encoder,match", [
+    (dict(kind="trajectory", hidden_size=32), "'preln' does not read"),
+    (dict(kind="trajectory", block="preln", num_held=2), "'preln' does not read"),
+    (dict(TOY, features=128), "'mla_moe' does not read"),
+    (dict(TOY, first_held=7, num_held=2), "lie outside"),
+    (dict(kind="trajectory", block="other"), "not in preln"),
+])
+def test_a_key_of_the_other_family_is_an_error(encoder, match):
+    cfg = Config(
+        algo=Config(name="ppo", horizon=8), model=Config(encoder=Config(**encoder))
+    )
+    with pytest.raises(ValueError, match=match):
+        build_learner(cfg, SPECS)
+
+
+def test_impala_refuses_the_expert_blocks():
+    cfg = Config(
+        algo=Config(name="impala", horizon=8),
+        model=Config(encoder=Config(**TOY)),
+    )
+    with pytest.raises(ValueError, match="wired into PPO alone"):
+        build_learner(cfg, SPECS)
+
+
+# -- the routed layer -----------------------------------------------------------
+
+SHARE_CFG = latent_moe.resolve(dict(
+    TOY, n_routed_experts=32, num_experts_per_tok=8, num_held=32,
+))
+
+
+def _routed(cfg, params, x):
+    layer = latent_moe.RoutedExperts(cfg, jnp.float32)
+    y, sown = layer.apply({"params": params}, x, mutable=["moe", "moe_routing"])
+    return y, sown["moe"]
+
+
+# both forms of the held experts' product (ops/moe.py): these passes' few
+# tokens take the dense one; with the threshold at 0 every pass sorts
+@pytest.fixture(params=["sorted", "dense"])
+def form(request, monkeypatch):
+    if request.param == "sorted":
+        monkeypatch.setattr(moe, "DENSE_MAX_TOKENS", 0)
+    return request.param
+
+
+def test_four_shares_and_the_shared_expert_once_equal_the_uncut_layer(form):
+    """32 experts in 4 shares of 8: each share routes over all 32 and
+    computes its own experts' part; the parts and the shared expert add up
+    to the layer that holds all 32."""
+    x = jax.random.normal(jax.random.key(0), (64, 32), jnp.float32)
+    whole = latent_moe.RoutedExperts(SHARE_CFG, jnp.float32).init(
+        jax.random.key(1), x
+    )["params"]
+    whole = dict(whole, router=5.0 * whole["router"])   # decisive scores
+    uncut, stats = _routed(SHARE_CFG, whole, x)
+    assert float(stats["load"][-1].sum()) == 64 * 8
+    total = moe.swiglu(x, *(whole["shared0"][k] for k in ("gate", "up", "down")))
+    for share in range(4):
+        cfg = dict(SHARE_CFG, first_held=8 * share, num_held=8, n_shared_experts=0)
+        mine = {
+            k: v[8 * share:8 * share + 8] if k in ("gate", "up", "down") else v
+            for k, v in whole.items() if k != "shared0"
+        }
+        part, stats = _routed(cfg, mine, x)
+        assert float(stats["overflow"][-1]) == 0.0
+        total = total + part
+    np.testing.assert_allclose(total, uncut, rtol=1e-5, atol=1e-6)
+
+
+def test_no_token_is_dropped_when_all_route_to_one_held_expert(form):
+    """Every token's first choice is held expert 3 (a router column that
+    always wins); the other seven choices scatter. Nothing overflows, and
+    the output is the plain sum over each token's held choices."""
+    cfg = dict(SHARE_CFG, first_held=0, num_held=8, n_shared_experts=0)
+    x = jnp.abs(jax.random.normal(jax.random.key(0), (96, 32), jnp.float32))
+    params = latent_moe.RoutedExperts(cfg, jnp.float32).init(
+        jax.random.key(1), x
+    )["params"]
+    params = dict(params, router=params["router"].at[:, 3].set(1.0))
+    y, stats = _routed(cfg, params, x)
+    assert float(stats["load"][-1][3]) == 96 and float(stats["overflow"][-1]) == 0
+    idx, weights, _ = moe.route(
+        x @ params["router"], params["e_score_correction_bias"], 8, 2.5
+    )
+    want = jnp.zeros_like(x)
+    for e in range(8):
+        w_e = (weights * (idx == e)).sum(-1)
+        want = want + w_e[:, None] * moe.swiglu(
+            x, params["gate"][e], params["up"][e], params["down"][e]
+        )
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-6)
+
+
+def test_a_bound_under_the_worst_case_counts_what_it_drops():
+    idx = jnp.zeros((64, 2), jnp.int32).at[:, 1].set(1)   # all on experts 0, 1
+    weights = jnp.ones((64, 2), jnp.float32)
+    token, weight, valid, sizes, overflow = moe.sort_by_expert(
+        idx, weights, 0, 2, rows=100
+    )
+    assert int(overflow) == 28 and sizes.tolist() == [64, 36]
+    assert int(valid.sum()) == 100 and float(weight.sum()) == 100.0
+    # under the bound, the last group takes the slack: the groups cover
+    # every row, so the kernel's work is the bound whatever the load
+    some = idx[:20].at[:, 1].set(5)      # expert 5 is not held
+    *_, valid, sizes, overflow = moe.sort_by_expert(some, weights[:20], 0, 2, 32)
+    assert int(overflow) == 0 and sizes.tolist() == [20, 12]
+    assert int(valid.sum()) == 20
+    # a learn pass's bound is the capacity factor over the even
+    # expectation, and never more than the worst case
+    assert moe.row_bound(8192, 8, 16, 256) == 16384   # the cell's minibatch
+    assert moe.row_bound(16512, 8, 16, 256) == 33024  # its value pass
+    assert moe.row_bound(64, 2, 2, 8) == 128 == 64 * 2
+    assert moe.row_bound(64, 8, 2, 8) == 128 == 64 * 2  # min(top_k, held)
+
+
+@pytest.mark.parametrize("tokens,dense", [
+    (128, True),      # the cell's acting step: 128 envs
+    (256, True),      # still under the chip's ridge point
+    (1024, False),    # the reference check's passes: 8 envs x 128
+    (8192, False),    # the cell's minibatch
+])
+def test_the_form_follows_the_shape_of_the_pass(tokens, dense, monkeypatch):
+    """No key chooses between the dense and the sorted product: the
+    number of tokens of the pass does, and a routed layer built at either
+    size sows an overflow only the sorted form can raise."""
+    assert moe.dense_form(tokens) is dense
+    seen = []
+    monkeypatch.setattr(
+        moe, "sort_by_expert",
+        lambda *a, **k: seen.append(a[-1]) or moe_sort(*a, **k),
+    )
+    cfg = dict(SHARE_CFG, first_held=0, num_held=8, n_shared_experts=0)
+    x = jax.ShapeDtypeStruct((tokens, 32), jnp.float32)
+    layer = latent_moe.RoutedExperts(cfg, jnp.float32)
+    jax.eval_shape(lambda x: layer.init(jax.random.key(0), x), x)
+    assert seen == ([] if dense else [moe.row_bound(tokens, 8, 8, 32)])
+
+
+moe_sort = moe.sort_by_expert
+
+
+def test_the_selection_bias_moves_toward_balance_and_takes_no_gradient():
+    learner = _learner(8, "f32")
+    state = learner.init(jax.random.key(0))
+    obs = jax.random.normal(jax.random.key(1), (4, 8, 5), jnp.float32)
+
+    def loss(params):
+        out, stats = learner._apply(params, obs)
+        return (out.value ** 2).mean() + (out.mean ** 2).mean(), stats
+
+    grads, stats = jax.grad(loss, has_aux=True)(state.params)
+    for g in latent_moe.router_biases(grads):
+        assert float(jnp.abs(g).max()) == 0.0
+    # the loss stops at the router's product (one chip's slice of the
+    # experts: models/latent_moe.py), so Adam never moves the router
+    router = grads["params"]["trunk"]["layer1"]["moe"]["router"]
+    assert float(jnp.abs(router).max()) == 0.0
+    experts = grads["params"]["trunk"]["layer1"]["moe"]["gate"]
+    assert float(jnp.abs(experts).max()) > 0.0
+    load = stats["load"]                          # [2 routed layers, 8]
+    assert load.shape == (2, 8) and float(load.sum()) == 2 * 32 * 2
+    new = latent_moe.update_router_bias(state.params, load, 0.001)
+    for b, row in zip(latent_moe.router_biases(new), load):
+        np.testing.assert_allclose(
+            b, 0.001 * jnp.sign(row.mean() - row), rtol=0, atol=1e-9
+        )
+    # the rule balances: repeated steps on the same 128 tokens bring the
+    # busiest expert's load over the mean down (1.39 -> 1.07 here)
+    obs = jax.random.normal(jax.random.key(1), (16, 8, 5), jnp.float32)
+    loads = jax.jit(lambda p: learner._apply(p, obs)[1]["load"])
+    params, spread = state.params, []
+    for _ in range(150):
+        load = loads(params)
+        spread.append(float((load.max(-1) / load.mean(-1)).mean()))
+        params = latent_moe.update_router_bias(params, load, 0.001)
+    assert np.mean(spread[-20:]) < 1.0 + 0.5 * (spread[0] - 1.0)
+
+
+def test_learn_moves_the_bias_by_its_rule_and_reports_the_router(form):
+    learner = _learner(8, "mixed")
+    state = learner.init(jax.random.key(0))
+    T, B = 8, 4
+    k = jax.random.split(jax.random.key(2), 4)
+    batch = {
+        "obs": jax.random.normal(k[0], (T, B, 5)),
+        "next_obs": jax.random.normal(k[1], (T, B, 5)),
+        "action": jax.random.normal(k[2], (T, B, 2)),
+        "reward": jax.random.normal(k[3], (T, B)),
+        "done": jnp.zeros((T, B), bool), "terminated": jnp.zeros((T, B), bool),
+        "behavior_logp": jnp.full((T, B), -2.0),
+        "behavior": {
+            "mean": jnp.zeros((T, B, 2)), "log_std": jnp.full((T, B, 2), -0.5),
+        },
+    }
+    new, metrics = jax.jit(learner.learn)(state, batch, jax.random.key(3))
+    # 2 epochs x 2 minibatches: each bias moved by at most 4 steps of 0.001
+    assert 0.0 < float(metrics["moe/bias_abs_max"]) <= 4 * 0.001 + 1e-9
+    assert float(metrics["moe/overflow"]) == 0.0
+    assert 0.0 < float(metrics["moe/held_share"]) < 1.0
+    assert float(metrics["moe/load_max_over_mean"]) >= 1.0
+    # Adam left the bias where the rule put it: no gradient, no moment
+    mu = new.opt_state[1][0].mu
+    for m in latent_moe.router_biases(mu):
+        assert float(jnp.abs(m).max()) == 0.0
+    assert float(metrics["health/update_ratio"]) > 0.0
+
+
+def test_a_dropped_assignment_ends_the_session():
+    """``moe/overflow`` is not a gauge to watch: the cadence's metrics
+    sync raises on a non-zero (launch/hooks.py)."""
+    from surreal_tpu.launch.hooks import refuse_dropped_assignments
+
+    refuse_dropped_assignments({"loss/pg": 0.1})
+    refuse_dropped_assignments({"moe/overflow": 0.0})
+    with pytest.raises(RuntimeError, match="168 assignments"):
+        refuse_dropped_assignments({"moe/overflow": 168.0})
+
+
+# -- parts -----------------------------------------------------------------------
+
+def test_part_refuses_a_foreign_name_and_reads_through_transforms():
+    from surreal_tpu.utils.phases import PARTS, PHASES, part, part_of, phase_of
+
+    assert not set(PARTS) & set(PHASES)
+    with pytest.raises(ValueError, match="not in the vocabulary"):
+        part("attention")
+    with pytest.raises(ValueError, match="not in the vocabulary"):
+        part("sgd")
+    name = "jit(train_iter)/sgd/while/body/transpose(jvp(attn))/dot_general"
+    assert part_of(name) == "attn" and phase_of(name) == "sgd"
+    assert part_of("jit(train_iter)/collect/while/body/env/add") == "unattributed"
+
+
+def test_parts_sum_with_unattributed_to_busy():
+    from surreal_tpu.session.profile import reduce_digest
+
+    ops = [
+        (0, 100, "while.1", "sgd", "unattributed"),
+        (10, 40, "fusion.1", "sgd", "attn"),
+        (40, 70, "ragged-dot.2", "sgd", "moe_experts"),
+        (120, 150, "fusion.3", "collect", "attn"),
+        (150, 160, "fusion.4", "collect"),          # an old 4-tuple: no part
+    ]
+    d = reduce_digest({"/device:TPU:0": ops}, [], steps=1)
+    parts, phases = d["parts"], d["phases"]
+    busy_ms = d["busy_s"] * 1e3
+    assert sum(p["ms_per_iter"] for p in parts.values()) == pytest.approx(busy_ms)
+    assert sum(p["ms_per_iter"] for p in phases.values()) == pytest.approx(busy_ms)
+    assert parts["attn"]["ms_per_iter"] == pytest.approx(60e-6)
+    assert parts["moe_experts"]["ms_per_iter"] == pytest.approx(30e-6)
+    assert parts["unattributed"]["ms_per_iter"] == pytest.approx(50e-6)
+    assert parts["attn"]["top_ops"][0][0] in ("fusion.1", "fusion.3")
+    assert sum(p["share_of_busy"] for p in parts.values()) == pytest.approx(1.0)
+
+
+def test_diag_prints_the_parts_beside_the_phases_only_where_a_model_has_them():
+    from surreal_tpu.session.profile import reduce_digest
+    from surreal_tpu.session.telemetry import _digest_lines
+
+    ops = [
+        (0, 40, "fusion.1", "sgd", "attn"),
+        (40, 70, "ragged-dot.2", "sgd", "moe_experts"),
+        (70, 100, "fusion.3", "collect", "unattributed"),
+    ]
+    with_parts = reduce_digest({"/device:TPU:0": ops}, [], steps=1)
+    text = "\n".join(_digest_lines({"digest": with_parts}))
+    assert "model part" in text and "moe_experts" in text and "phase" in text
+    without = reduce_digest({"/device:TPU:0": [op[:4] for op in ops]}, [], steps=1)
+    assert set(without["parts"]) == {"unattributed"}
+    assert "model part" not in "\n".join(_digest_lines({"digest": without}))
+
+
+def test_the_compiled_program_names_every_part():
+    """The scopes reach the ops of a jitted learn step: the HLO maps hold
+    each part of the vocabulary, under phase ``sgd``."""
+    from surreal_tpu.session.profile import hlo_op_phases
+    from surreal_tpu.utils.phases import PARTS, part_of
+
+    learner = _learner(8, "mixed")
+    state = jax.eval_shape(learner.init, jax.random.key(0))
+    T, B = 8, 4
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    batch = {
+        "obs": f32(T, B, 5), "next_obs": f32(T, B, 5), "action": f32(T, B, 2),
+        "reward": f32(T, B), "done": jax.ShapeDtypeStruct((T, B), bool),
+        "terminated": jax.ShapeDtypeStruct((T, B), bool),
+        "behavior_logp": f32(T, B),
+        "behavior": {"mean": f32(T, B, 2), "log_std": f32(T, B, 2)},
+    }
+    text = jax.jit(learner.learn).lower(
+        state, batch, jax.eval_shape(lambda: jax.random.key(0))
+    ).compile().as_text()
+    _, parts = hlo_op_phases(text, part_of)
+    _, phases = hlo_op_phases(text)
+    assert set(parts.values()) == set(PARTS)
+    both = [phases[i] for i, p in parts.items() if p == "optimizer" and i in phases]
+    # (XLA fuses a few of them into an op it names after a neighbour)
+    assert both and max(set(both), key=both.count) == "sgd"

@@ -242,3 +242,84 @@ def test_fused_ppo_65536x256_reads_blocks_in_place(sds):
     layouts = set(re.findall(r"bf16\[256,65536,17\]\{[^}]*\}", text))
     assert len(layouts) == 1, f"the obs leaf is relaid: {layouts}"
     assert compiled.memory_analysis().temp_size_in_bytes < 6.1e9
+
+
+def _joyai_learner(num_layers: int, horizon: int = 128):
+    """The 'mla_moe' trajectory policy at JoyAI-LLM-Flash's published
+    widths (the family's defaults), cut in depth alone."""
+    from surreal_tpu.envs import make_env
+    from surreal_tpu.learners import build_learner
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import base_config
+
+    cfg = Config(
+        learner_config=Config(
+            algo=Config(name="ppo", horizon=horizon, epochs=2, num_minibatches=2),
+            model=Config(encoder=Config(
+                kind="trajectory", block="mla_moe", num_layers=num_layers,
+                num_heads=32,
+            )),
+        ),
+        env_config=Config(name="jax:lift", num_envs=128),
+        session_config=Config(folder="unused"),
+    ).extend(base_config())
+    env = make_env(cfg.env_config)
+    return build_learner(cfg.learner_config, env.specs), env
+
+
+def test_acting_scan_carries_the_latent_rows_and_nothing_per_head(chip):
+    """``ppo_lift_joyai_128x128``'s acting carry, read from the compiled
+    rollout (two layers deep, the published widths): the scan's loop
+    carries ``bf16[128,128,576]`` a layer, and no array with the 32 heads
+    beside the 128 cached positions (expanded keys would be
+    ``[128,128,32,192]``, values ``[128,128,32,128]``)."""
+    import re
+
+    from surreal_tpu.launch.rollout import device_rollout, init_device_carry
+
+    learner, env = _joyai_learner(num_layers=2)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+    )
+    state = jax.eval_shape(learner.init, key)
+    carry = jax.eval_shape(lambda k: init_device_carry(env, k, 128), key)
+    text = (
+        jax.jit(lambda s, c, k: device_rollout(env, learner, s, c, k, 128))
+        .lower(on_chip(state), on_chip(carry), on_chip(key)).compile().as_text()
+    )
+    loops = [
+        line for line in text.splitlines()
+        if re.search(r" while\(", line) and "bf16[128,128,576]" in line
+    ]
+    assert loops, "no loop carries the latent cache"
+    for line in loops:
+        carried = line.split(" while(")[0]
+        assert carried.count("bf16[128,128,576]") >= 2      # one a layer
+        assert not re.search(r"\[128,128,32,\d+\]", carried), carried[:400]
+
+
+def test_routed_layer_compiles_to_the_ragged_kernel(chip):
+    """One routed layer at the published widths over a minibatch's 8192
+    tokens, forward and backward: XLA:TPU takes ``jax.lax.ragged_dot`` as
+    a kernel of its own (a ``ragged-dot`` custom call: three forward, six
+    backward), not as sixteen masked dense products."""
+    from surreal_tpu.models import latent_moe
+
+    cfg = latent_moe.resolve(dict(num_layers=5, num_heads=32))
+    layer = latent_moe.RoutedExperts(cfg, jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros((8, 2048), jnp.bfloat16))
+    )["params"]
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), params
+    )
+
+    def loss(p, x):
+        return layer.apply({"params": p}, x).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    text = compiled.as_text()
+    assert text.count("custom-call") >= 9 and "agged" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
