@@ -10,11 +10,22 @@ whole learn is a single fused backward pass; V-trace is the reverse scan
 in ``ops/vtrace.py``. Shares the PPO batch contract, so the same Trainer
 and collectors drive it.
 
+One forward gives both sides of the TD error. The rollout writes
+``next_obs[t] = obs[t + 1]`` wherever ``done[t]`` is false, so there
+``V(next_obs[t])`` is ``values[t + 1]`` of the forward over ``obs`` that
+the loss runs anyway, shifted by one step. A model apply of its own runs
+only over the steps whose successor is not in the batch: the last one,
+and those an episode was truncated at (``next_obs`` is the terminal
+observation there, ``obs[t + 1]`` the reset one). A loop visits them,
+``B`` frames at a time (``_values_apart``), so the whole pass over
+``next_obs`` that ``learn`` once always made is its limit, reached only by
+an input with a cut in every step. ``impala/boot_rows`` and
+``impala/boot_full`` in the metrics say how much of it ran.
+
 Phases (``utils/phases.py``; with the rollout's ``collect`` that is four):
-``bootstrap`` is the value forward over ``next_obs`` in ``learn`` (it
-carries no gradient, so it runs outside the differentiated function);
-``vtrace`` is ``_vtrace`` inside ``loss_fn``; ``learn`` is the rest of
-``learn``: the obs filter, the forward over ``obs``, the three losses, the
+``bootstrap`` is that loop (no gradient); ``vtrace`` is
+``_vtrace`` inside ``loss_fn``; ``learn`` is the rest of ``learn``: the
+obs filter, the forward over ``obs``, the shift, the three losses, the
 backward pass, the dp ``pmean`` (``learn/psum``), the optimizer apply, the
 new state and the metrics. A trajectory policy reads its bootstrap values
 from the one extended forward, so it has no ``bootstrap``.
@@ -164,7 +175,40 @@ class IMPALALearner(SequenceActingMixin, Learner):
         return self._head_act(out, key, mode)
 
     # -- learning ------------------------------------------------------------
+    def _values_apart(self, params, obs_stats, batch: dict):
+        """``V(next_obs)`` of a memoryless policy on the steps where it is
+        not ``V(obs)`` a step on: the last step, and every step that holds
+        a truncated row (a terminated row's bootstrap is masked by
+        V-trace, so it keeps the finite shifted value). A loop applies the
+        model to those steps alone, ``B`` frames at a time where they lie
+        (a slice of the leading axis: no frame is gathered or copied), so
+        its cost follows what the input holds: one step of ``T`` when no
+        episode was cut, every step (the whole ``next_obs`` pass) when
+        every step holds a cut. Returns ``(evaluated, steps)``: float32
+        values ``[T, B]``, defined on the steps that ``steps`` ``[T]``
+        marks."""
+        T, B = batch["done"].shape
+        steps = (batch["done"] & ~batch["terminated"]).any(axis=1).at[-1].set(True)
+        order = jnp.nonzero(steps, size=T, fill_value=0)[0]
+
+        def one_step(i, evaluated):
+            t = order[i]
+            frames = jax.lax.dynamic_index_in_dim(batch["next_obs"], t, keepdims=False)
+            v = self.model.apply(params, self._norm_obs(obs_stats, frames)).value
+            return evaluated.at[t].set(v.astype(jnp.float32))
+
+        evaluated = jax.lax.fori_loop(
+            0, steps.sum(), one_step, jnp.zeros((T, B), jnp.float32)
+        )
+        return evaluated, steps
+
     def learn(self, state: IMPALAState, batch: dict, key: jax.Array, axis_name=None):
+        """One V-trace update. A memoryless policy runs ONE forward over
+        the batch (``obs``, differentiated) and reads ``V(next_obs)`` from
+        it a step on; ``_values_apart`` evaluates the steps that have no
+        successor in the batch. ``impala/boot_rows`` counts the rows so
+        evaluated and ``impala/boot_full`` is 1.0 when they were all of
+        ``next_obs`` (the whole pass)."""
         del key
         from surreal_tpu.utils.asserts import check_learn_batch
 
@@ -187,14 +231,18 @@ class IMPALALearner(SequenceActingMixin, Learner):
 
         if self.seq_policy:
             next_obs = self._norm_obs(obs_stats, batch["next_obs"])
-            boot_values = None
+            boot = {}
         else:
-            # V(s'_t) enters V-trace as a constant: computed once, outside
-            # the differentiated function
+            # V(s'_t) enters V-trace as a constant: the forward's own
+            # values a step on, and outside the differentiated function an
+            # apply over the steps that have no successor in the batch
             with phase("bootstrap"):
-                boot_values = self.model.apply(
-                    state.params, self._norm_obs(obs_stats, batch["next_obs"])
-                ).value
+                evaluated, steps = self._values_apart(state.params, obs_stats, batch)
+                apart = steps.sum().astype(jnp.float32)
+                boot = {
+                    "impala/boot_rows": apart * batch["done"].shape[1],
+                    "impala/boot_full": (apart == T).astype(jnp.float32),
+                }
 
         def loss_fn(params):
             with phase("learn"):
@@ -215,7 +263,11 @@ class IMPALALearner(SequenceActingMixin, Learner):
                 else:
                     out = self.model.apply(params, obs)
                     values = out.value
-                    values_next = boot_values
+                    values_next = jnp.where(
+                        steps[:, None],
+                        evaluated.astype(values.dtype),
+                        jnp.concatenate([values[1:], values[-1:]]),
+                    )
                 if self.discrete:
                     logp = D.categorical_logp(out.logits, batch["action"])
                     entropy = D.categorical_entropy(out.logits).mean()
@@ -254,7 +306,7 @@ class IMPALALearner(SequenceActingMixin, Learner):
             if axis_name is not None:
                 with phase("learn/psum"):
                     grads = jax.lax.pmean(grads, axis_name)
-                    aux = jax.lax.pmean(aux, axis_name)
+                    aux, boot = jax.lax.pmean((aux, boot), axis_name)
             updates, opt_state = self.tx.update(grads, state.opt_state, state.params)
             params = optax.apply_updates(state.params, updates)
 
@@ -269,6 +321,9 @@ class IMPALALearner(SequenceActingMixin, Learner):
                 "loss/value": aux["v_loss"],
                 "policy/entropy": aux["entropy"],
                 "policy/rho_mean": aux["rho_mean"],
+                # rows evaluated apart for their successor value, and 1.0
+                # when that was the whole next_obs pass (dp: shard means)
+                **boot,
                 # grads are already pmean'd, so the health scalars replicate;
                 # the norm is divided by the (power-of-two) loss scale so
                 # health thresholds see the true magnitude — inf/nan survive

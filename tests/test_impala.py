@@ -9,6 +9,7 @@ import pytest
 from surreal_tpu.envs.base import ArraySpec, DiscreteSpec, EnvSpecs
 from surreal_tpu.launch.trainer import Trainer
 from surreal_tpu.learners import build_learner
+from surreal_tpu.learners.impala import IMPALALearner
 from surreal_tpu.ops.vtrace import vtrace, vtrace_nextobs
 from surreal_tpu.session.config import Config
 from surreal_tpu.session.default_configs import base_config
@@ -124,3 +125,209 @@ def test_impala_cartpole_256_envs_learns():
 
     trainer.run(on_metrics=cb)
     assert best["ret"] >= 400.0, f"best return {best['ret']}"
+
+
+# -- successor values from the forward over obs (one pass, not two) ----------
+#
+# The reference, written here: apply the model to EVERY next_obs frame, as
+# `learn` did before it read V(next_obs[t]) from values[t + 1].
+
+class _WholePassIMPALA(IMPALALearner):
+    def _values_apart(self, params, obs_stats, batch):
+        v = self.model.apply(
+            params, self._norm_obs(obs_stats, batch["next_obs"])
+        ).value
+        return v, jnp.ones(v.shape[0], bool)
+
+
+_CNN = Config(enabled=True, channels=(4, 8), kernels=(4, 3), strides=(2, 1), dense=16)
+
+
+def _successor_setup(kind, T, B, cut, terminated):
+    """A learner of ``kind`` ('mlp' float obs with the obs filter, 'cnn'
+    uint8 frames), its whole-pass twin, a state and a batch laid out as the
+    rollout lays it out: ``next_obs[t]`` IS ``obs[t + 1]`` except where
+    ``done[t]``, where it is a terminal frame of its own and ``obs[t + 1]``
+    is the reset one. ``cut``/``terminated`` are lists of (t, b)."""
+    pixels = kind == "cnn"
+    shape, dtype = ((12, 12, 2), np.uint8) if pixels else ((5,), np.float32)
+    specs = EnvSpecs(
+        obs=ArraySpec(shape=shape, dtype=np.dtype(dtype)),
+        action=DiscreteSpec(shape=(), dtype=np.dtype(np.int32), n=3),
+    )
+    cfg = Config(
+        algo=Config(name="impala", precision="f32"),
+        model=Config(cnn=_CNN if pixels else Config(enabled=False)),
+    )
+    learner = build_learner(cfg, specs)
+    whole = _WholePassIMPALA(learner.config, specs)
+    ks = jax.random.split(jax.random.key(T * 100 + B), 6)
+
+    def frames(key, lead):
+        if pixels:
+            return jax.random.randint(key, (*lead, *shape), 0, 256).astype(jnp.uint8)
+        return jax.random.normal(key, (*lead, *shape))
+
+    walk = frames(ks[0], (T + 1, B))
+    terminal = frames(ks[1], (T, B))
+    done = np.zeros((T, B), bool)
+    term = np.zeros((T, B), bool)
+    for t, b in list(cut) + list(terminated):
+        done[t, b] = True
+    for t, b in terminated:
+        term[t, b] = True
+    mask = jnp.asarray(done).reshape(T, B, *([1] * len(shape)))
+    logits = jax.random.normal(ks[2], (T, B, 3))
+    action = jax.random.randint(ks[3], (T, B), 0, 3)
+    batch = {
+        "obs": walk[:-1],
+        "next_obs": jnp.where(mask, terminal, walk[1:]),
+        "action": action,
+        "reward": jax.random.normal(ks[4], (T, B)),
+        "done": jnp.asarray(done),
+        "terminated": jnp.asarray(term),
+        "behavior_logp": jnp.take_along_axis(
+            jax.nn.log_softmax(logits), action[..., None], -1
+        )[..., 0],
+        "behavior": {"logits": logits},
+    }
+    state = learner.init(jax.random.key(7))
+    # a value head of the size of a reward, so the bootstrap moves the loss
+    state = state._replace(
+        params=jax.tree.map(lambda p: p * 3.0, state.params)
+    )
+    return learner, whole, state, batch
+
+
+def _learn_and_spy(learner, state, batch, step=None):
+    """(new state, metrics, the values_next V-trace was fed)."""
+    seen = {}
+    inner = learner._vtrace
+
+    def spy(**kw):
+        jax.debug.callback(
+            lambda v: seen.__setitem__("values_next", np.asarray(v)),
+            kw["values_next"],
+        )
+        return inner(**kw)
+
+    learner._vtrace = spy
+    try:
+        step = step or jax.jit(learner.learn)
+        new_state, metrics = step(state, batch, jax.random.key(2))
+        jax.block_until_ready(new_state)
+        jax.effects_barrier()
+    finally:
+        del learner._vtrace
+    return new_state, metrics, seen["values_next"]
+
+
+_T, _B = 6, 4
+_SUCCESSOR_CASES = {
+    # name: (T, B, truncated rows, terminated rows, steps evaluated apart)
+    "no_cut": (_T, _B, [], [], 1),
+    "terminated_mid": (_T, _B, [], [(2, 1)], 1),
+    "truncated_mid": (_T, _B, [(3, 0)], [], 2),
+    "truncated_at_the_last_step": (_T, _B, [(_T - 1, 2)], [], 1),
+    "all_envs_truncated_at_one_step": (_T, _B, [(2, b) for b in range(_B)], [], 2),
+    "cuts_in_three_steps": (_T, _B, [(0, 1), (1, 3), (4, 0), (4, 2)], [(0, 3)], 4),
+    "a_cut_in_every_step": (_T, _B, [(t, t % _B) for t in range(_T)], [(1, 0)], _T),
+    "T_is_1": (1, _B, [], [], 1),
+}
+
+
+@pytest.mark.parametrize("kind", ["mlp", "cnn"])
+@pytest.mark.parametrize("case", list(_SUCCESSOR_CASES))
+def test_successor_values_match_the_whole_next_obs_pass(case, kind):
+    """`learn` reads V(next_obs[t]) from values[t + 1] and evaluates apart
+    only the steps with no successor in the batch; the result is the whole
+    pass's: parameters, every metric, and the values_next fed to V-trace."""
+    T, B, cut, terminated, steps = _SUCCESSOR_CASES[case]
+    learner, whole, state, batch = _successor_setup(kind, T, B, cut, terminated)
+    new, metrics, values_next = _learn_and_spy(learner, state, batch)
+    ref, ref_metrics, ref_values_next = _learn_and_spy(whole, state, batch)
+
+    # a terminated row's bootstrap is masked by V-trace; anywhere else
+    # values_next is V(next_obs), to float32 rounding
+    live = ~np.asarray(batch["terminated"])
+    assert np.isfinite(values_next).all()
+    np.testing.assert_allclose(
+        values_next[live], ref_values_next[live], rtol=1e-5, atol=1e-5
+    )
+    assert np.abs(ref_values_next).max() > 0.05  # the comparison has teeth
+    for a, b in zip(jax.tree.leaves(new.params), jax.tree.leaves(ref.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(new.obs_stats), jax.tree.leaves(ref.obs_stats)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert set(metrics) == set(ref_metrics)
+    for k in ref_metrics:
+        if not k.startswith("impala/"):
+            np.testing.assert_allclose(
+                float(metrics[k]), float(ref_metrics[k]), rtol=1e-4, atol=1e-6,
+                err_msg=k,
+            )
+    assert float(metrics["impala/boot_rows"]) == steps * B
+    assert float(metrics["impala/boot_full"]) == float(steps == T)
+
+
+def _conv_batches(jaxpr, in_loop=False, out=None):
+    """[(batch size, inside a while loop)] of every convolution."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "conv_general_dilated":
+            lhs_batch_dim = eqn.params["dimension_numbers"].lhs_spec[0]
+            out.append((eqn.invars[0].aval.shape[lhs_batch_dim], in_loop))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _conv_batches(sub, in_loop or eqn.primitive.name == "while", out)
+    return out
+
+
+def test_learn_holds_no_convolution_over_the_whole_of_next_obs():
+    """`learn` traces one convolution stack over T * B frames fewer than
+    the whole-pass reference (the next_obs pass); in its place stands one
+    stack over the B frames of a step, inside the loop over the steps that
+    have no successor in the batch."""
+    T, B, layers = _T, _B, len(_CNN.channels)
+    learner, whole, state, batch = _successor_setup("cnn", T, B, [], [])
+    key = jax.random.key(2)
+    got = _conv_batches(jax.make_jaxpr(learner.learn)(state, batch, key).jaxpr)
+    ref = _conv_batches(jax.make_jaxpr(whole.learn)(state, batch, key).jaxpr)
+    assert not any(in_loop for _, in_loop in ref)
+    outside = sorted(n for n, in_loop in got if not in_loop)
+    ref_outside = sorted(n for n, _ in ref)
+    assert ref_outside.count(T * B) - outside.count(T * B) == layers
+    assert len(ref_outside) - len(outside) == layers
+    assert [n for n, in_loop in got if in_loop] == [B] * layers
+
+
+def test_successor_values_under_dp_match_one_device():
+    """Each dp shard shifts inside its own [T, B_local] and visits the
+    steps its own rows were cut at: the update is the one-device update."""
+    from surreal_tpu.parallel import dp_learn, make_mesh
+
+    T, B = 6, 16
+    # shard 0 (envs 0, 1) holds cuts in three steps, shards 2 and 4 in one
+    cut = [(1, 0), (1, 1), (3, 0), (0, 1), (2, 5), (4, 9)]
+    learner, _, state, batch = _successor_setup("mlp", T, B, cut, [(0, 12)])
+    one, one_metrics, _ = _learn_and_spy(learner, state, batch)
+
+    mesh = make_mesh(Config(mesh=Config({"dp": 8})))
+    dp, dp_metrics, _ = _learn_and_spy(
+        learner, state, batch, step=dp_learn(learner, mesh, donate=False)
+    )
+    for a, b in zip(jax.tree.leaves(one.params), jax.tree.leaves(dp.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=2e-6)
+    for k in ("loss/pg", "loss/value", "policy/entropy", "policy/rho_mean"):
+        np.testing.assert_allclose(
+            float(one_metrics[k]), float(dp_metrics[k]), rtol=1e-4, atol=1e-6,
+            err_msg=k,
+        )
+    # one device visits the 5 cut steps and the last; the counters of the
+    # mesh are means over its shards: 4 + 2 + 2 + 5 x 1 steps of 2 rows
+    assert float(one_metrics["impala/boot_rows"]) == 6 * B
+    assert float(one_metrics["impala/boot_full"]) == 1.0
+    assert float(dp_metrics["impala/boot_rows"]) == 13 * 2 / 8
+    assert float(dp_metrics["impala/boot_full"]) == 0.0
