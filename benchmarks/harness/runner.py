@@ -9,7 +9,11 @@ number. ``on_metrics`` fires after the cadence's one device->host sync, so
 every call is a fenced point on the host clock:
 
     process start --launch--> first stamp --warm-up, until the ring is
-    full--> reference check --> window start ... --seconds--> last stamp.
+    full--> window start ... --seconds--> last stamp.
+
+Once the window has closed the peaks are read, the trainer's buffers are
+freed, and only then the configuration's reference check runs: it is no part
+of ``setup_s`` and nothing of it is on the device beside the measured session.
 
 Nothing here calls into a trainer's private step.
 """
@@ -74,7 +78,7 @@ class Run:
 
     cell: dict
     config: dict
-    seed: int
+    seed: int                      # the session's seed (session_seed)
     seconds: float
     trace: bool
     rehearse: bool
@@ -94,12 +98,12 @@ class Run:
     events: dict = field(default_factory=dict)     # the run's telemetry, by type
     reference: dict = field(default_factory=dict)  # the reference check's record
     reduced: dict | None = None                    # trace_reduce.reduce_file
-    standalone: dict = field(default_factory=dict) # layers timed alone
     live_bytes: int = 0            # allocator bytes in use at the window start
     program_temp_bytes: int = 0    # largest temporaries of a program of the launch
     memory: dict = field(default_factory=dict)     # the peak and its two parts
     marks: dict = field(default_factory=dict)      # seconds since t0 along the launch
     folder: str = ""
+    asked_seed: int = 0            # --seed as given
 
     @property
     def traffic(self) -> dict:
@@ -158,6 +162,16 @@ def sized(cell: dict, rehearse: bool) -> dict:
         overrides=list(cell["overrides"]) + list(toy["overrides"]),
         learning=toy["learning"],
     )
+
+
+def session_seed(cell: dict, seed: int) -> int:
+    """The seed the session, the reference check and the phase session take
+    for ``--seed``. A cell whose program refuses some initialisations lists
+    the ``session_seeds`` it is known to launch from (its workload file says
+    why), and ``--seed`` picks among them; every other cell takes ``--seed``
+    itself."""
+    listed = cell.get("session_seeds")
+    return int(listed[int(seed) % len(listed)]) if listed else int(seed)
 
 
 def train_argv(config: dict, cell: dict, folder: str, seed: int,
@@ -281,9 +295,8 @@ class Window:
     """The ``on_metrics`` callback: stamps every fenced point and walks
     launch -> warm-up -> window; returns truthy to end the run."""
 
-    def __init__(self, run: Run, before_window, trace_dir: str | None):
+    def __init__(self, run: Run, trace_dir: str | None):
         self.run = run
-        self.before_window = before_window
         self.trace_dir = trace_dir
         self.phase = "launch"
         self.tracing = False
@@ -297,7 +310,8 @@ class Window:
         if self.phase == "launch":
             run.launch_s = run.marks["first_stamp"] = now - run.t0
             # every program of the launch is loaded and none of the
-            # harness's own (reference, standalone timers) is yet
+            # harness's own (the reference's, the phase session's) is yet:
+            # those run once the window has closed
             run.program_temp_bytes = program_temp_bytes()
             self.phase = "warmup"
             return False
@@ -306,7 +320,7 @@ class Window:
             # ring is full
             if not ring_full(row):
                 return False
-            self.before_window()
+            run.mark("warm")
             self.phase = "window"
             # the device is idle here (the cadence sync has returned and
             # nothing is dispatched until this returns): a fenced start
@@ -349,8 +363,9 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
     """Run one cell once and return its record."""
     cell = sized(manifest.load_cell(cell_name), rehearse)
     config = manifest.load_config(cell["config"])
+    asked_seed, seed = int(seed), session_seed(cell, seed)
     run = Run(cell=cell, config=config, seed=seed, seconds=seconds,
-              trace=trace, rehearse=rehearse, t0=t0)
+              trace=trace, rehearse=rehearse, t0=t0, asked_seed=asked_seed)
     reference = manifest.load_reference(config["reference"])
     run.cost = reference.iteration_cost(config, cell["traffic"])
 
@@ -386,31 +401,34 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
         trainer = launch.select_trainer(cfg)
         run.mark("trainer_built")
 
-        def before_window():
-            run.mark("warm")
-            run.reference = reference.check(cfg, run)
-
         trace_dir = os.path.join(run.folder, "trace") if trace else None
-        window = Window(run, before_window, trace_dir)
+        window = Window(run, trace_dir)
         trainer.run(on_metrics=window)
         if window.tracing:  # the run ended under the trace: a bug, not a result
             jax.profiler.stop_trace()
             raise RuntimeError("run ended while the profiler was tracing")
-        run.cache = {
-            k: compile_cache_counts()[k] - cache_before[k]
-            for k in ("hits", "misses")
-        }
         run.events = read_events(run.folder)
         run.memory = memory_parts(run)
         if trace:
-            from benchmarks.harness import standalone, trace_reduce
+            from benchmarks.harness import trace_reduce
 
             run.reduced = trace_reduce.reduce_dir(trace_dir, host_stand_in=rehearse)
             run.reduced["host_span_s"] = window.trace_span[1] - window.trace_span[0]
             shutil.rmtree(trace_dir)  # tens of MB a run; the numbers are kept
-            del trainer
-            gc.collect()
-            run.standalone = standalone.time_layers(cfg, run)
+        # the window has closed and the peaks are read: the session's buffers
+        # go before the reference check builds its own learner, and before
+        # the phase session (harness/phase_session.py) trains a second
+        # session when a reader first asks
+        del trainer, window
+        gc.collect()
+        run.mark("session_freed")
+        run.memory["bytes_in_use_after_session"] = max(bytes_in_use("bytes_in_use"))
+        run.reference = reference.check(cfg, run)
+        run.mark("reference_checked")
+        run.cache = {
+            k: compile_cache_counts()[k] - cache_before[k]
+            for k in ("hits", "misses")
+        }
     finally:
         jax.monitoring.unregister_event_duration_listener(on_compile)
     return run

@@ -2,7 +2,7 @@
 ``cadence`` phase (end of one metrics sync to the end of the next, over
 its iterations) of the MEASURED run's ``phases`` telemetry events. Only
 the cadences that lie wholly inside the window count (the one that ends at
-the window's first stamp began before the reference check), and of those
+the window's first stamp began at the window's start, not at a sync's end), and of those
 the low median: in a traced run of three cadences the profiler's stop
 stretches one of the two that remain. Beside ``iter_ms_p50``, which the
 harness's stamps give."""

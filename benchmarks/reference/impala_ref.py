@@ -135,37 +135,37 @@ def frame_macs(widths: dict) -> dict:
 
 def iteration_cost(config: dict, traffic: dict) -> dict:
     """Required operations and bytes of one fused IMPALA iteration
-    (harness/flops.py has the rules). Per env step: one forward to act;
-    in ``learn`` a forward over ``obs``, a forward over ``next_obs`` and one
-    backward pass, which costs two forwards less the first convolution's
-    input gradient (the pixels need none). At the published widths a
-    frame's forward is 9 345 024 MACs and an env step 43 448 320."""
+    (harness/flops.py has the rules). Per env step: one forward to act; in
+    ``learn`` a forward over ``obs`` and one backward pass, which costs two
+    forwards less the first convolution's input gradient (the pixels need
+    none). A step's successor value is the next step's own, so the one
+    further forward is over the last step's ``num_envs`` successor frames
+    (a truncated row elsewhere is the input's doing, not required work). A
+    frame's forward is 9 345 024 MACs; an env step of 32, 34 395 328."""
     widths = config["widths"]
     m = frame_macs(widths)
-    samples = int(traffic["num_envs"]) * int(traffic["horizon"])
+    envs, horizon = int(traffic["num_envs"]), int(traffic["horizon"])
+    samples = envs * horizon
     backward = 2 * m["forward"] - m["convs"][0]
     rollout = samples * m["forward"]
-    learn = samples * (2 * m["forward"] + backward)
-    # required HBM traffic per stored step: obs and next_obs (uint8) are
-    # each written once by the rollout and read once by learn; the
-    # activations the backward pass needs are written by the forward over
-    # obs and read back once, in the compute dtype (bfloat16); action,
-    # reward, two flags, behaviour log-prob and logits written and read
+    learn = samples * (m["forward"] + backward) + envs * m["forward"]
+    # required HBM traffic per stored step: obs (uint8) written once by the
+    # rollout and read once by learn, as are the last step's successor
+    # frames; the activations the backward pass needs, written by the
+    # forward over obs and read back once in the compute dtype (bfloat16);
+    # action, reward, two flags, behaviour log-prob and logits written, read
     h, w, c = widths["input"]
     obs_row = h * w * c
-    row = 2 * (2 * obs_row) + 2 * (2 * m["activations"]) + 2 * 4 * (
-        5 + widths["actions"]
-    )
-    # parameters (float32): read by every act of the horizon, by the two
-    # forwards and the backward of learn; the gradient written and read;
-    # Adam's two moments read and written; the parameters written
-    params = 4 * m["parameters"] * (int(traffic["horizon"]) + 3 + 2 + 4 + 1)
+    row = 2 * obs_row + 4 * m["activations"] + 8 * (5 + widths["actions"])
+    # parameters (float32): read by every act, by learn's two forwards and
+    # its backward; gradient and Adam's moments written and read; one write
+    params = 4 * m["parameters"] * (horizon + 3 + 2 + 4 + 1)
     return {
         "samples": samples,
         "flops": 2 * (rollout + learn),
         "flops_rollout": 2 * rollout,
         "flops_learn": 2 * learn,
-        "bytes": samples * row + params,
+        "bytes": samples * row + envs * 2 * obs_row + params,
     }
 
 

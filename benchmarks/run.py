@@ -68,7 +68,8 @@ def result_line(run) -> dict:
             "return_at_mark": checks.return_at_mark(run),
         },
         "cell": cell,
-        "seed": run.seed,
+        "seed": run.asked_seed,
+        "session_seed": run.seed,
         "window_s": run.window_seconds(),
         "iterations": run.window_iterations(),
         "cadence_s_per_iteration": run.cadence_seconds(),
@@ -92,8 +93,20 @@ def result_line(run) -> dict:
                 "host_span_s",
             )
         }
-        line["standalone_s"] = run.standalone
     return line
+
+
+def print_comparisons(line: dict) -> None:
+    """Every number ``correct`` was decided from beside its limit, one to a
+    line, as the last lines of standard error: what the driver's record
+    keeps of a run that is not correct."""
+    for name, entry in line["reference"].get("comparisons", {}).items():
+        print(f"compared {name}: {json.dumps(entry, default=float)}",
+              file=sys.stderr)
+    for name, ok in line["checks"].items():
+        print(f"check {name}: {'ok' if ok else 'FALSE'}", file=sys.stderr)
+    print(f"correct: {line['correct']} (failed rows {line['failed']} of "
+          f"{line['attempted']})", file=sys.stderr, flush=True)
 
 
 def main(argv=None) -> int:
@@ -131,7 +144,9 @@ def main(argv=None) -> int:
     except runner.NoAccelerator as e:
         print(f"benchmarks/run.py: {e}", file=sys.stderr)
         return 2
-    print(json.dumps(result_line(run), default=float), flush=True)
+    line = result_line(run)
+    print_comparisons(line)
+    print(json.dumps(line, default=float), flush=True)
     return REHEARSAL_EXIT if args.rehearse else 0
 
 

@@ -125,12 +125,22 @@ def test_iteration_cost_against_a_count_by_hand():
     cost = impala_ref.iteration_cost(config, cell["traffic"])
     samples = cell["traffic"]["num_envs"] * 32
     assert cost["samples"] == samples
-    # act: one forward; learn: two forwards and a backward of two forwards
-    # less the first convolution's input gradient
+    # act: one forward. learn: one forward over obs, a backward of two
+    # forwards less the first convolution's input gradient, and one forward
+    # over the last step's 1024 successor frames, the only rows whose
+    # successor value no row of the batch holds (PR 36): 3.68 forwards an
+    # env step, where a second whole pass over next_obs made it 4.65
+    backward = 2 * 9_345_024 - 3_276_800
     assert cost["flops_rollout"] == 2 * samples * 9_345_024
-    assert cost["flops_learn"] == 2 * samples * (43_448_320 - 9_345_024)
-    assert cost["flops"] == 2 * samples * 43_448_320
-    # obs and next_obs written and read; the stored activations (bfloat16)
-    # written and read; eight scalars a step; then the parameters' passes
-    row = 4 * 84 * 84 * 4 + 4 * (12800 + 5184 + 3136 + 512) + 2 * 4 * 8
-    assert cost["bytes"] == samples * row + 4 * 1_686_180 * (32 + 10)
+    assert cost["flops_learn"] == 2 * (
+        samples * (9_345_024 + backward) + 1024 * 9_345_024
+    )
+    assert cost["flops"] == 2 * samples * 34_395_328
+    assert cost["flops"] == cost["flops_rollout"] + cost["flops_learn"]
+    # obs written and read, and the last step's successor frames; the
+    # stored activations (bfloat16) written and read; eight scalars a
+    # step; then the parameters' passes
+    row = 2 * 84 * 84 * 4 + 4 * (12800 + 5184 + 3136 + 512) + 2 * 4 * 8
+    assert cost["bytes"] == (
+        samples * row + 1024 * 2 * 84 * 84 * 4 + 4 * 1_686_180 * (32 + 10)
+    )

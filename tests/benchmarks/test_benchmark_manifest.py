@@ -15,8 +15,19 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "projection",
-               "head", "expansion", "experts_per_tok")
+# a width's key, matched whole (a name before it is fine: ``moe_``,
+# ``mamba_``): a hidden, intermediate, latent, state or projection size,
+# a head size, an expansion factor, the number of experts per token, and
+# whatever ends in ``_dim`` or ``_rank``. ``num_hidden_layers`` holds the
+# word "hidden" and is a depth.
+WIDTH_KEY = re.compile(
+    r"(?:.*_)?(?:"
+    r"(?:hidden|intermediate|latent|state|projection|head)_size"
+    r"|d_(?:model|inner|state|ssm|head|conv)|head_?dim"
+    r"|expand|expansion_(?:factor|rate)"
+    r"|experts_per_tok(?:en)?|top_?k"
+    r")|.*_(?:dim|rank)"
+)
 
 M = manifest.load_manifest()
 CELLS = [w["name"] for w in M["workloads"]]
@@ -70,8 +81,7 @@ def test_config_entry_and_file(entry):
     assert len(entry["reduced"]) <= 16
     for key in entry["reduced"]:
         assert NAME.match(key)
-        assert not key.endswith(("_dim", "_rank")), key
-        assert not any(w in key for w in WIDTH_WORDS), f"{key} names a width"
+        assert not WIDTH_KEY.fullmatch(key), f"{key} names a width"
     cfg = manifest.load_config(entry["name"])
     assert cfg["source"] == entry["source"] and cfg["reduced"] == entry["reduced"]
     # a key changed from the source is in the file as it is run, and the
@@ -84,6 +94,21 @@ def test_config_entry_and_file(entry):
     ref = manifest.load_reference(cfg["reference"])
     assert callable(ref.check)
     assert entry["name"] in {w["config"] for w in M["workloads"]}
+
+
+@pytest.mark.parametrize("key,is_width", [
+    ("hidden_size", True), ("intermediate_size", True),
+    ("moe_intermediate_size", True), ("head_dim", True),
+    ("qk_rope_head_dim", True), ("kv_lora_rank", True),
+    ("ssm_state_size", True), ("mamba_d_state", True),
+    ("mamba_expand", True), ("num_experts_per_tok", True),
+    # depths, counts held here and scale: what a cut may name
+    ("num_hidden_layers", False), ("n_routed_experts", False),
+    ("vocab_size", False), ("num_nextn_predict_layers", False),
+    ("replay_capacity", False),
+])
+def test_a_reduced_key_is_refused_when_it_is_a_widths_key(key, is_width):
+    assert bool(WIDTH_KEY.fullmatch(key)) is is_width
 
 
 def test_config_files_are_one_to_one():
@@ -179,6 +204,20 @@ def test_every_cell_reports_a_per_layer_metric():
         assert manifest.metrics_of("per_layer", cell)
 
 
+@pytest.mark.parametrize("metric", E2E)
+def test_every_end_to_end_metric_has_a_layer_under_it_in_every_cell(metric):
+    """In every cell that reports it, some per-layer metric of a layer of
+    the program (not the device's own readings) says it moves it: a change
+    to that cell's end-to-end number can be looked for in a layer."""
+    entry = next(m for m in M["end_to_end"] if m["name"] == metric)
+    for cell in entry.get("workloads", CELLS):
+        movers = [
+            m["name"] for m in manifest.metrics_of("per_layer", cell)
+            if m["moves"] == metric and m["layer"] != "device"
+        ]
+        assert movers, (metric, cell)
+
+
 def test_layers_are_spelled_one_way():
     layers = {m["layer"] for m in M["per_layer"]}
     assert len({l.lower().strip() for l in layers}) == len(layers)
@@ -204,11 +243,33 @@ def test_peak_table_names_its_source():
         assert peaks["bf16_flops_per_s"] > 0 and peaks["hbm_bytes_per_s"] > 0
 
 
+def sources(*folders):
+    """``(path, text)`` of every ``.py`` under ``benchmarks/<folder>``, or
+    under ``benchmarks/`` itself with no folder named."""
+    for folder in folders or ("",):
+        for root, _, files in os.walk(os.path.join(manifest.BENCH_DIR, folder)):
+            for f in files:
+                if f.endswith(".py"):
+                    path = os.path.join(root, f)
+                    yield path, open(path).read()
+
+
 def test_no_private_step_is_called():
     """Every run goes through ``select_trainer(...).run``; nothing under
     benchmarks/ names the trainers' private step."""
-    for root, _, files in os.walk(manifest.BENCH_DIR):
-        for f in files:
-            if f.endswith(".py"):
-                text = open(os.path.join(root, f)).read()
-                assert "_train_iter" not in text, os.path.join(root, f)
+    for path, text in sources():
+        assert "_train_iter" not in text, path
+
+
+def test_no_learner_is_built_beside_the_sessions():
+    """A traced run holds a configuration's training state once, in the
+    sessions it trains through ``select_trainer(cfg).run``: no file of the
+    harness or of a reader builds a learner, calls ``learn`` or
+    ``device_rollout``, or initialises a state of its own (a second state
+    beside the first capped a trained configuration near 500M parameters:
+    PERF.md section 6, PR 38). The references' checks, on a few envs, are
+    not the harness."""
+    for path, text in sources("harness", "layer_metrics"):
+        for word in ("build_learner", ".learn(", "device_rollout",
+                     "learner.init", "standalone"):
+            assert word not in text, (path, word)

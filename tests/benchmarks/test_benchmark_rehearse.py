@@ -26,6 +26,25 @@ def cache_dir(tmp_path_factory):
     return str(tmp_path_factory.mktemp("rehearse_jax_cache"))
 
 
+@pytest.fixture(scope="module")
+def rehearsed(cache_dir):
+    """``(cell, trace) ->`` the rehearsal's last line, each rehearsed once
+    in the module: ``test_cell_rehearses`` fills it and the tests after it
+    read what it left (a test run alone rehearses its own cell)."""
+    done: dict = {}
+
+    def get(cell: str, trace: int):
+        if (cell, trace) not in done:
+            proc = run_cell(cell, trace, "--rehearse", cache=cache_dir)
+            assert proc.returncode == 3, proc.stderr[-2000:]
+            done[cell, trace] = dict(
+                last_line(proc), stderr_tail=proc.stderr.splitlines()[-60:]
+            )
+        return done[cell, trace]
+
+    return get
+
+
 # The child pins itself to one core and then becomes the command: the suite
 # runs one worker per core and holds timing-sensitive tests, which a child
 # that spread XLA's thread pool over every core would slow.
@@ -55,14 +74,26 @@ def last_line(proc: subprocess.CompletedProcess) -> dict:
     return json.loads(lines[-1])
 
 
+def declared_and_required(cell: str, trace: int):
+    """The manifest's entries, by name, for the metrics this cell's line may
+    carry, and the names it must: some readers need the chip (its published
+    peak, its allocator) and may find nothing to read in a rehearsal."""
+    if not trace:
+        declared = {m["name"]: m for m in manifest.metrics_of("end_to_end", cell)}
+        return declared, set(declared)
+    declared = {m["name"]: m for m in manifest.metrics_of("per_layer", cell)}
+    return declared, {
+        name for name in declared
+        if not getattr(manifest.load_layer_metric(name), "CHIP_ONLY", False)
+    }
+
+
 @pytest.mark.parametrize(
     "cell,trace", [(c, t) for c in CELLS for t in (0, 1)],
     ids=[f"{c}-trace{t}" for c in CELLS for t in (0, 1)],
 )
-def test_cell_rehearses(cell, trace, cache_dir):
-    proc = run_cell(cell, trace, "--rehearse", cache=cache_dir)
-    assert proc.returncode == 3, proc.stderr[-2000:]
-    line = last_line(proc)
+def test_cell_rehearses(cell, trace, rehearsed):
+    line = rehearsed(cell, trace)
     assert LINE_KEYS <= set(line)
     assert line["correct"] is False
     chips = next(w["chips"] for w in M["workloads"] if w["name"] == cell)
@@ -74,14 +105,7 @@ def test_cell_rehearses(cell, trace, cache_dir):
     failed = [k for k, ok in line["checks"].items() if not ok]
     assert failed == ["device_is_tpu_in_peak_table"], line["checks"]
     assert line["compiles_in_window"] == 0
-    kind = "per_layer" if trace else "end_to_end"
-    declared = {m["name"]: m for m in manifest.metrics_of(kind, cell)}
-    # some readers need the chip (its published peak, its allocator) and
-    # may find nothing to read here
-    required = {
-        name for name in declared
-        if not getattr(manifest.load_layer_metric(name), "CHIP_ONLY", False)
-    } if trace else set(declared)
+    declared, required = declared_and_required(cell, trace)
     assert required <= set(line["metrics"]) <= set(declared)
     for name, got in line["metrics"].items():
         assert got["unit"] == declared[name]["unit"]
@@ -93,6 +117,64 @@ def test_cell_rehearses(cell, trace, cache_dir):
             assert len(line["breakdown"][key]) <= 10
     else:
         assert line["metrics"]["setup_s"]["value"] >= line["launch_marks_s"]["first_stamp"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_traced_line_has_only_what_the_sessions_gave(cell, rehearsed):
+    """A traced run reads its per-layer metrics from the measured session
+    and the phase session and times nothing as a program of its own: the
+    line has no ``standalone_s``, and its metrics are the cell's declared
+    per-layer ones (all that need no chip, none from outside the list)."""
+    line = rehearsed(cell, 1)
+    assert "standalone_s" not in line
+    declared, required = declared_and_required(cell, 1)
+    assert required <= set(line["metrics"]) <= set(declared)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_reference_check_follows_the_window(cell, rehearsed):
+    """The reference check is no part of ``setup_s`` and runs beside nothing
+    of the measured session: the window closes, the session is freed, then
+    the check runs; and the run's last lines on standard error are every
+    number compared beside its limit, every check by name, and the verdict."""
+    line = rehearsed(cell, 0)
+    marks = line["launch_marks_s"]
+    setup = line["metrics"]["setup_s"]["value"]
+    assert marks["warm"] <= setup
+    assert setup + line["window_s"] <= marks["session_freed"]
+    assert marks["session_freed"] <= marks["reference_checked"]
+    assert "bytes_in_use_after_session" in line["memory"]
+    assert line["reference"]["ok"] is True
+    tail = line["stderr_tail"]
+    assert tail[-1].startswith("correct: False")
+    said = "\n".join(tail)
+    for name in line["reference"]["comparisons"]:
+        assert f"compared {name}: " in said
+    for name in line["checks"]:
+        assert f"check {name}: " in said
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_session_seed_is_listed_or_the_seed_itself(cell):
+    """A cell that lists ``session_seeds`` (and says why) launches from one
+    of them whatever ``--seed`` is, the same one for the same seed; every
+    other cell launches from ``--seed`` itself."""
+    from benchmarks.harness import runner
+
+    loaded = manifest.load_cell(cell)
+    listed = loaded.get("session_seeds")
+    for seed in (0, 7, 31337, 2**31 - 1, 2**31, 2**31 + 123456):
+        got = runner.session_seed(loaded, seed)
+        assert got == runner.session_seed(runner.sized(loaded, True), seed)
+        if listed is None:
+            assert got == seed
+        else:
+            assert got in listed and 0 <= got < 2**31 + 2**20
+    if listed is not None:
+        assert loaded["session_seeds_why"]
+        assert len(set(listed)) == len(listed) >= 12
+        picked = {runner.session_seed(loaded, s) for s in range(len(listed))}
+        assert picked == set(listed)
 
 
 def test_no_tpu_means_no_result():
