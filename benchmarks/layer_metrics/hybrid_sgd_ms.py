@@ -1,0 +1,16 @@
+"""Device milliseconds per iteration owned by the ops of phase ``sgd``:
+the loss's forward over a minibatch's 8192 tokens, its backward with each
+layer recomputed, and the optimizer step, four times an iteration.
+From the digest of the phase session's capture (harness/phase_session.py).
+As ``phase_sgd_ms`` reads it for the ``ppo_lift`` cells and
+``sgd_phase_ms`` for ``ppo_lift_joyai_128x128``, whose lists may not be
+edited."""
+
+from benchmarks.harness import phase_session
+
+NAME = "hybrid_sgd_ms"
+CHIP_ONLY = True  # the CPU's capture has no device plane
+
+
+def read(run):
+    return phase_session.phase_ms(run, "sgd")

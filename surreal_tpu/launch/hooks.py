@@ -698,9 +698,15 @@ class SessionHooks:
                         self.ckpt.save_extra(iteration, self.extra_state_fn())
         # a capture starts and stops on an idle device, so that it holds
         # whole iterations: the state is the last dispatched one's output
+        t_tick = time.monotonic()
         self.profile.tick(
             iteration, fence=lambda: jax.block_until_ready(resolve_state())
         )
+        # ... and holds this thread while it does (a minute to write three
+        # iterations of a thousand acting steps): not its tiers' silence
+        held = time.monotonic() - t_tick
+        if held > 1.0:
+            self.ops.excuse_pause(held)
         # chaos-harness visibility: mirror any faults fired since the last
         # boundary into the telemetry spine (empty list in normal runs) —
         # and into the flight recorder, whose dump freezes the snapshots

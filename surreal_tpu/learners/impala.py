@@ -99,9 +99,11 @@ class IMPALALearner(SequenceActingMixin, Learner):
         self.requires_act_carry = self.seq_policy
         if self.seq_policy and block_family(enc) != "preln":
             raise ValueError(
-                "model.encoder.block='mla_moe' is wired into PPO alone: its "
-                "router-bias rule runs after each optimizer step "
-                "(learners/ppo.py); IMPALA takes the 'preln' blocks"
+                f"model.encoder.block={block_family(enc)!r} is wired into "
+                "PPO alone ('mla_moe': its router-bias rule runs after each "
+                "optimizer step; 'ssm_hybrid': its counters ride PPO's "
+                "minibatch steps; learners/ppo.py); IMPALA takes the "
+                "'preln' blocks"
             )
         # precision: model dtypes materialize from the resolved policy
         # (Learner.__init__), 'auto' knobs -> concrete per algo.precision

@@ -45,7 +45,12 @@ from surreal_tpu.learners.seq_policy import (
     family_config,
 )
 from surreal_tpu.models import latent_moe
-from surreal_tpu.models.attention import block_family
+from surreal_tpu.models.attention import (
+    COUNTERS_COLLECTION,
+    block_family,
+    read_counters,
+    trunk_counters,
+)
 from surreal_tpu.models.ppo_net import CategoricalPPOModel, PPOModel
 from surreal_tpu.ops import distributions as D
 from surreal_tpu.ops.running_stats import (
@@ -173,10 +178,15 @@ class PPOLearner(SequenceActingMixin, Learner):
         # that learns reads the router's statistics, and the selection
         # bias moves by its own rule after each optimizer step
         self.moe = None
+        # scalars a trunk sows on every apply that learns, whatever the
+        # family (models/attention.py::trunk_counters): they ride the
+        # minibatch steps' aux to the metrics row
+        self.counters = None
         if self.seq_policy:
             enc_cfg = family_config(enc.to_dict())
             if block_family(enc_cfg) == "mla_moe":
                 self.moe = enc_cfg
+            self.counters = trunk_counters(enc_cfg)
         # precision: model dtypes materialize from the resolved policy
         # (Learner.__init__), 'auto' knobs -> concrete per algo.precision
         model_cfg = self.policy.model_config(learner_config.model)
@@ -245,14 +255,18 @@ class PPOLearner(SequenceActingMixin, Learner):
         return normalize(stats, obs.astype(jnp.float32))
 
     def _apply(self, params, obs):
-        """``(model output, router statistics)`` of one learn-side apply:
-        ``{"load": [layers, n_routed], "overflow": scalar}`` for
-        routed-expert blocks (models/latent_moe.py), else ``None``."""
-        if self.moe is None:
+        """``(model output, the trunk's statistics)`` of one learn-side
+        apply: ``{"load": [layers, n_routed], "overflow": scalar}`` for
+        routed-expert blocks (models/latent_moe.py), ``{name: scalar}`` for
+        a trunk that sows counters, else ``None``."""
+        if self.moe is not None:
+            collection, read = latent_moe.MOE_COLLECTION, latent_moe.moe_stats
+        elif self.counters is not None:
+            collection, read = COUNTERS_COLLECTION, read_counters
+        else:
             return self.model.apply(params, obs), None
-        collection = latent_moe.MOE_COLLECTION
         out, sown = self.model.apply(params, obs, mutable=[collection])
-        return out, latent_moe.moe_stats(sown[collection])
+        return out, read(sown[collection])
 
     # -- acting --------------------------------------------------------------
     def act(self, state: PPOState, obs: jax.Array, key: jax.Array, mode: str = TRAINING):
@@ -406,7 +420,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         chain divides the gradients back down and skips overflowed steps.
         """
         algo = self.config.algo
-        out, moe = self._apply(params, mb["obs"])
+        out, stats = self._apply(params, mb["obs"])
         if self.discrete:
             logp = D.categorical_logp(out.logits, mb["action"])
             kl = D.categorical_kl(mb["b_logits"], out.logits).mean()
@@ -446,9 +460,11 @@ class PPOLearner(SequenceActingMixin, Learner):
             "entropy": entropy,
             "kl": kl,
         }
-        if moe is not None:
-            aux["moe_load"] = jax.lax.stop_gradient(moe["load"])
-            aux["moe_overflow"] = jax.lax.stop_gradient(moe["overflow"])
+        if self.moe is not None:
+            aux["moe_load"] = jax.lax.stop_gradient(stats["load"])
+            aux["moe_overflow"] = jax.lax.stop_gradient(stats["overflow"])
+        elif self.counters is not None:
+            aux["counters"] = jax.lax.stop_gradient(stats)
         return total * loss_scale, aux
 
     @part("optimizer")
@@ -690,6 +706,12 @@ class PPOLearner(SequenceActingMixin, Learner):
                     [jnp.abs(b).max() for b in latent_moe.router_biases(params)]
                 ).max(),
             })
+        if self.counters is not None:
+            # each over every minibatch step, reduced as the trunk declares
+            metrics.update({
+                row: getattr(jnp, how)(auxs["counters"][name])
+                for name, (row, how) in self.counters.items()
+            })
         # precision: loss-scale telemetry (device scalars riding the
         # metrics cadence); empty dict when the policy carries no scale
         metrics.update(loss_scale_metrics(opt_state))
@@ -739,7 +761,7 @@ class PPOLearner(SequenceActingMixin, Learner):
         return self._finalize(
             state, obs_stats, sgd_out, values, value_targets, advantages,
             axis_name,
-            prepare_overflow=0.0 if moe is None else moe["overflow"],
+            prepare_overflow=0.0 if self.moe is None else moe["overflow"],
         )
 
     def _prepare_seq(self, state, batch, axis_name):

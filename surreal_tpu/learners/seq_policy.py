@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from surreal_tpu.learners.base import EVAL_DETERMINISTIC, TRAINING
+from surreal_tpu.models.attention import reset_recurrent
 from surreal_tpu.ops import distributions as D
 
 
@@ -99,7 +100,8 @@ class SequenceActingMixin(PolicyHeadMixin):
             }
         # the cache's form is the model's own (models/attention.py
         # acting_cache): full keys and values for the 'preln' blocks, the
-        # latent rows alone for 'mla_moe'
+        # latent rows alone for 'mla_moe', state + ring + shared cache
+        # for 'ssm_hybrid'
         return {
             "cache": self.model.init_cache(num_envs, T),
             "pos": jnp.zeros((), jnp.int32),
@@ -116,12 +118,17 @@ class SequenceActingMixin(PolicyHeadMixin):
             return super().act_step(state, act_carry, obs, key, mode)
         if "cache" in act_carry:
             # incremental decode: one position through the trunk against
-            # the K/V caches; positions > pos in the caches are masked,
-            # so the wrap reset only needs the index (stale K/V rows are
-            # overwritten as the new segment advances)
+            # the caches; positions > pos in a position-indexed cache are
+            # masked, so the wrap reset only needs the index (stale K/V
+            # rows are overwritten as the new segment advances). A
+            # recurrent state has no position: the horizon is the
+            # config's, not a leaf's shape, and the leaves a model marks
+            # recurrent are zeroed at the wrap (those alone: a `where`
+            # over the whole carry would re-write all of it every step)
             cache, pos = act_carry["cache"], act_carry["pos"]
-            T = jax.tree.leaves(cache)[0].shape[1]
-            pos = jnp.where(pos >= T, 0, pos)
+            wrap = pos >= int(self.config.algo.horizon)
+            pos = jnp.where(wrap, 0, pos)
+            cache = reset_recurrent(self.model.encoder_cfg, cache, wrap)
             out_t, cache = self.model.apply(
                 state.params,
                 self._norm_obs(state.obs_stats, obs),
@@ -157,37 +164,43 @@ class SequenceActingMixin(PolicyHeadMixin):
 
 # model.encoder keys only the 'preln' blocks read (their defaults are
 # session/default_configs.py's); 'mla_moe' reads models/latent_moe.py's
-# FAMILY_DEFAULTS, which default to None there. Both read kind, block,
-# num_layers, num_heads and act_impl.
+# FAMILY_DEFAULTS and 'ssm_hybrid' models/ssm_hybrid.py's, which default to
+# None there. All read kind, block, num_heads and act_impl; 'ssm_hybrid'
+# counts its layers in pairs_before / pairs_after, not num_layers.
 _PRELN_KEYS = ("features", "head_dim", "max_len")
 
 
 def family_config(enc_cfg: dict) -> dict:
-    """``model.encoder`` as its block family reads it: a key of the other
-    family that was set is an error, not ignored, and 'mla_moe' gets its
-    unset keys' published values."""
-    from surreal_tpu.models import latent_moe
+    """``model.encoder`` as its block family reads it: a key of another
+    family that was set is an error, not ignored, and 'mla_moe' and
+    'ssm_hybrid' get their unset keys' published values."""
+    from surreal_tpu.models import latent_moe, ssm_hybrid
     from surreal_tpu.models.attention import block_family
     from surreal_tpu.session.default_configs import BASE_LEARNER_CONFIG
 
-    if block_family(enc_cfg) == "mla_moe":
-        unset = BASE_LEARNER_CONFIG.model.encoder
-        stray = [k for k in _PRELN_KEYS if enc_cfg.get(k, unset[k]) != unset[k]]
+    family = block_family(enc_cfg)
+    families = {"mla_moe": latent_moe, "ssm_hybrid": ssm_hybrid}
+    own = set(families[family].FAMILY_DEFAULTS) if family in families else set()
+    stray = sorted(
+        k for mod in families.values() for k in mod.FAMILY_DEFAULTS
+        if k not in own and enc_cfg.get(k) is not None
+    )
+    if family == "preln":
         if stray:
             raise ValueError(
-                f"model.encoder.block='mla_moe' does not read {stray} "
-                "('preln' keys; its widths are hidden_size, q_lora_rank, ...)"
+                f"model.encoder.block='preln' does not read {stray}: set "
+                "model.encoder.block=mla_moe or ssm_hybrid, or leave them unset"
             )
-        return latent_moe.resolve(enc_cfg)
-    stray = [
-        k for k in latent_moe.FAMILY_DEFAULTS if enc_cfg.get(k) is not None
-    ]
+        return enc_cfg
+    unset = BASE_LEARNER_CONFIG.model.encoder
+    not_read = _PRELN_KEYS + (("num_layers",) if family == "ssm_hybrid" else ())
+    stray += [k for k in not_read if enc_cfg.get(k, unset[k]) != unset[k]]
     if stray:
         raise ValueError(
-            f"model.encoder.block='preln' does not read {stray}: set "
-            "model.encoder.block=mla_moe, or leave them unset"
+            f"model.encoder.block={family!r} does not read {stray} (keys of "
+            f"another family; its own are {sorted(own)})"
         )
-    return enc_cfg
+    return families[family].resolve(enc_cfg)
 
 
 def build_seq_model(
@@ -209,18 +222,20 @@ def build_seq_model(
     from surreal_tpu.models.attention import block_family
 
     enc_cfg = family_config(model_config.encoder.to_dict())
-    moe_block = block_family(enc_cfg) == "mla_moe"
+    family = block_family(enc_cfg)
+    wide = family != "preln"   # a family at a published model's widths
     max_len = int(enc_cfg.get("max_len", 4096))
-    # 'mla_moe' has no learned positions: its rotary part takes any index
-    if not moe_block and horizon is not None and int(horizon) + 1 > max_len:
+    # 'mla_moe' has no learned positions (its rotary part takes any index)
+    # and 'ssm_hybrid' no positional term at all
+    if not wide and horizon is not None and int(horizon) + 1 > max_len:
         raise ValueError(
             f"algo.horizon={int(horizon)} needs model.encoder.max_len >= "
             f"{int(horizon) + 1} (the sequence learn pass extends the "
             f"segment by one bootstrap position); got max_len={max_len}"
         )
-    if moe_block and (model_config.cnn.enabled or mesh is not None):
+    if wide and (model_config.cnn.enabled or mesh is not None):
         raise ValueError(
-            "model.encoder.block='mla_moe' runs flat vector obs on one "
+            f"model.encoder.block={family!r} runs flat vector obs on one "
             "chip: no CNN stem and no sp mesh path yet (ROADMAP: a trunk "
             "over pixels at these widths; an expert axis in parallel/mesh.py)"
         )

@@ -224,24 +224,34 @@ class TrajectoryEncoder(nn.Module):
         return (out, new_cache) if decode else out
 
 
+BLOCK_FAMILIES = ("preln", "mla_moe", "ssm_hybrid")
+
+
 def block_family(encoder_cfg) -> str:
     """``model.encoder.block``: 'preln' (this module's
     :class:`TrajectoryEncoder`, the default) | 'mla_moe'
-    (``models/latent_moe.py``)."""
+    (``models/latent_moe.py``) | 'ssm_hybrid' (``models/ssm_hybrid.py``)."""
     block = encoder_cfg.get("block", "preln") or "preln"
-    if block not in ("preln", "mla_moe"):
-        raise ValueError(f"model.encoder.block {block!r} not in preln|mla_moe")
+    if block not in BLOCK_FAMILIES:
+        raise ValueError(
+            f"model.encoder.block {block!r} not in {'|'.join(BLOCK_FAMILIES)}"
+        )
     return block
 
 
 def build_trunk(cfg, *, cnn_cfg, mesh, sp_axis, batch_axis, compute_dtype):
     """The trunk ``model.encoder.block`` selects, under the name both
-    heads give it. Both take ``[B, T, obs]``, or ``[B, obs]`` with
+    heads give it. Each takes ``[B, T, obs]``, or ``[B, obs]`` with
     ``cache`` and ``pos``."""
-    if block_family(cfg) == "mla_moe":
+    family = block_family(cfg)
+    if family == "mla_moe":
         from surreal_tpu.models.latent_moe import LatentMoETrunk
 
         return LatentMoETrunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
+    if family == "ssm_hybrid":
+        from surreal_tpu.models.ssm_hybrid import SSMHybridTrunk
+
+        return SSMHybridTrunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
     return TrajectoryEncoder(
         features=cfg["features"], num_layers=cfg["num_layers"],
         num_heads=cfg["num_heads"], head_dim=cfg["head_dim"],
@@ -253,20 +263,62 @@ def build_trunk(cfg, *, cnn_cfg, mesh, sp_axis, batch_axis, compute_dtype):
     )
 
 
-def acting_cache(cfg, num_envs: int, horizon: int, dtype) -> list:
-    """The cache of the incremental acting carry, one entry a layer, as
-    the trunk's decode path takes it: full keys and values for 'preln',
-    the latent rows alone for 'mla_moe'. In the compute dtype, the
-    attention math's own, so decode and the full-segment recompute round
-    alike (precision policy, ops/precision.py)."""
-    if block_family(cfg) == "mla_moe":
+def acting_cache(cfg, num_envs: int, horizon: int, dtype):
+    """The cache of the incremental acting carry, as the trunk's decode
+    path takes it: full keys and values a layer for 'preln', the latent
+    rows alone for 'mla_moe', and for 'ssm_hybrid' three kinds side by
+    side (a constant-size state, a ring that forgets, one shared cache).
+    In the compute dtype, the attention math's own, so decode and the
+    full-segment recompute round alike (precision policy,
+    ops/precision.py); a recurrent state is float32."""
+    family = block_family(cfg)
+    if family == "mla_moe":
         from surreal_tpu.models import latent_moe
 
         return latent_moe.acting_cache(cfg, num_envs, horizon, dtype)
+    if family == "ssm_hybrid":
+        from surreal_tpu.models import ssm_hybrid
+
+        return ssm_hybrid.acting_cache(cfg, num_envs, horizon, dtype)
     mk = lambda: jnp.zeros(
         (num_envs, horizon, int(cfg["num_heads"]), int(cfg["head_dim"])), dtype
     )
     return [{"k": mk(), "v": mk()} for _ in range(int(cfg["num_layers"]))]
+
+
+def reset_recurrent(cfg, cache, wrap):
+    """``cache`` as a new segment starts with it where ``wrap`` is set:
+    the leaves a family marks recurrent zeroed, the rest untouched (a
+    position-indexed cache needs nothing: its stale rows are masked). Only
+    'ssm_hybrid' holds such leaves; for the others this is the identity,
+    and traces to nothing."""
+    if block_family(cfg) == "ssm_hybrid":
+        from surreal_tpu.models import ssm_hybrid
+
+        return ssm_hybrid.reset_recurrent(cache, wrap)
+    return cache
+
+
+# the variable collection a trunk sows its counters into on a whole-segment
+# apply: scalars a learner carries to the metrics row without knowing the
+# family (learners/ppo.py)
+COUNTERS_COLLECTION = "counters"
+
+
+def trunk_counters(cfg) -> dict | None:
+    """``{sown name: (metrics row, 'max' | 'mean' over an iteration's
+    minibatch steps)}`` of the family's trunk, ``None`` where it sows
+    none."""
+    if block_family(cfg) == "ssm_hybrid":
+        from surreal_tpu.models import ssm_hybrid
+
+        return ssm_hybrid.COUNTERS
+    return None
+
+
+def read_counters(collection: dict) -> dict:
+    """``{name: scalar}`` of one whole-segment apply's sown counters."""
+    return {name: sown[-1] for name, sown in collection["trunk"].items()}
 
 
 def _obs_dtype(obs):
@@ -295,7 +347,7 @@ class TrajectoryPPOModel(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16  # precision policy's compute
                                              # dtype (learners/seq_policy)
 
-    def init_cache(self, num_envs: int, horizon: int) -> list:
+    def init_cache(self, num_envs: int, horizon: int):
         """The acting carry's cache, as this model's decode path takes it."""
         return acting_cache(
             self.encoder_cfg, num_envs, horizon, self.compute_dtype
@@ -347,7 +399,7 @@ class TrajectoryCategoricalPPOModel(nn.Module):
     compute_dtype: jnp.dtype = jnp.bfloat16  # precision policy's compute
                                              # dtype (learners/seq_policy)
 
-    def init_cache(self, num_envs: int, horizon: int) -> list:
+    def init_cache(self, num_envs: int, horizon: int):
         """The acting carry's cache, as this model's decode path takes it."""
         return acting_cache(
             self.encoder_cfg, num_envs, horizon, self.compute_dtype

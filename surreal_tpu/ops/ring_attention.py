@@ -73,6 +73,53 @@ def full_attention(q, k, v, causal: bool = False):
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(q.dtype)
 
 
+def blocked_attention(q, k, v, window: int | None = None, block: int = 256):
+    """Causal grouped-query attention a block of queries at a time, with
+    an optional sliding window: ``q [B, T, H, D]`` over ``k, v [B, T, G,
+    D]`` (``H`` a multiple of ``G``; query head ``h`` reads key-value head
+    ``h // (H / G)``) -> ``([B, T, H, D], keys a query saw on average)``.
+    A query at ``t`` sees keys ``max(0, t - window + 1) .. t``.
+
+    Queries ``[i, i + block)`` meet only the keys they can see, a static
+    slice, so a window costs its width and not the segment's, and the
+    ``[block, keys]`` float32 scores of one block are the largest thing
+    alive (``full_attention`` holds ``[T, T]`` a head: 1.3 GB at 8 x 40
+    heads x 1024 positions). Each block is recomputed in the backward
+    (``jax.checkpoint``), so nothing of the scores is kept. Scores and
+    softmax in float32; both products take their operands in the inputs'
+    dtype and accumulate in float32. Any ``T``: the last block is short."""
+    B, T, H, D = q.shape
+    G = k.shape[2]
+    q = q.reshape(B, T, G, H // G, D)
+    scale = 1.0 / jnp.sqrt(jnp.asarray(D, jnp.float32))
+
+    @jax.checkpoint
+    def attend(q_blk, k_blk, v_blk, mask):
+        scores = jnp.einsum(
+            "bqgrd,bkgd->bgrqk", q_blk, k_blk,
+            preferred_element_type=jnp.float32,
+        ) * scale
+        p = jax.nn.softmax(jnp.where(mask, scores, _NEG_BIG), axis=-1)
+        return jnp.einsum(
+            "bgrqk,bkgd->bqgrd", p.astype(v_blk.dtype), v_blk,
+            preferred_element_type=jnp.float32,
+        ).astype(q_blk.dtype)
+
+    outs, seen = [], 0.0
+    for lo in range(0, T, block):
+        hi = min(lo + block, T)
+        first = 0 if window is None else max(0, lo - window + 1)
+        qpos = jnp.arange(lo, hi)[:, None]
+        kpos = jnp.arange(first, hi)[None, :]
+        mask = kpos <= qpos
+        if window is not None:
+            mask &= kpos > qpos - window
+        outs.append(attend(q[:, lo:hi], k[:, first:hi], v[:, first:hi], mask))
+        seen = seen + mask.sum()
+    out = jnp.concatenate(outs, axis=1).reshape(B, T, H, D)
+    return out, seen.astype(jnp.float32) / T
+
+
 def decode_attention(q_t, k_cache, v_cache, pos):
     """Single-position causal attention against a K/V cache — the O(T)
     incremental acting step for trajectory policies, matching

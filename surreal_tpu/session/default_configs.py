@@ -79,7 +79,13 @@ BASE_LEARNER_CONFIG = Config(
             #   sigmoid-routed experts of which this chip holds a share.
             #   Reads the keys below that default to None (None = the
             #   published JoyAI-LLM-Flash value, FAMILY_DEFAULTS there).
-            # Both read kind, block, num_layers, num_heads, act_impl.
+            # 'ssm_hybrid' (models/ssm_hybrid.py): LayerNorm, state-space
+            #   layers with a constant-size acting state, window attention
+            #   with a ring that forgets, one full layer whose keys and
+            #   values cross-attention layers read, gated memory units;
+            #   Phi-4-mini-flash-reasoning's widths where a key is None.
+            # All read kind, block, num_heads, act_impl; the first two
+            # num_layers ('ssm_hybrid': pairs_before, pairs_after).
             block="preln",
             features=64,
             num_layers=2,
@@ -113,6 +119,18 @@ BASE_LEARNER_CONFIG = Config(
             first_held=None,
             num_held=None,
             bias_update_speed=None,        # router selection bias, a step
+            # -- 'ssm_hybrid' only (it reads hidden_size and
+            # intermediate_size above too; None = the published
+            # Phi-4-mini-flash-reasoning value, FAMILY_DEFAULTS in
+            # models/ssm_hybrid.py) ---------------------------------------
+            num_kv_heads=None,
+            sliding_window=None,
+            ssm_state_size=None,
+            ssm_dt_rank=None,              # None = ceil(hidden_size / 16)
+            # depth: [ssm, window] x pairs_before, ssm + full,
+            # [gmu, cross] x pairs_after
+            pairs_before=None,
+            pairs_after=None,
         ),
         cnn=Config(
             enabled=False,          # pixel observations -> Nature-CNN stem

@@ -310,7 +310,24 @@ class OpsAggregator:
         if body is not None:
             row["body"] = body
         with self._lock:
-            self._tiers[tier] = {"row": row, "t_recv": time.monotonic()}
+            self._tiers[tier] = {
+                "row": row, "t_recv": time.monotonic(), "local": True,
+            }
+
+    def excuse_pause(self, seconds: float) -> None:
+        """The learner thread was held for ``seconds`` by work of the
+        session's own (a profiler capture's fences and trace write: a
+        minute for three 4 s iterations of a thousand acting steps each).
+        The tiers that live on that thread could not have reported
+        meanwhile, so their rows are not that much older: without this the
+        next snapshot reads them DEAD, opens an incident and captures
+        again. Rows that came over the wire are judged as ever."""
+        if seconds <= 0.0:
+            return
+        with self._lock:
+            for rec in self._tiers.values():
+                if rec.get("local"):
+                    rec["t_recv"] += seconds
 
     # -- incidents -----------------------------------------------------------
     def record_fault(self, ev: dict) -> None:

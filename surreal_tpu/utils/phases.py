@@ -33,17 +33,24 @@ An op outside every scope is ``unattributed``.
 
 Phases say *when* in the iteration an op runs. A second, short
 vocabulary of **parts** says *which part of the model* it belongs to, for
-a trunk large enough that this is the question (``models/latent_moe.py``).
+a trunk large enough that this is the question (``models/latent_moe.py``,
+``models/ssm_hybrid.py``).
 A part's scope sits inside whatever phase runs the model, so an op has
 one phase and at most one part, and the digest sums each on its own:
 
-    attn         latent attention: both low-rank paths, rotary part,
-                 scores, softmax, output projection (expanded or absorbed)
+    attn         attention: projections (latent attention's two low-rank
+                 paths and rotary part; a hybrid trunk's window, full and
+                 cross layers), scores, softmax, output projection, in
+                 the learn pass and against the acting cache
     moe_route    router scores, biased top-k, weights, the sort by expert
     moe_experts  row gather, the held experts' grouped products, the
                  weighted combine, and the shared expert
     dense_ffn    a dense layer's SwiGLU
     optimizer    PPO: clip, Adam, apply, the router-bias rule
+    ssm_scan     a state-space layer's conv, ``softplus``, the selective
+                 scan (or an acting step of it), the skip and the gate
+    ssm_proj     a state-space layer's four products: in, x, dt, out
+    gmu          a gated memory unit: both products and the gate
 """
 
 from __future__ import annotations
@@ -53,7 +60,10 @@ PHASES = (
     "replay_insert", "replay_sample", "replay_priority", "update",
     "bootstrap", "vtrace", "learn",
 )
-PARTS = ("attn", "moe_route", "moe_experts", "dense_ffn", "optimizer")
+PARTS = (
+    "attn", "moe_route", "moe_experts", "dense_ffn", "optimizer",
+    "ssm_scan", "ssm_proj", "gmu",
+)
 UNATTRIBUTED = "unattributed"
 _VOCABULARY = frozenset(PHASES)
 _PARTS = frozenset(PARTS)

@@ -42,15 +42,7 @@ import pytest  # noqa: E402
 # Cases a file of the benchmark gets wrong, until a `benchmark` PR may edit
 # it (tests/benchmarks/ is in BENCHMARK.json's `paths`; no other kind of PR
 # touches what is there): node id -> why.
-KNOWN_WRONG = {
-    "tests/benchmarks/test_benchmark_manifest.py::"
-    "test_config_entry_and_file[ppo_lift_joyai]": (
-        "WIDTH_WORDS matches substrings, so 'hidden' refuses "
-        "num_hidden_layers in `reduced`: a depth, and the contract's own "
-        "example of a cut. The repair is to match whole keys (hidden_size); "
-        "test_benchmark_joyai_reference.py holds this file to the catalog row"
-    ),
-}
+KNOWN_WRONG: dict = {}
 
 
 def pytest_collection_modifyitems(config, items):

@@ -449,3 +449,31 @@ def _events(folder):
     return list(_iter_jsonl(
         os.path.join(folder, "telemetry", "events.jsonl")
     ))
+
+
+def test_a_pause_of_the_learner_threads_own_making_is_not_silence(tmp_path):
+    """A profiler capture's stop holds the learner thread for as long as
+    its trace takes to write (a minute at a thousand acting steps an
+    iteration): the tiers that live on that thread are excused that long,
+    rows that came over the wire are not (launch/hooks.py calls
+    ``excuse_pause`` around ``profile.tick``)."""
+    import time
+
+    from surreal_tpu.session.opsplane import OpsAggregator
+
+    agg = OpsAggregator(None)
+    try:
+        agg.push_local("engine", gauges={"engine/occupancy": 0.5}, cadence_s=0.01)
+        with agg._lock:
+            agg._tiers["remote"] = {
+                "row": {"tier": "remote", "cadence_s": 0.01, "gauges": {}},
+                "t_recv": time.monotonic(),
+            }
+        time.sleep(0.1)
+        assert agg.snapshot()["tiers"]["engine"]["dead"]
+        agg.excuse_pause(10.0)
+        tiers = agg.snapshot()["tiers"]
+        assert not tiers["engine"]["dead"] and tiers["remote"]["dead"]
+        agg.excuse_pause(0.0)
+    finally:
+        agg.close()

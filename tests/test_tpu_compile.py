@@ -323,3 +323,61 @@ def test_routed_layer_compiles_to_the_ragged_kernel(chip):
     text = compiled.as_text()
     assert text.count("custom-call") >= 9 and "agged" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def test_phi4flash_iteration_fits_the_chip_with_each_layer_recomputed(sds):
+    """The fused iteration of ``ppo_lift_phi4flash_16x1024`` (16 envs x
+    1024, 2 x 2 minibatches of 8192 tokens, the family's published widths,
+    one layer of each kind: 633M parameters, 10.1 GB of state) compiles
+    for the v5e inside its 16.9 GB: each layer is recomputed in the
+    backward (models/ssm_hybrid.py chooses that from the shapes), the scan
+    keeps chunk starts and not every state (ops/selective_scan.py:
+    ``[1024, 8, 16, 5120]`` float32 would be 2.7 GB a tensor), and the
+    acting scan carries three kinds of state side by side."""
+    import re
+
+    from surreal_tpu.launch.rollout import init_device_carry
+    from surreal_tpu.launch.trainer import Trainer
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import base_config
+
+    envs, horizon = 16, 1024
+    cfg = Config(
+        learner_config=Config(
+            algo=Config(
+                name="ppo", horizon=horizon, epochs=2, num_minibatches=2,
+                precision="mixed", clip_ratio=0.2,
+            ),
+            model=Config(encoder=Config(
+                kind="trajectory", block="ssm_hybrid", num_heads=40,
+                pairs_before=1, pairs_after=1,
+            )),
+            optimizer=Config(lr=3e-4),
+        ),
+        env_config=Config(name="jax:lift", num_envs=envs),
+        session_config=Config(folder="unused"),
+    ).extend(base_config())
+    trainer = Trainer(cfg)
+    like = lambda tree: jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    state = jax.eval_shape(trainer.learner.init, key)
+    carry = jax.eval_shape(lambda k: init_device_carry(trainer.env, k, envs), key)
+    compiled = (
+        jax.jit(trainer._device_train_iter, donate_argnums=(0, 1))
+        .lower(like(state), like(carry), like(key)).compile()
+    )
+    mem = compiled.memory_analysis()
+    held = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert held < 15.0e9, held          # 14.05 GB when this was written
+    text = compiled.as_text()
+    assert not re.search(r"f32\[(1024|1025|1056),\d+,16,5120\]", text)
+    # the acting loop's carry: the state, the ring, the shared cache
+    loops = [line.split(" while(")[0] for line in text.splitlines()
+             if " while(" in line and "f32[16,16,5120]" in line]
+    assert any(
+        "bf16[16,512,20,64]" in c and "bf16[16,1024,20,64]" in c
+        and "bf16[16,3,5120]" in c for c in loops
+    )
