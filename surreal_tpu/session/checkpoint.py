@@ -3,6 +3,20 @@
 ``restore_folder`` path through learner setup; SURVEY.md §2.1 Checkpoint
 row and §5.4), built on orbax.
 
+When orbax is loaded: at the first :class:`CheckpointManager`, not when
+this module is imported. ``import orbax.checkpoint`` pulls in its cloud
+logger (``google.cloud.logging``) and tensorstore: 26 of the 29.5 s that
+``import surreal_tpu.main.launch`` took on the chip host, 44-48 s inside
+a launch (PERF.md §6, PR 40), and
+``surreal_tpu.session`` re-exports this module, so every process that
+imports a config would pay it: env workers, fleet replicas, the gateway,
+``diag``. A session with ``checkpoint.every_n_iters=0`` and no
+``restore_from`` builds no manager and never imports it; one that does
+checkpoint pays the import once, at its first manager, and every manager
+hands its sink the seconds it took (0 when orbax was loaded already) as a
+``phases`` event with the one phase ``checkpoint-import``: a row of
+``surreal_tpu diag``'s phase table.
+
 What is checkpointed: the **learner state pytree** (params, optimizer
 state, obs-normalizer stats, adaptive scalars) plus run metadata
 (iteration, env_steps). Environment/rollout carries are NOT checkpointed —
@@ -22,10 +36,20 @@ from __future__ import annotations
 
 import json
 import os
+import sys
+import time
 from typing import Any
 
 import jax
-import orbax.checkpoint as ocp
+
+
+def _orbax():
+    """``orbax.checkpoint``: imported by the first call, found in
+    ``sys.modules`` by every later one (the module docstring says why it
+    is not imported at this module's top)."""
+    import orbax.checkpoint as ocp
+
+    return ocp
 
 
 class PrecisionMismatchError(ValueError):
@@ -86,6 +110,19 @@ class CheckpointManager:
         # session tracer's .event) — restore-fallback decisions must be
         # visible in `surreal_tpu diag`, not only in a log file
         self._on_event = on_event
+        loaded = "orbax.checkpoint" in sys.modules
+        t0 = time.perf_counter()
+        ocp = _orbax()
+        import_s = 0.0 if loaded else time.perf_counter() - t0
+        if on_event is not None:
+            # a row of diag's phase table: the seconds a quiet launch no
+            # longer spends show up here in a session that checkpoints
+            on_event(
+                "phases", step=-1,
+                phases={"checkpoint-import": {
+                    "count": 1, "total_s": import_s, "max_ms": import_s * 1e3,
+                }},
+            )
         self.directory = os.path.join(os.path.abspath(folder), "checkpoints")
         os.makedirs(self.directory, exist_ok=True)
         self.keep_best = keep_best
@@ -125,13 +162,14 @@ class CheckpointManager:
         )
         self._mp_options = mp_options
         self._keep_last = keep_last
-        self._extra_mgr: ocp.CheckpointManager | None = None
+        self._extra_mgr = None
 
-    def _extra(self) -> ocp.CheckpointManager:
+    def _extra(self):
         """Lazy manager for auxiliary step-aligned state (the replay
         buffer) — a SEPARATE tree under ``extra/`` so the main payload's
         shape stays stable across configs and old sessions restore fine."""
         if self._extra_mgr is None:
+            ocp = _orbax()
             root = os.path.join(self.directory, "extra")
             os.makedirs(root, exist_ok=True)
             self._extra_mgr = ocp.CheckpointManager(
@@ -159,7 +197,7 @@ class CheckpointManager:
             "state": state,
             "meta": {"iteration": step, "env_steps": env_steps},
         }
-        self._mgr.save(step, args=ocp.args.StandardSave(payload))
+        self._mgr.save(step, args=_orbax().args.StandardSave(payload))
         self._mgr.wait_until_finished()
 
         if not (self.keep_best and metrics):
@@ -183,7 +221,7 @@ class CheckpointManager:
     def save_extra(self, step: int, tree: Any) -> None:
         """Persist auxiliary state aligned to ``step`` (see ``_extra``)."""
         mgr = self._extra()
-        mgr.save(step, args=ocp.args.StandardSave(tree))
+        mgr.save(step, args=_orbax().args.StandardSave(tree))
         mgr.wait_until_finished()
 
     def restore_extra(self, template: Any, step: int):
@@ -192,6 +230,7 @@ class CheckpointManager:
         back to a fresh buffer, same as resuming an old session."""
         if step not in self._extra().all_steps():
             return None
+        ocp = _orbax()
         abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, template)
         return self._extra().restore(step, args=ocp.args.StandardRestore(abstract))
 
@@ -275,6 +314,7 @@ class CheckpointManager:
             "state": template_state,
             "meta": {"iteration": 0, "env_steps": 0},
         }
+        ocp = _orbax()
         abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, template)
         if step is not None:
             payload = self._mgr.restore(
@@ -318,7 +358,7 @@ class CheckpointManager:
             "state": template_state,
             "meta": {"iteration": 0, "env_steps": 0},
         }
-        abstract = jax.tree.map(ocp.utils.to_shape_dtype_struct, template)
+        abstract = jax.tree.map(_orbax().utils.to_shape_dtype_struct, template)
         payload = self._best_ckptr.restore(self._best_dir, abstract)
         return payload["state"], payload["meta"]
 

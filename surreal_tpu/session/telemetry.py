@@ -38,6 +38,10 @@ line, ``t`` = unix seconds):
     {"type": "session",   "t": ..., "name": "train", "pid": ...}
     {"type": "phases",    "t": ..., "step": ..., "phases":
         {"<phase>": {"count": N, "total_s": S, "max_ms": M}}}
+                    (a CheckpointManager writes one of its own at
+                     construction, step -1 and the one phase
+                     ``checkpoint-import``: the seconds its import of
+                     orbax took, 0 when it was loaded already)
     {"type": "span",      "t": ..., "name": "...", "dur_s": ...}
                     (low-frequency side-band spans via span(emit=True);
                      ISSUE 14 adds CAUSAL spans from Tracer.emit_span —
@@ -223,7 +227,9 @@ PROFILES_DIR = "profiles"  # <folder>/telemetry/profiles/<tag>/ captures
 # above and diag can never silently drift from what the code writes.
 EVENT_REGISTRY = {
     "session": "Tracer.__init__ (session/telemetry.py)",
-    "phases": "Tracer.flush_phases (session/telemetry.py)",
+    "phases": "Tracer.flush_phases (session/telemetry.py); a "
+              "CheckpointManager's import of orbax, the one phase "
+              "'checkpoint-import' with step -1 (session/checkpoint.py)",
     "span": "Tracer.span(emit=True) side-bands + Tracer.emit_span causal "
             "trace exemplars (session/telemetry.py)",
     "metrics": "Tracer.log_metrics (session/telemetry.py)",
