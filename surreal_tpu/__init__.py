@@ -23,4 +23,11 @@ Layer map (mirrors SURVEY.md §1, re-homed for TPU):
 - ``surreal_tpu.launch``     — experiment launcher / component dispatch (ref L7)
 """
 
+from time import perf_counter as _perf_counter
+
+# the launch record's first boundary (session/telemetry.py): process start
+# to this line is the span ``launch.process``, from here to the entry
+# point's first call ``launch.import``
+IMPORTED_AT = _perf_counter()
+
 __version__ = "0.1.0"

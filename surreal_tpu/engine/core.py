@@ -35,7 +35,12 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
-from surreal_tpu.session.telemetry import step_annotation, trace_annotation
+from surreal_tpu.session.telemetry import (
+    launch_begin,
+    launch_span,
+    step_annotation,
+    trace_annotation,
+)
 from surreal_tpu.utils import faults
 
 
@@ -131,6 +136,7 @@ class LoopEngine:
         self._kills = 0
         self._t0 = None
         self._warned_wedged = False
+        self._launching = True  # until the first step has been dispatched
 
     # -- observability --------------------------------------------------------
     def gauge_row(self) -> dict[str, float]:
@@ -311,8 +317,12 @@ class LoopEngine:
             if f is not None and self.apply_fault is not None:
                 self.apply_fault(ls, f)
         t_step = time.perf_counter()
-        with trace_annotation("engine.step"):
-            out = self.step(ls)
+        if self._launching:
+            self._launching = False
+            out = self._first_step(ls)
+        else:
+            with trace_annotation("engine.step"):
+                out = self.step(ls)
         self._step_ms.append((time.perf_counter() - t_step) * 1e3)
         if out.skip_boundary:
             ls.env_steps += out.steps
@@ -324,6 +334,18 @@ class LoopEngine:
         if not self.pipelined:
             return self._inline_boundary(ls, out)
         return self._pipelined_boundary(ls, out)
+
+    def _first_step(self, ls: LoopState) -> Outcome:
+        """The launch's first dispatch as a span of the launch record
+        (session/telemetry.py), here so that every driver has it: the
+        trace, the lowering, the cache look-up and the executable's load.
+        From its end to the end of the first metrics-sync, where
+        SessionHooks closes the record, is ``launch.first_cadence``."""
+        with launch_span("launch.first_dispatch"):
+            with trace_annotation("engine.step"):
+                out = self.step(ls)
+        launch_begin("launch.first_cadence")
+        return out
 
     def _inline_boundary(self, ls: LoopState, out: Outcome) -> bool:
         stop = False

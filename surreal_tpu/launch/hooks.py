@@ -18,6 +18,8 @@ story: a killed job relaunched with the same config continues its curve.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import os
 import time
 
@@ -31,10 +33,23 @@ from surreal_tpu.session.interrupt import InterruptSentinel
 from surreal_tpu.session.metrics import get_logger, make_metrics_writer
 from surreal_tpu.session.opsplane import OpsAggregator
 from surreal_tpu.session.profile import ProfileManager
-from surreal_tpu.session.telemetry import Tracer
+from surreal_tpu.session.telemetry import Tracer, launch_close, launch_span
 from surreal_tpu.session.tracker import PeriodicTracker
 from surreal_tpu.utils import faults
 from surreal_tpu.utils.compat import compile_cache_counts, enable_compile_cache
+
+
+def _launch_session(method):
+    """The call is a ``launch.session`` span of the launch record
+    (session/telemetry.py) while the session's launch is open: here, so
+    that every driver has it."""
+
+    @functools.wraps(method)
+    def spanned(self, *args, **kwargs):
+        with self._launch_span("launch.session"):
+            return method(self, *args, **kwargs)
+
+    return spanned
 
 
 class SessionHooks:
@@ -60,6 +75,19 @@ class SessionHooks:
     forwards fired metrics to the caller's ``on_metrics``.
     """
 
+    # until the first metrics-sync ends (or close(), for a run that ended
+    # before): the launch record's spans are open to this session, and
+    # closing it writes the session's one ``launch`` event
+    _launching = True
+
+    def _launch_span(self, name: str):
+        return launch_span(name) if self._launching else contextlib.nullcontext()
+
+    def _end_launch(self, closed: bool) -> None:
+        self._launching = False
+        launch_close(self.tracer, closed)
+
+    @_launch_session
     def __init__(self, config, learner, name: str = "train"):
         self.config = config
         cfg = config.session_config
@@ -116,6 +144,7 @@ class SessionHooks:
         # persistent XLA compile cache (utils/compat.py decides where):
         # on before the driver's first hot program compiles
         self.compile_cache_dir = enable_compile_cache()
+        self._cache_counts: dict | None = None  # the last ones written
         if self.compile_cache_dir is not None:
             self.log.info(
                 "persistent compile cache at %s", self.compile_cache_dir
@@ -376,11 +405,15 @@ class SessionHooks:
         no dedicated phase (the SEED act closure) pass None and are
         recorded for diag without contributing to the live gauges.
         Host-side work only (lower + HLO cost pass): safe before the
-        first dispatch and on donated-arg programs."""
-        self.costs.record_program(
-            name, jitted, *args,
-            phase=phase, calls_per_phase=calls_per_phase, **kwargs,
-        )
+        first dispatch and on donated-arg programs. While the launch is
+        open it is the span ``launch.cost_record``, whose counters say
+        what this lowering and its cache read took beside the first
+        dispatch's own."""
+        with self._launch_span("launch.cost_record"):
+            self.costs.record_program(
+                name, jitted, *args,
+                phase=phase, calls_per_phase=calls_per_phase, **kwargs,
+            )
 
     def tune_event(self, **info) -> None:
         """Record the autotuner's build-time decision (mode, cache
@@ -411,6 +444,7 @@ class SessionHooks:
         self.tracer.log_metrics(env_steps, m)
 
     # -- restore -------------------------------------------------------------
+    @_launch_session
     def restore(self, init_state):
         """-> (state, start_iteration, start_env_steps).
 
@@ -477,6 +511,7 @@ class SessionHooks:
             )
 
     # -- per-iteration -------------------------------------------------------
+    @_launch_session
     def begin_run(self, iteration: int, env_steps: int) -> None:
         """Start the wall-clock + cadence counters from the (possibly
         resumed) position."""
@@ -547,6 +582,9 @@ class SessionHooks:
             with self.tracer.span("metrics-sync"):
                 raw = metrics() if callable(metrics) else (metrics or {})
                 m = {k: float(v) for k, v in raw.items()}
+            if self._launching:
+                # the launch ends with its first fenced point
+                self._end_launch(closed=True)
             refuse_dropped_assignments(m)
             # fence to fence: the only span whose total is device time
             # (perf/* divide by it; a rollback's backward step is skipped)
@@ -776,15 +814,18 @@ class SessionHooks:
 
     def _emit_cache_event(self) -> None:
         """Mirror the compile-cache hit/miss counters into the telemetry
-        log (one 'compile_cache' event per metrics cadence + one at close;
-        `surreal_tpu diag` reports the last one). Host-side ints only —
-        no device sync rides on this."""
+        log when they changed: at the first metrics cadence (or at close,
+        for a run shorter than one), and after that only for a recompile
+        in the steady loop (`surreal_tpu diag` reports the last one).
+        Host-side ints only — no device sync rides on this."""
         if self.compile_cache_dir is None:
             return
-        self.tracer.event(
-            "compile_cache", dir=self.compile_cache_dir,
-            **compile_cache_counts(),
-        )
+        counts = compile_cache_counts()
+        if counts != self._cache_counts:
+            self._cache_counts = counts
+            self.tracer.event(
+                "compile_cache", dir=self.compile_cache_dir, **counts
+            )
 
     def close(self) -> None:
         self.interrupt.close()  # restore the process's previous handlers
@@ -828,6 +869,8 @@ class SessionHooks:
             self.ckpt.close()
         self.writer.close()
         self._emit_cache_event()  # final counts for runs shorter than a cadence
+        if self._launching:  # ... whose launch never reached a fenced point
+            self._end_launch(closed=False)
         self.tracer.close()
         # detach + close this session's file log handler: without this the
         # fd into <folder>/logs/ outlives the session for the rest of the

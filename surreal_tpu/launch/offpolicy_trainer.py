@@ -522,13 +522,17 @@ class OffPolicyTrainer:
         max_env_steps: int | None = None,
         on_metrics: Callable[[int, dict], None] | None = None,
     ):
+        # imported here and not at the top: see launch/trainer.py's run
+        from surreal_tpu.session.telemetry import launch_span
+
         cfg = self.config.session_config
         total = max_env_steps or cfg.total_env_steps
         steps_per_iter = self.horizon * self.num_envs
 
-        key = jax.random.key(self.seed)
-        key, init_key, env_key = jax.random.split(key, 3)
-        state = self.learner.init(init_key)
+        with launch_span("launch.state_init"):
+            key = jax.random.key(self.seed)
+            key, init_key, env_key = jax.random.split(key, 3)
+            state = self.learner.init(init_key)
         # chaos harness: install (or RESET) the fault registry for this run
         faults.configure_from(self.config.session_config)
         # divergence-rollback fallback when no finite checkpoint exists yet
@@ -552,7 +556,8 @@ class OffPolicyTrainer:
                 from surreal_tpu.parallel.mesh import replicate_state
 
                 state = replicate_state(self.mesh, state)
-            carry, replay_state = self.init_loop_state(env_key)
+            with launch_span("launch.carry_init"):
+                carry, replay_state = self.init_loop_state(env_key)
             if (
                 cfg.checkpoint.get("include_replay", False)
                 and hooks.ckpt is not None

@@ -247,13 +247,19 @@ class Trainer:
         fires every metrics.every_n_iters with host-side floats; returning
         truthy from it stops training (used by reward-target runs).
         """
+        # imported here and not at the top: the compile cache's key holds
+        # each traced op's source line, and every traced body of this file
+        # lies above (PERF.md section 6, PR 23-25)
+        from surreal_tpu.session.telemetry import launch_span
+
         cfg = self.config.session_config
         total = max_env_steps or cfg.total_env_steps
         steps_per_iter = self.horizon * self.num_envs
 
-        key = jax.random.key(self.seed)
-        key, init_key, env_key = jax.random.split(key, 3)
-        state = self.learner.init(init_key)
+        with launch_span("launch.state_init"):
+            key = jax.random.key(self.seed)
+            key, init_key, env_key = jax.random.split(key, 3)
+            state = self.learner.init(init_key)
         # chaos harness: install (or RESET) the fault registry for this run
         faults.configure_from(self.config.session_config)
         # divergence-rollback fallback when no finite checkpoint exists yet:
@@ -275,7 +281,8 @@ class Trainer:
                 hooks.tune_event(**self.tune_decision.telemetry())
 
             if self.device_mode:
-                carry = self.init_loop_state(env_key)
+                with launch_span("launch.carry_init"):
+                    carry = self.init_loop_state(env_key)
                 # cost/MFU accounting: register the fused program's XLA
                 # cost model once, before the first dispatch (host-side
                 # lower + HLO cost pass — no compile, no transfers; the

@@ -46,12 +46,16 @@ import time
 
 from surreal_tpu.session.config import Config
 from surreal_tpu.session.default_configs import base_config
+from surreal_tpu.session.telemetry import launch_imported, launch_span
 
 ALGOS = ("ppo", "ddpg", "impala")
 
 
 def build_config(args) -> Config:
-    """CLI args -> fully-extended three-tree config bundle."""
+    """CLI args -> fully-extended three-tree config bundle. An entry
+    point's first call: the launch record's ``launch.import`` ends here
+    (session/telemetry.py)."""
+    launch_imported()
     overrides = Config(
         learner_config=Config(algo=Config(name=args.algo)),
         env_config=Config(name=args.env, num_envs=args.num_envs),
@@ -68,6 +72,7 @@ def build_config(args) -> Config:
     return overrides.extend(base_config())
 
 
+@launch_span("launch.backend")
 def _apply_backend(backend: str) -> None:
     """``session_config.backend``, before first jax use: 'cpu' selects the
     host CPU for this process; 'tpu' (default) selects nothing here and
@@ -95,6 +100,7 @@ def _cpu_selected() -> bool:
     return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
 
 
+@launch_span("launch.backend")
 def _require_platform(backend: str) -> None:
     """A chip that fails to initialise must not become a CPU run that
     exits 0: with ``backend='tpu'`` and no explicit CPU selection
@@ -134,6 +140,7 @@ def _validate_seed_topology(config) -> int:
     return workers
 
 
+@launch_span("launch.build")
 def select_trainer(config):
     """Map config -> driver (the component-dispatch role of the reference's
     launcher, collapsed to one decision):
@@ -407,20 +414,23 @@ def run_train(args) -> int:
             f.write(config.dumps())
         os.replace(cfg_path + ".tmp", cfg_path)
     if multihost:
-        if config.session_config.topology.num_env_workers > 0:
-            from surreal_tpu.launch.multihost_trainer import MultiHostSEEDTrainer
+        with launch_span("launch.build"):
+            if config.session_config.topology.num_env_workers > 0:
+                from surreal_tpu.launch.multihost_trainer import (
+                    MultiHostSEEDTrainer,
+                )
 
-            trainer = MultiHostSEEDTrainer(config)
-        elif config.learner_config.algo.name == "ddpg":
-            from surreal_tpu.launch.multihost_trainer import (
-                MultiHostOffPolicyTrainer,
-            )
+                trainer = MultiHostSEEDTrainer(config)
+            elif config.learner_config.algo.name == "ddpg":
+                from surreal_tpu.launch.multihost_trainer import (
+                    MultiHostOffPolicyTrainer,
+                )
 
-            trainer = MultiHostOffPolicyTrainer(config)
-        else:
-            from surreal_tpu.launch.multihost_trainer import MultiHostTrainer
+                trainer = MultiHostOffPolicyTrainer(config)
+            else:
+                from surreal_tpu.launch.multihost_trainer import MultiHostTrainer
 
-            trainer = MultiHostTrainer(config)
+                trainer = MultiHostTrainer(config)
     else:
         trainer = select_trainer(config)
     state, metrics = trainer.run()

@@ -56,7 +56,33 @@ line, ``t`` = unix seconds):
                      resolved, not the one the config asked for)
     {"type": "compile_cache", "t": ..., "dir": "...", "hits": H,
      "misses": M}   (cumulative; written by SessionHooks while the
-                     persistent compile cache is on, utils/compat.py)
+                     persistent compile cache is on, utils/compat.py:
+                     at the first metrics cadence, then only when a
+                     count changed. After the launch a changed count is
+                     a recompile in the steady loop)
+    {"type": "launch", "t": ..., "origin": "os|import|session",
+     "t0_unix": ..., "total_s": ..., "unattributed_s": ..., "closed":
+     true, "spans": [{"name": "launch.<span>", "parent": "launch|<the
+     enclosing span>", "start_s": ..., "end_s": ..., ["trace_s": ...,
+     "lower_s": ..., "compile_s": ..., "cache_read_s": ..., "cache_hits":
+     H, "cache_misses": M]}, ...], ["outside": {...the same six}]}
+                    (one a session, written when its launch closes: the
+                     end of the first ``metrics-sync``, or
+                     SessionHooks.close with ``closed: false`` for a run
+                     that ended before. The launch record below: every
+                     time in seconds since the origin, which is the
+                     process's start as the OS has it (``os``), the
+                     package's import where the OS gives none
+                     (``import``), or the first span of a later session
+                     of one process (``session``). ``unattributed_s`` is
+                     ``total_s`` less the union of the spans whose parent
+                     is ``launch``. The compiler's seconds are JAX's own
+                     duration events, added to the innermost span open
+                     when each fired (``outside`` where none was):
+                     ``compile_s`` is the backend's compile or, on a
+                     cache hit, the read that ``cache_read_s`` counts
+                     alone; a function traced inside another's trace
+                     counts once)
     {"type": "data_plane", "t": ..., "transport": "...", "pipeline": ...,
      "shm_workers": N, "pickle_workers": M, "wire_bytes_per_step": B,
      ...}           (SEED drivers via SessionHooks.data_plane_event; the
@@ -234,7 +260,11 @@ EVENT_REGISTRY = {
             "trace exemplars (session/telemetry.py)",
     "metrics": "Tracer.log_metrics (session/telemetry.py)",
     "heartbeat": "HeartbeatWriter (session/telemetry.py, own file)",
-    "compile_cache": "SessionHooks compile-cache counters (launch/hooks.py)",
+    "compile_cache": "SessionHooks compile-cache counters, when they "
+                     "changed (launch/hooks.py)",
+    "launch": "the launch record's spans, process start to the first "
+              "metrics-sync's end (LaunchRecord.close, "
+              "session/telemetry.py; SessionHooks closes it)",
     "device": "the platform/kind/count JAX resolved for the run "
               "(SessionHooks.begin_run)",
     "data_plane": "SEED drivers via SessionHooks.data_plane_event",
@@ -382,6 +412,254 @@ class LineageReducer:
             "lineage/versions_per_batch": float(len(stal)),
         }
         return dict(self.last)
+
+
+# -- the launch record --------------------------------------------------------
+#
+# A launch is over before most of a session exists: imports, the TPU
+# client's start and the trainer's build happen before there is a folder
+# to write to. So its spans are kept process-wide, in memory, from the
+# first launch_span on, and the session's Tracer writes them as ONE
+# ``launch`` event when the first ``metrics-sync`` ends. The ten spans
+# whose parent is ``launch``, each opened inside the function it times:
+#
+#   launch.process         process start -> surreal_tpu/__init__.py
+#   launch.import          -> main/launch.py::build_config (launch_imported)
+#   launch.backend         main/launch.py::_apply_backend, _require_platform
+#   launch.build           main/launch.py::select_trainer (and run_train's
+#                          multi-host constructors)
+#   launch.state_init      learner.init in Trainer.run / OffPolicyTrainer.run
+#   launch.session         SessionHooks.__init__, restore, begin_run
+#   launch.carry_init      init_loop_state in the two fused drivers
+#   launch.cost_record     SessionHooks.record_program_costs
+#   launch.first_dispatch  the engine's first step (engine/core.py)
+#   launch.first_cadence   -> the first metrics-sync's end (end_iteration)
+#
+# Spans of one name add up (a caller that makes the calls one by one
+# records what run_train records); a driver without some of them reports
+# that time as unattributed.
+
+LAUNCH_SPANS = (
+    "launch.process", "launch.import", "launch.backend", "launch.build",
+    "launch.state_init", "launch.session", "launch.carry_init",
+    "launch.cost_record", "launch.first_dispatch", "launch.first_cadence",
+)
+# what utils/compat.py's jax.monitoring listeners add to a span
+LAUNCH_COUNTERS = (
+    "trace_s", "lower_s", "compile_s", "cache_read_s",
+    "cache_hits", "cache_misses",
+)
+
+
+def _process_age_s() -> float | None:
+    """Seconds since the OS started this process: field 22 of
+    ``/proc/self/stat`` (clock ticks after boot) against the boot clock.
+    None where the OS gives none."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name may hold spaces: fields count from its ')'
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        return (
+            time.clock_gettime(time.CLOCK_BOOTTIME)
+            - start_ticks / os.sysconf("SC_CLK_TCK")
+        )
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+class LaunchRecord:
+    """One launch's spans, in memory until :meth:`close` hands them to a
+    Tracer. Times are seconds since ``t0`` (a ``perf_counter`` reading).
+    Thread-safe: with ``engine.pipeline_sidebands`` on, the staging
+    thread closes the record while the loop's thread may be compiling."""
+
+    def __init__(self, origin: str, t0: float):
+        self.origin = origin
+        self.t0 = t0
+        self.t0_unix = time.time() - (time.perf_counter() - t0)
+        self.spans: list[dict] = []   # in start order
+        self.outside: dict = {}       # counters that fired between spans
+        self.closed = False
+        self._open: list[dict] = []   # innermost last
+        # (a span's id, counter) -> its (start, end) that no later one held
+        self._intervals: dict[tuple, list] = {}
+        self._lock = threading.Lock()
+
+    def _past(self, name: str, start: float, end: float) -> None:
+        """A span that was over before the record existed."""
+        self.spans.append({
+            "name": name, "parent": "launch",
+            "start_s": start - self.t0, "end_s": end - self.t0,
+        })
+
+    def begin(self, name: str) -> dict:
+        """Open a span and leave it open: :meth:`end` or :meth:`close`
+        ends it (``launch.first_cadence`` begins in the engine and ends
+        in SessionHooks, on another thread when the boundary is
+        deferred, so it is no annotation)."""
+        with self._lock:
+            span = {
+                "name": name,
+                "parent": self._open[-1]["name"] if self._open else "launch",
+                "start_s": time.perf_counter() - self.t0, "end_s": None,
+            }
+            self.spans.append(span)
+            self._open.append(span)
+        return span
+
+    def end(self, span: dict) -> None:
+        with self._lock:
+            if span["end_s"] is None:  # close() may have ended it
+                span["end_s"] = time.perf_counter() - self.t0
+            self._open = [s for s in self._open if s is not span]
+
+    @contextmanager
+    def span(self, name: str):
+        span = self.begin(name)
+        try:
+            with trace_annotation(name):
+                yield
+        finally:
+            self.end(span)
+
+    def _into(self) -> dict | None:
+        """Where a counter goes now (the lock is held): the innermost open
+        span, ``outside`` between spans, nowhere once closed."""
+        if self.closed:
+            return None
+        return self._open[-1] if self._open else self.outside
+
+    def add(self, counter: str, amount: float) -> None:
+        """Add to the innermost open span's ``counter``."""
+        with self._lock:
+            into = self._into()
+            if into is not None:
+                into[counter] = into.get(counter, 0) + amount
+
+    def add_interval(self, counter: str, start: float, end: float) -> None:
+        """Add ``end - start`` seconds to the innermost open span's
+        ``counter``, less what intervals inside it already added: JAX
+        times a function traced inside another's trace twice, the inner
+        one first. ``start`` and ``end`` are on any one clock."""
+        with self._lock:
+            into = self._into()
+            if into is None:
+                return
+            kept = self._intervals.setdefault((id(into), counter), [])
+            seconds = end - start
+            # they end in order, so what this one holds is the list's tail
+            while kept and start <= kept[-1][0] and kept[-1][1] <= end:
+                inner = kept.pop()
+                seconds -= inner[1] - inner[0]
+            kept.append((start, end))
+            into[counter] = into.get(counter, 0) + seconds
+
+    def close(self, tracer: "Tracer", closed: bool = True) -> None:
+        """End what is still open and write the ``launch`` event through
+        ``tracer`` (nothing, where it is disabled). ``closed=False``: the
+        run ended before its first metrics-sync."""
+        with self._lock:
+            if self.closed:
+                return
+            self.closed = True
+            total = time.perf_counter() - self.t0
+            for span in self._open:
+                span["end_s"] = total
+            self._open.clear()
+            self._intervals.clear()
+        covered, edge = 0.0, 0.0
+        for span in self.spans:  # in start order; top-level ones may touch
+            if span["parent"] == "launch":
+                covered += max(span["end_s"], edge) - max(span["start_s"], edge)
+                edge = max(edge, span["end_s"])
+        fields = {"outside": self.outside} if self.outside else {}
+        tracer.event(
+            "launch", origin=self.origin, t0_unix=self.t0_unix,
+            total_s=total, unattributed_s=total - covered, closed=closed,
+            spans=self.spans, **fields,
+        )
+
+
+_LAUNCH: LaunchRecord | None = None   # the process's open (or last) record
+_LAUNCH_LOCK = threading.Lock()
+
+
+def _process_launch() -> LaunchRecord:
+    """The process's first record: its origin is the process's start as
+    the OS records it, so the interpreter's start and whatever the caller
+    imported before the package are inside ``launch.process``."""
+    from surreal_tpu import IMPORTED_AT
+
+    now = time.perf_counter()
+    age = _process_age_s()
+    if age is not None and age >= now - IMPORTED_AT:
+        rec = LaunchRecord("os", now - age)
+        rec._past("launch.process", rec.t0, IMPORTED_AT)
+    else:
+        rec = LaunchRecord("import", IMPORTED_AT)
+    rec._past("launch.import", IMPORTED_AT, now)
+    return rec
+
+
+def launch_record() -> LaunchRecord:
+    """The open launch record. Where there is none: the process's own the
+    first time, and from then on (a second session of one process) one
+    whose origin is this call, the start of its first span."""
+    global _LAUNCH
+    with _LAUNCH_LOCK:
+        if _LAUNCH is None:
+            _LAUNCH = _process_launch()
+        elif _LAUNCH.closed:
+            _LAUNCH = LaunchRecord("session", time.perf_counter())
+        return _LAUNCH
+
+
+def launch_imported() -> None:
+    """The entry point's first call: ``launch.import`` ends here (or at
+    the first launch span, where a caller builds no config through
+    ``main/launch.py``). Nothing after the process's first record."""
+    if _LAUNCH is None:
+        launch_record()
+
+
+@contextmanager
+def launch_span(name: str):
+    """A span of the open launch record (one is opened where none is)
+    and, like every ``Tracer.span``, a ``trace_annotation`` of the same
+    name. Usable before a Tracer exists, and as a decorator."""
+    with launch_record().span(name):
+        yield
+
+
+def launch_begin(name: str) -> None:
+    """Open a span that the record's close ends (see
+    :meth:`LaunchRecord.begin`)."""
+    launch_record().begin(name)
+
+
+def launch_add(counter: str, amount: float) -> None:
+    """Add to the innermost open launch span's ``counter`` (one of
+    ``LAUNCH_COUNTERS``); nothing once the launch has closed."""
+    rec = _LAUNCH
+    if rec is not None and not rec.closed:
+        rec.add(counter, amount)
+
+
+def launch_add_interval(counter: str, start: float, end: float) -> None:
+    """:func:`launch_add` for a timed interval that may lie inside
+    another of the same counter (see
+    :meth:`LaunchRecord.add_interval`)."""
+    rec = _LAUNCH
+    if rec is not None and not rec.closed:
+        rec.add_interval(counter, start, end)
+
+
+def launch_close(tracer: "Tracer", closed: bool = True) -> None:
+    """Close the open launch record into ``tracer``'s log, if one is
+    open."""
+    rec = _LAUNCH
+    if rec is not None:
+        rec.close(tracer, closed)
 
 
 class Tracer:
@@ -738,6 +1016,7 @@ def diag_summary(folder: str) -> dict | None:
     phases: dict[str, dict] = {}
     health: dict[str, dict] = {}
     compile_cache = None
+    launch = None
     data_plane = None
     experience = None
     serving = None
@@ -783,6 +1062,12 @@ def diag_summary(folder: str) -> dict | None:
                 "dir": ev.get("dir"),
                 "hits": int(ev.get("hits", 0)),
                 "misses": int(ev.get("misses", 0)),
+            }
+        elif ev.get("type") == "launch":
+            # one a session; a folder relaunched into holds one a launch,
+            # and the newest is the one an operator asks about
+            launch = {
+                k: v for k, v in ev.items() if k not in ("type", "t", "trace", "seq")
             }
         elif ev.get("type") == "data_plane":
             # the last event is the settled negotiation (SEED drivers emit
@@ -924,6 +1209,7 @@ def diag_summary(folder: str) -> dict | None:
         "phases": phases,
         "health": health,
         "compile_cache": compile_cache,
+        "launch": launch,
         "data_plane": data_plane,
         "experience": experience,
         "serving": serving,
@@ -985,6 +1271,9 @@ def diag_report(folder: str) -> str | None:
         )
     else:
         lines.append("  (no phase windows recorded)")
+    launch_lines = _launch_lines(s)
+    if launch_lines:
+        lines += [""] + launch_lines
     cc = s.get("compile_cache")
     if cc is not None:
         total = cc["hits"] + cc["misses"]
@@ -1132,6 +1421,56 @@ def diag_report(folder: str) -> str | None:
         lines += ["", "Incidents (surreal_tpu why for the full report)"]
         lines += inc_lines
     return "\n".join(lines)
+
+
+def _launch_lines(s: dict) -> list[str]:
+    """The diag 'Launch' section, from the session's ``launch`` event: one
+    row a span name in order of first start (spans of one name add up; a
+    nested span is indented under its parent's share), its seconds, its
+    share of the launch, and the compiler's seconds inside it where there
+    were any; then the unattributed rest and the cache's hits and misses.
+    Empty list when the session wrote no such event."""
+    launch = s.get("launch")
+    if not launch:
+        return []
+    total = float(launch.get("total_s", 0.0))
+    origin = {
+        "os": "process start", "import": "the package's import",
+        "session": "the session's first span",
+    }.get(launch.get("origin"), str(launch.get("origin")))
+    lines = [
+        f"Launch — {total:.2f} s from {origin} to "
+        + ("the first metrics-sync's end" if launch.get("closed", True)
+           else "the run's end (no metrics-sync was reached)"),
+        f"  {'span':<26} {'seconds':>9} {'share':>7}  "
+        "trace / lower / compile / cache read s",
+    ]
+    rows: dict[tuple, dict] = {}
+    for span in launch.get("spans") or []:
+        nested = span.get("parent", "launch") != "launch"
+        row = rows.setdefault((span.get("name", "?"), nested), {"s": 0.0})
+        row["s"] += float(span.get("end_s", 0.0)) - float(span.get("start_s", 0.0))
+        for k in LAUNCH_COUNTERS:
+            row[k] = row.get(k, 0) + span.get(k, 0)
+    outside = launch.get("outside") or {}
+    rows[("unattributed", False)] = {
+        "s": float(launch.get("unattributed_s", 0.0)), **outside,
+    }
+    hits = misses = 0
+    for (name, nested), row in rows.items():
+        hits += row.get("cache_hits", 0)
+        misses += row.get("cache_misses", 0)
+        seconds = [row.get(k, 0.0) for k in LAUNCH_COUNTERS[:4]]
+        lines.append(
+            f"  {('  ' if nested else '') + name:<26} {row['s']:>9.2f} "
+            f"{100.0 * row['s'] / total if total > 0 else 0.0:>6.1f}%"
+            + (
+                "  " + " / ".join(f"{x:.2f}" for x in seconds)
+                if any(seconds) else ""
+            )
+        )
+    lines.append(f"  compile cache in the launch: {hits} hits / {misses} misses")
+    return lines
 
 
 def _engine_lines(s: dict) -> list[str]:

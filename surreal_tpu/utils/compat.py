@@ -12,6 +12,8 @@ import re
 
 from jax import shard_map  # noqa: F401 — call sites import it from here
 
+from surreal_tpu.session.telemetry import launch_add, launch_add_interval
+
 
 def axis_size(axis_name) -> int:
     """Static size of a named mapped axis."""
@@ -40,14 +42,44 @@ _CHECKOUT = os.path.dirname(
 _CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
 
 _CACHE_COUNTS = {"hits": 0, "misses": 0}
-_CACHE_LISTENER_INSTALLED = False
+_LISTENERS_INSTALLED = False
+
+
+# JAX's own timing events (jax 0.9.0: _src/dispatch.py:60-62,
+# _src/compiler.py:452) -> the counter of the innermost open launch span
+# each adds to (session/telemetry.py): which span traced, lowered,
+# compiled and read the cache for how long. The three of dispatch.py come
+# with their start and end, which is what tells a function traced inside
+# another's trace from one traced after it; the cache's read comes as a
+# duration alone. The backend's event spans compile_or_get_cached, so on
+# a hit it is the read over again.
+_LAUNCH_INTERVALS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 def _count_cache_event(event: str, **_kwargs) -> None:
     if event == "/jax/compilation_cache/cache_hits":
         _CACHE_COUNTS["hits"] += 1
+        launch_add("cache_hits", 1)
     elif event == "/jax/compilation_cache/cache_misses":
         _CACHE_COUNTS["misses"] += 1
+        launch_add("cache_misses", 1)
+
+
+def _count_compile_interval(event: str, start: float, end: float,
+                            **_kwargs) -> None:
+    counter = _LAUNCH_INTERVALS.get(event)
+    if counter is not None:
+        launch_add_interval(counter, float(start), float(end))
+
+
+def _count_cache_read(event: str, duration: float, **_kwargs) -> None:
+    if event == _CACHE_READ_EVENT:
+        launch_add("cache_read_s", float(duration))
 
 
 def compile_cache_active() -> bool:
@@ -76,15 +108,24 @@ def enable_compile_cache() -> str | None:
     at the fixed ``.jax_cache`` of this checkout. Every program is
     eligible (an RL session compiles a handful of large programs, and the
     small ones are what a warm start otherwise waits for one by one).
-    Returns None, touching nothing, where JAX's own switch
+    Returns None, touching no setting, where JAX's own switch
     (``jax_enable_compilation_cache`` / ``JAX_ENABLE_COMPILATION_CACHE``)
     has the cache off — tests/conftest.py does that for the CPU suite.
     Every entry point (CLI, SessionHooks, chip_smoke.py) calls
     this; may be called any number of times, before or after the
     process's first compile."""
-    global _CACHE_LISTENER_INSTALLED
+    global _LISTENERS_INSTALLED
     import jax
 
+    if not _LISTENERS_INSTALLED:
+        # with the cache off too: a launch's trace, lowering and compile
+        # seconds are counted either way
+        jax.monitoring.register_event_listener(_count_cache_event)
+        jax.monitoring.register_event_time_span_listener(
+            _count_compile_interval
+        )
+        jax.monitoring.register_event_duration_secs_listener(_count_cache_read)
+        _LISTENERS_INSTALLED = True
     if not jax.config.jax_enable_compilation_cache:
         return None
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
@@ -105,7 +146,4 @@ def enable_compile_cache() -> str | None:
         "jax_hlo_source_file_canonicalization_regex",
         "^" + re.escape(_CHECKOUT) + "/",
     )
-    if not _CACHE_LISTENER_INSTALLED:
-        jax.monitoring.register_event_listener(_count_cache_event)
-        _CACHE_LISTENER_INSTALLED = True
     return jax.config.jax_compilation_cache_dir

@@ -174,40 +174,6 @@ def test_dp_learn_donate_flag_keeps_state_alive():
 
 # -- persistent compile cache -------------------------------------------------
 
-@pytest.fixture
-def compile_cache_on(tmp_path, monkeypatch):
-    """The suite runs with JAX's cache switch off (conftest); these tests
-    turn it on with the checkout's fixed path moved under tmp_path, and
-    put everything back. reset_cache() on both sides: JAX latches
-    whether the cache is used at the process's first compile, and keeps
-    an initialised cache object serving its old directory."""
-    from jax.experimental.compilation_cache.compilation_cache import reset_cache
-
-    from surreal_tpu.utils import compat
-
-    fixed = str(tmp_path / "fixed_cache")
-    monkeypatch.setattr(compat, "_CACHE_DIR", fixed)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    old = {
-        k: getattr(jax.config, k)
-        for k in (
-            "jax_enable_compilation_cache", "jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs",
-            "jax_compilation_cache_include_metadata_in_key",
-            "jax_traceback_in_locations_limit",
-            "jax_hlo_source_file_canonicalization_regex",
-        )
-    }
-    jax.config.update("jax_enable_compilation_cache", True)
-    reset_cache()
-    try:
-        yield fixed
-    finally:
-        for k, v in old.items():
-            jax.config.update(k, v)
-        reset_cache()
-
-
 def test_compile_cache_fixed_path_plumbs_through(tmp_path, compile_cache_on):
     """No JAX_COMPILATION_CACHE_DIR: a session turns the cache on at the
     one fixed path (not under its own folder), jax's config points at it,
