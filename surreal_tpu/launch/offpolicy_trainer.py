@@ -438,10 +438,10 @@ class OffPolicyTrainer:
                 return state, replay_state, jax.tree.map(jnp.mean, metrics)
 
             def one_update(c, update_key):
-                state, replay_state = c
+                state, replay_state, mass = c
                 if self.prioritized:
                     replay_state, batch, info = self.replay.sample(
-                        replay_state, update_key, beta=beta
+                        replay_state, update_key, beta=beta, mass=mass
                     )
                     batch = dict(batch, is_weights=info["is_weights"])
                 else:
@@ -464,12 +464,28 @@ class OffPolicyTrainer:
                     replay_state = self.replay.update_priorities(
                         replay_state, info["idx"], td_abs
                     )
-                return (state, replay_state), metrics
+                    # only the drawn slots' blocks changed: add those up
+                    # again, not the ring (replay/prioritized.py)
+                    mass = self.replay.refresh_mass(
+                        mass, replay_state, info["idx"]
+                    )
+                    refreshed = self.replay.blocks_touched(info["idx"])
+                    if axis_name is not None:
+                        refreshed = jax.lax.pmean(refreshed, axis_name)
+                    metrics["replay/mass_blocks_refreshed"] = refreshed
+                return (state, replay_state, mass), metrics
 
+            # the block sums of p^alpha ride the loop's carry: one pass
+            # over the priorities an iteration, after the insert, and
+            # dropped after the scan (None, an empty carry, without them)
+            mass = (
+                self.replay.block_mass(replay_state)
+                if self.prioritized else None
+            )
             # searched update-loop unroll (algo.update_unroll)
-            (state, replay_state), metrics = jax.lax.scan(
+            (state, replay_state, _), metrics = jax.lax.scan(
                 one_update,
-                (state, replay_state),
+                (state, replay_state, mass),
                 ukeys,
                 unroll=self._update_unroll,
             )

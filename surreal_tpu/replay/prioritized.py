@@ -18,6 +18,21 @@ flat ``cumsum`` over the whole vector, which this replaces, is not the
 (PERF_LEDGER.jsonl, PR 25, ``ddpg_lift_per20m``). Priority updates are pure
 scatters.
 
+Stateless across iterations, that is. Inside the fused update loop
+(``launch/offpolicy_trainer.py``: sample -> learn -> ``update_priorities``,
+64 times an iteration) an update moves at most ``batch_size`` priorities, so
+re-adding every block each time was 64 passes over the vector for 256
+changed blocks: 12.37 ms an iteration, 28% of it (PERF_LEDGER.jsonl, PR 41,
+``ddpg_lift_per20m``). There the block sums ride the loop's carry:
+:meth:`PrioritizedReplay.block_mass` once after the insert, ``sample(...,
+mass=)``, :meth:`PrioritizedReplay.refresh_mass` after each scatter, and
+dropped when the loop ends. Not a field of :class:`PrioritizedState`: it
+would be a derived array in checkpoints and shard specs (the argument
+against the sum-tree above), and wrong in the hands of any caller that sets
+``priorities`` directly, as the benchmark's reference check does. A carried
+sum and a fresh one are one expression (``_block_sums``), so the loop draws
+what the stateless draw would, to the bit.
+
 The block level is where float32 runs out: a draw's position ``u`` and the
 block cdf are as large as the total mass, whose ulp is a slot's mass over
 10^4 slots and a block's over 10^7, and a draw that falls on the other side
@@ -181,31 +196,75 @@ class PrioritizedReplay:
     def can_sample(self, state: PrioritizedState) -> jax.Array:
         return can_sample(state.ring.size, self.start_sample_size)
 
+    def _blocks(self, priorities: jax.Array) -> jax.Array:
+        """[blocks, BLOCK] view of the priorities; padding and empty slots
+        are 0 and 0^alpha = 0, so they carry no mass."""
+        return jnp.pad(priorities, (0, -self.capacity % BLOCK)).reshape(-1, BLOCK)
+
+    def _block_sums(self, blocks: jax.Array) -> jax.Array:
+        """The mass of each row of ``blocks``: the one expression a fresh
+        pass and a refreshed block share, so both are the same arithmetic."""
+        return (blocks**self.alpha).sum(axis=1)
+
+    def block_mass(self, state: PrioritizedState) -> jax.Array:
+        """-> f32[ceil(capacity / BLOCK)], the sum of ``p^alpha`` over each
+        block: the one pass over the priority vector that ``sample`` makes
+        when it is handed no ``mass``."""
+        with phase("replay_sample/mass"):
+            return self._block_sums(self._blocks(state.priorities))
+
+    def refresh_mass(
+        self, mass: jax.Array, state: PrioritizedState, idx: jax.Array
+    ) -> jax.Array:
+        """``mass`` with the blocks that hold the slots ``idx`` added up
+        again from ``state.priorities`` (the updated ones). Recomputed from
+        the block's slots, never adjusted by a difference, so nothing drifts
+        and duplicate blocks write the same value whichever write wins.
+        Reads ``len(idx)`` rows of the view ``sample`` gathers its blocks
+        from: in place where the capacity is a multiple of :data:`BLOCK`
+        (else through the padded copy that ``sample`` makes too). Rows, not
+        slots: 256 x 128 gathered elements took the chip longer than the
+        pass they stood in for (PERF.md section 6, PR 42)."""
+        with phase("replay_priority"):
+            b = idx // BLOCK
+            return mass.at[b].set(
+                self._block_sums(self._blocks(state.priorities)[b])
+            )
+
+    def blocks_touched(self, idx: jax.Array) -> jax.Array:
+        """How many distinct blocks hold the slots ``idx`` (what one
+        ``refresh_mass`` adds up again), as a float32 gauge."""
+        with phase("replay_priority"):
+            b = idx // BLOCK
+            repeat = jnp.tril(b[:, None] == b[None, :], -1).any(axis=1)
+            return (~repeat).sum().astype(jnp.float32)
+
     def sample(
         self,
         state: PrioritizedState,
         key: jax.Array,
         batch_size: int | None = None,
         beta: jax.Array | float | None = None,
+        mass: jax.Array | None = None,
     ):
         """-> (state, batch, info) with info = {idx, is_weights}.
 
         ``beta`` is the IS-correction exponent (anneal 0.4 -> 1.0 over
-        training from the caller; defaults to beta0).
+        training from the caller; defaults to beta0). ``mass`` is the block
+        sums of ``state.priorities`` where a caller keeps them
+        (:meth:`block_mass`, :meth:`refresh_mass`); without it they are
+        added up here.
         """
         bs = batch_size or self.batch_size
         beta = self.beta0 if beta is None else beta
         with phase("replay_sample"):
             with phase("replay_sample/mass"):
-                # [blocks, BLOCK] view of the priorities; padding and empty
-                # slots are 0 and 0^alpha = 0, so they carry no mass
-                blocks = jnp.pad(
-                    state.priorities, (0, -self.capacity % BLOCK)
-                ).reshape(-1, BLOCK)
+                blocks = self._blocks(state.priorities)
                 # (the barrier: XLA would else read the vector a second time
                 # to add up the total that ``_dd_cumsum`` takes its grid from)
-                sums = jax.lax.optimization_barrier(
-                    (blocks**self.alpha).sum(axis=1)
+                sums = (
+                    jax.lax.optimization_barrier(self._block_sums(blocks))
+                    if mass is None else mass
                 )
                 # the block cdf in double-float (module docstring); a left
                 # search reads its hi part

@@ -96,6 +96,12 @@ GAUGE_REGISTRY = {
     "replay/max_priority": _g("scalar",
         "prioritized replay's fresh-insert priority scale (pmax-synced "
         'across dp shards).'),
+    "replay/mass_blocks_refreshed": _g("count",
+        "prioritized replay inside the fused update loop: distinct blocks "
+        "of 128 slots whose sum of p^alpha an update's priority scatter "
+        'made the loop add up again (mean over the iteration\'s updates; at '
+        'most batch_size). 0 = no update ran, or the loop is not carrying the '
+        'block sums.'),
     "replay/sample_age_frac": _g("ratio",
         'mean staleness of a sampled index batch as a fraction of the '
         'current fill (0 = just written).'),

@@ -1025,7 +1025,7 @@ def diag_summary(folder: str) -> dict | None:
     trace_id = None
     programs: dict[str, dict] = {}   # program_cost events (last per name)
     precision = None                 # last 'precision' event (active policy)
-    perf_last: dict[str, float] = {}  # perf/* gauges from the last row
+    perf_last: dict[str, float] = {}  # perf/*, replay/* gauges, last row
     hops = None                      # last 'hops' event's percentiles
     profiles: list[dict] = []        # 'profile' capture events
     tune = None
@@ -1148,7 +1148,7 @@ def diag_summary(folder: str) -> dict | None:
             vals = ev.get("values") or {}
             for k, v in vals.items():
                 if (
-                    k.startswith(("perf/", "lineage/", "trace/"))
+                    k.startswith(("perf/", "lineage/", "trace/", "replay/"))
                     and isinstance(v, (int, float))
                 ):
                     perf_last[k] = v
@@ -1665,7 +1665,7 @@ def _gateway_lines(s: dict) -> list[str]:
 def _performance_lines(s: dict) -> list[str]:
     """The diag 'Performance' section: per-program roofline numbers
     (FLOPs / bytes / arithmetic intensity from program_cost events), the
-    live perf/* gauges from the last metrics row, per-hop latency
+    live perf/* and replay/* gauges from the last metrics row, per-hop latency
     percentiles (the stitched cross-process timeline), and captured
     profiler traces. Empty list when the session recorded none of them."""
     progs = s.get("programs") or {}
@@ -1721,7 +1721,21 @@ def _performance_lines(s: dict) -> list[str]:
             bits.append(
                 f"flops/s {perf['perf/flops_per_s'] / 1e9:.2f} G"
             )
-        lines.append("  gauges (last metrics row): " + ", ".join(bits))
+        if bits:
+            lines.append("  gauges (last metrics row): " + ", ".join(bits))
+    if "replay/fill" in perf:
+        bits = [f"fill {perf['replay/fill'] * 100:.1f}%"]
+        if "replay/sample_age_frac" in perf:
+            bits.append(f"sample age {perf['replay/sample_age_frac']:.3f} of the fill")
+        if "replay/max_priority" in perf:
+            bits.append(f"max priority {perf['replay/max_priority']:.4g}")
+        if "replay/mass_blocks_refreshed" in perf:
+            # 0 = the fused update loop is not carrying the draw's block sums
+            bits.append(
+                "block sums refreshed an update "
+                f"{perf['replay/mass_blocks_refreshed']:.1f}"
+            )
+        lines.append("  replay (last metrics row): " + ", ".join(bits))
     lin_p50 = perf.get("lineage/staleness_p50")
     if lin_p50 is not None:
         lines.append(

@@ -337,3 +337,83 @@ def test_offpolicy_replay_checkpoint_resume_skips_warmup(tmp_path):
     assert resumed[0][0] > 4  # iteration counter continued
     # the buffer came back with the checkpoint: updates ran immediately
     assert resumed[0][1] != 0.0, resumed
+
+
+def test_fused_prioritized_iteration_is_the_sequence_run_by_hand(monkeypatch):
+    """The fused iteration keeps the draw's block sums in its update loop's
+    carry (replay/prioritized.py). From a fixed seed it gives the state, the
+    replay state and the metrics row of the sequence it fuses, sample ->
+    learn -> update_priorities with no ``mass`` and one program a step, run
+    here by hand on the ring as the iteration's insert left it."""
+    one = jax.devices()[:1]  # one ring, not the suite's eight dp shards
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    updates, batch_size = 4, 32
+    cfg = Config(
+        learner_config=Config(
+            algo=Config(name="ddpg", horizon=8, updates_per_iter=updates,
+                        exploration=Config(warmup_steps=0)),
+            replay=Config(kind="prioritized", capacity=1000,
+                          start_sample_size=64, batch_size=batch_size),
+        ),
+        env_config=Config(name="jax:pendulum", num_envs=8),
+        session_config=Config(folder="/tmp/test_ddpg_by_hand"),
+    ).extend(base_config())
+    trainer = OffPolicyTrainer(cfg)
+    assert trainer.prioritized and not trainer._batched_sampling
+    replay, learner = trainer.replay, trainer.learner
+    iterate = jax.jit(trainer._device_train_iter)
+    key = jax.random.key(3)
+    carry, replay_state = trainer.init_loop_state(key)
+    state = learner.init(key)
+    beta, warmup = jnp.float32(0.4), jnp.asarray(False)
+    # two iterations of 64 rows fill the ring past start_sample_size and
+    # leave priorities that TD errors have set
+    for it in range(2):
+        state, replay_state, carry, row = iterate(
+            state, replay_state, carry, jax.random.fold_in(key, it),
+            beta, warmup, jnp.asarray(it == 0),
+        )
+    assert row["loss/critic"] != 0.0
+    args = (state, replay_state, carry, jax.random.fold_in(key, 2),
+            beta, warmup, jnp.asarray(False))
+    fused_state, fused_replay, _, fused_row = iterate(*args)
+
+    # the iteration up to its update loop: collect, insert, no update
+    monkeypatch.setattr(replay, "can_sample", lambda s: jnp.asarray(False))
+    # (a function of its own: the bound method's trace is cached above)
+    state, replay_state, _, skipped_row = jax.jit(
+        lambda *a: trainer._device_train_iter(*a)
+    )(*args)
+    assert int(state.iteration) == int(args[0].iteration)
+    monkeypatch.undo()
+    sample = jax.jit(lambda s, k: replay.sample(s, k, beta=beta))
+    learn, rescatter = jax.jit(learner.learn), jax.jit(replay.update_priorities)
+    rows = []
+    for update_key in jax.random.split(jax.random.split(args[3])[1], updates):
+        replay_state, batch, info = sample(replay_state, update_key)
+        state, metrics = learn(
+            state, dict(batch, is_weights=info["is_weights"]), update_key
+        )
+        metrics["replay/sample_age_frac"] = replay.age_frac(replay_state, info["idx"])
+        replay_state = rescatter(
+            replay_state, info["idx"], metrics.pop("priority/td_abs")
+        )
+        rows.append(metrics)
+    by_hand_row = dict(
+        skipped_row,
+        **{k: jnp.mean(jnp.stack([r[k] for r in rows])) for k in rows[0]},
+        **replay.gauges(replay_state),
+    )
+
+    refreshed = float(fused_row.pop("replay/mass_blocks_refreshed"))
+    assert 0 < refreshed <= batch_size
+    by_hand_row.pop("replay/mass_blocks_refreshed")  # the skipped loop's zero
+    jax.tree.map(
+        np.testing.assert_array_equal,
+        (fused_state, fused_replay), (state, replay_state),
+    )
+    assert set(fused_row) == set(by_hand_row)
+    for k in fused_row:
+        np.testing.assert_allclose(
+            float(fused_row[k]), float(by_hand_row[k]), rtol=1e-6, err_msg=k
+        )

@@ -525,6 +525,36 @@ def test_seed_session_diag_stitches_cross_process_timeline(tmp_path):
     assert main(["diag", str(folder)]) == 0
 
 
+@pytest.mark.parametrize("refreshed", [None, 0.0, 31.5])
+def test_diag_prints_the_replay_gauges_of_the_last_row(tmp_path, refreshed):
+    """The replay/* gauges of the newest metrics row get a line of the
+    Performance section; a prioritized fused loop's row also says how many
+    block sums an update refreshed (0 = the loop is not carrying them)."""
+    tel = tmp_path / "telemetry"
+    os.makedirs(tel)
+    values = {"replay/fill": 0.25, "replay/sample_age_frac": 0.5,
+              "replay/max_priority": 3.0, "loss/critic": 0.1}
+    if refreshed is not None:
+        values["replay/mass_blocks_refreshed"] = refreshed
+    with open(tel / "events.jsonl", "w") as f:
+        for step, fill in ((10, 0.125), (20, 0.25)):
+            f.write(json.dumps({
+                "type": "metrics", "t": time.time(), "step": step,
+                "values": dict(values, **{"replay/fill": fill}),
+            }) + "\n")
+    assert diag_summary(str(tmp_path))["perf"]["replay/fill"] == 0.25
+    line = [
+        l for l in diag_report(str(tmp_path)).splitlines()
+        if "replay (last metrics row)" in l
+    ]
+    assert len(line) == 1 and "fill 25.0%" in line[0]
+    assert "max priority 3" in line[0] and "sample age 0.500" in line[0]
+    assert ("block sums refreshed" in line[0]) == (refreshed is not None)
+    if refreshed is not None:
+        assert f"refreshed an update {refreshed:.1f}" in line[0]
+    assert "gauges (last metrics row)" not in diag_report(str(tmp_path))
+
+
 # -- heartbeat staleness -------------------------------------------------------
 
 def test_diag_flags_stale_heartbeats_dead(tmp_path):

@@ -338,35 +338,167 @@ def test_prioritized_draw_matches_float64_reference(case, jit, monkeypatch):
         )
 
 
-def test_prioritized_draw_in_scan_with_priority_updates():
+def _mass_in_one_block(capacity=5120):
+    """Nearly all of the mass in block 3, and most of that in one slot: a
+    stratified draw repeats that slot, and the rest of its draws share the
+    block."""
+    p = np.full(capacity, 1e-4, np.float32)
+    p[3 * 128:4 * 128] = 50.0
+    p[3 * 128 + 17] = 1e4
+    return p
+
+
+# the rings the update loop's draw is pinned on: priorities, filled slots
+SCAN_RINGS = {
+    "partly_filled": lambda: (_abs_normal(5120, 4000, seed=9), 4000),
+    "capacity_5000": lambda: (_abs_normal(5000, seed=9), 5000),  # padded last block
+    "mass_in_one_block": lambda: (_mass_in_one_block(), 5120),
+}
+
+
+def _scan_of_updates(replay, state, keys, beta, carried):
     """The fused DDPG iteration's loop: ``lax.scan`` under ``jit`` of sample
-    -> new priorities for the drawn slots -> the next sample. Every draw is
-    checked against the reference on the priorities it saw."""
-    ref = _ddpg_ref()
-    capacity, filled, steps, beta = 5120, 4000, 8, 0.5
+    -> new priorities for the drawn slots -> the next sample; ``carried``
+    with the block sums in the scan's carry, as the trainer keeps them.
+    -> (final state, final mass, (priorities seen, idx, weights) per step)"""
+
+    def one_update(c, key):
+        state, mass = c
+        seen = state.priorities
+        state, _, info = replay.sample(state, key, beta=beta, mass=mass)
+        td = jnp.abs(jnp.sin(info["idx"].astype(jnp.float32))) * 4.0
+        state = replay.update_priorities(state, info["idx"], td)
+        if carried:
+            mass = replay.refresh_mass(mass, state, info["idx"])
+        return (state, mass), (seen, info["idx"], info["is_weights"])
+
+    def run(state, keys):
+        mass = replay.block_mass(state) if carried else None
+        return jax.lax.scan(one_update, (state, mass), keys)
+
+    (final, mass), out = jax.jit(run)(state, keys)
+    return final, mass, out
+
+
+@pytest.mark.parametrize("how", ["stateless", "carried"])
+@pytest.mark.parametrize("ring", SCAN_RINGS)
+def test_prioritized_draw_in_scan_with_priority_updates(ring, how):
+    """Stateless, every draw is checked against the reference on the
+    priorities it saw. Carried, the loop is the stateless one bit for bit:
+    indices, weights and final priorities, and the sums it carried out are
+    a fresh pass over the final priorities."""
+    priorities, filled = SCAN_RINGS[ring]()
+    steps, beta = 8, 0.5
+    replay = build_replay(replay_cfg(
+        "prioritized", capacity=len(priorities), batch_size=256,
+        start_sample_size=1,
+    ))
+    state = _prioritized_state(replay, priorities)
+    keys = jax.random.split(jax.random.key(12), steps)
+    final, _, (seen, idx, weights) = _scan_of_updates(
+        replay, state, keys, beta, carried=False
+    )
+    assert not np.array_equal(np.asarray(seen[0]), np.asarray(seen[-1]))
+    if how == "stateless":
+        ref = _ddpg_ref()
+        assert (np.asarray(final.priorities)[filled:] == 0).all()
+        for k in range(steps):
+            _check_draw(
+                ref, np.asarray(seen[k]), filled, replay.alpha, beta, keys[k],
+                idx[k], weights[k],
+            )
+        return
+    if ring == "mass_in_one_block":
+        drawn = np.asarray(idx[0])
+        assert len(np.unique(drawn)) < len(drawn)            # a slot repeats
+        assert len(np.unique(drawn // 128)) < len(np.unique(drawn))  # a block too
+    final_c, mass, (_, idx_c, weights_c) = _scan_of_updates(
+        replay, state, keys, beta, carried=True
+    )
+    np.testing.assert_array_equal(np.asarray(idx_c), np.asarray(idx))
+    np.testing.assert_array_equal(np.asarray(weights_c), np.asarray(weights))
+    np.testing.assert_array_equal(
+        np.asarray(final_c.priorities), np.asarray(final.priorities)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(mass), np.asarray(jax.jit(replay.block_mass)(final_c))
+    )
+
+
+@pytest.mark.parametrize("capacity", [64, 5000, 5120, 65536])
+def test_refresh_mass_equals_a_fresh_pass(capacity):
+    """Sums taken before a scatter and refreshed on its slots (the ring's
+    last slot, slot 0 and duplicates among them) are the sums of a fresh
+    pass, to the bit, whether the last block is whole or padded."""
+    from surreal_tpu.replay.prioritized import BLOCK
+
     replay = build_replay(replay_cfg(
         "prioritized", capacity=capacity, batch_size=256, start_sample_size=1,
     ))
-    state = _prioritized_state(replay, _abs_normal(capacity, filled, seed=9))
-    keys = jax.random.split(jax.random.key(12), steps)
+    state = _prioritized_state(replay, _abs_normal(capacity, seed=3))
+    rng = np.random.default_rng(4)
+    idx = rng.integers(0, capacity, 256).astype(np.int32)
+    idx[:6] = [capacity - 1, 0, capacity - 1, 0, idx[6], idx[7]]
+    idx = jnp.asarray(idx)
+    mass = jax.jit(replay.block_mass)(state)
+    assert mass.shape == (-(-capacity // BLOCK),) and mass.dtype == jnp.float32
+    after = replay.update_priorities(
+        state, idx, jnp.asarray(rng.uniform(0.0, 9.0, 256), jnp.float32)
+    )
+    fresh = np.asarray(jax.jit(replay.block_mass)(after))
+    assert not np.array_equal(fresh, np.asarray(mass))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(replay.refresh_mass)(mass, after, idx)), fresh
+    )
+    blocks = len(np.unique(np.asarray(idx) // BLOCK))
+    assert float(jax.jit(replay.blocks_touched)(idx)) == blocks
+    assert (np.asarray(mass) != fresh).sum() <= blocks
 
-    def one_update(state, key):
-        seen = state.priorities
-        state, _, info = replay.sample(state, key, beta=beta)
-        td = jnp.abs(jnp.sin(info["idx"].astype(jnp.float32))) * 4.0
-        state = replay.update_priorities(state, info["idx"], td)
-        return state, (seen, info["idx"], info["is_weights"])
 
-    final, (seen, idx, weights) = jax.jit(
-        lambda s, ks: jax.lax.scan(one_update, s, ks)
-    )(state, keys)
-    assert not np.array_equal(np.asarray(seen[0]), np.asarray(seen[-1]))
-    assert (np.asarray(final.priorities)[filled:] == 0).all()
-    for k in range(steps):
-        _check_draw(
-            ref, np.asarray(seen[k]), filled, replay.alpha, beta, keys[k],
-            idx[k], weights[k],
+def _pows_by_place(jaxpr, length, elements, inside=False, found=None):
+    """``pow`` equations with an operand of ``elements`` entries or more,
+    counted (inside, outside) the scans of ``length`` steps."""
+    found = [0, 0] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pow" and any(
+            v.aval.size >= elements for v in eqn.invars
+        ):
+            found[0 if inside else 1] += 1
+        within = inside or (
+            eqn.primitive.name == "scan" and eqn.params["length"] == length
         )
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pows_by_place(sub, length, elements, within, found)
+    return found
+
+
+def test_fused_ddpg_iteration_raises_the_whole_ring_once(tmp_path, monkeypatch):
+    """The structure of the fused iteration at ``ddpg_lift_per20m``'s
+    rehearsal sizes: nothing in the update loop's body raises ``capacity``
+    priorities to alpha, and one op outside it does (the loop's carry takes
+    the block sums from there)."""
+    from benchmarks.harness import manifest, runner
+    from surreal_tpu.main import launch
+
+    one = jax.devices()[:1]  # the suite simulates eight; the cell has one chip
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: one)
+    cell = runner.sized(manifest.load_cell("ddpg_lift_per20m"), True)
+    argv = runner.train_argv(
+        manifest.load_config(cell["config"]), cell, str(tmp_path), 0, True
+    )
+    trainer = launch.select_trainer(
+        launch.build_config(launch.build_parser().parse_args(argv))
+    )
+    updates, capacity = trainer.algo.updates_per_iter, trainer.replay.capacity
+    assert trainer.prioritized and capacity == cell["traffic"]["replay_capacity"]
+    assert updates != trainer.horizon  # the rollout's scan is no update loop
+    key = jax.random.key(0)
+    carry, replay_state = trainer.init_loop_state(key)
+    jaxpr = jax.make_jaxpr(trainer._device_train_iter)(
+        trainer.learner.init(key), replay_state, carry, key,
+        jnp.float32(0.4), jnp.asarray(False), jnp.asarray(True),
+    )
+    assert _pows_by_place(jaxpr.jaxpr, updates, capacity) == [0, 1]
 
 
 # capacity, rows of each successive insert[, the obs leaf's storage dtype]
