@@ -1,0 +1,21 @@
+"""The acting scan's required bytes over what HBM could move in the
+device time of phase ``collect``: the bfloat16 weights once a step, the two
+full caches and the three rings read to the step's reach, a row written in
+each (``ppo_laguna_ref.iteration_cost``'s ``collect_bytes``), over
+``phase_collect_ms`` x the HBM peak (harness/peaks.json). Required bytes
+only, so the share cannot pass 100. As ``collect_stream_roofline_pct`` reads
+it for ``ppo_lift_phi4flash_16x1024``, whose list may not be edited."""
+
+from benchmarks.harness import phase_session
+
+NAME = "laguna_collect_stream_roofline_pct"
+CHIP_ONLY = True  # the CPU's capture has no device plane
+
+
+def read(run):
+    ms = phase_session.phase_ms(run, "collect")
+    if not ms or not run.peaks or "collect_bytes" not in run.cost:
+        return None
+    return 100.0 * run.cost["collect_bytes"] / (
+        1e-3 * ms * run.peaks["hbm_bytes_per_s"]
+    )

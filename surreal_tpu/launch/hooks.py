@@ -275,6 +275,8 @@ class SessionHooks:
         self.profile = ProfileManager(
             cfg, cfg.folder, self.tracer, self.log,
             op_phases=self.costs.op_phases, op_parts=self.costs.op_parts,
+            # a digest's parse holds this thread too: not its tiers' silence
+            on_hold=self.ops.excuse_pause,
         )
         # watchdog & incident engine (ISSUE 15): detector sweeps over each
         # merged ops snapshot, firings correlated into root-caused

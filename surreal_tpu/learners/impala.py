@@ -100,9 +100,9 @@ class IMPALALearner(SequenceActingMixin, Learner):
         if self.seq_policy and block_family(enc) != "preln":
             raise ValueError(
                 f"model.encoder.block={block_family(enc)!r} is wired into "
-                "PPO alone ('mla_moe': its router-bias rule runs after each "
-                "optimizer step; 'ssm_hybrid': its counters ride PPO's "
-                "minibatch steps; learners/ppo.py); IMPALA takes the "
+                "PPO alone (a routed family's statistics and bias rule, and "
+                "a trunk's counters, ride PPO's minibatch steps: "
+                "learners/ppo.py); IMPALA takes the "
                 "'preln' blocks"
             )
         # precision: model dtypes materialize from the resolved policy

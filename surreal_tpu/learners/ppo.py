@@ -44,12 +44,11 @@ from surreal_tpu.learners.seq_policy import (
     build_seq_model,
     family_config,
 )
-from surreal_tpu.models import latent_moe
 from surreal_tpu.models.attention import (
     COUNTERS_COLLECTION,
-    block_family,
+    MOE_COLLECTION,
+    family_of,
     read_counters,
-    trunk_counters,
 )
 from surreal_tpu.models.ppo_net import CategoricalPPOModel, PPOModel
 from surreal_tpu.ops import distributions as D
@@ -174,19 +173,24 @@ class PPOLearner(SequenceActingMixin, Learner):
         enc = learner_config.model.get("encoder", None)
         self.seq_policy = bool(enc is not None and enc.get("kind") == "trajectory")
         self.requires_act_carry = self.seq_policy
-        # routed-expert blocks (model.encoder.block='mla_moe'): every apply
-        # that learns reads the router's statistics, and the selection
-        # bias moves by its own rule after each optimizer step
-        self.moe = None
-        # scalars a trunk sows on every apply that learns, whatever the
-        # family (models/attention.py::trunk_counters): they ride the
+        # what the trajectory trunk's block family offers a learner
+        # (models/attention.py::Family; None for the 'preln' blocks and a
+        # memoryless policy). A routed family (``moe_stats``): every apply
+        # that learns reads the router's statistics, and ``self.moe`` is
+        # its resolved config; where it has a rule for the selection bias
+        # the bias moves by it after each optimizer step. ``counters``:
+        # scalars a trunk sows on every apply that learns, which ride the
         # minibatch steps' aux to the metrics row
+        self.family = None
+        self.moe = None
         self.counters = None
         if self.seq_policy:
             enc_cfg = family_config(enc.to_dict())
-            if block_family(enc_cfg) == "mla_moe":
+            self.family = family_of(enc_cfg)
+        if self.family is not None:
+            if self.family.moe_stats is not None:
                 self.moe = enc_cfg
-            self.counters = trunk_counters(enc_cfg)
+            self.counters = self.family.counters
         # precision: model dtypes materialize from the resolved policy
         # (Learner.__init__), 'auto' knobs -> concrete per algo.precision
         model_cfg = self.policy.model_config(learner_config.model)
@@ -256,17 +260,23 @@ class PPOLearner(SequenceActingMixin, Learner):
 
     def _apply(self, params, obs):
         """``(model output, the trunk's statistics)`` of one learn-side
-        apply: ``{"load": [layers, n_routed], "overflow": scalar}`` for
-        routed-expert blocks (models/latent_moe.py), ``{name: scalar}`` for
-        a trunk that sows counters, else ``None``."""
+        apply, one dict: ``"load" [layers, n_routed]`` and ``"overflow"``
+        for a routed family, ``name: scalar`` for each counter a trunk
+        sows; ``None`` for a trunk that does neither."""
+        collections = []
         if self.moe is not None:
-            collection, read = latent_moe.MOE_COLLECTION, latent_moe.moe_stats
-        elif self.counters is not None:
-            collection, read = COUNTERS_COLLECTION, read_counters
-        else:
+            collections.append(MOE_COLLECTION)
+        if self.counters is not None:
+            collections.append(COUNTERS_COLLECTION)
+        if not collections:
             return self.model.apply(params, obs), None
-        out, sown = self.model.apply(params, obs, mutable=[collection])
-        return out, read(sown[collection])
+        out, sown = self.model.apply(params, obs, mutable=collections)
+        stats = {}
+        if self.moe is not None:
+            stats.update(self.family.moe_stats(sown[MOE_COLLECTION]))
+        if self.counters is not None:
+            stats.update(read_counters(sown[COUNTERS_COLLECTION]))
+        return out, stats
 
     # -- acting --------------------------------------------------------------
     def act(self, state: PPOState, obs: jax.Array, key: jax.Array, mode: str = TRAINING):
@@ -463,20 +473,22 @@ class PPOLearner(SequenceActingMixin, Learner):
         if self.moe is not None:
             aux["moe_load"] = jax.lax.stop_gradient(stats["load"])
             aux["moe_overflow"] = jax.lax.stop_gradient(stats["overflow"])
-        elif self.counters is not None:
-            aux["counters"] = jax.lax.stop_gradient(stats)
+        if self.counters is not None:
+            aux["counters"] = jax.lax.stop_gradient(
+                {name: stats[name] for name in self.counters}
+            )
         return total * loss_scale, aux
 
     @part("optimizer")
     def _optimizer_step(self, params, opt_state, grads, aux):
         """One optimizer step on a minibatch's gradient: ``(params,
-        opt_state)``. With routed-expert blocks the selection bias, which
-        has no gradient (Adam leaves it where it is), then moves by its own
-        rule on this step's loads (``aux["moe_load"]``)."""
+        opt_state)``. Where the family has a rule for the router's selection
+        bias, which has no gradient (Adam leaves it where it is), the bias
+        then moves by it on this step's loads (``aux["moe_load"]``)."""
         updates, opt_state = self.tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        if self.moe is not None:
-            params = latent_moe.update_router_bias(
+        if self.moe is not None and self.family.update_router_bias is not None:
+            params = self.family.update_router_bias(
                 params, aux["moe_load"], float(self.moe["bias_update_speed"])
             )
         return params, opt_state
@@ -702,10 +714,12 @@ class PPOLearner(SequenceActingMixin, Learner):
                 "moe/held_share": mine.sum() / load.sum(),
                 "moe/load_max_over_mean": mine.max() / mine.mean(),
                 "moe/overflow": auxs["moe_overflow"].sum() + prepare_overflow,
-                "moe/bias_abs_max": jnp.stack(
-                    [jnp.abs(b).max() for b in latent_moe.router_biases(params)]
-                ).max(),
             })
+            if self.family.router_biases is not None:
+                metrics["moe/bias_abs_max"] = jnp.stack([
+                    jnp.abs(b).max()
+                    for b in self.family.router_biases(params)
+                ]).max()
         if self.counters is not None:
             # each over every minibatch step, reduced as the trunk declares
             metrics.update({

@@ -101,7 +101,7 @@ class SequenceActingMixin(PolicyHeadMixin):
         # the cache's form is the model's own (models/attention.py
         # acting_cache): full keys and values for the 'preln' blocks, the
         # latent rows alone for 'mla_moe', state + ring + shared cache
-        # for 'ssm_hybrid'
+        # for 'ssm_hybrid', full caches + rings for 'swa_moe'
         return {
             "cache": self.model.init_cache(num_envs, T),
             "pos": jnp.zeros((), jnp.int32),
@@ -163,44 +163,46 @@ class SequenceActingMixin(PolicyHeadMixin):
 
 
 # model.encoder keys only the 'preln' blocks read (their defaults are
-# session/default_configs.py's); 'mla_moe' reads models/latent_moe.py's
-# FAMILY_DEFAULTS and 'ssm_hybrid' models/ssm_hybrid.py's, which default to
-# None there. All read kind, block, num_heads and act_impl; 'ssm_hybrid'
-# counts its layers in pairs_before / pairs_after, not num_layers.
+# session/default_configs.py's). A family of models/attention.py's table
+# reads its entry's ``defaults``, which default to None there, and the
+# shared keys (kind, block, num_layers, num_heads, act_impl) but those its
+# entry lists under ``not_read``.
 _PRELN_KEYS = ("features", "head_dim", "max_len")
 
 
 def family_config(enc_cfg: dict) -> dict:
     """``model.encoder`` as its block family reads it: a key of another
-    family that was set is an error, not ignored, and 'mla_moe' and
-    'ssm_hybrid' get their unset keys' published values."""
-    from surreal_tpu.models import latent_moe, ssm_hybrid
-    from surreal_tpu.models.attention import block_family
+    family that was set is an error, not ignored, and a family of the table
+    gets its unset keys' published values."""
+    from surreal_tpu.models.attention import (
+        FAMILY_MODULES, block_family, family_named,
+    )
     from surreal_tpu.session.default_configs import BASE_LEARNER_CONFIG
 
-    family = block_family(enc_cfg)
-    families = {"mla_moe": latent_moe, "ssm_hybrid": ssm_hybrid}
-    own = set(families[family].FAMILY_DEFAULTS) if family in families else set()
-    stray = sorted(
-        k for mod in families.values() for k in mod.FAMILY_DEFAULTS
+    name = block_family(enc_cfg)
+    families = {k: family_named(k) for k in FAMILY_MODULES}
+    own = set(families[name].defaults) if name in families else set()
+    stray = sorted({
+        k for family in families.values() for k in family.defaults
         if k not in own and enc_cfg.get(k) is not None
-    )
-    if family == "preln":
+    })
+    if name not in families:
         if stray:
             raise ValueError(
                 f"model.encoder.block='preln' does not read {stray}: set "
-                "model.encoder.block=mla_moe or ssm_hybrid, or leave them unset"
+                f"model.encoder.block={' or '.join(families)}, or leave "
+                "them unset"
             )
         return enc_cfg
     unset = BASE_LEARNER_CONFIG.model.encoder
-    not_read = _PRELN_KEYS + (("num_layers",) if family == "ssm_hybrid" else ())
+    not_read = _PRELN_KEYS + tuple(families[name].not_read)
     stray += [k for k in not_read if enc_cfg.get(k, unset[k]) != unset[k]]
     if stray:
         raise ValueError(
-            f"model.encoder.block={family!r} does not read {stray} (keys of "
+            f"model.encoder.block={name!r} does not read {stray} (keys of "
             f"another family; its own are {sorted(own)})"
         )
-    return families[family].resolve(enc_cfg)
+    return families[name].resolve(enc_cfg)
 
 
 def build_seq_model(
@@ -225,8 +227,8 @@ def build_seq_model(
     family = block_family(enc_cfg)
     wide = family != "preln"   # a family at a published model's widths
     max_len = int(enc_cfg.get("max_len", 4096))
-    # 'mla_moe' has no learned positions (its rotary part takes any index)
-    # and 'ssm_hybrid' no positional term at all
+    # 'mla_moe' and 'swa_moe' have no learned positions (a rotary part
+    # takes any index) and 'ssm_hybrid' no positional term at all
     if not wide and horizon is not None and int(horizon) + 1 > max_len:
         raise ValueError(
             f"algo.horizon={int(horizon)} needs model.encoder.max_len >= "

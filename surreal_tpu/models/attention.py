@@ -17,7 +17,8 @@ site. Causal throughout — policies must not see the future.
 
 from __future__ import annotations
 
-from typing import Any
+import importlib
+from typing import Any, Callable, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -224,13 +225,57 @@ class TrajectoryEncoder(nn.Module):
         return (out, new_cache) if decode else out
 
 
-BLOCK_FAMILIES = ("preln", "mla_moe", "ssm_hybrid")
+# the variable collections a trunk sows into on a whole-segment apply:
+# scalars a learner carries to the metrics row without knowing the family
+# (learners/ppo.py); a routed trunk's statistics; and, on request, each
+# token's chosen experts and the router's input
+COUNTERS_COLLECTION = "counters"
+MOE_COLLECTION = "moe"
+ROUTING_COLLECTION = "moe_routing"
+
+
+class Family(NamedTuple):
+    """What a block family at a published model's widths offers the heads,
+    the acting carry, the config system and the learner. One entry a
+    family (its file's ``FAMILY``); everything that used to ask for a
+    family by name reads this."""
+
+    trunk: Any              # flax module: (cfg=, compute_dtype=, name=)
+    acting_cache: Callable  # (cfg, num_envs, horizon, dtype) -> cache
+    defaults: dict          # its model.encoder keys: None -> these values
+    resolve: Callable       # encoder cfg -> cfg with those filled in
+    # shared keys (session/default_configs.py) it does not read
+    not_read: tuple = ()
+    # (cache, wrap) -> cache with the recurrent leaves zeroed; None where
+    # every leaf is indexed by position (stale rows are masked)
+    reset_recurrent: Callable | None = None
+    # {sown name: (metrics row, 'max' | 'mean' over the minibatch steps)}
+    counters: dict | None = None
+    # the 'moe' collection of one apply -> {"load": [layers, n_routed],
+    # "overflow": scalar}; None without routed experts
+    moe_stats: Callable | None = None
+    # (params, load, speed) -> params: a rule that moves the router's
+    # selection bias after each optimizer step, and params -> [bias a
+    # layer]; None where nothing moves the router
+    update_router_bias: Callable | None = None
+    router_biases: Callable | None = None
+
+
+# model.encoder.block -> the module under surreal_tpu/models/ whose
+# ``FAMILY`` is the entry. 'preln' is this module's TrajectoryEncoder, the
+# toy default: it has no entry, and `family_of` says None.
+FAMILY_MODULES = {
+    "mla_moe": "latent_moe",
+    "ssm_hybrid": "ssm_hybrid",
+    "swa_moe": "swa_moe",
+}
+BLOCK_FAMILIES = ("preln", *FAMILY_MODULES)
 
 
 def block_family(encoder_cfg) -> str:
     """``model.encoder.block``: 'preln' (this module's
-    :class:`TrajectoryEncoder`, the default) | 'mla_moe'
-    (``models/latent_moe.py``) | 'ssm_hybrid' (``models/ssm_hybrid.py``)."""
+    :class:`TrajectoryEncoder`, the default) or a key of
+    :data:`FAMILY_MODULES`."""
     block = encoder_cfg.get("block", "preln") or "preln"
     if block not in BLOCK_FAMILIES:
         raise ValueError(
@@ -239,19 +284,28 @@ def block_family(encoder_cfg) -> str:
     return block
 
 
+def family_named(name: str) -> Family:
+    # imported on demand: the family files import this module
+    module = importlib.import_module(
+        f"surreal_tpu.models.{FAMILY_MODULES[name]}"
+    )
+    return module.FAMILY
+
+
+def family_of(encoder_cfg) -> Family | None:
+    """The entry of the family ``model.encoder.block`` names, ``None`` for
+    'preln'."""
+    name = block_family(encoder_cfg)
+    return family_named(name) if name in FAMILY_MODULES else None
+
+
 def build_trunk(cfg, *, cnn_cfg, mesh, sp_axis, batch_axis, compute_dtype):
     """The trunk ``model.encoder.block`` selects, under the name both
     heads give it. Each takes ``[B, T, obs]``, or ``[B, obs]`` with
     ``cache`` and ``pos``."""
-    family = block_family(cfg)
-    if family == "mla_moe":
-        from surreal_tpu.models.latent_moe import LatentMoETrunk
-
-        return LatentMoETrunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
-    if family == "ssm_hybrid":
-        from surreal_tpu.models.ssm_hybrid import SSMHybridTrunk
-
-        return SSMHybridTrunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
+    family = family_of(cfg)
+    if family is not None:
+        return family.trunk(cfg=cfg, compute_dtype=compute_dtype, name="trunk")
     return TrajectoryEncoder(
         features=cfg["features"], num_layers=cfg["num_layers"],
         num_heads=cfg["num_heads"], head_dim=cfg["head_dim"],
@@ -265,21 +319,16 @@ def build_trunk(cfg, *, cnn_cfg, mesh, sp_axis, batch_axis, compute_dtype):
 
 def acting_cache(cfg, num_envs: int, horizon: int, dtype):
     """The cache of the incremental acting carry, as the trunk's decode
-    path takes it: full keys and values a layer for 'preln', the latent
-    rows alone for 'mla_moe', and for 'ssm_hybrid' three kinds side by
-    side (a constant-size state, a ring that forgets, one shared cache).
+    path takes it: full keys and values a layer for 'preln', else the
+    family's own (the latent rows alone for 'mla_moe'; a constant-size
+    state, a ring that forgets and one shared cache for 'ssm_hybrid'; full
+    caches and rings of rotated keys for 'swa_moe').
     In the compute dtype, the attention math's own, so decode and the
     full-segment recompute round alike (precision policy,
     ops/precision.py); a recurrent state is float32."""
-    family = block_family(cfg)
-    if family == "mla_moe":
-        from surreal_tpu.models import latent_moe
-
-        return latent_moe.acting_cache(cfg, num_envs, horizon, dtype)
-    if family == "ssm_hybrid":
-        from surreal_tpu.models import ssm_hybrid
-
-        return ssm_hybrid.acting_cache(cfg, num_envs, horizon, dtype)
+    family = family_of(cfg)
+    if family is not None:
+        return family.acting_cache(cfg, num_envs, horizon, dtype)
     mk = lambda: jnp.zeros(
         (num_envs, horizon, int(cfg["num_heads"]), int(cfg["head_dim"])), dtype
     )
@@ -289,31 +338,13 @@ def acting_cache(cfg, num_envs: int, horizon: int, dtype):
 def reset_recurrent(cfg, cache, wrap):
     """``cache`` as a new segment starts with it where ``wrap`` is set:
     the leaves a family marks recurrent zeroed, the rest untouched (a
-    position-indexed cache needs nothing: its stale rows are masked). Only
-    'ssm_hybrid' holds such leaves; for the others this is the identity,
-    and traces to nothing."""
-    if block_family(cfg) == "ssm_hybrid":
-        from surreal_tpu.models import ssm_hybrid
-
-        return ssm_hybrid.reset_recurrent(cache, wrap)
-    return cache
-
-
-# the variable collection a trunk sows its counters into on a whole-segment
-# apply: scalars a learner carries to the metrics row without knowing the
-# family (learners/ppo.py)
-COUNTERS_COLLECTION = "counters"
-
-
-def trunk_counters(cfg) -> dict | None:
-    """``{sown name: (metrics row, 'max' | 'mean' over an iteration's
-    minibatch steps)}`` of the family's trunk, ``None`` where it sows
-    none."""
-    if block_family(cfg) == "ssm_hybrid":
-        from surreal_tpu.models import ssm_hybrid
-
-        return ssm_hybrid.COUNTERS
-    return None
+    position-indexed cache needs nothing: its stale rows are masked). For
+    a family without such leaves this is the identity, and traces to
+    nothing."""
+    family = family_of(cfg)
+    if family is None or family.reset_recurrent is None:
+        return cache
+    return family.reset_recurrent(cache, wrap)
 
 
 def read_counters(collection: dict) -> dict:

@@ -57,15 +57,17 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from surreal_tpu.models.attention import (
+    MOE_COLLECTION, ROUTING_COLLECTION, Family,
+)
 from surreal_tpu.ops import moe
 from surreal_tpu.ops.ring_attention import _NEG_BIG, full_attention
 from surreal_tpu.utils.phases import part
 
 BLOCK = "mla_moe"
-MOE_COLLECTION = "moe"   # the variable collection the statistics are sown in
+# the statistics are sown in MOE_COLLECTION, each token's chosen experts and
+# the router's input, on request, in ROUTING_COLLECTION (models/attention.py)
 ROUTED = "moe"           # a routed layer's submodule, in params and there
-ROUTING_COLLECTION = "moe_routing"   # each token's chosen experts and the
-                                     # router's input, on request
 BIAS_NAME = "e_score_correction_bias"
 INIT_STD = 0.02
 
@@ -100,12 +102,7 @@ def resolve(encoder_cfg: dict) -> dict:
     for k, v in FAMILY_DEFAULTS.items():
         if out.get(k) is None:
             out[k] = v
-    held_end = int(out["first_held"]) + int(out["num_held"])
-    if not 0 <= int(out["first_held"]) < held_end <= int(out["n_routed_experts"]):
-        raise ValueError(
-            f"held experts [{out['first_held']}, {held_end}) lie outside "
-            f"the {out['n_routed_experts']} routed"
-        )
+    moe.check_held(out["first_held"], out["num_held"], out["n_routed_experts"])
     return out
 
 
@@ -402,3 +399,10 @@ def update_router_bias(params, load, speed: float):
 def router_biases(params) -> list:
     trunk = params["params"]["trunk"]
     return [trunk[k][ROUTED][BIAS_NAME] for k in _routed_layers(trunk)]
+
+
+FAMILY = Family(
+    trunk=LatentMoETrunk, acting_cache=acting_cache, defaults=FAMILY_DEFAULTS,
+    resolve=resolve, moe_stats=moe_stats,
+    update_router_bias=update_router_bias, router_biases=router_biases,
+)

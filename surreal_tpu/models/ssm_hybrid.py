@@ -75,7 +75,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from surreal_tpu.models.attention import COUNTERS_COLLECTION
+from surreal_tpu.models.attention import COUNTERS_COLLECTION, Family
 from surreal_tpu.ops import moe
 from surreal_tpu.ops.ring_attention import _NEG_BIG, blocked_attention
 from surreal_tpu.ops.selective_scan import selective_scan, selective_step
@@ -562,3 +562,11 @@ def reset_recurrent(cache: dict, wrap) -> dict:
     left as they are: their stale rows are masked by the position."""
     zero = lambda x: jnp.where(wrap, jnp.zeros_like(x), x)
     return dict(cache, ssm=jax.tree.map(zero, cache["ssm"]))
+
+
+# it counts its layers in pairs_before / pairs_after, not num_layers
+FAMILY = Family(
+    trunk=SSMHybridTrunk, acting_cache=acting_cache, defaults=FAMILY_DEFAULTS,
+    resolve=resolve, not_read=("num_layers",), reset_recurrent=reset_recurrent,
+    counters=COUNTERS,
+)

@@ -1,4 +1,4 @@
-"""Sigmoid routing over all experts and the held experts' grouped product.
+"""Routing over all experts and the held experts' grouped product.
 
 An expert layer on one chip of an expert-parallel group is told which
 experts it holds (``first_held``, and as many as its weights have), routes
@@ -11,6 +11,8 @@ group): ``s = sigmoid(logits)`` in float32; the ``top_k`` largest of
 ``s + b`` are taken, ``b`` the selection bias, which enters nothing else
 and takes no gradient; the weights are ``s_i / sum_{j in top_k} s_j x
 scale``, normalised over all ``top_k`` whether held here or not.
+``models/swa_moe.py`` routes by a softmax over all experts instead, with no
+bias (:func:`route`); everything after the routing is shared.
 
 The product is ragged: expert ``e`` gets however many tokens chose it. Two
 forms compute it, chosen from the pass's static shape (:func:`dense_form`):
@@ -48,14 +50,36 @@ import jax
 import jax.numpy as jnp
 
 
-def route(logits, bias, top_k: int, scale: float):
+def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid"):
     """``logits [N, E]`` float32 -> ``(idx [N, top_k] int32, weights
-    [N, top_k] float32, scores [N, E])``."""
-    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    [N, top_k] float32, scores [N, E])``. ``scoring`` 'sigmoid' (the module
+    docstring's; ``bias [E]`` enters the selection alone) or 'softmax'
+    (``p = softmax(logits)`` over all ``E``, the ``top_k`` largest, the
+    weights normalised over them as above: the Qwen-MoE lineage's
+    ``norm_topk_prob``); ``bias`` None selects by the scores alone."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring {scoring!r} not in sigmoid|softmax")
+    logits = logits.astype(jnp.float32)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    picked = scores if bias is None else scores + jax.lax.stop_gradient(bias)
+    _, idx = jax.lax.top_k(picked, top_k)
     chosen = jnp.take_along_axis(scores, idx, axis=-1)
     weights = chosen / chosen.sum(-1, keepdims=True) * scale
     return idx.astype(jnp.int32), weights, scores
+
+
+def check_held(first_held: int, num_held: int, n_routed: int) -> None:
+    """Refuse a share ``[first_held, first_held + num_held)`` that is empty
+    or lies outside the ``n_routed`` experts."""
+    held_end = int(first_held) + int(num_held)
+    if not 0 <= int(first_held) < held_end <= int(n_routed):
+        raise ValueError(
+            f"held experts [{first_held}, {held_end}) lie outside the "
+            f"{n_routed} routed"
+        )
 
 
 def expert_load(idx, n_routed: int):

@@ -84,8 +84,16 @@ BASE_LEARNER_CONFIG = Config(
             #   with a ring that forgets, one full layer whose keys and
             #   values cross-attention layers read, gated memory units;
             #   Phi-4-mini-flash-reasoning's widths where a key is None.
-            # All read kind, block, num_heads, act_impl; the first two
-            # num_layers ('ssm_hybrid': pairs_before, pairs_after).
+            # 'swa_moe' (models/swa_moe.py): RMSNorm, grouped-query
+            #   attention that is full in every fourth layer (num_heads
+            #   query heads, YaRN rotary on half the head) and sliding in
+            #   the others (window_heads, plain rotary), a sigmoid gate a
+            #   head, a leading dense SwiGLU layer, then softmax-routed
+            #   experts of which this chip holds a share beside a shared
+            #   one; full caches and rings of rotated keys to act from.
+            #   Laguna-S-2.1's widths where a key is None.
+            # All read kind, block, num_heads, act_impl, and num_layers
+            # ('ssm_hybrid': pairs_before, pairs_after instead).
             block="preln",
             features=64,
             num_layers=2,
@@ -99,7 +107,10 @@ BASE_LEARNER_CONFIG = Config(
             # pos_embed capacity; the sequence learn pass uses horizon+1
             # positions, validated at learner build (seq_policy.py)
             max_len=4096,
-            # -- 'mla_moe' only ------------------------------------------
+            # -- 'mla_moe' (the keys 'swa_moe' reads too: hidden_size,
+            # intermediate_size, moe_intermediate_size, n_routed_experts,
+            # num_experts_per_tok, routed_scaling_factor,
+            # first_k_dense_replace, rms_norm_eps, first_held, num_held) --
             hidden_size=None,
             q_lora_rank=None,
             kv_lora_rank=None,
@@ -131,6 +142,12 @@ BASE_LEARNER_CONFIG = Config(
             # [gmu, cross] x pairs_after
             pairs_before=None,
             pairs_after=None,
+            # -- 'swa_moe' only (it reads num_kv_heads and sliding_window
+            # above too; None = the published Laguna-S-2.1 value,
+            # FAMILY_DEFAULTS in models/swa_moe.py) -------------------------
+            window_heads=None,             # a sliding layer's query heads
+            attn_head_dim=None,            # a head's size (not hidden / heads)
+            shared_intermediate_size=None, # the shared expert's width
         ),
         cnn=Config(
             enabled=False,          # pixel observations -> Nature-CNN stem

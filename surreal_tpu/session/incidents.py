@@ -377,9 +377,16 @@ class IncidentEngine:
         }
         self._absorb(inc, firings, snap, now)
         # one profile capture + one flightrec dump per incident, bounded
-        # by a run-wide count and a cooldown across incidents
+        # by a run-wide count and a cooldown after the last capture, be it
+        # an incident's or an operator's: a capture's fences, its write and
+        # its digest disturb the iterations after it, and an incident
+        # opened by that would capture the disturbance of its own capture
+        last = max(
+            self._last_capture,
+            getattr(self._profile, "last_capture_t", None) or 0.0,
+        )
         if (self._captures < self.max_captures
-                and now - self._last_capture >= self.capture_cooldown_s):
+                and now - last >= self.capture_cooldown_s):
             self._captures += 1
             self._last_capture = now
             if self._profile is not None and self._profile.request(

@@ -382,20 +382,28 @@ def _instruction(event_name: str) -> tuple[str, str]:
 
 
 def read_capture(path: str, op_phases: dict, span_names,
-                 op_parts: dict | None = None) -> tuple:
+                 op_parts: dict | None = None, on_parsed=None) -> tuple:
     """``(device_ops, loop_spans, host_span_counts)`` of one
     ``.xplane.pb``: per device plane the ``XLA Ops`` line as ``(start_ns,
     end_ns, name, phase, part)``, each op's phase and part looked up under
     the module (``XLA Modules`` line) it ran in; the program's spans on the loop's
     thread (the host line with the most ``engine.step``); and how often
-    each program span appears on any host line."""
+    each program span appears on any host line. ``on_parsed(seconds)`` is
+    told how long the file took to parse: the parser is one foreign call
+    that keeps the interpreter to itself, so every other thread of the
+    process stood still that long (35 s for three iterations of a thousand
+    five-layer acting steps)."""
     from jax.profiler import ProfileData
 
     span_names = set(span_names) | set(ENGINE_SPANS)
     op_parts = op_parts or {}
     device_ops: dict[str, list] = {}
     lines: list[list] = []
-    for plane in ProfileData.from_file(path).planes:
+    t0 = time.monotonic()
+    planes = ProfileData.from_file(path).planes
+    if on_parsed is not None:
+        on_parsed(time.monotonic() - t0)
+    for plane in planes:
         if plane.name.startswith("/device:"):
             by_name = {line.name: line for line in plane.lines}
             if "XLA Ops" not in by_name:
@@ -441,7 +449,7 @@ def read_capture(path: str, op_phases: dict, span_names,
 
 def digest_capture(trace_dir: str, op_phases: dict, span_names,
                    steps: int | None = None,
-                   op_parts: dict | None = None) -> dict:
+                   op_parts: dict | None = None, on_parsed=None) -> dict:
     """Reduce the one ``.xplane.pb`` a capture left under ``trace_dir``.
     ``steps`` is the number of iterations the fenced window holds; a
     capture cut short has none, and the ``iteration`` steps seen on the
@@ -455,7 +463,7 @@ def digest_capture(trace_dir: str, op_phases: dict, span_names,
             f"{len(found)} .xplane.pb files under {trace_dir}, expected 1"
         )
     device_ops, loop_spans, counts = read_capture(
-        found[0], op_phases, span_names, op_parts
+        found[0], op_phases, span_names, op_parts, on_parsed
     )
     if steps is None:
         steps = counts.get("iteration", 0)
@@ -475,7 +483,7 @@ class ProfileManager:
     most once per second) one ``os.path.exists``."""
 
     def __init__(self, session_cfg, folder: str, tracer, log, op_phases=None,
-                 op_parts=None):
+                 op_parts=None, on_hold=None):
         self._folder = folder
         self._tracer = tracer
         self._log = log
@@ -483,6 +491,9 @@ class ProfileManager:
         # digest (CostAccountant.op_phases); called off the loop's thread
         self._op_phases = op_phases or dict
         self._op_parts = op_parts or dict   # the same, by model part
+        # told the seconds for which a digest's parse kept every thread of
+        # the process still (read_capture), from the digest's thread
+        self._on_hold = on_hold
         prof = session_cfg.get("profile", None)
         self._trigger_enabled = (
             bool(prof.get("trigger_file", True)) if prof is not None else True
@@ -502,6 +513,9 @@ class ProfileManager:
         # newest completed capture directory — the incident engine links
         # the capture it auto-requested into the incident record from here
         self.last_capture_dir: str | None = None
+        # when the newest capture's digest ended (host clock; None before
+        # any): last_capture_t
+        self._settled_t: float | None = None
         self._last_tick: float | None = None
         self._last_iter = 0  # newest iteration ticked (close() reports it)
         self._ewma_s: float | None = None
@@ -559,6 +573,7 @@ class ProfileManager:
         if act is None:
             return
         self.last_capture_dir = act["dir"]
+        self._settled_t = time.time()
         self._log.info("profiler capture saved -> %s", act["dir"])
         fields = dict(
             dir=act["dir"], reason=act["reason"],
@@ -580,12 +595,26 @@ class ProfileManager:
             fields["digest"] = digest_capture(
                 fields["dir"], self._op_phases(),
                 getattr(self._tracer, "span_names", ()), steps,
-                op_parts=self._op_parts(),
+                op_parts=self._op_parts(), on_parsed=self._on_hold,
             )
         except Exception as e:
             self._log.warning("profile digest failed: %s", e)
             fields["digest_error"] = f"{type(e).__name__}: {e}"
+        self._settled_t = time.time()
         self._tracer.event("profile", **fields)
+
+    @property
+    def last_capture_t(self) -> float | None:
+        """Host clock at which a capture last disturbed the loop: now while
+        one is open or its digest is being reduced (the parse alone keeps
+        the interpreter for 35 s after a capture of three thousand-step
+        iterations), else when the newest one's digest ended; None before
+        any. An incident that opens in that wake does not capture again
+        (``IncidentEngine``'s cooldown counts from here)."""
+        digesting = self._digest is not None and self._digest.is_alive()
+        if self._active is not None or digesting:
+            return time.time()
+        return self._settled_t
 
     def _join_digest(self) -> None:
         if self._digest is not None:

@@ -34,7 +34,7 @@ An op outside every scope is ``unattributed``.
 Phases say *when* in the iteration an op runs. A second, short
 vocabulary of **parts** says *which part of the model* it belongs to, for
 a trunk large enough that this is the question (``models/latent_moe.py``,
-``models/ssm_hybrid.py``).
+``models/ssm_hybrid.py``, ``models/swa_moe.py``).
 A part's scope sits inside whatever phase runs the model, so an op has
 one phase and at most one part, and the digest sums each on its own:
 
@@ -51,6 +51,12 @@ one phase and at most one part, and the digest sums each on its own:
                  scan (or an acting step of it), the skip and the gate
     ssm_proj     a state-space layer's four products: in, x, dt, out
     gmu          a gated memory unit: both products and the gate
+    attn_window  ``models/swa_moe.py``'s sliding layers (72 query heads at
+                 the published widths): projections, the rotation, scores
+                 over the window, softmax, the gate a head, the output
+                 projection, in the learn pass and against the ring
+    attn_full    the same of its full layers (48 heads, YaRN frequencies
+                 on half the head), against the whole segment or cache
 """
 
 from __future__ import annotations
@@ -62,7 +68,7 @@ PHASES = (
 )
 PARTS = (
     "attn", "moe_route", "moe_experts", "dense_ffn", "optimizer",
-    "ssm_scan", "ssm_proj", "gmu",
+    "ssm_scan", "ssm_proj", "gmu", "attn_window", "attn_full",
 )
 UNATTRIBUTED = "unattributed"
 _VOCABULARY = frozenset(PHASES)

@@ -3,6 +3,7 @@ metadata of the compiled fused programs, the program's spans and the loop
 engine's steps on a profile's host plane, and the digest of a triggered
 capture that ``diag`` renders."""
 
+import glob
 import json
 import os
 import re
@@ -213,3 +214,28 @@ def test_diag_renders_a_device_digest(tmp_path):
     assert re.search(r"sgd\s+0\.000\s+50\.0%\s+fusion\.1 f32\[8\]", report)
     assert re.search(r"unattributed\s+0\.000\s+25\.0%", report)
     assert "idle by span: metrics-sync" in report
+
+
+def test_a_digest_says_how_long_its_parse_held_the_process(captured, tmp_path):
+    """The capture's parser is one foreign call that keeps the interpreter:
+    the digest reports its seconds, and the session excuses the tiers that
+    live on the learner thread for them (``launch/hooks.py`` hands the
+    profiler ``ops.excuse_pause``; found on the chip, PR 44: 35 s of it read
+    as a dead engine tier and opened an incident)."""
+    from surreal_tpu.launch.hooks import SessionHooks
+    from surreal_tpu.learners import build_learner
+    from surreal_tpu.envs import make_env
+    from surreal_tpu.session.profile import digest_capture
+
+    (trace_dir,) = glob.glob(os.path.join(captured, "telemetry", "profiles", "*"))
+    held = []
+    digest = digest_capture(trace_dir, {}, (), on_parsed=held.append)
+    assert len(held) == 1 and 0.0 <= held[0] <= digest["digest_s"]
+    cfg = _config("ppo", str(tmp_path))
+    hooks = SessionHooks(
+        cfg, build_learner(cfg.learner_config, make_env(cfg.env_config).specs)
+    )
+    try:
+        assert hooks.profile._on_hold == hooks.ops.excuse_pause
+    finally:
+        hooks.close()

@@ -104,7 +104,7 @@ def _scatter(capacity):
     return build
 
 
-def _fused_ppo(sds, envs=B, learner=None):
+def _fused_ppo(sds, envs=B, learner=None, horizon=T):
     """Trainer's fused rollout+learn step at the headline geometry. The
     test jits the step itself: ``Trainer._train_iter`` is built over this
     process's (CPU) devices."""
@@ -114,7 +114,7 @@ def _fused_ppo(sds, envs=B, learner=None):
     from surreal_tpu.session.default_configs import base_config
 
     cfg = Config(
-        learner_config=Config(algo=Config(name="ppo", horizon=T)).extend(
+        learner_config=Config(algo=Config(name="ppo", horizon=horizon)).extend(
             learner or {}
         ),
         env_config=Config(name="jax:lift", num_envs=envs),
@@ -381,3 +381,76 @@ def test_phi4flash_iteration_fits_the_chip_with_each_layer_recomputed(sds):
         "bf16[16,512,20,64]" in c and "bf16[16,1024,20,64]" in c
         and "bf16[16,3,5120]" in c for c in loops
     )
+
+
+def test_laguna_iteration_fits_the_chip_with_each_layer_recomputed(sds):
+    """The fused iteration of ``ppo_lift_laguna_16x1024`` (16 envs x 1024,
+    2 x 4 minibatches of 4096 tokens, the family's published widths, layer 0
+    and the period after it: 734M parameters, 11.7 GB of state) compiles for
+    the v5e inside its 16.9 GB: each layer is recomputed in the backward
+    (models/swa_moe.py chooses that from the shapes), attention keeps no
+    scores, and the acting scan carries two full caches beside three rings."""
+    from surreal_tpu.session.config import Config
+
+    cell = Config(
+        algo=Config(
+            epochs=2, num_minibatches=4, precision="mixed", clip_ratio=0.2,
+        ),
+        model=Config(encoder=Config(
+            kind="trajectory", block="swa_moe", num_heads=48, num_layers=5,
+        )),
+        optimizer=Config(lr=3e-4),
+    )
+    step, args = _fused_ppo(sds, envs=16, learner=cell, horizon=1024)
+    assert sum(x.size for x in jax.tree.leaves(args[0].params)) == 734_014_473
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert held < 15.6e9, held          # 15.22 GB when this was written
+    text = compiled.as_text()
+    # the acting loop's carry: both kinds of cache, in the compute dtype
+    loops = [line.split(" while(")[0] for line in text.splitlines()
+             if " while(" in line and "bf16[16,512,8,128]" in line]
+    assert any("bf16[16,1024,8,128]" in c for c in loops)
+    # the routed layers run the ragged kernel over the bound's rows
+    assert "agged" in text
+
+
+def test_laguna_check_asks_the_program_for_its_experts_inside_highest(chip):
+    """``ppo_laguna_ref.program_choice`` runs the program's own learn-side
+    apply from inside the reference's ``default_matmul_precision("highest")``.
+    Mosaic refuses the ragged product's bfloat16 operands at that precision
+    ("Bad lhs type": found on the chip, PR 44; the CPU has no such kernel),
+    so the function sets the program's own precision back. Three layers at
+    the published widths (the third's router reads what the second's experts
+    gave, so their product stays), a minibatch's 4 x 1024 tokens."""
+    from benchmarks.harness import manifest
+    from surreal_tpu.envs import make_env
+    from surreal_tpu.learners import build_learner
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import base_config
+
+    cfg = Config(
+        learner_config=Config(
+            algo=Config(name="ppo", horizon=1024, epochs=2, num_minibatches=4),
+            model=Config(encoder=Config(
+                kind="trajectory", block="swa_moe", num_heads=48, num_layers=3,
+            )),
+        ),
+        env_config=Config(name="jax:lift", num_envs=16),
+        session_config=Config(folder="unused"),
+    ).extend(base_config())
+    learner = build_learner(cfg.learner_config, make_env(cfg.env_config).specs)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+    )
+    params = on_chip(jax.eval_shape(learner.init, jax.random.key(0)).params)
+    obs = jax.ShapeDtypeStruct((4, 1024, 17), jnp.float32, sharding=chip)
+    choose = manifest.load_reference("ppo_laguna_ref").program_choice(
+        {"learner": learner}
+    )
+    with jax.default_matmul_precision("highest"):
+        choose.lower(params, obs).compile()

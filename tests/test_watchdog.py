@@ -618,3 +618,67 @@ def _events(folder):
     return list(_iter_jsonl(
         os.path.join(folder, "telemetry", "events.jsonl")
     ))
+
+
+@pytest.mark.parametrize("since_capture_s,captures", [(5.0, 0), (None, 1), (3600.0, 1)])
+def test_an_incident_in_the_wake_of_a_capture_does_not_capture_again(
+    since_capture_s, captures,
+):
+    """A capture's fences, its write and its digest disturb the iterations
+    after it (found on the chip, PR 44: a benchmark's triggered capture was
+    followed by an incident whose own empty capture was the session's last
+    ``profile`` event). The incident engine's cooldown counts from the
+    newest capture of the session's profiler, an operator's too."""
+
+    class Profiler:
+        last_capture_dir = None
+        last_capture_t = (
+            None if since_capture_s is None else time.time() - since_capture_s
+        )
+        requested = []
+
+        def request(self, reason, num_iters=None):
+            self.requested.append(reason)
+            return True
+
+    profiler = Profiler()
+    eng = IncidentEngine(profile=profiler, trace_id="tr-test")
+    firing = {
+        "detector": "liveness", "signal": "engine", "tier": "learner",
+        "value": 44.0, "baseline": 30.0, "direction": "high", "t": time.time(),
+    }
+    eng.observe([firing], make_snap(8))
+    assert eng.opened == 1                  # the incident itself is recorded
+    assert len(profiler.requested) == captures
+    want = "pending" if captures else None
+    assert eng._open["artifacts"]["profile"] == want
+
+
+def test_the_profiler_counts_its_digest_into_the_wake_of_a_capture(tmp_path):
+    """``ProfileManager.last_capture_t`` is now while a capture is open or
+    its digest is being reduced, and the digest's end after that: on the
+    chip the parse of three thousand-step iterations ended 44 s after the
+    capture was saved, so a wake counted from the save alone ran out at a
+    parse a third longer."""
+    from surreal_tpu.session.profile import ProfileManager
+
+    class Quiet:
+        def info(self, *a, **k):
+            pass
+
+        warning = event = info
+
+    pm = ProfileManager(
+        Config(profile=Config(trigger_file=False)), str(tmp_path), Quiet(), Quiet()
+    )
+    assert pm.last_capture_t is None
+    done = threading.Event()
+    pm._settled_t = 5.0                     # the save, long ago
+    pm._digest = threading.Thread(target=done.wait, daemon=True)
+    pm._digest.start()
+    assert time.time() - pm.last_capture_t < 1.0
+    done.set()
+    pm._digest.join()
+    assert pm.last_capture_t == 5.0
+    pm._reduce({"dir": str(tmp_path / "none")}, None)   # a digest that fails
+    assert time.time() - pm.last_capture_t < 1.0
