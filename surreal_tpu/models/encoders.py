@@ -78,7 +78,9 @@ class NatureCNN(nn.Module):
     shared conv encoder for frame-stacked 84x84 pixels).
 
     Input: [..., H, W, C] uint8 or float. uint8 is scaled to [0, 1] on
-    device so the host ships compact bytes over DCN.
+    device so the host ships compact bytes over DCN. A rank-5 input, a
+    rollout's [T, B, H, W, C], keeps both leading axes through the
+    convolutions (``_FramesConv``); the parameters are the same.
     """
 
     channels: Sequence[int] = (32, 64, 64)
@@ -99,16 +101,27 @@ class NatureCNN(nn.Module):
             x = x.astype(self.compute_dtype) / 255.0
         else:
             x = x.astype(self.compute_dtype)
-        for ch, k, s in zip(self.channels, self.kernels, self.strides):
-            x = nn.Conv(
-                ch,
-                kernel_size=(k, k),
-                strides=(s, s),
-                padding="VALID",
-                kernel_init=orthogonal_init(),
-                dtype=self.compute_dtype,
-                param_dtype=self.param_dtype,
-            )(x)
+        for i, (ch, k, s) in enumerate(
+            zip(self.channels, self.kernels, self.strides)
+        ):
+            if x.ndim == 5:
+                # a rollout's [T, B, H, W, C] is convolved where it lies:
+                # nn.Conv would flatten T and B into one batch, and they
+                # are not neighbours in the rollout's buffer
+                x = _FramesConv(
+                    ch, k, s, self.compute_dtype, self.param_dtype,
+                    name=f"Conv_{i}",
+                )(x)
+            else:
+                x = nn.Conv(
+                    ch,
+                    kernel_size=(k, k),
+                    strides=(s, s),
+                    padding="VALID",
+                    kernel_init=orthogonal_init(),
+                    dtype=self.compute_dtype,
+                    param_dtype=self.param_dtype,
+                )(x)
             x = act(x)
         x = x.reshape(*x.shape[:-3], -1)
         x = nn.Dense(
@@ -169,3 +182,51 @@ def make_trunk(model_cfg, hidden: Sequence[int]) -> nn.Module:
         param_dtype=param_dtype,
         use_fp8=use_fp8,
     )
+
+
+class _FramesConv(nn.Module):
+    """One of ``NatureCNN``'s convolutions over ``[A, B, H, W, C]`` with the
+    leading axes left apart: ``B`` is the batch and ``A`` a spatial axis of
+    kernel 1 and stride 1, which adds no term to any sum. Flattened to one
+    batch of ``A * B`` (what ``nn.Conv`` does) the frames of a rollout,
+    whose envs lie on the lanes with ``H, W, C`` between them and the steps,
+    are first written out in the compute dtype and transposed: 10.0 of
+    ``impala_pong_1k32``'s 68.3 ms (PERF.md section 6, PR 45). Here XLA
+    casts and scales them inside the convolution's read of the uint8
+    buffer, and the activations keep the buffer's order.
+
+    Declares ``nn.Conv``'s parameters (``kernel`` ``[k, k, in, out]``,
+    ``bias`` ``[out]``, its initialisers) and is named ``Conv_<i>`` by its
+    caller, so a model's tree is the same whichever rank initialised it.
+    """
+
+    features: int
+    kernel: int
+    stride: int
+    dtype: jnp.dtype
+    param_dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        k = self.kernel
+        kernel = self.param(
+            "kernel", orthogonal_init(), (k, k, x.shape[-1], self.features),
+            self.param_dtype,
+        )
+        bias = self.param(
+            "bias", nn.initializers.zeros_init(), (self.features,),
+            self.param_dtype,
+        )
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias, dtype=self.dtype)
+        y = jax.lax.conv_general_dilated(
+            x,
+            kernel[None],
+            window_strides=(1, self.stride, self.stride),
+            padding="VALID",
+            dimension_numbers=jax.lax.ConvDimensionNumbers(
+                lhs_spec=(1, 4, 0, 2, 3),
+                rhs_spec=(4, 3, 0, 1, 2),
+                out_spec=(1, 4, 0, 2, 3),
+            ),
+        )
+        return y + bias

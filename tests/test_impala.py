@@ -271,12 +271,18 @@ def test_successor_values_match_the_whole_next_obs_pass(case, kind):
 
 
 def _conv_batches(jaxpr, in_loop=False, out=None):
-    """[(batch size, inside a while loop)] of every convolution."""
+    """[(frames, inside a while loop)] of every convolution: its batch
+    size, times any spatial axis its kernel is 1 wide along (a rollout's
+    ``[T, B, ...]`` is convolved with ``T`` as such an axis)."""
     out = [] if out is None else out
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "conv_general_dilated":
-            lhs_batch_dim = eqn.params["dimension_numbers"].lhs_spec[0]
-            out.append((eqn.invars[0].aval.shape[lhs_batch_dim], in_loop))
+            dims = eqn.params["dimension_numbers"]
+            lhs, rhs = (v.aval.shape for v in eqn.invars)
+            frames = lhs[dims.lhs_spec[0]]
+            for l, r in zip(dims.lhs_spec[2:], dims.rhs_spec[2:]):
+                frames *= lhs[l] if rhs[r] == 1 else 1
+            out.append((frames, in_loop))
         for v in eqn.params.values():
             for sub in v if isinstance(v, (tuple, list)) else (v,):
                 sub = getattr(sub, "jaxpr", sub)

@@ -175,3 +175,70 @@ def test_trajectory_encoder_is_causal():
         rtol=1e-5, atol=1e-5,
     )
     assert not np.allclose(np.asarray(out2[:, T - 1]), np.asarray(out[:, T - 1]))
+
+
+def _nature_cnn():
+    from surreal_tpu.models import NatureCNN
+
+    return NatureCNN(compute_dtype=jnp.float32)
+
+
+def _frames(shape, dtype):
+    x = jax.random.randint(jax.random.key(1), shape, 0, 256)
+    return x.astype(jnp.uint8) if dtype == jnp.uint8 else x / 255.0
+
+
+@pytest.mark.parametrize("dtype", [jnp.uint8, jnp.float32], ids=["uint8", "float32"])
+@pytest.mark.parametrize("T,B", [(1, 3), (4, 2), (3, 1)])
+def test_nature_cnn_over_a_rollout_is_the_flattened_pass(T, B, dtype):
+    """A rank-5 ``[T, B, H, W, C]`` input keeps its leading axes through
+    the three convolutions (``_FramesConv``: ``B`` the batch, ``T`` a
+    spatial axis of kernel 1). Same parameters, same products, same sums
+    as the ``[T*B, H, W, C]`` pass: outputs and parameter gradients."""
+    cnn = _nature_cnn()
+    x = _frames((T, B, 84, 84, 4), dtype)
+    flat = x.reshape(T * B, 84, 84, 4)
+    params = cnn.init(jax.random.key(0), flat)
+    w = jax.random.normal(jax.random.key(2), (T, B, 512))
+
+    def loss(p, frames):
+        y = cnn.apply(p, frames)
+        return (y.reshape(T, B, 512) * w).sum(), y
+
+    (_, y5), g5 = jax.value_and_grad(loss, has_aux=True)(params, x)
+    (_, y4), g4 = jax.value_and_grad(loss, has_aux=True)(params, flat)
+    assert y5.shape == (T, B, 512)
+    np.testing.assert_allclose(y5, y4.reshape(T, B, 512), rtol=1e-5, atol=1e-5)
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(g5), jax.tree.leaves(g4)
+    ):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(
+            a, b, atol=1e-5 * scale, rtol=1e-4, err_msg=jax.tree_util.keystr(path)
+        )
+
+
+def test_nature_cnn_tree_is_the_same_from_either_rank():
+    """``init`` over a rollout yields the tree (paths, shapes, values) that
+    ``init`` over a batch of frames does, so a checkpoint written by one
+    restores into the other."""
+    cnn = _nature_cnn()
+    p4 = cnn.init(jax.random.key(0), jnp.zeros((2, 84, 84, 4), jnp.uint8))
+    p5 = cnn.init(jax.random.key(0), jnp.zeros((3, 2, 84, 84, 4), jnp.uint8))
+    shapes = {
+        jax.tree_util.keystr(k): v.shape
+        for k, v in jax.tree_util.tree_leaves_with_path(p4)
+    }
+    assert shapes == {
+        "['params']['Conv_0']['bias']": (32,),
+        "['params']['Conv_0']['kernel']": (8, 8, 4, 32),
+        "['params']['Conv_1']['bias']": (64,),
+        "['params']['Conv_1']['kernel']": (4, 4, 32, 64),
+        "['params']['Conv_2']['bias']": (64,),
+        "['params']['Conv_2']['kernel']": (3, 3, 64, 64),
+        "['params']['Dense_0']['bias']": (512,),
+        "['params']['Dense_0']['kernel']": (3136, 512),
+    }
+    assert jax.tree.structure(p4) == jax.tree.structure(p5)
+    for a, b in zip(jax.tree.leaves(p4), jax.tree.leaves(p5)):
+        np.testing.assert_array_equal(a, b)

@@ -104,8 +104,9 @@ def _scatter(capacity):
     return build
 
 
-def _fused_ppo(sds, envs=B, learner=None, horizon=T):
-    """Trainer's fused rollout+learn step at the headline geometry. The
+def _fused_step(sds, envs=B, learner=None, horizon=T, algo="ppo", env="jax:lift"):
+    """Trainer's fused rollout+learn step, PPO at the headline geometry
+    unless told otherwise. The
     test jits the step itself: ``Trainer._train_iter`` is built over this
     process's (CPU) devices."""
     from surreal_tpu.launch.rollout import init_device_carry
@@ -114,10 +115,10 @@ def _fused_ppo(sds, envs=B, learner=None, horizon=T):
     from surreal_tpu.session.default_configs import base_config
 
     cfg = Config(
-        learner_config=Config(algo=Config(name="ppo", horizon=horizon)).extend(
+        learner_config=Config(algo=Config(name=algo, horizon=horizon)).extend(
             learner or {}
         ),
-        env_config=Config(name="jax:lift", num_envs=envs),
+        env_config=Config(name=env, num_envs=envs),
         session_config=Config(folder="unused"),
     ).extend(base_config())
     trainer = Trainer(cfg)
@@ -147,7 +148,7 @@ CASES = [
         pytest.param(_scatter(cap), True, id=f"scatter-{cap}")
         for cap in (200_000, 1_000_000)
     ],
-    pytest.param(_fused_ppo, False, id="fused-ppo-4096x256"),
+    pytest.param(_fused_step, False, id="fused-ppo-4096x256"),
 ]
 
 
@@ -206,7 +207,7 @@ def test_ring_insert_compiles_to_window_writes(chip):
     assert mem.temp_size_in_bytes < 0.6e9
 
 
-def test_fused_ppo_65536x256_reads_blocks_in_place(sds):
+def test_fused_step_65536x256_reads_blocks_in_place(sds):
     """The fused PPO iteration at the geometry and overrides of
     ``ppo_lift_long`` (65 536 envs x 256, 64-64 tanh, ``mixed``): a
     minibatch's 64 blocks are each one time step of the rollout's own
@@ -229,7 +230,7 @@ def test_fused_ppo_65536x256_reads_blocks_in_place(sds):
         ),
         optimizer=Config(lr=3e-4),
     )
-    step, args = _fused_ppo(sds, envs=65536, learner=cell)
+    step, args = _fused_step(sds, envs=65536, learner=cell)
     compiled = step.lower(*args).compile()
     text = compiled.as_text()
     _, phases = hlo_op_phases(text)
@@ -242,6 +243,61 @@ def test_fused_ppo_65536x256_reads_blocks_in_place(sds):
     layouts = set(re.findall(r"bf16\[256,65536,17\]\{[^}]*\}", text))
     assert len(layouts) == 1, f"the obs leaf is relaid: {layouts}"
     assert compiled.memory_analysis().temp_size_in_bytes < 6.1e9
+
+
+def test_fused_impala_1024x32_convolves_the_frames_where_they_lie(sds):
+    """The fused IMPALA iteration at the geometry and overrides of
+    ``impala_pong_1k32`` (1024 envs x 32, ``jax:pong84``, the Nature CNN,
+    ``mixed``). The rollout's scan stacks the frames as
+    ``u8[32,1024,84,84,4]{1,4,3,2,0}``: envs on the lanes, ``H, W, C``
+    between them and the steps. ``learn``'s one pass convolves that buffer
+    as it lies (``models/encoders.py::_FramesConv``): the cast and the
+    ``/ 255`` are inside conv1's read of it. Flattened to a batch of
+    32 768, XLA first wrote the frames out in bfloat16 and transposed all
+    1.85 GB (PERF_LEDGER.jsonl, PR 44: ``multiply_bitcast_fusion`` and
+    ``copy.98 bf16[32,84,84,1,4,1024]``, 10.0 of the cell's 68.3 ms). The
+    names below are the ones the ledger's ``breakdown`` prints: this
+    compile is the chip's program (``hbm_program_temp_gb`` to the byte)."""
+    import math
+    import re
+
+    from surreal_tpu.session.config import Config
+
+    cell = Config(
+        algo=Config(
+            gamma=0.99, entropy_coeff=0.01, value_coeff=0.5, clip_rho=1.0,
+            clip_c=1.0, precision="mixed", vtrace_impl="xla",
+        ),
+        model=Config(cnn=Config(enabled=True)),
+    )
+    step, args = _fused_step(
+        sds, envs=VB, learner=cell, horizon=VT, algo="impala", env="jax:pong84"
+    )
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    frames = VT * VB * 84 * 84 * 4
+    whole = []
+    for line in entry:
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) == frames:
+            whole.append(line.strip()[:160])
+    assert not whole, f"the frames are written out in bfloat16: {whole}"
+    conv1 = [
+        line for line in entry
+        if f" = bf16[{VT},{VB},20,20,32]" in line
+        and "NatureCNN_0/Conv_0/conv_general_dilated" in line
+    ]
+    assert len(conv1) == 1, conv1
+    operands = re.search(r" fusion\(([^)]*)\)", conv1[0]).group(1).split(", ")
+    loop_out = re.compile(
+        rf"\s*(%[\w.\-]+) = u8\[{VT},{VB},84,84,4\]\S* get-tuple-element\(%while"
+    )
+    rollout = {m.group(1) for m in map(loop_out.match, entry) if m}
+    assert rollout & set(operands), (
+        f"conv1 does not read the rollout's buffer {rollout}: {conv1[0][:300]}"
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.45e9
 
 
 def _joyai_learner(num_layers: int, horizon: int = 128):
@@ -401,7 +457,7 @@ def test_laguna_iteration_fits_the_chip_with_each_layer_recomputed(sds):
         )),
         optimizer=Config(lr=3e-4),
     )
-    step, args = _fused_ppo(sds, envs=16, learner=cell, horizon=1024)
+    step, args = _fused_step(sds, envs=16, learner=cell, horizon=1024)
     assert sum(x.size for x in jax.tree.leaves(args[0].params)) == 734_014_473
     compiled = step.lower(*args).compile()
     mem = compiled.memory_analysis()
