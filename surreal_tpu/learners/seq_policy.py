@@ -101,7 +101,8 @@ class SequenceActingMixin(PolicyHeadMixin):
         # the cache's form is the model's own (models/attention.py
         # acting_cache): full keys and values for the 'preln' blocks, the
         # latent rows alone for 'mla_moe', state + ring + shared cache
-        # for 'ssm_hybrid', full caches + rings for 'swa_moe'
+        # for 'ssm_hybrid', full caches + rings for 'swa_moe', matrix
+        # states + conv tails + latent rows for 'kda_moe'
         return {
             "cache": self.model.init_cache(num_envs, T),
             "pos": jnp.zeros((), jnp.int32),
@@ -228,7 +229,7 @@ def build_seq_model(
     wide = family != "preln"   # a family at a published model's widths
     max_len = int(enc_cfg.get("max_len", 4096))
     # 'mla_moe' and 'swa_moe' have no learned positions (a rotary part
-    # takes any index) and 'ssm_hybrid' no positional term at all
+    # takes any index), 'ssm_hybrid' and 'kda_moe' no positional term at all
     if not wide and horizon is not None and int(horizon) + 1 > max_len:
         raise ValueError(
             f"algo.horizon={int(horizon)} needs model.encoder.max_len >= "

@@ -268,6 +268,7 @@ FAMILY_MODULES = {
     "mla_moe": "latent_moe",
     "ssm_hybrid": "ssm_hybrid",
     "swa_moe": "swa_moe",
+    "kda_moe": "kda_moe",
 }
 BLOCK_FAMILIES = ("preln", *FAMILY_MODULES)
 
@@ -282,6 +283,21 @@ def block_family(encoder_cfg) -> str:
             f"model.encoder.block {block!r} not in {'|'.join(BLOCK_FAMILIES)}"
         )
     return block
+
+
+def recomputed(layer, residual_bytes: int, above_bytes: int):
+    """The wide families' shape-driven recomputation rule, in one place
+    (``ssm_hybrid``, ``swa_moe`` and ``kda_moe`` read it, each with its own
+    estimate and threshold): ``layer`` (a function of arrays, or a flax module class) as it is while
+    the residuals a differentiated pass would keep (the family's own
+    estimate, from the pass's shapes; no key) stay within ``above_bytes``,
+    else wrapped so that the backward keeps the layer's input and recomputes
+    the rest, one layer at a time."""
+    if residual_bytes <= above_bytes:
+        return layer
+    if isinstance(layer, type) and issubclass(layer, nn.Module):
+        return nn.remat(layer)
+    return jax.checkpoint(layer)
 
 
 def family_named(name: str) -> Family:
@@ -322,7 +338,8 @@ def acting_cache(cfg, num_envs: int, horizon: int, dtype):
     path takes it: full keys and values a layer for 'preln', else the
     family's own (the latent rows alone for 'mla_moe'; a constant-size
     state, a ring that forgets and one shared cache for 'ssm_hybrid'; full
-    caches and rings of rotated keys for 'swa_moe').
+    caches and rings of rotated keys for 'swa_moe'; a matrix state and conv
+    tails a delta-rule layer beside latent rows for 'kda_moe').
     In the compute dtype, the attention math's own, so decode and the
     full-segment recompute round alike (precision policy,
     ops/precision.py); a recurrent state is float32."""

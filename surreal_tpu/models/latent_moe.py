@@ -24,6 +24,14 @@ in the latent space with ``W_kvb`` absorbed: ``q~_h = q_nope,h
 (W_kvb,h^K)^T``, scores ``(q~_h . c_kv + q_pe . k_pe) / sqrt(nope + rope)``,
 ``o_h = (P c_kv) W_kvb,h^V``. Nothing per head is held across steps.
 
+Two cases beside the published JoyAI-LLM-Flash one, both read from the
+layer's own ``cfg`` and used by ``models/kda_moe.py`` (a family that leaves
+the keys out; this family's :func:`resolve` always fills them):
+``q_lora_rank`` none projects the queries in one product ``q = x W_q`` (no
+low-rank pair, no query norm), and ``rope_theta`` none turns nothing
+(``k_pe`` is a plain shared key part, ``q_pe`` a plain query part), in the
+expanded pass and the absorbed decode alike.
+
 **FFN**: the first ``first_k_dense_replace`` layers a SwiGLU of
 ``intermediate_size``; the others the routed layer of ``ops/moe.py``, told
 which experts this chip holds (``first_held``, ``num_held`` of
@@ -76,6 +84,9 @@ INIT_STD = 0.02
 # key takes: jdopensource/JoyAI-LLM-Flash config.json, one chip of 16
 FAMILY_DEFAULTS = dict(
     hidden_size=2048,
+    # this family always has the query's low-rank pair and the rotary turn:
+    # LatentAttention's other two cases (q_lora_rank none, rope_theta none)
+    # are reached by a family that leaves the keys out (models/kda_moe.py)
     q_lora_rank=1536,
     kv_lora_rank=512,
     qk_nope_head_dim=128,
@@ -136,6 +147,11 @@ class RMSNorm(nn.Module):
 
 
 class LatentAttention(nn.Module):
+    """``cfg`` keys: hidden_size, num_heads, qk_nope_head_dim,
+    qk_rope_head_dim, v_head_dim, kv_lora_rank, rms_norm_eps; ``q_lora_rank``
+    (none or absent: one query product ``q``, else the pair ``q_a``, ``q_b``
+    around ``q_a_norm``) and ``rope_theta`` (none or absent: no rotary turn)."""
+
     cfg: dict
     dtype: Any = jnp.bfloat16
 
@@ -146,30 +162,40 @@ class LatentAttention(nn.Module):
         self.vd, self.lat = int(c["v_head_dim"]), int(c["kv_lora_rank"])
         init = nn.initializers.normal(INIT_STD)
         mat = lambda name, shape: self.param(name, init, shape, jnp.float32)
-        self.q_a = mat("q_a", (D, int(c["q_lora_rank"])))
-        self.q_b = mat("q_b", (int(c["q_lora_rank"]), H, self.nope + self.rot))
+        eps = float(c["rms_norm_eps"])
+        if c.get("q_lora_rank") is None:
+            self.q = mat("q", (D, H, self.nope + self.rot))
+        else:
+            self.q_a = mat("q_a", (D, int(c["q_lora_rank"])))
+            self.q_b = mat("q_b", (int(c["q_lora_rank"]), H, self.nope + self.rot))
+            self.q_norm = RMSNorm(eps, self.dtype, name="q_a_norm")
         self.kv_a = mat("kv_a", (D, self.lat + self.rot))
         self.kv_b = mat("kv_b", (self.lat, H, self.nope + self.vd))
         self.o = mat("o", (H, self.vd, D))
-        eps = float(c["rms_norm_eps"])
-        self.q_norm = RMSNorm(eps, self.dtype, name="q_a_norm")
         self.kv_norm = RMSNorm(eps, self.dtype, name="kv_a_norm")
 
     def _project(self, x, positions):
-        """``x [..., T, D]`` at ``positions [T]`` -> (``q_nope``, rotated
-        ``q_pe`` ``[..., T, H, .]``, normed ``c_kv [..., T, lat]``,
-        rotated ``k_pe [..., T, rot]``)."""
-        dt, theta = self.dtype, float(self.cfg["rope_theta"])
-        q = jnp.einsum(
-            "...r,rhd->...hd", self.q_norm(x @ self.q_a.astype(dt)),
-            self.q_b.astype(dt),
-        )
+        """``x [..., T, D]`` at ``positions [T]`` -> (``q_nope``, ``q_pe``
+        ``[..., T, H, .]``, normed ``c_kv [..., T, lat]``, ``k_pe [..., T,
+        rot]``), the two ``pe`` parts rotated where the layer has a
+        ``rope_theta``."""
+        dt, theta = self.dtype, self.cfg.get("rope_theta")
+        if self.cfg.get("q_lora_rank") is None:
+            q = jnp.einsum("...d,dhe->...he", x, self.q.astype(dt))
+        else:
+            q = jnp.einsum(
+                "...r,rhd->...hd", self.q_norm(x @ self.q_a.astype(dt)),
+                self.q_b.astype(dt),
+            )
         ckv = x @ self.kv_a.astype(dt)
+        turn = lambda part, heads=False: part if theta is None else rope(  # noqa: E731
+            part, positions, float(theta), heads=heads
+        )
         return (
             q[..., : self.nope],
-            rope(q[..., self.nope:], positions, theta, heads=True),
+            turn(q[..., self.nope:], heads=True),
             self.kv_norm(ckv[..., : self.lat]),
-            rope(ckv[..., self.lat:], positions, theta),
+            turn(ckv[..., self.lat:]),
         )
 
     def __call__(self, x):
@@ -189,7 +215,12 @@ class LatentAttention(nn.Module):
     def decode(self, x, cache, pos):
         """Absorbed path: one position ``x [B, D]`` against the latent
         cache ``[B, T, lat + rot]``; returns ``([B, D], cache)`` with row
-        ``pos`` written. Rows past ``pos`` are masked, whatever they hold."""
+        ``pos`` written. Rows past ``pos`` are masked: their weight is an
+        exact zero, so any finite values there (a wrapped segment's stale
+        rows) change nothing, and a NaN there is a NaN in the output. The
+        cache therefore starts as real zeros, handed in as an argument: the
+        chip's compiler leaves out zeros made inside the jit when ``pos`` is
+        a scan's own counter (tests/test_tpu_compile.py)."""
         dt = self.dtype
         q_nope, q_pe, c_kv, k_pe = self._project(x[:, None], pos[None])
         row = jnp.concatenate([c_kv, k_pe], -1).astype(cache.dtype)

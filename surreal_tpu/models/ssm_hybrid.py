@@ -55,7 +55,8 @@ takes bfloat16 operands; LayerNorm, the conv's sum, ``softplus``, ``exp(delta
 A)``, the state and the scan's accumulations, and the softmax are float32.
 
 **Recomputation.** Past :data:`REMAT_ABOVE_BYTES` of estimated residuals
-(from the pass's own shapes, no key) each layer is a ``jax.checkpoint``:
+(from the pass's own shapes, no key) each layer is a ``jax.checkpoint``
+(``models/attention.py::recomputed`` has the rule):
 the backward keeps a layer's input and recomputes the rest, one layer at a
 time. At 8192 tokens six layers' residuals are 6 GB beside 10 GB of
 parameters, gradients and Adam moments.
@@ -75,7 +76,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from surreal_tpu.models.attention import COUNTERS_COLLECTION, Family
+from surreal_tpu.models.attention import (
+    COUNTERS_COLLECTION, Family, recomputed,
+)
 from surreal_tpu.ops import moe
 from surreal_tpu.ops.ring_attention import _NEG_BIG, blocked_attention
 from surreal_tpu.ops.selective_scan import selective_scan, selective_step
@@ -441,17 +444,17 @@ def residual_bytes(cfg: dict, tokens: int) -> int:
     return tokens * per_token * len(layer_kinds(cfg))
 
 
-def forward(params: dict, x, cfg: dict, dt, remat: bool):
+def forward(params: dict, x, cfg: dict, dt, residual: int):
     """The learn pass: ``x [B, T, D]`` -> ``(x, stats)``; ``params`` is
-    ``{"layer<i>": leaves}``."""
+    ``{"layer<i>": leaves}``, ``residual`` the estimate the recomputation
+    rule reads."""
     s = _sizes(cfg)
     kept, stats = {}, []
     for i, (kind, keeps) in enumerate(layer_kinds(cfg)):
         def layer(p, x, kept, kind=kind, keeps=keeps):
             return _layer(kind, keeps, s, dt, p, x, kept)
 
-        if remat:
-            layer = jax.checkpoint(layer)
+        layer = recomputed(layer, residual, REMAT_ABOVE_BYTES)
         x, kept, st = layer(params[f"layer{i}"], x, kept)
         stats.append(st)
     states = [st["state_abs_max"] for st in stats if "state_abs_max" in st]
@@ -523,7 +526,7 @@ class SSMHybridTrunk(nn.Module):
         tokens = x.shape[0] * x.shape[1]
         x, stats = forward(
             params, x, c, dt,
-            remat=residual_bytes(c, tokens) > REMAT_ABOVE_BYTES,
+            residual=residual_bytes(c, tokens),
         )
         for name, value in stats.items():
             self.sow(COUNTERS_COLLECTION, name, value)

@@ -60,9 +60,9 @@ wrap to a new segment moves the position and the masks hide what is stale.
 
 **Recomputation.** Past :data:`REMAT_ABOVE_BYTES` of estimated residuals
 (:func:`residual_bytes`, from the pass's own shapes, no key) each layer is
-a ``jax.checkpoint``. At the published widths a 4096-token minibatch's
-five layers keep about 2 GB beside 11.7 GB of parameters, gradients and
-Adam moments.
+a ``jax.checkpoint`` (``models/attention.py::recomputed`` has the rule).
+At the published widths a 4096-token minibatch's five layers keep about
+2 GB beside 11.7 GB of parameters, gradients and Adam moments.
 
 Matrices initialise normal(0, ``INIT_STD``), norms 1 (the config gives no
 range).
@@ -80,6 +80,7 @@ import numpy as np
 
 from surreal_tpu.models.attention import (
     COUNTERS_COLLECTION, MOE_COLLECTION, ROUTING_COLLECTION, Family,
+    recomputed,
 )
 from surreal_tpu.models.ssm_hybrid import Leaves, _attend_one, _heads
 from surreal_tpu.ops import moe
@@ -412,10 +413,11 @@ def residual_bytes(cfg: dict, tokens: int) -> int:
     return int(total)
 
 
-def forward(params: dict, x, cfg: dict, dt, remat: bool):
+def forward(params: dict, x, cfg: dict, dt, residual: int):
     """The learn pass: ``x [B, T, D]`` -> ``(x, stats)``; ``params`` is
     ``{"layer<i>": leaves}``, ``stats`` the counters and, a list a routed
-    layer, what :func:`routed_ffn` reports."""
+    layer, what :func:`routed_ffn` reports; ``residual`` the estimate the
+    recomputation rule reads."""
     s = _sizes(cfg)
     seen, gates, routed = [], [], []
     for i, (kind, _) in enumerate(layer_kinds(cfg)):
@@ -425,8 +427,7 @@ def forward(params: dict, x, cfg: dict, dt, remat: bool):
             x, stats = _ffn(p, x + out, s, dt)
             return x, keys, gate, stats
 
-        if remat:
-            layer = jax.checkpoint(layer)
+        layer = recomputed(layer, residual, REMAT_ABOVE_BYTES)
         x, keys, gate, stats = layer(params[f"layer{i}"], x)
         gates.append(gate)
         if kind == "window":
@@ -497,7 +498,7 @@ class SwaMoETrunk(nn.Module):
         tokens = x.shape[0] * x.shape[1]
         x, stats = forward(
             params, x, c, dt,
-            remat=residual_bytes(c, tokens) > REMAT_ABOVE_BYTES,
+            residual=residual_bytes(c, tokens),
         )
         routed = stats.pop("routed")
         for name, value in stats.items():

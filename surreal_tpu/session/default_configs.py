@@ -92,6 +92,14 @@ BASE_LEARNER_CONFIG = Config(
             #   experts of which this chip holds a share beside a shared
             #   one; full caches and rings of rotated keys to act from.
             #   Laguna-S-2.1's widths where a key is None.
+            # 'kda_moe' (models/kda_moe.py): RMSNorm, Kimi Delta Attention
+            #   (a gated delta rule with a decay a channel over a matrix
+            #   state a head, a short conv before it) three layers to one
+            #   of latent attention without rotary, a leading dense SwiGLU
+            #   layer, then sigmoid-routed experts of which this chip holds
+            #   a share beside a shared one; constant-size states and conv
+            #   tails beside a latent cache to act from.
+            #   Kimi-Linear-48B-A3B-Instruct's widths where a key is None.
             # All read kind, block, num_heads, act_impl, and num_layers
             # ('ssm_hybrid': pairs_before, pairs_after instead).
             block="preln",
@@ -148,6 +156,12 @@ BASE_LEARNER_CONFIG = Config(
             window_heads=None,             # a sliding layer's query heads
             attn_head_dim=None,            # a head's size (not hidden / heads)
             shared_intermediate_size=None, # the shared expert's width
+            # -- 'kda_moe' only (it reads 'mla_moe''s keys above too, but
+            # q_lora_rank and rope_theta: its latent layers have neither;
+            # None = the published Kimi-Linear-48B-A3B-Instruct value,
+            # FAMILY_DEFAULTS in models/kda_moe.py) --------------------------
+            kda_head_dim=None,             # a delta-rule head's keys and values
+            short_conv_kernel_size=None,   # taps of the conv before the rule
         ),
         cnn=Config(
             enabled=False,          # pixel observations -> Nature-CNN stem

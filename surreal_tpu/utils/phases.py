@@ -34,7 +34,7 @@ An op outside every scope is ``unattributed``.
 Phases say *when* in the iteration an op runs. A second, short
 vocabulary of **parts** says *which part of the model* it belongs to, for
 a trunk large enough that this is the question (``models/latent_moe.py``,
-``models/ssm_hybrid.py``, ``models/swa_moe.py``).
+``models/ssm_hybrid.py``, ``models/swa_moe.py``, ``models/kda_moe.py``).
 A part's scope sits inside whatever phase runs the model, so an op has
 one phase and at most one part, and the digest sums each on its own:
 
@@ -57,6 +57,12 @@ one phase and at most one part, and the digest sums each on its own:
                  projection, in the learn pass and against the ring
     attn_full    the same of its full layers (48 heads, YaRN frequencies
                  on half the head), against the whole segment or cache
+    kda_scan     a Kimi Delta Attention layer's (``models/kda_moe.py``)
+                 convs, SiLU, the L2 norms, the decay's ``softplus`` and
+                 ``exp``, the chunked delta rule (or an acting step of it),
+                 the output norm and gate
+    kda_proj     its products: q, k, v, the decay's and the gate's low-rank
+                 pairs, ``beta``, the output projection
 """
 
 from __future__ import annotations
@@ -69,6 +75,7 @@ PHASES = (
 PARTS = (
     "attn", "moe_route", "moe_experts", "dense_ffn", "optimizer",
     "ssm_scan", "ssm_proj", "gmu", "attn_window", "attn_full",
+    "kda_scan", "kda_proj",
 )
 UNATTRIBUTED = "unattributed"
 _VOCABULARY = frozenset(PHASES)

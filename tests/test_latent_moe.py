@@ -397,3 +397,114 @@ def test_the_compiled_program_names_every_part():
     both = [phases[i] for i, p in parts.items() if p == "optimizer" and i in phases]
     # (XLA fuses a few of them into an op it names after a neighbour)
     assert both and max(set(both), key=both.count) == "sgd"
+
+
+# -- LatentAttention's other two cases (models/kda_moe.py's latent layers) ------
+
+_ATTN = dict(
+    hidden_size=32, num_heads=2, kv_lora_rank=16, qk_nope_head_dim=8,
+    qk_rope_head_dim=4, v_head_dim=8, rms_norm_eps=1e-6,
+)
+
+
+@pytest.mark.parametrize("q_lora_rank", [None, 24])
+@pytest.mark.parametrize("rope_theta", [None, 1e4])
+def test_expanded_pass_equals_absorbed_decode_in_every_case(q_lora_rank, rope_theta):
+    """With or without the query's low-rank pair, with or without the rotary
+    turn (a key that is none or absent): one position at a time through the
+    latent cache with ``W_kvb`` absorbed is the expanded whole-segment pass,
+    and the parameters are the case's own."""
+    cfg = dict(_ATTN)
+    if q_lora_rank is not None:
+        cfg["q_lora_rank"] = q_lora_rank
+    if rope_theta is not None:
+        cfg["rope_theta"] = rope_theta
+    attn = latent_moe.LatentAttention(cfg, jnp.float32)
+    x = jax.random.normal(jax.random.key(0), (3, 9, 32))
+    params = attn.init(jax.random.key(1), x)
+    names = set(params["params"])
+    assert ("q" in names) == (q_lora_rank is None)
+    assert ({"q_a", "q_b", "q_a_norm"} <= names) == (q_lora_rank is not None)
+    with jax.default_matmul_precision("highest"):
+        whole = attn.apply(params, x)
+        cache = jnp.zeros((3, 9, 16 + 4))
+        outs = []
+        for t in range(9):
+            out, cache = attn.apply(
+                params, x[:, t], cache, jnp.int32(t), method=attn.decode
+            )
+            outs.append(out)
+        # a layer without the turn does not see positions: the same segment
+        # two places later in the cache gives the same outputs
+        if rope_theta is None:
+            late = jnp.zeros((3, 11, 20)).at[:, :2].set(cache[:, :2])
+            for t in range(2, 4):
+                shifted, late = attn.apply(
+                    params, x[:, t], late, jnp.int32(t), method=attn.decode
+                )
+            np.testing.assert_allclose(shifted, outs[3], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(jnp.stack(outs, 1), whole, rtol=2e-5, atol=2e-6)
+
+
+# sha256 (16 hex) of the StableHLO of the published JoyAI case at _ATTN's
+# widths, recorded once on PR 46's parent (jax 0.9.0), where LatentAttention
+# had that one case: the whole-segment pass with its gradient, and the
+# absorbed decode step
+_JOYAI_ATTENTION_ON_THE_PARENT = ("352a2d227a78bd68", "8e16ba876dee6c80")
+
+
+def test_joyais_attention_lowers_to_the_program_it_had():
+    """The published JoyAI case (``q_lora_rank`` and ``rope_theta`` set) is
+    unchanged by the two new cases: the whole-segment pass with its gradient
+    and the absorbed decode step lower to the parent's StableHLO, to the
+    byte. (The builder of PR 46 compared the whole ``learn`` and ``act_step``
+    of the toy learner above on the parent's checkout and this one:
+    identical.)"""
+    import hashlib
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the hashes were recorded under jax 0.9.0")
+    cfg = dict(_ATTN, q_lora_rank=24, rope_theta=32e6)
+    x = jax.ShapeDtypeStruct((3, 9, 32), jnp.bfloat16)
+    attn = latent_moe.LatentAttention(cfg)
+    params = jax.eval_shape(attn.init, jax.random.key(0), x)
+
+    def loss(p, x):
+        return attn.apply(p, x).astype(jnp.float32).sum()
+
+    def step(p, x, c, t):
+        return attn.apply(p, x, c, t, method=attn.decode)
+
+    texts = (
+        jax.jit(jax.grad(loss)).lower(params, x).as_text(),
+        jax.jit(step).lower(
+            params, jax.ShapeDtypeStruct((3, 32), jnp.bfloat16),
+            jax.ShapeDtypeStruct((3, 9, 20), jnp.bfloat16),
+            jax.ShapeDtypeStruct((), jnp.int32),
+        ).as_text(),
+    )
+    assert tuple(
+        hashlib.sha256(t.encode()).hexdigest()[:16] for t in texts
+    ) == _JOYAI_ATTENTION_ON_THE_PARENT
+    assert "cosine" in texts[0] and texts[0].count("dot_general") >= 10
+
+
+def test_a_masked_row_of_the_cache_has_to_be_finite():
+    """What the decode path's mask promises, and what it does not: rows past
+    ``pos`` are weighted by an exact zero, so whatever finite values they
+    hold (a wrapped segment's stale rows) change nothing; a NaN there is a
+    NaN in the output all the same, so the cache has to start as real zeros
+    (tests/test_tpu_compile.py has the case in which the chip's compiler
+    leaves them out)."""
+    attn = latent_moe.LatentAttention(dict(_ATTN), jnp.float32)
+    x = jax.random.normal(jax.random.key(0), (3, 32))
+    params = attn.init(jax.random.key(1), x[:, None])
+    step = lambda cache: attn.apply(   # noqa: E731
+        params, x, cache, jnp.int32(2), method=attn.decode
+    )[0]
+    clean = step(jnp.zeros((3, 9, 20)))
+    stale = step(jnp.zeros((3, 9, 20)).at[:, 3:].set(7.0))
+    np.testing.assert_array_equal(stale, clean)
+    poisoned = step(jnp.zeros((3, 9, 20)).at[1, 5].set(jnp.nan))
+    assert bool(jnp.isnan(poisoned[1]).all())
+    assert not bool(jnp.isnan(poisoned[::2]).any())

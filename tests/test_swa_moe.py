@@ -347,7 +347,9 @@ def test_route_softmax_takes_the_largest_and_normalises_over_them():
 def test_the_table_has_one_entry_a_family_and_the_selectors_read_it():
     from surreal_tpu.models import attention, latent_moe, ssm_hybrid
 
-    assert attention.BLOCK_FAMILIES == ("preln", "mla_moe", "ssm_hybrid", "swa_moe")
+    assert attention.BLOCK_FAMILIES == (
+        "preln", "mla_moe", "ssm_hybrid", "swa_moe", "kda_moe",
+    )
     assert attention.family_of({"block": "preln"}) is None
     assert attention.family_of({}) is None
     for name, module in (

@@ -510,3 +510,127 @@ def test_laguna_check_asks_the_program_for_its_experts_inside_highest(chip):
     )
     with jax.default_matmul_precision("highest"):
         choose.lower(params, obs).compile()
+
+
+def test_kimilinear_iteration_fits_the_chip_with_each_layer_recomputed(sds):
+    """The fused iteration of ``ppo_lift_kimilinear_16x1024`` (16 envs x 1024,
+    2 x 2 minibatches of 8192 tokens, the family's published widths, layer 1
+    and the period after it: 508M parameters, 8.1 GB of state) compiles for
+    the v5e inside its 16.9 GB: each layer is recomputed in the backward
+    (models/attention.py::recomputed, from the shapes), the delta rule keeps
+    chunk starts and not every state (ops/delta_rule.py: ``[1024, 8, 32, 128,
+    128]`` float32 would be 17 GB), and the acting scan carries four matrix
+    states and their conv tails beside one latent cache."""
+    import re
+
+    from surreal_tpu.session.config import Config
+
+    cell = Config(
+        algo=Config(
+            epochs=2, num_minibatches=2, precision="mixed", clip_ratio=0.2,
+        ),
+        model=Config(encoder=Config(
+            kind="trajectory", block="kda_moe", num_heads=32, num_layers=5,
+        )),
+        optimizer=Config(lr=3e-4),
+    )
+    step, args = _fused_step(sds, envs=16, learner=cell, horizon=1024)
+    # the layers' 508 060 288, the projection in, the last norm and the heads
+    assert sum(x.size for x in jax.tree.leaves(args[0].params)) == (
+        508_060_288 + 17 * 2304 + 2304 + 2304 * 5 + 5 + 4
+    )
+    compiled = step.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = (
+        mem.temp_size_in_bytes + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert held < 13.0e9, held          # 12.35 GB when this was written
+    text = compiled.as_text()
+    # no pass keeps a state a position
+    assert not re.search(r"f32\[(1024|1025|1088),\d+,32,128,128\]", text)
+    # the acting loop's carry: the matrix states, the tails, the latent rows
+    loops = [line.split(" while(")[0] for line in text.splitlines()
+             if " while(" in line and "f32[16,32,128,128]" in line]
+    assert any(
+        "bf16[16,3,32,128]" in c and "bf16[16,1024,576]" in c for c in loops
+    )
+    assert "agged" in text
+
+
+@pytest.mark.parametrize("carry_is", [
+    "an_argument",
+    pytest.param("a_constant", marks=pytest.mark.xfail(
+        reason="XLA:TPU (libtpu 0.0.34) takes the latent cache for a scan's "
+        "output when it starts as zeros inside the jit and `pos` is the "
+        "scan's own counter, and allocates it without writing the zeros; "
+        "rows past `pos` then hold whatever the memory held, and a masked "
+        "row's zero weight times a NaN is a NaN (seen on the chip at 1024 "
+        "positions: PERF.md section 7, PR 46)",
+        strict=False,
+    )),
+])
+def test_a_decode_scan_reads_a_latent_cache_that_was_zeroed(chip, carry_is):
+    """A scan of the bare decode path (``model.apply(cache=, pos=)``) over a
+    segment, ``kda_moe`` at toy widths, compiled for the v5e: the latent
+    cache the loop reads whole at every step has to hold the zeros
+    ``act_init`` gave it. With the carry handed in (``act_step``'s rollout,
+    the reference's replay) it does. With ``act_init`` called inside the jit
+    and no reset between the steps the compiler drops the zeros for an
+    ``AllocateBuffer``: the one decode path of the product never does that,
+    and a second caller must not either."""
+    import re
+
+    import numpy as np
+
+    from surreal_tpu.envs.base import ArraySpec, EnvSpecs
+    from surreal_tpu.learners import build_learner
+    from surreal_tpu.session.config import Config
+
+    envs, T = 4, 24
+    specs = EnvSpecs(
+        obs=ArraySpec(shape=(17,), dtype=np.dtype(np.float32)),
+        action=ArraySpec(shape=(4,), dtype=np.dtype(np.float32)),
+    )
+    learner = build_learner(Config(
+        algo=Config(name="ppo", horizon=T, epochs=2, num_minibatches=2,
+                    precision="mixed"),
+        model=Config(encoder=Config(
+            kind="trajectory", block="kda_moe", num_layers=5, num_heads=2,
+            hidden_size=32, kda_head_dim=8, kv_lora_rank=16,
+            qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=8,
+            intermediate_size=64, moe_intermediate_size=16,
+            n_routed_experts=8, num_experts_per_tok=2, num_held=2,
+        )),
+    ), specs)
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+    )
+    state = on_chip(jax.eval_shape(learner.init, jax.random.key(0)))
+    obs = jax.ShapeDtypeStruct((T, envs, 17), jnp.float32, sharding=chip)
+
+    def step(state, carry, o):
+        out, cache = learner.model.apply(
+            state.params, learner._norm_obs(state.obs_stats, o),
+            cache=carry["cache"], pos=carry["pos"],
+        )
+        return {"cache": cache, "pos": carry["pos"] + 1}, out.mean
+
+    if carry_is == "an_argument":
+        scan = jax.jit(lambda s, c, o: jax.lax.scan(
+            lambda c, x: step(s, c, x), c, o
+        ))
+        carry = on_chip(jax.eval_shape(lambda: learner.act_init(envs)))
+        text = scan.lower(state, carry, obs).compile().as_text()
+    else:
+        scan = jax.jit(lambda s, o: jax.lax.scan(
+            lambda c, x: step(s, c, x), learner.act_init(envs), o
+        ))
+        text = scan.lower(state, obs).compile().as_text()
+    rows = re.escape(f"bf16[{envs},{T},20]")        # kv_lora 16 + rope 4
+    assert re.search(rows, text), "no latent cache in the program"
+    assert not [
+        line for line in text.splitlines()
+        if re.search(rf"= {rows}\S* custom-call\(\)", line)
+        and "AllocateBuffer" in line
+    ]
