@@ -81,7 +81,7 @@ from surreal_tpu.models.latent_moe import (
 # log-uniform in [1e-3, 1e-1], conv taps uniform in +-1/sqrt(taps)
 from surreal_tpu.models.ssm_hybrid import _conv_init, _dt_bias_init
 from surreal_tpu.ops import moe
-from surreal_tpu.ops.delta_rule import delta_rule, delta_step
+from surreal_tpu.ops.delta_rule import delta_rule, delta_step, gram_in_vmem
 from surreal_tpu.utils.phases import part
 
 BLOCK = "kda_moe"
@@ -124,11 +124,15 @@ FAMILY_DEFAULTS = dict(
 # row, how the row reduces it over an iteration's minibatch steps)}``): the
 # largest entry of a matrix state a segment ended with (a rule that blows up
 # shows before the loss does), the mean decay a channel a step (1 forgets
-# nothing) and the mean share of a key's content a step rewrites
+# nothing), the mean share of a key's content a step rewrites, and which form
+# of the rule's Gram matrices ran (1 the kernel that keeps a chunk's pairwise
+# decays in VMEM, 0 the ``lax`` form: ops/delta_rule.py chooses from the
+# device and the shapes)
 COUNTERS = {
     "state_abs_max": ("kda/state_abs_max", "max"),
     "decay_mean": ("kda/decay_mean", "mean"),
     "beta_mean": ("kda/beta_mean", "mean"),
+    "gram_in_vmem": ("kda/gram_in_vmem", "mean"),
 }
 
 
@@ -252,6 +256,7 @@ class DeltaAttention(nn.Module):
             stats = {
                 "state_abs_max": jnp.abs(state).max(),
                 "decay_mean": jnp.exp(g).mean(), "beta_mean": beta.mean(),
+                "gram_in_vmem": gram_in_vmem(q),
             }
         return self._out(o, gate), stats
 
@@ -387,7 +392,7 @@ class KDAMoETrunk(nn.Module):
                 stats.append(st)
         pick = lambda name: jnp.stack([st[name] for st in stats])  # noqa: E731
         self.sow(COUNTERS_COLLECTION, "state_abs_max", pick("state_abs_max").max())
-        for name in ("decay_mean", "beta_mean"):
+        for name in ("decay_mean", "beta_mean", "gram_in_vmem"):
             self.sow(COUNTERS_COLLECTION, name, pick(name).mean())
         return norm(x)
 

@@ -1,7 +1,10 @@
 """ops/delta_rule.py: the chunked gated delta rule against the recurrence a
 position at a time (values and, through the ``custom_vjp``, gradients), a
 length that is no multiple of the chunk, steps against a scan, and decays at
-both ends of (0, 1)."""
+both ends of (0, 1); the Gram kernels (interpreted) against the ``lax`` form
+they stand in for on the chip."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -139,3 +142,96 @@ def test_chunk_must_hold_whole_blocks(monkeypatch):
     q, k, v, g, beta, _ = _inputs(6, 1, 8, 1, 4, 4)
     with pytest.raises(ValueError, match="multiple"):
         delta_rule(q, k, v, g, beta)
+
+
+# -- the decayed Gram matrices: the kernels against the ``lax`` form -----------
+
+def _gram_inputs(log_decay, K=128, chunks=2, heads=3):
+    """``q, k, G [chunks, heads, CHUNK, K]`` (the chunks as batch rows) and a
+    cotangent for the Gram; ``log_decay`` a step where it is not ``None``."""
+    q, k, _, g, _, _ = _inputs(11, chunks, CHUNK, heads, K, K)
+    if log_decay is not None:
+        g = jnp.full_like(g, log_decay)
+    q, k, g = (jnp.moveaxis(x, 1, 2) for x in (q, k, g))
+    d = jax.random.normal(jax.random.key(12), q.shape[:-1] + (2 * CHUNK,))
+    return q, k, jnp.cumsum(g, axis=2), d
+
+
+@functools.cache
+def _gram_forms(cd):
+    """``(kernels, lax form)``, each ``q, k, G, d -> (gram, (dq, dk, dG))``,
+    jitted once for every case."""
+    def kernels(q, k, G, d):
+        return (
+            delta_rule_module._gram_pallas(q, k, G, cd, interpret=True),
+            delta_rule_module._gram_pallas_bwd(q, k, G, d, cd, interpret=True),
+        )
+
+    def lax_form(q, k, G, d):
+        gram, vjp = jax.vjp(
+            functools.partial(delta_rule_module._gram_lax, cd=cd), q, k, G
+        )
+        return gram, vjp(d)
+
+    return jax.jit(kernels), jax.jit(lax_form)
+
+
+@pytest.mark.parametrize("log_decay", [None, -100.0, 0.0])
+def test_gram_kernels_are_the_lax_form(log_decay):
+    """Both matrices and ``dq, dk, dG`` of the kernels (interpreted) against
+    the ``lax`` form and its ``jax.vjp`` at a shape the kernel accepts:
+    decays as a layer gives them, ``e^-100`` a step (every decay between two
+    positions underflows to an exact 0) and none (ties in every clamp)."""
+    x = _gram_inputs(log_decay)
+    kernels, lax_form = _gram_forms(jnp.float32)
+    (gram, grads), (gram_ref, grads_ref) = kernels(*x), lax_form(*x)
+    assert gram.shape == x[0].shape[:-1] + (2 * CHUNK,)
+    assert all(bool(jnp.isfinite(a).all()) for a in (gram, *grads))
+    np.testing.assert_allclose(gram, gram_ref, rtol=1e-5, atol=1e-6)
+    for name, a, b in zip(("dq", "dk", "dG"), grads, grads_ref):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-5 * max(float(jnp.abs(b).max()), 0.1),
+            err_msg=name,   # dG cancels to 0 where every decay underflows
+        )
+    if log_decay == -100.0:
+        # what is left is each position on itself: the two diagonals
+        both = jnp.concatenate([jnp.eye(CHUNK, dtype=bool)] * 2, axis=1)
+        assert not bool(jnp.where(both, 0.0, gram).any())
+
+
+def test_gram_kernel_rounds_the_products_between_blocks_only():
+    """With ``v`` in bfloat16 the products between blocks take bfloat16
+    operands in the kernel as in the ``lax`` form (the same roundings, so the
+    matrices agree to float32's precision), and a row's own block stays
+    float32."""
+    x = _gram_inputs(None, heads=1, chunks=1)
+    kernels, lax_form = _gram_forms(jnp.bfloat16)
+    (gram, grads), (gram_ref, grads_ref) = kernels(*x), lax_form(*x)
+    np.testing.assert_allclose(gram, gram_ref, rtol=1e-5, atol=1e-6)
+    exact = _gram_forms(jnp.float32)[1](*x)[0]
+    own = jnp.kron(jnp.eye(CHUNK // SUB), jnp.ones((SUB, SUB))) > 0
+    own = jnp.concatenate([own, own], axis=1)
+    np.testing.assert_allclose(
+        jnp.where(own, gram, 0.0), jnp.where(own, exact, 0.0),
+        rtol=1e-5, atol=1e-6,
+    )
+    # the lax form's autodiff rounds each operand's cotangent to bfloat16
+    for a, b in zip(grads, grads_ref):
+        assert float(jnp.abs(a - b).max()) < 1e-2 * float(jnp.abs(b).max())
+
+
+@pytest.mark.parametrize("K, asks", [(8, False), (128, True)])
+def test_the_shapes_choose_the_form(K, asks):
+    """Heads of 8 channels never ask for the kernel; heads of 128 ask where
+    the program is lowered for a TPU, and on the CPU the ``lax`` form is what
+    runs there too (tests/test_tpu_compile.py sees the kernel chosen)."""
+    q, k, v, g, beta, state = _inputs(13, 1, CHUNK, 2, K, K)
+    text = str(jax.make_jaxpr(delta_rule)(q, k, v, g, beta, state))
+    assert ("pallas_call" in text) == asks
+    assert float(jax.jit(delta_rule_module.gram_in_vmem)(q)) == 0.0
+    if not asks:    # every other test of this file runs heads of 8 or 16
+        return
+    o, S = jax.jit(delta_rule)(q, k, v, g, beta, state)
+    o_ref, S_ref = jax.jit(recurrence)(q, k, v, g, beta, state)
+    np.testing.assert_allclose(o, o_ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(S, S_ref, rtol=2e-5, atol=2e-5)

@@ -558,6 +558,32 @@ def test_kimilinear_iteration_fits_the_chip_with_each_layer_recomputed(sds):
     assert "agged" in text
 
 
+def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
+    """``jax.grad`` of the delta rule at a learn pass's shapes (8 rows of
+    1024 positions, 32 heads of 128 channels, ``v`` in bfloat16), compiled
+    for the v5e in this CPU process: the lowering takes the two Gram kernels
+    (ops/delta_rule.py chooses from the device it lowers for and the shapes),
+    Mosaic accepts both, and no array of a chunk step's pairwise decays is
+    left in the program."""
+    import re
+
+    from surreal_tpu.ops.delta_rule import delta_rule
+
+    def loss(q, k, v, g, beta):
+        o, state = delta_rule(q, k, v, g, beta)
+        return (o * o).sum() + (state * state).sum()
+
+    wide = (8, 1024, 32, 128)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds(wide, jnp.float32), sds(wide, jnp.float32), sds(wide, jnp.bfloat16),
+        sds(wide, jnp.float32), sds(wide[:3], jnp.float32),
+    ).compile().as_text()
+    calls = re.findall(r"%(decayed_gram(?:_bwd)?)[.\d]* = [^\n]*tpu_custom_call", text)
+    # the forward's, the backward's recomputed chunk's, and the cotangents'
+    assert sorted(calls) == ["decayed_gram", "decayed_gram", "decayed_gram_bwd"]
+    assert not re.search(r"f32\[8,32,4,16,16,128\]", text)
+
+
 @pytest.mark.parametrize("carry_is", [
     "an_argument",
     pytest.param("a_constant", marks=pytest.mark.xfail(
