@@ -69,9 +69,9 @@ def device_rollout(
 ):
     """Collect ``horizon`` steps across B batched on-device envs.
 
-    Returns (new_carry, batch) — batch has the learner batch contract plus
-    ``ep_return``/``ep_done`` for metrics. Pure; callers jit it (usually
-    fused with ``learner.learn``).
+    Returns (new_carry, batch): the learner batch contract, ``ep_return``/
+    ``ep_done`` for metrics, ``acting`` (the rows ``learner.act_rows`` reads
+    off the acting carry). Pure; callers jit it (fused with ``learn``).
 
     ``unroll`` is the rollout scan's unroll factor (``algo.rollout_unroll``
     — a searched autotuner dimension, surreal_tpu/tune/space.py): the
@@ -121,11 +121,11 @@ def device_rollout(
     # memoryless learners get None, which scans as an empty pytree
     with phase("collect"):
         keys = jax.random.split(key, horizon)
-        (new_carry, _), batch = jax.lax.scan(
+        (new_carry, act_carry), batch = jax.lax.scan(
             step, (carry, learner.act_init(carry.obs.shape[0])), keys,
             unroll=max(1, min(int(unroll), horizon)),
         )
-    return new_carry, batch
+    return new_carry, dict(batch, acting=learner.act_rows(act_carry))
 
 
 def init_device_carry(env: AutoReset, key: jax.Array, num_envs: int) -> RolloutCarry:

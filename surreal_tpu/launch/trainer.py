@@ -211,7 +211,7 @@ class Trainer:
                 n_done > 0, ep_return_sum / jnp.maximum(n_done, 1), jnp.nan
             )
             metrics["episode/count"] = n_done.astype(jnp.float32)
-        return state, carry, metrics
+        return state, carry, metrics | _over_mesh(batch["acting"], axis_name)
 
     def init_loop_state(self, env_key: jax.Array) -> RolloutCarry:
         """Device-mode rollout carry committed to the active mesh — ONE
@@ -564,3 +564,11 @@ class Trainer:
                     break
             collector.join(timeout=30)
         return state, iteration, env_steps
+
+
+def _over_mesh(rows: dict, axis_name) -> dict:
+    """Metrics rows a rollout counted on its own shard (``batch["acting"]``),
+    averaged over the mesh axis the iteration runs under; as they are
+    without one. (Down here so that no traced line above moves: the compile
+    cache's key holds them.)"""
+    return rows if axis_name is None else jax.lax.pmean(rows, axis_name)

@@ -245,6 +245,12 @@ class Learner(abc.ABC):
         action, info = self.act(state, obs, key, mode)
         return action, info, act_carry
 
+    def act_rows(self, act_carry: Any) -> dict:
+        """``{metrics row: scalar}`` that the acting steps behind
+        ``act_carry`` counted into it (nothing for most learners): a fused
+        rollout reads it where it ends, and the iteration's row takes it."""
+        return {}
+
     # -- bookkeeping ---------------------------------------------------------
     def default_config(self):  # override per algorithm
         raise NotImplementedError

@@ -162,6 +162,17 @@ class SequenceActingMixin(PolicyHeadMixin):
         action, info = self._head_act(out_t, key, mode)
         return action, info, {"buf": buf, "pos": pos + 1}
 
+    def act_rows(self, act_carry) -> dict:
+        """What a routed family's acting steps tallied in the carry's cache
+        (``ops/moe.py::acting_rows``); nothing from a cache without the
+        tally."""
+        from surreal_tpu.ops import moe
+
+        cache = act_carry.get("cache") if self.seq_policy else None
+        if isinstance(cache, dict) and moe.EXPERTS_READ in cache:
+            return moe.acting_rows(cache)
+        return {}
+
 
 # model.encoder keys only the 'preln' blocks read (their defaults are
 # session/default_configs.py's). A family of models/attention.py's table

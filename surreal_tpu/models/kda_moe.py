@@ -279,7 +279,8 @@ class DeltaAttention(nn.Module):
 class Block(nn.Module):
     """One layer: ``__call__`` over a whole segment -> ``(x, the KDA
     counters or {})``; :meth:`decode` one position against the layer's leaf
-    of the carry -> ``(x, new leaf)``."""
+    of the carry -> ``(x, the routed experts' read share or None, new
+    leaf)``."""
 
     cfg: dict
     kind: str
@@ -301,10 +302,12 @@ class Block(nn.Module):
             self.moe = RoutedExperts(c, self.dtype)
 
     def _ffn(self, x):
+        """``(x + FFN(RMSNorm(x)), the routed experts' read share or None)``."""
         if self.dense:
             with part("dense_ffn"):
-                return x + self.ffn(self.ffn_norm(x))
-        return x + self.moe(self.ffn_norm(x))
+                return x + self.ffn(self.ffn_norm(x)), None
+        y, read = self.moe(self.ffn_norm(x))
+        return x + y, read
 
     def __call__(self, x):
         if self.kind == "kda":
@@ -312,7 +315,7 @@ class Block(nn.Module):
         else:
             with part("attn"):
                 out, stats = self.attn(self.attn_norm(x)), {}
-        return self._ffn(x + out), stats
+        return self._ffn(x + out)[0], stats
 
     def decode(self, x, leaf, pos):
         if self.kind == "kda":
@@ -320,7 +323,7 @@ class Block(nn.Module):
         else:
             with part("attn"):
                 out, leaf = self.attn.decode(self.attn_norm(x), leaf, pos)
-        return self._ffn(x + out), leaf
+        return *self._ffn(x + out), leaf
 
 
 def residual_bytes(cfg: dict, tokens: int) -> int:
@@ -375,13 +378,16 @@ class KDAMoETrunk(nn.Module):
         if cache is not None:
             new = {"kda": list(cache["kda"]), "latent": list(cache["latent"])}
             seen = {"kda": 0, "latent": 0}
+            reads = []
             for i, (kind, dense) in enumerate(kinds):
                 n = seen[kind]
-                x, new[kind][n] = Block(c, kind, dense, dt, name=f"layer{i}").decode(
-                    x, new[kind][n], pos
-                )
+                x, read, new[kind][n] = Block(
+                    c, kind, dense, dt, name=f"layer{i}"
+                ).decode(x, new[kind][n], pos)
                 seen[kind] = n + 1
-            return norm(x), new
+                reads.append(read)
+            tally = moe.count_reads(cache[moe.EXPERTS_READ], reads)
+            return norm(x), {**new, moe.EXPERTS_READ: tally}
         block = recomputed(
             Block, residual_bytes(c, x.shape[0] * x.shape[1]), REMAT_ABOVE_BYTES
         )
@@ -401,7 +407,9 @@ def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
     """The acting carry's cache, two kinds side by side: ``{"kda": [{"state"
     [envs, H, K, K] float32, "conv": {"q", "k", "v" [envs, taps - 1, H, K]}},
     ...], "latent": [[envs, horizon, kv_lora + rope], ...]}``, a leaf a layer
-    of the kind; the conv tails and the latent rows in the compute dtype."""
+    of the kind; the conv tails and the latent rows in the compute dtype.
+    Beside them the routed layers' tally of the experts they read
+    (``ops/moe.py::no_reads``)."""
     H, K = int(cfg["num_heads"]), int(cfg["kda_head_dim"])
     taps = int(cfg["short_conv_kernel_size"])
     kinds = [k for k, _ in layer_kinds(cfg)]
@@ -421,6 +429,7 @@ def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
             jnp.zeros((num_envs, horizon, width), dtype)
             for _ in range(kinds.count("latent"))
         ],
+        moe.EXPERTS_READ: moe.no_reads(),
     }
 
 

@@ -263,6 +263,9 @@ class RoutedExperts(nn.Module):
 
     @nn.compact
     def __call__(self, x):
+        """``x [..., D]`` -> ``([..., D], the share of the held experts whose
+        weights the pass read)`` (``ops/moe.py``: under 1 where an acting
+        step streams its live experts alone)."""
         c = self.cfg
         lead, D = x.shape[:-1], x.shape[-1]
         x = x.reshape(-1, D)
@@ -299,14 +302,16 @@ class RoutedExperts(nn.Module):
             self.sow(MOE_COLLECTION, "overflow", overflow.astype(jnp.float32))
         with part("moe_experts"):
             if dense:
-                y = moe.held_experts_dense(x, idx, weights, first, gate, up, down)
-            else:
-                y = moe.held_experts(
-                    x, token, weight, valid, sizes, gate, up, down
+                y, read = moe.held_experts_dense(
+                    x, idx, weights, first, E, gate, up, down
                 )
+            else:
+                y, read = moe.held_experts(
+                    x, token, weight, valid, sizes, gate, up, down
+                ), jnp.float32(1.0)
             for i in range(int(c["n_shared_experts"])):
                 y = y + SwiGLU(F, self.dtype, name=f"shared{i}")(x)
-        return y.reshape(*lead, D)
+        return y.reshape(*lead, D), read
 
 
 class Block(nn.Module):
@@ -326,26 +331,30 @@ class Block(nn.Module):
             self.moe = RoutedExperts(c, self.dtype)
 
     def _ffn(self, h):
+        """``(h + FFN(RMSNorm(h)), the routed experts' read share or None)``."""
         if self.dense:
             with part("dense_ffn"):
-                return h + self.ffn(self.ffn_norm(h))
-        return h + self.moe(self.ffn_norm(h))
+                return h + self.ffn(self.ffn_norm(h)), None
+        y, read = self.moe(self.ffn_norm(h))
+        return h + y, read
 
     def __call__(self, x):
         with part("attn"):
             h = x + self.attn(self.attn_norm(x))
-        return self._ffn(h)
+        return self._ffn(h)[0]
 
     def decode(self, x, cache, pos):
+        """``(x, the routed experts' read share or None, the layer's
+        cache)``."""
         with part("attn"):
             a, cache = self.attn.decode(self.attn_norm(x), cache, pos)
-        return self._ffn(x + a), cache
+        return *self._ffn(x + a), cache
 
 
 class LatentMoETrunk(nn.Module):
     """``[B, T, obs] -> [B, T, hidden]`` (float32, after the last norm);
-    with ``cache`` (a list of ``[B, T, kv_lora + rope]``, one a layer) and
-    ``pos``, ``[B, obs] -> ([B, hidden], new cache)``."""
+    with ``cache`` (:func:`acting_cache`) and ``pos``, ``[B, obs] -> ([B,
+    hidden], new cache)``."""
 
     cfg: dict               # resolve()d model.encoder subtree
     compute_dtype: Any = jnp.bfloat16
@@ -359,7 +368,7 @@ class LatentMoETrunk(nn.Module):
             param_dtype=jnp.float32, name="embed",
             kernel_init=nn.initializers.normal(INIT_STD),
         )(obs.astype(dt))
-        new_cache = []
+        rows, reads = [], []
         for i in range(int(c["num_layers"])):
             layer = Block(
                 c, i < int(c["first_k_dense_replace"]), dt, name=f"layer{i}"
@@ -367,20 +376,28 @@ class LatentMoETrunk(nn.Module):
             if cache is None:
                 x = layer(x)
             else:
-                x, c_i = layer.decode(x, cache[i], pos)
-                new_cache.append(c_i)
+                x, read, c_i = layer.decode(x, cache["latent"][i], pos)
+                rows.append(c_i)
+                reads.append(read)
         out = RMSNorm(float(c["rms_norm_eps"]), jnp.float32, name="norm")(x)
-        return out if cache is None else (out, new_cache)
+        if cache is None:
+            return out
+        tally = moe.count_reads(cache[moe.EXPERTS_READ], reads)
+        return out, {"latent": rows, moe.EXPERTS_READ: tally}
 
 
-def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> list:
-    """The acting carry's cache: a ``[num_envs, horizon, kv_lora + rope]``
-    a layer, in the compute dtype."""
+def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
+    """The acting carry's cache: ``{"latent": [[num_envs, horizon, kv_lora +
+    rope], ...]}``, a leaf a layer in the compute dtype, beside the routed
+    layers' tally of the experts they read (``ops/moe.py::no_reads``)."""
     width = int(cfg["kv_lora_rank"]) + int(cfg["qk_rope_head_dim"])
-    return [
-        jnp.zeros((num_envs, horizon, width), dtype)
-        for _ in range(int(cfg["num_layers"]))
-    ]
+    return {
+        "latent": [
+            jnp.zeros((num_envs, horizon, width), dtype)
+            for _ in range(int(cfg["num_layers"]))
+        ],
+        moe.EXPERTS_READ: moe.no_reads(),
+    }
 
 
 # -- what the learner does with the sown statistics and the bias -------------

@@ -345,7 +345,8 @@ def attention_step(p, h, cache, pos, s, dt, kind: str):
 
 def routed_ffn(p, shared, x, s):
     """A routed layer over ``x [N, D]``: ``(y, {"load" [E], "overflow",
-    "experts" [N, K], "inputs" [N, D]})``."""
+    "experts" [N, K], "inputs" [N, D], "read": the share of the held
+    experts whose weights the pass read})``."""
     E, K, held, first = s["E"], s["K"], s["held"], s["first"]
     with part("moe_route"):
         # the loss stops here (module docstring)
@@ -371,13 +372,13 @@ def routed_ffn(p, shared, x, s):
         }
     with part("moe_experts"):
         if dense:
-            y = moe.held_experts_dense(
-                x, idx, weights, first, p["gate"], p["up"], p["down"]
+            y, stats["read"] = moe.held_experts_dense(
+                x, idx, weights, first, E, p["gate"], p["up"], p["down"]
             )
         else:
-            y = moe.held_experts(
+            y, stats["read"] = moe.held_experts(
                 x, token, weight, valid, sizes, p["gate"], p["up"], p["down"]
-            )
+            ), jnp.float32(1.0)
         y = y + moe.swiglu(x, shared["gate"], shared["up"], shared["down"])
     return y, stats
 
@@ -459,7 +460,8 @@ def decode(params: dict, x, cache: dict, pos, cfg: dict, dt):
         x, stats = _ffn(p, x + out, s, dt)
         if stats:
             routed.append(stats)
-    return x, new, routed
+    tally = moe.count_reads(cache[moe.EXPERTS_READ], [r["read"] for r in routed])
+    return x, {**new, moe.EXPERTS_READ: tally}, routed
 
 
 class SwaMoETrunk(nn.Module):
@@ -514,7 +516,8 @@ def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
     """The acting carry's cache, two kinds side by side: ``{"full": [{"k",
     "v" [envs, horizon, G, hd]}, ...], "window": [{"k", "v" [envs,
     min(window, horizon), G, hd]}, ...]}``, a dict a layer of the kind, in
-    the compute dtype; keys are held rotated."""
+    the compute dtype; keys are held rotated. Beside them the routed layers'
+    tally of the experts they read (``ops/moe.py::no_reads``)."""
     s = _sizes(cfg)
     kinds = [k for k, _ in layer_kinds(cfg)]
     kv = lambda slots: {
@@ -526,6 +529,7 @@ def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
         "window": [
             kv(min(s["W"], horizon)) for _ in range(kinds.count("window"))
         ],
+        moe.EXPERTS_READ: moe.no_reads(),
     }
 
 

@@ -37,17 +37,61 @@ forms compute it, chosen from the pass's static shape (:func:`dense_form`):
   the benchmark, and with the work following the load the same cell moved
   by 4% between seeds (the held share of a random router is 0.03-0.09).
   Take the one marked line out to let the work follow the load;
-- **dense** (an acting step, a few hundred tokens at most): every held
-  expert on every token, weighted by the token's weight for it or zero.
-  Under the chip's ridge point an expert's products hide behind the stream
-  of its weights from HBM, which a step reads whatever the routing;
-  nothing is sorted.
+- **dense** (an acting step, a few hundred tokens at most): nothing is
+  sorted; a held expert runs over every token of the pass, weighted by the
+  token's weight for it or zero. Under the chip's ridge point an expert's
+  products hide behind the stream of its weights from HBM, so the form's
+  cost is the bytes it reads, and two forms read them
+  (:func:`held_experts_dense` chooses, from the pass's shape and where the
+  program is lowered, by no key):
+
+  - **every held expert** (``lax``: three einsums over ``[held, D, F]``).
+    XLA's fusions stream the weights at the HBM's peak (141 us for a
+    layer's 113 MB at 2304 wide, 198 for 151 MB at 3072: the readings
+    beside ``LIVE_SHARE_MAX``), whatever the routing;
+  - **the live experts alone** (:func:`_live_pallas`, a Pallas TPU kernel).
+    An expert that no token of the pass chose has weight zero on every
+    row, so leaving it out is exact. The held experts some token chose are
+    compacted to the front of a list (:func:`live_experts`); the list and
+    its length are scalar-prefetched and the kernel's grid walks ``held``
+    slots x tiles of ``F``: a live slot's ``index_map`` names its expert's
+    tiles of ``gate``, ``up`` and ``down``; a slot past the count names the
+    block the last live step left in VMEM, so no copy is issued for it, and
+    ``pl.when`` skips its products. ``out [N, D]`` float32 stays in VMEM
+    over the whole grid. The rounding points are the ``lax`` form's (the
+    two products, ``h`` and an expert's ``y`` in the compute dtype, the
+    weighted sum in float32), so acting and the learn pass go on differing
+    exactly as they did. A cotangent takes the ``lax`` form
+    (``jax.custom_vjp``).
+
+  The kernel runs where the program is lowered for a TPU
+  (``jax.lax.platform_dependent``), the lanes divide ``D`` and ``F`` and
+  the sublanes the tokens, and the share of held experts the pass expects to be live under even routing,
+  ``1 - (1 - 1 / n_routed) ^ (tokens x top_k)``
+  (:func:`expected_live_share`), is at most ``LIVE_SHARE_MAX``: 0.39 for
+  ``ppo_lift_kimilinear_16x1024``'s step (16 tokens x top-8 over 256), 0.47
+  for ``ppo_lift_laguna_16x1024``'s (top-10), 0.98 for
+  ``ppo_lift_joyai_128x128``'s 128 tokens, which keeps XLA's form: with
+  nothing to skip the kernel, which streams at four fifths of the peak,
+  only loses. :func:`held_experts_dense` also says what share of the held
+  experts' weights the pass read; the routed layers of an acting step add
+  it to a tally in the acting carry's cache (:func:`count_reads`), a fused
+  rollout reads the tally where it ends (``Learner.act_rows``) and the
+  iteration's metrics row carries it as ``moe/acting_live_share``. The
+  price: an acting step's time now follows the router (the sorted form's
+  does not, above); a step's count is averaged over an iteration's
+  thousands of layer-steps and a seed moves only its mean (PERF.md
+  section 7 has the spread measured over seeds).
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def route(logits, bias, top_k: int, scale: float, scoring: str = "sigmoid"):
@@ -97,6 +141,27 @@ CAPACITY_FACTOR = 4.0
 # under the ridge point (v5e: 197 TFLOP/s over 819 GB/s = 240 rows a
 # bfloat16 weight) the products wait for the weights, which stream anyway
 DENSE_MAX_TOKENS = 256
+# a dense pass takes the live experts' kernel while the share of held experts
+# it expects to be live is at most this. Chip readings (PR 48, v5e, a scan of
+# 256 steps x 4 routed layers, us a layer and step, XLA's dense form / the
+# kernel at the live share measured): 2304 wide, top-8: 16 tokens 141.0 /
+# 74.3 at 0.41, 32 tokens 144.2 / 109.0 at 0.64, 64 tokens 161.5 / 137.4 at
+# 0.87, 128 tokens 166.2 / 173.6 at 0.98; 3072 wide, top-10: 197.6 / 106.2 at
+# 0.47, 196.7 / 155.5 at 0.72, 223.0 / 224.1 at 0.92, 220.0 / 223.7 at 0.99.
+# XLA's fusions stream all eight experts at the HBM's peak and the kernel its
+# live ones at four fifths of it, so above nine tenths nothing is left to win
+LIVE_SHARE_MAX = 0.9
+# the kernel's tile of an expert's width F: three [D, 256] bfloat16 tiles
+# double-buffered are 7-9 MB of VMEM at D = 2304-3072, inside the 16 MiB a
+# kernel gets without asking, so the compiler's own use of VMEM around the
+# call stays as it was (an acting step's carried matrix states: the compile
+# for the described v5e pins three of four there, tests/test_tpu_compile.py).
+# Tiles of 512 (14-19 MB) read 68.4 for 74.3 us at 2304 wide and 104.5 for
+# 106.2 at 3072; a hand-made DMA pipeline three to six tiles deep over the
+# live tiles alone 73.6-70.9 and 103.6-100.7 (my chip runs, PR 48): what is
+# left over the bytes' 57 and 87 us is a tile's copy before the first product
+# and a third of a microsecond a dead slot, not the pipeline's depth
+WIDTH_TILE = 256
 
 
 def dense_form(tokens: int) -> bool:
@@ -144,19 +209,216 @@ def swiglu(x, gate, up, down):
     return h @ down.astype(x.dtype)
 
 
-def held_experts_dense(x, idx, weights, first_held: int, gate, up, down):
-    """The same sum with every held expert applied to every token:
-    ``x [N, D]`` -> ``[N, D]``; a token's weight for an expert it did not
-    choose is zero."""
+def expected_live_share(tokens: int, top_k: int, n_routed: int) -> float:
+    """The share of held experts that some token of a pass chooses, under
+    even routing: ``1 - (1 - 1 / n_routed) ^ (tokens x top_k)``."""
+    return 1.0 - (1.0 - 1.0 / n_routed) ** (tokens * top_k)
+
+
+def streams_live_only(tokens: int, top_k: int, n_routed: int, D: int, F: int) -> bool:
+    """Whether a dense pass of this shape takes the kernel where it is
+    lowered for a TPU (the module docstring's static rule)."""
+    return (
+        D % 128 == 0 and F % 128 == 0 and tokens % 8 == 0
+        and expected_live_share(tokens, top_k, n_routed) <= LIVE_SHARE_MAX
+    )
+
+
+def live_experts(hit):
+    """``hit [N, top_k, held]`` (assignment x held expert) -> ``(ids [held]
+    int32, count)``: the held experts some token chose, in order, at the
+    front of ``ids`` (the rest 0), and how many they are. Comparisons and
+    sums over ``held x held``: no sort, no scan."""
+    held = hit.shape[-1]
+    live = hit.any((0, 1))
+    at = jnp.arange(held, dtype=jnp.int32)
+    rank = (live[None, :] & (at[None, :] < at[:, None])).sum(1)     # live before e
+    slot = live[:, None] & (rank[:, None] == at[None, :])           # [expert, slot]
+    ids = (slot * at[:, None]).sum(0).astype(jnp.int32)
+    return ids, live.sum().astype(jnp.int32)
+
+
+def _dense_lax(x, w, gate, up, down):
+    """Every held expert on every token: ``sum_e w[:, e] x E_e(x)``, ``w [N,
+    held]`` float32 (zero where a token did not choose the expert)."""
     dt = x.dtype
-    held = gate.shape[0]
-    experts = first_held + jnp.arange(held, dtype=idx.dtype)
-    w = (weights[..., None] * (idx[..., None] == experts)).sum(1)   # [N, held]
     h = jax.nn.silu(jnp.einsum("nd,gdf->gnf", x, gate.astype(dt))) * jnp.einsum(
         "nd,gdf->gnf", x, up.astype(dt)
     )
     ys = jnp.einsum("gnf,gfd->gnd", h, down.astype(dt)).astype(jnp.float32)
     return (ys * w.T[..., None]).sum(0).astype(dt)
+
+
+def _live_kernel(ids_ref, count_ref, x_ref, w_ref, gate_ref, up_ref, down_ref,
+                 out_ref, acc_ref):
+    """Grid step (slot, tile of ``F``): the slot's expert's ``[D, tile]``
+    of ``gate`` and ``up`` and ``[tile, D]`` of ``down`` are here; ``out [N,
+    D]`` float32 stays over the whole grid. The rounding points are
+    :func:`_dense_lax`'s: the two products, ``h`` and an expert's ``y`` in
+    ``x``'s dtype, the weighted sum over experts in float32."""
+    del ids_ref     # the index maps read it
+    slot, tile = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=f32, precision=jax.lax.Precision.DEFAULT
+    )
+
+    @pl.when((slot == 0) & (tile == 0))
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(slot < count_ref[0])
+    def _():
+        x = x_ref[...]
+        dt = x.dtype
+        a = dot(x, gate_ref[...]).astype(dt).astype(f32)
+        b = dot(x, up_ref[...]).astype(dt).astype(f32)
+        h = (jax.nn.silu(a).astype(dt).astype(f32) * b).astype(dt)
+        y = dot(h, down_ref[...])
+
+        @pl.when(tile == 0)
+        def _():
+            acc_ref[...] = y
+
+        @pl.when(tile > 0)
+        def _():
+            acc_ref[...] += y
+
+        @pl.when(tile == pl.num_programs(1) - 1)
+        def _():
+            out_ref[...] += acc_ref[...].astype(dt).astype(f32) * w_ref[...]
+
+
+# jitted so that a program traces and lowers the kernel once a shape, not
+# once a routed layer
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _live_pallas(x, w, ids, count, gate, up, down, interpret=False):
+    """The same sum from the live experts alone: slot ``s`` of the grid
+    takes expert ``ids[s]``'s weights while ``s < count``; a slot past the
+    count names the block the last live step left in VMEM, so nothing is
+    copied for it, and computes nothing."""
+    dt = x.dtype
+    (N, D), (held, _, F) = x.shape, gate.shape
+    tile = next(t for t in (WIDTH_TILE, 128) if F % t == 0)
+    tiles = F // tile
+
+    def at(s, j, ids, count):
+        live = s < count[0]
+        expert = ids[jnp.where(live, s, jnp.maximum(count[0] - 1, 0))]
+        return expert, jnp.where(live, j, tiles - 1)
+
+    def wide(s, j, ids, count):        # gate, up: [held, D, F]
+        expert, j = at(s, j, ids, count)
+        return expert, 0, j
+
+    def narrow(s, j, ids, count):      # down: [held, F, D]
+        expert, j = at(s, j, ids, count)
+        return expert, j, 0
+
+    def column(s, j, ids, count):      # the expert's weights a token: [held, N, 1]
+        return at(s, j, ids, count)[0], 0, 0
+
+    whole = lambda s, j, ids, count: (0, 0)     # noqa: E731
+    out = pl.pallas_call(
+        _live_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(held, tiles),
+            in_specs=[
+                pl.BlockSpec((N, D), whole),
+                pl.BlockSpec((None, N, 1), column),
+                pl.BlockSpec((None, D, tile), wide),
+                pl.BlockSpec((None, D, tile), wide),
+                pl.BlockSpec((None, tile, D), narrow),
+            ],
+            out_specs=pl.BlockSpec((N, D), whole),
+            scratch_shapes=[pltpu.VMEM((N, D), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((N, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")
+        ),
+        interpret=interpret,
+        name="held_experts_live",
+    )(
+        ids, count.reshape(1), x, w.T[..., None],
+        gate.astype(dt), up.astype(dt), down.astype(dt),
+    )
+    return out.astype(dt)
+
+
+@jax.custom_vjp
+def _dense_where_lowered(x, w, ids, count, gate, up, down):
+    return jax.lax.platform_dependent(
+        x, w, ids, count, gate, up, down, tpu=_live_pallas,
+        default=lambda x, w, ids, count, gate, up, down: _dense_lax(
+            x, w, gate, up, down
+        ),
+    )
+
+
+def _dense_where_lowered_fwd(x, w, ids, count, gate, up, down):
+    out = _dense_where_lowered(x, w, ids, count, gate, up, down)
+    return out, (x, w, gate, up, down)
+
+
+def _dense_where_lowered_bwd(res, d):
+    # a cotangent takes the lax form whatever computed the value
+    dx, dw, *dweights = jax.vjp(_dense_lax, *res)[1](d)
+    return dx, dw, None, None, *dweights
+
+
+_dense_where_lowered.defvjp(_dense_where_lowered_fwd, _dense_where_lowered_bwd)
+
+
+def held_experts_dense(x, idx, weights, first_held: int, n_routed: int,
+                       gate, up, down):
+    """The same sum by the dense form: ``x [N, D]`` -> ``([N, D], the share
+    of the held experts whose weights the pass reads)``; a token's weight
+    for an expert it did not choose is zero. Where the pass's shape leaves
+    experts unchosen and the program is lowered for a TPU
+    (:func:`streams_live_only`, the module docstring), the live experts
+    alone, and the share is theirs; else all of them, and the share is 1."""
+    held = gate.shape[0]
+    experts = first_held + jnp.arange(held, dtype=idx.dtype)
+    hit = idx[..., None] == experts                                 # [N, top_k, held]
+    w = (weights[..., None] * hit).sum(1)                           # [N, held]
+    if not streams_live_only(
+        x.shape[0], idx.shape[-1], n_routed, x.shape[1], gate.shape[2]
+    ):
+        return _dense_lax(x, w, gate, up, down), jnp.float32(1.0)
+    ids, count = live_experts(hit)
+    share = jax.lax.platform_dependent(
+        count, tpu=lambda count: count.astype(jnp.float32) / held,
+        default=lambda count: jnp.float32(1.0),
+    )
+    return _dense_where_lowered(x, w, ids, count, gate, up, down), share
+
+
+# the acting carry's leaf where the routed layers tally what they read, and
+# the metrics row it becomes
+EXPERTS_READ = "experts_read"
+
+
+def no_reads():
+    """A fresh tally: ``[the read shares' sum, how many were added]``."""
+    return jnp.zeros((2,), jnp.float32)
+
+
+def count_reads(tally, shares):
+    """``tally`` with an acting step's routed layers added, one add a step:
+    ``shares`` holds what :func:`held_experts_dense` said each read, and
+    None for a layer that routes nothing."""
+    shares = [s for s in shares if s is not None]
+    return tally + jnp.stack([sum(shares), jnp.float32(len(shares))])
+
+
+def acting_rows(cache: dict) -> dict:
+    """The metrics row of an acting carry's ``cache``: the mean, over the
+    acting steps and routed layers tallied in it, of the share of the held
+    experts whose weights a step read (1.0 where every step reads all)."""
+    total, passes = cache[EXPERTS_READ]
+    return {"moe/acting_live_share": total / jnp.maximum(passes, 1.0)}
 
 
 def held_experts(x, token, weight, valid, group_sizes, gate, up, down):

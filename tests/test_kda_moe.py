@@ -189,7 +189,7 @@ def test_acting_through_states_tails_and_cache_is_the_whole_segment_forward():
     state = learner.init(jax.random.key(0))
     obs = jax.random.normal(jax.random.key(1), (horizon, envs, 5), jnp.float32)
     carry = learner.act_init(envs)
-    assert set(carry["cache"]) == {"kda", "latent"}
+    assert set(carry["cache"]) == {"kda", "latent", moe.EXPERTS_READ}
     assert len(carry["cache"]["kda"]) == 4 and len(carry["cache"]["latent"]) == 1
     leaf = carry["cache"]["kda"][0]
     assert leaf["state"].shape == (envs, 2, 8, 8)
@@ -280,7 +280,7 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(tokens):
             mine = dict(whole, **{
                 k: whole[k][2 * share:2 * share + 2] for k in ("gate", "up", "down")
             })
-            y, sown = latent_moe.RoutedExperts(
+            (y, _), sown = latent_moe.RoutedExperts(
                 dict(cfg, first_held=2 * share), jnp.float32
             ).apply({"params": mine}, h, mutable=["moe", "moe_routing"])
             assert float(sown["moe"]["overflow"][0]) == 0.0

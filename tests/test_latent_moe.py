@@ -74,7 +74,8 @@ def test_act_init_takes_its_carry_from_the_model(block):
     T, B = 8, 3
     if block == "mla_moe":
         learner = _learner(T, "mixed")
-        want = [(B, T, 16 + 4)] * 3   # the latent rows, nothing per head
+        # the latent rows, nothing per head; the routed layers' tally
+        want = [(2,)] + [(B, T, 16 + 4)] * 3
     else:
         learner = build_learner(
             Config(
@@ -90,7 +91,10 @@ def test_act_init_takes_its_carry_from_the_model(block):
     cache = learner.model.init_cache(B, T)
     got = [x.shape for x in jax.tree.leaves(carry["cache"])]
     assert got == want == [x.shape for x in jax.tree.leaves(cache)]
-    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(carry["cache"]))
+    assert all(
+        x.dtype == jnp.bfloat16
+        for x in jax.tree.leaves(carry["cache"]) if x.shape != (2,)
+    )
     assert int(carry["pos"]) == 0
 
 
@@ -127,7 +131,10 @@ SHARE_CFG = latent_moe.resolve(dict(
 
 def _routed(cfg, params, x):
     layer = latent_moe.RoutedExperts(cfg, jnp.float32)
-    y, sown = layer.apply({"params": params}, x, mutable=["moe", "moe_routing"])
+    (y, read), sown = layer.apply(
+        {"params": params}, x, mutable=["moe", "moe_routing"]
+    )
+    assert float(read) == 1.0       # off the TPU every held expert is read
     return y, sown["moe"]
 
 
@@ -234,6 +241,196 @@ def test_the_form_follows_the_shape_of_the_pass(tokens, dense, monkeypatch):
 
 
 moe_sort = moe.sort_by_expert
+
+
+# -- the live experts' kernel (ops/moe.py), interpreted -------------------------
+
+LIVE_FIRST, LIVE_HELD, LIVE_ROUTED, LIVE_TOP = 8, 8, 64, 4
+
+
+def _live_case(name):
+    """``(x [16, 256] bfloat16, idx [16, 4], weights, gate, up, down)`` of 8
+    held experts (8..15 of 64) of width 128, routed as ``name`` says."""
+    k = jax.random.split(jax.random.key(3), 5)
+    x = jax.random.normal(k[0], (16, 256), jnp.bfloat16)
+    gate, up = (
+        0.05 * jax.random.normal(k[i], (LIVE_HELD, 256, 128)) for i in (1, 2)
+    )
+    down = 0.05 * jax.random.normal(k[3], (LIVE_HELD, 128, 256))
+    # every token chooses experts 40..43: none of them held
+    idx = jnp.broadcast_to(40 + jnp.arange(LIVE_TOP, dtype=jnp.int32), (16, LIVE_TOP))
+    if name == "one":
+        idx = idx.at[3, 0].set(LIVE_FIRST + 5)
+    elif name == "two_of_one_token":
+        idx = idx.at[3, 0].set(LIVE_FIRST + 6).at[3, 2].set(LIVE_FIRST + 1)
+        idx = idx.at[9, 1].set(LIVE_FIRST + 6)
+    elif name == "all":
+        idx = LIVE_FIRST + (
+            jnp.arange(16, dtype=jnp.int32)[:, None] + jnp.arange(LIVE_TOP)
+        ) % LIVE_HELD
+    weights = jax.nn.softmax(jax.random.normal(k[4], idx.shape), -1)
+    return x, idx, weights, gate, up, down
+
+
+@pytest.mark.parametrize("name,ids,served", [
+    ("none", [], []),
+    ("one", [5], [3]),
+    ("two_of_one_token", [1, 6], [3, 9]),
+    ("all", list(range(8)), list(range(16))),
+])
+def test_the_kernel_reads_the_live_experts_and_equals_the_dense_form(
+    name, ids, served
+):
+    """The acting kernel, interpreted, against the lax form it stands in
+    for: the live experts' ids in order at the front of the list and their
+    count; the same sum to the rounding of bfloat16 (one step of it at the
+    largest output); and exact zeros for a token that chose no held
+    expert."""
+    x, idx, weights, gate, up, down = _live_case(name)
+    hit = idx[..., None] == LIVE_FIRST + jnp.arange(LIVE_HELD)
+    w = (weights[..., None] * hit).sum(1)
+    got_ids, count = moe.live_experts(hit)
+    assert int(count) == len(ids)
+    assert got_ids.tolist() == ids + [0] * (LIVE_HELD - len(ids))
+    want = moe._dense_lax(x, w, gate, up, down).astype(jnp.float32)
+    got = moe._live_pallas(
+        x, w, got_ids, count, gate, up, down, interpret=True
+    )
+    assert got.dtype == x.dtype and got.shape == x.shape
+    got = got.astype(jnp.float32)
+    step = 2.0 ** -7 * max(float(jnp.abs(want).max()), 1e-3)
+    np.testing.assert_allclose(got, want, rtol=0, atol=step)
+    idle = np.setdiff1d(np.arange(16), served)
+    assert not np.asarray(got)[idle].any() and not np.asarray(want)[idle].any()
+    if served:
+        assert np.abs(np.asarray(got)[served]).min(0).max() > 0
+
+
+@pytest.mark.parametrize("name", ["none", "two_of_one_token", "all"])
+def test_the_dispatching_form_differentiates_as_the_dense_form(name, monkeypatch):
+    """``held_experts_dense`` at a shape the kernel takes: off the TPU its
+    value is the lax form's to the bit and it says it read every expert;
+    its gradient (inputs, weights of the routing, the experts' weights) is
+    the lax form's, whatever computes the value."""
+    x, idx, weights, gate, up, down = _live_case(name)
+    assert moe.streams_live_only(16, LIVE_TOP, LIVE_ROUTED, 256, 128)
+
+    def dispatched(x, weights, gate, up, down):
+        y, read = moe.held_experts_dense(
+            x, idx, weights, LIVE_FIRST, LIVE_ROUTED, gate, up, down
+        )
+        assert float(read) == 1.0
+        return y
+
+    def lax_form(x, weights, gate, up, down):
+        hit = idx[..., None] == LIVE_FIRST + jnp.arange(LIVE_HELD)
+        return moe._dense_lax(x, (weights[..., None] * hit).sum(1), gate, up, down)
+
+    args = (x, weights, gate, up, down)
+    np.testing.assert_array_equal(dispatched(*args), lax_form(*args))
+    loss = lambda f: lambda *a: (f(*a).astype(jnp.float32) ** 2).sum()  # noqa: E731
+    got = jax.grad(loss(dispatched), argnums=range(5))(*args)
+    want = jax.grad(loss(lax_form), argnums=range(5))(*args)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+    # the value may come from the kernel, the cotangent takes the lax form
+    monkeypatch.setattr(
+        jax.lax, "platform_dependent",
+        lambda *a, tpu, default: (
+            tpu(*a, interpret=True) if tpu is moe._live_pallas else default(*a)
+        ),
+    )
+    through_kernel = jax.grad(loss(dispatched), argnums=range(5))(*args)
+    for g, w in zip(through_kernel, want):
+        scale = max(float(jnp.abs(w.astype(jnp.float32)).max()), 1e-6)
+        np.testing.assert_allclose(
+            g.astype(jnp.float32), w.astype(jnp.float32), rtol=0, atol=0.05 * scale
+        )
+
+
+@pytest.mark.parametrize("tokens,top_k,routed,D,F,live,kernel", [
+    (16, 8, 256, 2304, 1024, 0.39, True),     # ppo_lift_kimilinear's step
+    (16, 10, 256, 3072, 1024, 0.47, True),    # ppo_lift_laguna's
+    (128, 8, 256, 2048, 768, 0.98, False),    # ppo_lift_joyai's: none to skip
+    (16, 2, 8, 32, 16, 0.99, False),          # a rehearsal's widths
+    (16, 8, 256, 2304, 1000, 0.39, False),    # a width the tiles do not divide
+])
+def test_the_acting_form_follows_the_shape_of_the_pass(
+    tokens, top_k, routed, D, F, live, kernel
+):
+    """No key chooses the acting form: the share of held experts a pass of
+    this shape expects to be live, and whether the lanes divide the widths."""
+    assert abs(moe.expected_live_share(tokens, top_k, routed) - live) < 0.01
+    assert moe.streams_live_only(tokens, top_k, routed, D, F) is kernel
+
+
+_WIDE = dict(
+    hidden_size=32, intermediate_size=64, moe_intermediate_size=16,
+    n_routed_experts=8, num_experts_per_tok=2, num_held=2,
+)
+TALLY_TOYS = {
+    "preln": dict(kind="trajectory", features=32, num_layers=2, num_heads=2,
+                  head_dim=8),
+    "mla_moe": TOY,
+    "swa_moe": dict(
+        _WIDE, kind="trajectory", block="swa_moe", num_layers=5, num_heads=4,
+        window_heads=6, num_kv_heads=2, attn_head_dim=8,
+        shared_intermediate_size=16, sliding_window=4,
+    ),
+    "kda_moe": dict(
+        _WIDE, kind="trajectory", block="kda_moe", num_layers=5, num_heads=2,
+        kda_head_dim=8, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=8,
+    ),
+}
+
+
+@pytest.mark.parametrize("block,routed", [
+    ("preln", 0), ("mla_moe", 2), ("swa_moe", 4), ("kda_moe", 4),
+])
+def test_a_rollout_reports_what_its_acting_steps_read(block, routed):
+    """The routed layers of an acting step tally the share of the held
+    experts they read in the carry's cache; the rollout reads the tally
+    where it ends and the iteration's row carries it as
+    ``moe/acting_live_share``: 1.0 off the TPU, where every step reads
+    every held expert. A family without routed layers has no such row."""
+    from surreal_tpu.launch.rollout import device_rollout, init_device_carry
+    from surreal_tpu.launch.trainer import Trainer
+
+    horizon, envs = 6, 8      # the suite's eight CPU devices divide the envs
+    trainer = Trainer(Config(
+        learner_config=Config(
+            algo=Config(name="ppo", horizon=horizon, epochs=1, num_minibatches=1),
+            model=Config(encoder=Config(**TALLY_TOYS[block])),
+        ),
+        env_config=Config(name="jax:lift", num_envs=envs),
+        session_config=Config(folder="unused"),
+    ).extend(base_config()))
+    learner = trainer.learner
+    state = learner.init(jax.random.key(0))
+    carry = learner.act_init(envs)
+    assert learner.act_rows(carry) == ({"moe/acting_live_share": 0.0} if routed else {})
+    step = jax.jit(learner.act_step)
+    for t in range(3):
+        obs = jax.random.normal(jax.random.key(t), (envs, 17))
+        _, _, carry = step(state, carry, obs, jax.random.key(t))
+    if routed:
+        assert carry["cache"][moe.EXPERTS_READ].tolist() == [3 * routed, 3 * routed]
+    env_carry = init_device_carry(trainer.env, jax.random.key(1), envs)
+    if block == "mla_moe":      # one family through the whole iteration's row
+        _, _, rows = jax.jit(trainer._device_train_iter)(
+            state, env_carry, jax.random.key(2)
+        )
+        assert "loss/pg" in rows
+    else:
+        rows = jax.jit(
+            lambda s, c, k: device_rollout(trainer.env, learner, s, c, k, horizon)
+        )(state, env_carry, jax.random.key(2))[1]["acting"]
+    if routed:
+        assert float(rows["moe/acting_live_share"]) == 1.0
+    else:
+        assert rows == {}
 
 
 def test_the_selection_bias_moves_toward_balance_and_takes_no_gradient():

@@ -286,6 +286,8 @@ def test_the_shares_of_a_routed_layer_add_up_to_the_uncut_layer(tokens):
             })
             y, stats = swa_moe.routed_ffn(mine, shared, x, s)
             assert float(stats["overflow"]) == 0.0
+            # either form, off the TPU: every held expert's weights are read
+            assert float(stats["read"]) == 1.0
             loads.append(stats["load"])
             total = total + (y - shared_out)
         total = total + shared_out

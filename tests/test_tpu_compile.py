@@ -373,12 +373,83 @@ def test_routed_layer_compiles_to_the_ragged_kernel(chip):
     )
 
     def loss(p, x):
-        return layer.apply({"params": p}, x).astype(jnp.float32).sum()
+        return layer.apply({"params": p}, x)[0].astype(jnp.float32).sum()
 
     compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
     text = compiled.as_text()
     assert text.count("custom-call") >= 9 and "agged" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
+
+
+def _computations(text: str) -> list:
+    """A compiled module's text, a computation an entry."""
+    import re
+
+    return re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \([^\n]*\) -> )", text)
+
+
+def _live_calls(computation: str) -> list:
+    """The operands' names of each call of the live experts' kernel."""
+    import re
+
+    return [
+        [name.strip() for name in re.sub(r"/\*.*?\*/", "", line).split(", ")]
+        for line in re.findall(
+            r"= \S+ custom-call\(([^)]*)\), custom_call_target=\"tpu_custom_call\""
+            r"[^\n]*held_experts_live", computation
+        )
+    ]
+
+
+@pytest.mark.parametrize("family,tokens,kernel", [
+    pytest.param("kda_moe", 16, True, id="kimilinear-16-tokens-live-experts"),
+    pytest.param("mla_moe", 128, False, id="joyai-128-tokens-every-expert"),
+])
+def test_an_acting_steps_routed_layer_reads_the_live_experts(
+    chip, family, tokens, kernel
+):
+    """A routed layer's acting step inside a ``lax.scan`` at the published
+    widths. ``ppo_lift_kimilinear_16x1024``'s (``[16, 2304]``, 8 held of 256,
+    top-8: 0.39 of the held experts expected live) calls the live experts'
+    kernel, Mosaic takes it, and its weights are bfloat16 arrays that enter
+    the loop's body as its own operands: cast once outside and not a step
+    (float32 parameters cast inside would be three times the bytes).
+    ``ppo_lift_joyai_128x128``'s (``[128, 2048]``, 16 held, top-8: 0.98 live,
+    nothing to skip) keeps XLA's dense form and has no custom call."""
+    import re
+
+    from surreal_tpu.models import kda_moe, latent_moe
+
+    resolve = {"kda_moe": kda_moe.resolve, "mla_moe": latent_moe.resolve}[family]
+    cfg = resolve(dict(num_layers=5, num_heads=32))
+    D = int(cfg["hidden_size"])
+    layer = latent_moe.RoutedExperts(cfg, jnp.bfloat16)
+    on_chip = lambda tree: jax.tree.map(       # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip), tree
+    )
+    x = jax.ShapeDtypeStruct((tokens, D), jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jnp.zeros((tokens, D), jnp.bfloat16))
+    )["params"]
+
+    def acting(p, x):
+        def step(x, _):
+            y, read = layer.apply({"params": p}, x)
+            return x + y, read
+        return jax.lax.scan(step, x, None, length=8)
+
+    text = jax.jit(acting).lower(on_chip(params), on_chip(x)).compile().as_text()
+    if not kernel:
+        assert "tpu_custom_call" not in text
+        return
+    body = [c for c in _computations(text) if "held_experts_live" in c]
+    assert len(body) == 1 and " while(" in text
+    (operands,) = _live_calls(body[0])
+    assert len(operands) == 7
+    for name in operands[-3:]:      # gate, up, down
+        defined = re.search(rf"{re.escape(name)} = (\S+) (\S+)\(", body[0])
+        assert defined and defined.group(1).startswith("bf16[8,")
+        assert defined.group(2) == "get-tuple-element", defined.group(0)
 
 
 def test_phi4flash_iteration_fits_the_chip_with_each_layer_recomputed(sds):
@@ -520,7 +591,14 @@ def test_kimilinear_iteration_fits_the_chip_with_each_layer_recomputed(sds):
     (models/attention.py::recomputed, from the shapes), the delta rule keeps
     chunk starts and not every state (ops/delta_rule.py: ``[1024, 8, 32, 128,
     128]`` float32 would be 17 GB), and the acting scan carries four matrix
-    states and their conv tails beside one latent cache."""
+    states and their conv tails beside one latent cache. An acting step's
+    routed layers call the live experts' kernel (ops/moe.py) on bfloat16
+    weights cast once outside the loop, and the kernel's VMEM costs the
+    carried states none of theirs: of the step's four state updates
+    (``multiply_reduce_fusion (f32[16,32,128], f32[16,32,128,128])``) the
+    compiler writes three to memory space ``S(1)``, VMEM, where the parent
+    of PR 48, whose dense form streamed all eight experts through XLA's own
+    fusions, wrote two (my compiles here, PR 48)."""
     import re
 
     from surreal_tpu.session.config import Config
@@ -556,6 +634,23 @@ def test_kimilinear_iteration_fits_the_chip_with_each_layer_recomputed(sds):
         "bf16[16,3,32,128]" in c and "bf16[16,1024,576]" in c for c in loops
     )
     assert "agged" in text
+    # the acting step: four calls of the live experts' kernel, their weights
+    # the loop's own bfloat16 operands, and the states VMEM keeps
+    acting = [c for c in _computations(text) if "held_experts_live" in c]
+    assert len(acting) == 1
+    calls = _live_calls(acting[0])
+    assert len(calls) == 4
+    for operands in calls:
+        assert all(
+            re.search(rf"{re.escape(w)} = bf16\[8,\d+,\d+\]\S* get-tuple-element\(", acting[0])
+            for w in operands[-3:]
+        ), operands
+    resident = [
+        line for line in acting[0].splitlines()
+        if " fusion(" in line
+        and re.search(r"= \([^=]*f32\[16,32,128,128\]\{[^}]*S\(1\)\}", line)
+    ]
+    assert len(resident) >= 2, len(resident)    # the parent's 2; 3 with the kernel
 
 
 def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
