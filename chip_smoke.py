@@ -7,11 +7,11 @@ performance record.
 
 One chip, one process, these phases:
 
-- ``kernels``  each of the five Pallas entry points, compiled by Mosaic
+- ``kernels``  each of the three Pallas entry points, compiled by Mosaic
   (never interpreted), against its XLA twin at the shapes the trainers
-  use: GAE and discounted returns 256 x 4096, V-trace 32 x 1024, the row
-  gather over every leaf of the DDPG ``jax:lift`` replay at capacity
-  200 000 (batch 256), the priority scatter over ``[200 000]``.
+  use: discounted returns 256 x 4096, the row gather over every leaf of
+  the DDPG ``jax:lift`` replay at capacity 200 000 (batch 256), the
+  priority scatter over ``[200 000]``.
 - ``ppo``      ``train ppo jax:lift --num-envs 4096``, horizon 256, four
   fused iterations, through ``surreal_tpu.main.launch.main(argv)``.
 - ``ddpg`` / ``ddpg_pallas``  ``train ddpg jax:lift --num-envs 2048`` with
@@ -69,7 +69,6 @@ class Sizes:
 
     def __init__(self, rehearse: bool):
         self.ppo_envs, self.horizon = (64, 8) if rehearse else (4096, 256)
-        self.vtrace = (8, 128) if rehearse else (32, 1024)
         self.ddpg_envs = 16 if rehearse else 2048
         self.capacity = 2048 if rehearse else 200_000
         self.batch = 32 if rehearse else 256
@@ -129,14 +128,11 @@ def _has_kernel(fn, *args, **kwargs) -> bool:
 def kernels_phase(line: dict, sz: Sizes, rng, on_tpu: bool) -> None:
     from surreal_tpu.ops import pallas_interpret
     from surreal_tpu.ops import returns as R
-    from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
     from surreal_tpu.ops.pallas_replay import (
         gather_rows_pallas,
         scatter_rows_pallas,
     )
     from surreal_tpu.ops.pallas_returns import discounted_returns_pallas
-    from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
-    from surreal_tpu.ops.vtrace import vtrace_nextobs
 
     interp = pallas_interpret()
     check(interp != on_tpu, "pallas_interpret() must be False on the TPU")
@@ -161,17 +157,10 @@ def kernels_phase(line: dict, sz: Sizes, rng, on_tpu: bool) -> None:
                 _has_kernel(fn, *args, **kwargs), f"{name}: no tpu_custom_call"
             )
 
-    gamma, lam = 0.99, 0.95
+    gamma = 0.99
     T, B = sz.horizon, sz.ppo_envs
     rewards, values = f(T, B), f(T + 1, B)
     disc = gamma * (1.0 - coin(0.05, T, B).astype(jnp.float32))
-    gae_args = (rewards, disc, lam * disc, values[:-1], values[1:])
-    compiled("gae", gae_advantages_pallas_masked, *gae_args)
-    adv, tgt = gae_advantages_pallas_masked(*gae_args, interpret=interp)
-    adv_x, tgt_x = R.gae_advantages(rewards, disc, values, lam)
-    close("gae/advantages", adv, adv_x)
-    close("gae/targets", tgt, tgt_x)
-
     ret_args = (rewards, disc, values[-1])
     compiled("returns", discounted_returns_pallas, *ret_args)
     close(
@@ -179,20 +168,6 @@ def kernels_phase(line: dict, sz: Sizes, rng, on_tpu: bool) -> None:
         discounted_returns_pallas(*ret_args, interpret=interp),
         R.discounted_returns(*ret_args),
     )
-
-    VT, VB = sz.vtrace
-    done = coin(0.1, VT, VB)
-    vt = dict(
-        behaviour_logp=f(VT, VB) * 0.1 - 1.0,
-        target_logp=f(VT, VB) * 0.1 - 1.0,
-        rewards=f(VT, VB), values=f(VT, VB), values_next=f(VT, VB),
-        done=done, terminated=done & coin(0.5, VT, VB),
-    )
-    compiled("vtrace", vtrace_nextobs_pallas, **vt, gamma=gamma)
-    pal = vtrace_nextobs_pallas(**vt, gamma=gamma, interpret=interp)
-    ref = vtrace_nextobs(**vt, gamma=gamma)
-    close("vtrace/vs", pal.vs, ref.vs)
-    close("vtrace/pg_advantages", pal.pg_advantages, ref.pg_advantages)
 
     # replay rows: bit-equal to indexing, on every leaf shape of the
     # DDPG jax:lift replay (obs/next_obs [17], action [4], reward/discount [])

@@ -417,18 +417,6 @@ class SessionHooks:
                 phase=phase, calls_per_phase=calls_per_phase, **kwargs,
             )
 
-    def tune_event(self, **info) -> None:
-        """Record the autotuner's build-time decision (mode, cache
-        hit/miss, chosen config — and candidate timings when the search
-        ran) as one log line + one telemetry ``tune`` event, surfaced by
-        ``surreal_tpu diag`` so a session folder answers "which program
-        geometry actually trained?" without grepping configs."""
-        self.log.info(
-            "autotune: %s",
-            " ".join(f"{k}={v}" for k, v in sorted(info.items())),
-        )
-        self.tracer.event("tune", **info)
-
     def final_metrics(self, env_steps: int, extras=None) -> None:
         """Refresh the trailing metrics snapshot at run end. Drivers whose
         loop can consume env-step budget WITHOUT a metrics-cadence fire

@@ -84,25 +84,18 @@ class OffPolicyTrainer:
         self.config = config
         self.env = make_env(training_env_config(config.env_config))
         self.learner = build_learner(config.learner_config, self.env.specs)
-        # program autotuner: same build-time cache consult as Trainer's
-        # (launch/trainer.py) — applied knobs rewrite the learner overrides
-        from surreal_tpu.tune import resolve_autotune
-
-        self.tune_decision = resolve_autotune(config, self.learner.config)
-        if self.tune_decision.applied:
-            self.learner = build_learner(config.learner_config, self.env.specs)
         algo = self.learner.config.algo
         self.algo = algo
         # precision: the learner's resolved policy governs replay staging
         # (storage example dtype below) — one knob for models, learners,
         # AND replay dtypes (ops/precision.py). replay_gather routes the
-        # ring gather/scatter through the pallas row-DMA kernels (a
-        # searched dimension); injected into the replay build config so
-        # the replay layer stays algo-agnostic.
+        # ring gather/scatter through the pallas row-DMA kernels;
+        # injected into the replay build config so the replay layer stays
+        # algo-agnostic.
         self._replay_build_cfg = Config(
             gather_impl=algo.get("replay_gather", "xla")
         ).extend(self.learner.config.replay)
-        # searched scan unrolls (tune/space.py); `.get` keeps configs saved
+        # scan unrolls; `.get` keeps configs saved
         # before the knobs existed loadable
         self._rollout_unroll = int(algo.get("rollout_unroll", 1))
         self._update_unroll = max(
@@ -264,8 +257,7 @@ class OffPolicyTrainer:
 
     def init_loop_state(self, env_key: jax.Array):
         """(carry, replay_state) committed to the active mesh — ONE
-        constructor for run(), the autotuner's measurement harness
-        (tune/search.py), and tests, so none of them can drift from the
+        constructor for run() and tests, so neither can drift from the
         dp path's sharding/donation contract."""
         carry = self.committed_carry(env_key)
         example = self._replay_example()
@@ -349,7 +341,7 @@ class OffPolicyTrainer:
             return new_c, trans
 
         keys = jax.random.split(key, self.horizon)
-        # searched rollout-scan unroll (algo.rollout_unroll, tune/space.py)
+        # rollout-scan unroll (algo.rollout_unroll)
         return jax.lax.scan(
             step, carry, keys,
             unroll=max(1, min(self._rollout_unroll, self.horizon)),
@@ -410,7 +402,7 @@ class OffPolicyTrainer:
                 # equivalent by construction: sample_many derives set k
                 # from ukeys[k] exactly as sample() would, and learn
                 # consumes the same ukeys[k] — tests/test_replay.py pins
-                # bit-equal indices/batches, tests/test_tune.py pins the
+                # bit-equal indices/batches, tests/test_ddpg.py pins the
                 # fused iteration against the sequential path. Prioritized
                 # replay keeps the sequential path: priorities change
                 # between updates, so later draws depend on earlier TDs.
@@ -482,7 +474,7 @@ class OffPolicyTrainer:
                 self.replay.block_mass(replay_state)
                 if self.prioritized else None
             )
-            # searched update-loop unroll (algo.update_unroll)
+            # update-loop unroll (algo.update_unroll)
             (state, replay_state, _), metrics = jax.lax.scan(
                 one_update,
                 (state, replay_state, mass),
@@ -559,8 +551,6 @@ class OffPolicyTrainer:
         try:
             state, iteration, env_steps = hooks.restore(state)
             hooks.begin_run(iteration, env_steps)
-            if self.tune_decision.mode != "off":
-                hooks.tune_event(**self.tune_decision.telemetry())
             if not self.device_mode:
                 runner = (
                     self._run_host_remote if self.remote else self._run_host

@@ -73,11 +73,9 @@ def device_rollout(
     ``ep_done`` for metrics, ``acting`` (the rows ``learner.act_rows`` reads
     off the acting carry). Pure; callers jit it (fused with ``learn``).
 
-    ``unroll`` is the rollout scan's unroll factor (``algo.rollout_unroll``
-    — a searched autotuner dimension, surreal_tpu/tune/space.py): the
-    graded workloads are latency-bound on exactly this scan of tiny
-    elementwise env ops, so trading program size for fewer sequential loop
-    iterations is measured per workload, not guessed.
+    ``unroll`` is the rollout scan's unroll factor
+    (``algo.rollout_unroll``): program size for fewer sequential loop
+    iterations.
     """
 
     def step(scan_carry, step_key):

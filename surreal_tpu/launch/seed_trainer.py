@@ -226,18 +226,6 @@ class SEEDTrainer:
         self.specs = probe.specs
         probe.close()
         self.learner = build_learner(config.learner_config, self.specs)
-        # program autotuner: same build-time cache consult as the fused
-        # drivers. A host-env SEED workload has no fused device iteration,
-        # so its search surface is the jitted LEARN program alone
-        # (tune/search.py LEARN_PHASE_DIMS: sgd_unroll, gae_impl,
-        # gae_unroll, shuffle) — `surreal_tpu tune <algo> <host-env>`
-        # populates exactly this fingerprint, and 'search' mode runs the
-        # learn-only search at build
-        from surreal_tpu.tune import resolve_autotune
-
-        self.tune_decision = resolve_autotune(config, self.learner.config)
-        if self.tune_decision.applied:
-            self.learner = build_learner(config.learner_config, self.specs)
         if getattr(self.learner, "requires_act_carry", False):
             # Design note (round-5 VERDICT item 5): trajectory policies DO
             # act over the wire now — via Agent.remote_act / eval --follow,
@@ -603,8 +591,6 @@ class SEEDTrainer:
 
                 state = replicate_state(self.mesh, state)
             hooks.begin_run(iteration, env_steps)
-            if self.tune_decision.mode != "off":
-                hooks.tune_event(**self.tune_decision.telemetry())
             key_holder = [act_key]
             # workers inherit the run-scoped trace id via spawn kwargs
             self._trace_id = hooks.trace_id

@@ -53,19 +53,10 @@ class Trainer:
         self.config = config
         self.env = make_env(training_env_config(config.env_config))
         self.learner = build_learner(config.learner_config, self.env.specs)
-        # program autotuner (surreal_tpu/tune/): consult the per-workload
-        # tuning cache (or search, algo.autotune='search') BEFORE any
-        # jitted program is built; a non-empty decision rewrites the
-        # learner overrides, so rebuild the learner from them
-        from surreal_tpu.tune import resolve_autotune
-
-        self.tune_decision = resolve_autotune(config, self.learner.config)
-        if self.tune_decision.applied:
-            self.learner = build_learner(config.learner_config, self.env.specs)
         # the learner holds the fully-extended tree (algo defaults applied)
         self.horizon = self.learner.config.algo.horizon
-        # searched rollout-scan unroll (tune/space.py dimension); `.get`
-        # keeps configs saved before the knob existed loadable
+        # rollout-scan unroll; `.get` keeps configs saved before the knob
+        # existed loadable
         self._rollout_unroll = int(
             self.learner.config.algo.get("rollout_unroll", 1)
         )
@@ -215,8 +206,7 @@ class Trainer:
 
     def init_loop_state(self, env_key: jax.Array) -> RolloutCarry:
         """Device-mode rollout carry committed to the active mesh — ONE
-        constructor for run(), the autotuner's measurement harness
-        (tune/search.py), and tests, so none of them can drift from the
+        constructor for run() and tests, so neither can drift from the
         sharding/donation contract below."""
         carry = init_device_carry(self.env, env_key, self.num_envs)
         if getattr(self, "_sp_carry_sharding", None) is not None:
@@ -277,8 +267,6 @@ class Trainer:
 
                 state = replicate_state(self.mesh, state)
             hooks.begin_run(iteration, env_steps)
-            if self.tune_decision.mode != "off":
-                hooks.tune_event(**self.tune_decision.telemetry())
 
             if self.device_mode:
                 with launch_span("launch.carry_init"):

@@ -58,17 +58,16 @@ DDPG_LEARNER_CONFIG = Config(
             warmup_steps=2000,  # uniform-random actions before policy acting
         ),
         updates_per_iter=64,   # SGD updates per collect chunk (off-policy ratio)
-        update_unroll=1,       # update-loop scan unroll (searched autotuner
-                               # dimension — surreal_tpu/tune/space.py)
+        update_unroll=1,       # update-loop scan unroll
         # uniform replay only: draw ALL updates_per_iter index sets in one
         # batched gather before the update scan instead of one gather per
         # scan step (record-equivalent — same keys, same indices; see
         # OffPolicyTrainer._device_train_iter). Prioritized replay keeps
         # the sequential path: priorities change between updates.
         batched_uniform_sampling=True,
-        # replay gather implementation for the batched uniform fast path
-        # (a searched autotuner dimension, tune/space.py): 'xla' = one
-        # fused XLA ring gather | 'pallas' = scalar-prefetch gather
+        # replay gather implementation for the batched uniform fast
+        # path: 'xla' = one fused XLA ring gather | 'pallas' =
+        # scalar-prefetch gather
         # kernel (ops/pallas_replay.py; interpret mode off-TPU) — rows
         # DMA HBM->VMEM exactly once, driven by the index vector
         replay_gather="xla",

@@ -53,7 +53,7 @@ from surreal_tpu.models.ppo_net import CategoricalPPOModel, PPOModel
 from surreal_tpu.ops import distributions as D
 from surreal_tpu.ops.precision import current_loss_scale, loss_scale_metrics
 from surreal_tpu.ops.running_stats import RunningStats, init_stats, normalize, update_stats
-from surreal_tpu.ops.vtrace import vtrace_nextobs, vtrace_nextobs_assoc
+from surreal_tpu.ops.vtrace import vtrace_nextobs
 from surreal_tpu.session.config import Config
 from surreal_tpu.utils.phases import phase
 
@@ -67,12 +67,6 @@ IMPALA_LEARNER_CONFIG = Config(
         value_coeff=0.5,
         entropy_coeff=0.01,
         init_log_std=-0.5,    # continuous-action variant
-        # V-trace recurrence implementation (a searched autotuner
-        # dimension, tune/space.py — the per-op kernel twin of PPO's
-        # gae_impl): 'xla' lax.scan | 'assoc' log-depth associative_scan
-        # | 'pallas' fused kernel (ops/pallas_vtrace.py; interpret mode
-        # off-TPU)
-        vtrace_impl="xla",
     ),
     optimizer=Config(lr=6e-4),
     replay=Config(kind="fifo"),
@@ -339,35 +333,14 @@ class IMPALALearner(SequenceActingMixin, Learner):
         return new_state, metrics
 
     def _vtrace(self, **kw):
-        """V-trace with exact truncation handling, routed by
-        ``algo.vtrace_impl`` (the per-op kernel dimension, mirroring
-        PPO's ``gae_impl``): 'xla' reverse lax.scan | 'assoc' log-depth
-        associative_scan | 'pallas' fused VMEM-resident kernel
-        (ops/pallas_vtrace.py; interpret mode off-TPU so the CPU suite
-        covers it)."""
+        """V-trace with exact truncation handling
+        (``ops/vtrace.py::vtrace_nextobs``, a reverse ``lax.scan``)."""
         algo = self.config.algo
-        clips = dict(
+        return vtrace_nextobs(
+            **kw,
             gamma=algo.gamma, clip_rho=algo.clip_rho, clip_c=algo.clip_c,
             clip_pg_rho=algo.clip_pg_rho,
-        )
-        impl = algo.get("vtrace_impl", "xla")
-        if impl == "pallas":
-            from surreal_tpu.ops import pallas_interpret
-            from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
-
-            return vtrace_nextobs_pallas(
-                **kw, **clips, interpret=pallas_interpret()
-            )
-        if impl == "assoc":
-            return vtrace_nextobs_assoc(**kw, **clips)
-        if impl != "xla":
-            raise ValueError(
-                f"vtrace_impl {impl!r} not in xla|assoc|pallas"
-            )
-        return vtrace_nextobs(
-            **kw, **clips,
-            # searched recurrence unroll (tune/space.py); clamped in the
-            # op. `.get` keeps pre-knob configs loadable
+            # clamped in the op. `.get` keeps pre-knob configs loadable
             unroll=int(algo.get("gae_unroll", 1)),
         )
 

@@ -80,14 +80,7 @@ PPO_LEARNER_CONFIG = Config(
         clip_value=True,      # PPO-style value clipping
         norm_adv=True,
         init_log_std=-0.5,
-        gae_impl="xla",       # 'xla' (lax.scan) | 'assoc' (log-depth
-                              # associative_scan — ~T/log2(T) fewer
-                              # sequential steps, the right pick on
-                              # latency-bound backends) | 'pallas'
-                              # (ops/pallas_gae fused kernel; interpret
-                              # mode off-TPU)
         sgd_unroll=1,         # minibatch-scan unroll inside _sgd_epochs
-                              # (searched autotuner dimension — tune/space.py)
         shuffle="block",      # minibatch shuffling: 'block' permutes
                               # contiguous blocks, read where they lie
                               # when long enough (_sgd_epochs has the
@@ -368,38 +361,18 @@ class PPOLearner(SequenceActingMixin, Learner):
     def _gae(self, batch, values, v_next):
         """GAE over [T, B] arrays with the truncation-exact two-mask form
         (bootstrap discount gamma*(1-terminated) vs accumulation decay
-        gamma*lam*(1-done)), routed by ``algo.gae_impl``: 'xla' lax.scan,
-        'assoc' log-depth associative_scan (~log2(T) combine rounds — the
-        dispatch-latency pick), or the fused 'pallas' kernel."""
+        gamma*lam*(1-done)), as a reverse ``lax.scan``."""
         algo = self.config.algo
         gamma = jnp.asarray(algo.gamma, jnp.float32)
         boot_disc = gamma * (1.0 - batch["terminated"].astype(jnp.float32))
         decay = gamma * algo.lam * (1.0 - batch["done"].astype(jnp.float32))
-        gae_impl = algo.get("gae_impl", "xla")
-        if gae_impl == "pallas":
-            from surreal_tpu.ops import pallas_interpret
-            from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
-
-            return gae_advantages_pallas_masked(
-                batch["reward"], boot_disc, decay, values, v_next,
-                interpret=pallas_interpret(),
-            )
         deltas = batch["reward"] + boot_disc * v_next - values
-        if gae_impl == "assoc":
-            from surreal_tpu.ops.returns import reverse_linear_scan_assoc
-
-            advantages = reverse_linear_scan_assoc(decay, deltas)
-            return advantages, advantages + values
-        if gae_impl != "xla":
-            raise ValueError(f"gae_impl {gae_impl!r} not in xla|assoc|pallas")
 
         def gae_step(carry, xs):
             delta_t, decay_t = xs
             adv = delta_t + decay_t * carry
             return adv, adv
 
-        # unroll is the searched algo.gae_unroll (only this 'xla' path has
-        # a sequential scan to unroll; assoc/pallas restructure it instead)
         _, advs_rev = jax.lax.scan(
             gae_step, jnp.zeros_like(deltas[0]), (deltas[::-1], decay[::-1]),
             unroll=max(1, min(int(algo.get("gae_unroll", 1)), deltas.shape[0])),
@@ -633,9 +606,8 @@ class PPOLearner(SequenceActingMixin, Learner):
                 )
             return (params, opt_state, stopped), aux
 
-        # searched minibatch-scan unroll (algo.sgd_unroll, tune/space.py);
-        # clamped to the scan length so an oversized cache entry from a
-        # wider geometry cannot fail the trace
+        # minibatch-scan unroll (algo.sgd_unroll), clamped to the scan
+        # length so a value set for a wider geometry cannot fail the trace
         sgd_unroll = max(1, min(int(algo.get("sgd_unroll", 1)), num_mb))
 
         def epoch_update(carry, epoch_key):

@@ -742,125 +742,6 @@ def run_eval(args) -> int:
     return 0
 
 
-def _parse_dims(spec: str) -> list:
-    """``--dims`` spec -> [(name, [values])]; e.g.
-    ``rollout_unroll=1,2,4;gae_impl=xla,assoc``. Values parse as JSON when
-    possible (ints), else stay strings (impl names)."""
-    dims = []
-    for part in spec.split(";"):
-        part = part.strip()
-        if not part:
-            continue
-        name, sep, vals = part.partition("=")
-        if not sep or not vals:
-            raise ValueError(f"--dims entry {part!r} is not name=v1,v2,...")
-        values = []
-        for v in vals.split(","):
-            v = v.strip()
-            try:
-                values.append(json.loads(v))
-            except json.JSONDecodeError:
-                values.append(v)
-        dims.append((name.strip(), values))
-    return dims
-
-
-def _merge_tune_artifact(path: str, row: dict) -> None:
-    """Append/replace ``row`` (keyed by fingerprint) in the shared
-    ``tune --out`` artifact (e.g. ``tune.json``), atomically — repeated
-    `surreal_tpu tune` runs against different geometries accumulate into
-    one record instead of clobbering each other."""
-    import jax
-
-    data = {"metric": "autotune_fused_iter_ms", "workloads": []}
-    try:
-        with open(path) as f:
-            old = json.load(f)
-        if isinstance(old, dict) and isinstance(old.get("workloads"), list):
-            data = old
-    except (OSError, json.JSONDecodeError):
-        pass
-    data["workloads"] = [
-        w for w in data["workloads"] if w.get("key") != row.get("key")
-    ] + [row]
-    # record the device actually measured — a CPU fallback must never
-    # masquerade as a chip record
-    data["device"] = str(jax.devices()[0].device_kind)
-    data["platform"] = str(jax.devices()[0].platform)
-    with open(path + ".tmp", "w") as f:
-        json.dump(data, f, indent=2, default=float)
-    os.replace(path + ".tmp", path)
-
-
-def run_tune(args) -> int:
-    """Standalone autotuner run (surreal_tpu/tune/): search this
-    workload's candidate space with fenced chained timing,
-    persist the winner in the per-workload tuning cache, and record a
-    ``tune`` telemetry event (+ optional shared artifact). A second run on
-    the same fingerprint is a PURE cache hit — zero measurements — unless
-    ``--force``; trainers with ``algo.autotune='cache'`` then build with
-    the cached config without paying any search cost."""
-    config = build_config(args)
-    _apply_backend(config.session_config.backend)
-    _require_platform(config.session_config.backend)
-    from surreal_tpu.tune import resolve_tuning_cache_dir
-    from surreal_tpu.tune.search import tune_workload
-
-    result = tune_workload(
-        config,
-        dims=_parse_dims(args.dims) if args.dims else None,
-        warmup=args.warmup,
-        iters=args.iters,
-        force=args.force,
-        verbose=True,
-    )
-
-    fp = result.get("fingerprint", {})
-    summary = {
-        "workload": f"{args.algo} {args.env}",
-        "geometry": (
-            f"{fp.get('env', {}).get('num_envs', args.num_envs)} envs x "
-            f"{fp.get('algo', {}).get('horizon', '?')} horizon"
-        ),
-        "key": result["key"],
-        "cache_hit": result["cache_hit"],
-        "measured": result["measured"],
-        "config": result["config"],
-        "default": result.get("default", {}),
-        "default_ms": result.get("default_ms"),
-        "chosen_ms": result.get("chosen_ms"),
-        "speedup": result.get("speedup"),
-        "platform": result.get("platform"),
-        "device_kind": result.get("device_kind"),
-        "trials": result.get("trials", []),
-    }
-
-    # telemetry: the tune event lands in the session folder's spine so
-    # `surreal_tpu diag <folder>` renders the candidate timings + hit/miss
-    from surreal_tpu.session.telemetry import Tracer
-
-    os.makedirs(config.session_config.folder, exist_ok=True)
-    tracer = Tracer(config.session_config.folder, name="tune")
-    tracer.event(
-        "tune",
-        mode="search",
-        key=result["key"],
-        hit=bool(result["cache_hit"]),
-        source="cache" if result["cache_hit"] else "search",
-        cache_dir=resolve_tuning_cache_dir(config.session_config),
-        config=result["config"],
-        default_ms=result.get("default_ms"),
-        chosen_ms=result.get("chosen_ms"),
-        trials=result.get("trials", []),
-    )
-    tracer.close()
-
-    if args.out:
-        _merge_tune_artifact(args.out, summary)
-    print(json.dumps(summary, default=float))
-    return 0
-
-
 def run_profile(args) -> int:
     """Request an on-demand profiler capture from a LIVE training session:
     drops ``<folder>/profile.trigger``, which the session's ProfileManager
@@ -1110,42 +991,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "server/first publish")
     a.add_argument("--seed", type=int, default=0)
     a.set_defaults(fn=run_actor)
-
-    tu = sub.add_parser("tune", help="autotune a workload's program "
-                        "geometry: search scan-unroll/gae_impl/shuffle "
-                        "candidates with fenced chained timing and "
-                        "persist the winner in the per-workload tuning "
-                        "cache (trainers apply it via "
-                        "learner_config.algo.autotune='cache'|'search')")
-    tu.add_argument("algo", choices=ALGOS)
-    tu.add_argument("env", help="env with backend prefix; jax:* tunes the "
-                    "fused device iteration over the full space, host "
-                    "envs (gym:/dm_control: — the SEED fingerprints) "
-                    "tune the learn-phase knobs against the jitted learn "
-                    "program alone")
-    tu.add_argument("--folder", required=True,
-                    help="session directory (tuning cache + telemetry "
-                         "land here unless session_config.tuning_cache_dir"
-                         " points elsewhere)")
-    tu.add_argument("--num-envs", type=int, default=64)
-    tu.add_argument("--set", nargs="*", metavar="KEY=VAL", default=[],
-                    help="dotlist overrides (geometry knobs, "
-                         "session_config.tuning_cache_dir, ...)")
-    tu.add_argument("--iters", type=int, default=8,
-                    help="measured chained iterations per candidate")
-    tu.add_argument("--warmup", type=int, default=2,
-                    help="unmeasured compile/warmup iterations per candidate")
-    tu.add_argument("--dims", default=None,
-                    help="restrict the search space, e.g. "
-                         "'rollout_unroll=1,2,4;gae_impl=xla,assoc' "
-                         "(default: the full declared space, tune/space.py)")
-    tu.add_argument("--force", action="store_true",
-                    help="re-measure even on a cache hit")
-    tu.add_argument("--out", default=None,
-                    help="merge the result into a shared artifact, e.g. "
-                         "tune.json (keyed by fingerprint)")
-    tu.set_defaults(fn=run_tune, total_steps=None, restore_from=None,
-                    workers=None)
 
     p = sub.add_parser("profile", help="ask a LIVE session for an "
                        "on-demand jax.profiler capture (writes "

@@ -9,9 +9,7 @@ SCALAR-PREFETCH operand, so each sampled row is a single HBM->VMEM block
 DMA addressed directly by ``idx[i]`` — no gather HLO, no index
 materialization on the vector unit, and the scatter twin writes priority
 refreshes back with the same addressing (``input_output_aliases`` keeps
-it in-place). Selected per workload by ``algo.replay_gather='pallas'``
-(a searched autotuner dimension, tune/space.py — adopted only when
-MEASURED faster, like every kernel in the suite).
+it in-place). Selected per workload by ``algo.replay_gather='pallas'``.
 
 Layout contract: kernels operate on [rows, 1, features] views — the
 replay layer flattens each pytree leaf's trailing dims (and restores

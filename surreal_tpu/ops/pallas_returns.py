@@ -3,15 +3,12 @@ the third member of the hot-kernel suite (ISSUE 7 tentpole, piece 2).
 
 ``x_t = deltas_t + coeffs_t * x_{t+1}`` is THE recurrence of return
 estimation (ops/returns.py docstring): GAE, V-trace, and discounted
-returns are all instances. The GAE and V-trace kernels fuse their
-surrounding elementwise work into specialized single-pass kernels
-(ops/pallas_gae.py, ops/pallas_vtrace.py); this module provides the
-GENERIC solver as a kernel — one HBM->VMEM load per 128-lane batch
-stripe, the whole recurrence on-chip — plus the discounted-returns
-drop-in built on it.
+returns are all instances. This module provides the GENERIC solver as a
+kernel — one HBM->VMEM load per 128-lane batch stripe, the whole
+recurrence on-chip — plus the discounted-returns drop-in built on it.
 
-Dtype contract: float32 in/out regardless of input dtype, same as the
-sibling kernels (the recurrence accumulates T terms).
+Dtype contract: float32 in/out regardless of input dtype (the
+recurrence accumulates T terms).
 
 Runs in interpret mode off-TPU (``interpret=True``), which is how the
 CPU test suite bit-validates both entry points against their XLA

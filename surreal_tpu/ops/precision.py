@@ -8,8 +8,7 @@ stayed float32 regardless, and nothing guarded a low-precision run
 against silent gradient overflow. ``algo.precision`` replaces that with a
 named policy threaded through every learner (ppo/ddpg/impala), the
 models, the fused trainer programs, and the replay staging path — no
-per-driver forks, and a searchable autotuner dimension
-(surreal_tpu/tune/space.py) like every other program-geometry knob.
+per-driver forks.
 
 Policies (params and optimizer state are float32 under ALL of them — the
 Accelerated-Methods (arXiv:1803.02811) mixed-precision discipline):
@@ -25,7 +24,7 @@ Accelerated-Methods (arXiv:1803.02811) mixed-precision discipline):
   dynamic loss scaling on by default.
 - ``'bf16_fp8'`` — 'bf16' plus the experimental fp8 matmul path: Dense
   matmuls quantize both operands to float8_e4m3fn (per-tensor dynamic
-  scale) before the dot. Behind this knob only — never auto-searched.
+  scale) before the dot. Behind this knob only.
 
 Dynamic loss scaling (:func:`dynamic_loss_scaling`) wraps the whole
 optimizer chain so an overflow SKIPS the step entirely (Adam moments

@@ -36,8 +36,7 @@ def gae_advantages(
       discounts: [T, ...]  (= gamma * (1 - done))
       values:    [T+1, ...] value estimates incl. bootstrap at index T
       lam:       GAE lambda
-      unroll:    scan unroll factor (``algo.gae_unroll`` — a searched
-                 autotuner dimension, surreal_tpu/tune/space.py)
+      unroll:    scan unroll factor (``algo.gae_unroll``)
 
     Returns:
       (advantages [T, ...], value_targets [T, ...]) where targets = adv + v.

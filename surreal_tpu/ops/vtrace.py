@@ -46,8 +46,7 @@ def vtrace(
       discounts:      [T, ...] gamma * (1 - done)
       values:         [T+1, ...] learner value estimates incl. bootstrap
       clip_rho/clip_c/clip_pg_rho: IS-weight truncation levels (rho_bar etc.)
-      unroll: recurrence-scan unroll factor (``algo.gae_unroll`` — a
-        searched autotuner dimension, surreal_tpu/tune/space.py)
+      unroll: recurrence-scan unroll factor (``algo.gae_unroll``)
     """
     log_rhos = target_logp - behaviour_logp
     rhos = jnp.exp(log_rhos)
@@ -133,15 +132,17 @@ def vtrace_nextobs(
 
     All args are time-major [T, ...]; ``values``/``values_next`` are the
     learner's V(s_t) / V(s'_t). ``unroll`` is the recurrence scan's unroll
-    factor (``algo.gae_unroll`` — a searched autotuner dimension).
+    factor (``algo.gae_unroll``).
     """
     log_rhos = target_logp - behaviour_logp
     rhos = jnp.exp(log_rhos)
     clipped_rhos = jnp.minimum(clip_rho, rhos)
     cs = jnp.minimum(clip_c, rhos)
 
-    boot_disc = gamma * (1.0 - terminated.astype(rewards.dtype))
-    edge = 1.0 - done.astype(rewards.dtype)
+    # float32 masks, as PPO's ``_gae`` has them: bfloat16 rewards and values
+    # are promoted where they meet one, and the recurrence accumulates in f32
+    boot_disc = gamma * (1.0 - terminated.astype(jnp.float32))
+    edge = 1.0 - done.astype(jnp.float32)
 
     deltas = clipped_rhos * (rewards + boot_disc * values_next - values)
 
@@ -152,7 +153,7 @@ def vtrace_nextobs(
 
     _, acc_rev = lax.scan(
         step,
-        jnp.zeros_like(values[-1]),
+        jnp.zeros_like(deltas[-1]),
         (deltas[::-1], edge[::-1], cs[::-1]),
         unroll=max(1, min(int(unroll), deltas.shape[0])),
     )
@@ -161,50 +162,6 @@ def vtrace_nextobs(
     # pg advantage: q_t = r + boot_disc * (vs of the successor); at episode
     # boundaries the successor lives in the next episode, so fall back to
     # the value estimate of the terminal obs.
-    vs_shift = jnp.concatenate([vs[1:], values_next[-1:]], axis=0)
-    done_f = done.astype(rewards.dtype)
-    vs_next = done_f * values_next + (1.0 - done_f) * vs_shift
-    clipped_pg_rhos = jnp.minimum(clip_pg_rho, rhos)
-    pg_advantages = clipped_pg_rhos * (rewards + boot_disc * vs_next - values)
-
-    return VTraceOutput(
-        vs=lax.stop_gradient(vs), pg_advantages=lax.stop_gradient(pg_advantages)
-    )
-
-
-def vtrace_nextobs_assoc(
-    behaviour_logp: jax.Array,
-    target_logp: jax.Array,
-    rewards: jax.Array,
-    values: jax.Array,
-    values_next: jax.Array,
-    done: jax.Array,
-    terminated: jax.Array,
-    gamma: float,
-    clip_rho: float = 1.0,
-    clip_c: float = 1.0,
-    clip_pg_rho: float = 1.0,
-) -> VTraceOutput:
-    """:func:`vtrace_nextobs` via ``associative_scan`` — O(log T) depth.
-
-    Same recurrence shared with GAE's assoc path
-    (``ops.returns.reverse_linear_scan_assoc``): the per-step coefficient
-    is ``gamma * (1 - done) * c_t``, the additive term the clipped TD
-    delta. Selected by ``algo.vtrace_impl='assoc'`` (the dispatch-latency
-    pick, mirroring PPO's ``gae_impl='assoc'``).
-    """
-    from surreal_tpu.ops.returns import reverse_linear_scan_assoc
-
-    log_rhos = target_logp - behaviour_logp
-    rhos = jnp.exp(log_rhos)
-    clipped_rhos = jnp.minimum(clip_rho, rhos)
-    cs = jnp.minimum(clip_c, rhos)
-
-    boot_disc = gamma * (1.0 - terminated.astype(rewards.dtype))
-    edge = 1.0 - done.astype(rewards.dtype)
-    deltas = clipped_rhos * (rewards + boot_disc * values_next - values)
-    vs = reverse_linear_scan_assoc(gamma * edge * cs, deltas) + values
-
     vs_shift = jnp.concatenate([vs[1:], values_next[-1:]], axis=0)
     done_f = done.astype(rewards.dtype)
     vs_next = done_f * values_next + (1.0 - done_f) * vs_shift

@@ -106,9 +106,8 @@ def ring_insert(state: RingState, batch: Any, capacity: int) -> RingState:
 def ring_gather(state: RingState, idx: jax.Array, impl: str = "xla") -> Any:
     """Gather transitions at ``idx`` -> {k: [B, ...]}.
 
-    ``impl`` routes the data movement (``algo.replay_gather`` — a
-    searched autotuner dimension, tune/space.py): 'xla' = the fused XLA
-    gather; 'pallas' = the scalar-prefetch row-DMA kernel
+    ``impl`` routes the data movement (``algo.replay_gather``): 'xla' =
+    the fused XLA gather; 'pallas' = the scalar-prefetch row-DMA kernel
     (ops/pallas_replay.py; interpret mode off-TPU). Bit-equal outputs
     either way — the kernel copies rows verbatim.
     """

@@ -29,8 +29,8 @@ class UniformReplay:
         self.start_sample_size = int(replay_config.start_sample_size)
         # replay-gather routing ('xla' | 'pallas' — the scalar-prefetch
         # row-DMA kernel, ops/pallas_replay.py); injected from
-        # algo.replay_gather by the off-policy trainer, a searched
-        # autotuner dimension. `.get` keeps raw replay configs loadable.
+        # algo.replay_gather by the off-policy trainer. `.get` keeps raw
+        # replay configs loadable.
         self.gather_impl = replay_config.get("gather_impl", "xla")
 
     def init(self, example_transition: Any) -> RingState:
@@ -68,7 +68,7 @@ class UniformReplay:
         keys[k])`` bit-for-bit — same randint shape/bounds per key, same
         storage gather — so the fused iteration's training record is
         IDENTICAL either way (tested in tests/test_replay.py /
-        tests/test_tune.py). Uniform-only: the state doesn't change
+        tests/test_ddpg.py). Uniform-only: the state doesn't change
         between draws, which is exactly what prioritized replay violates.
         """
         bs = batch_size or self.batch_size

@@ -24,24 +24,14 @@ BASE_LEARNER_CONFIG = Config(
         # was acted more than this many updates ago (None = train on all;
         # V-trace absorbs bounded staleness, PPO-over-SEED should bound it)
         max_staleness=None,
-        # program autotuner (surreal_tpu/tune/): 'off' = hand-set knobs
-        # below; 'cache' = apply the tuning cache's winner for this
-        # workload fingerprint (falls back to defaults on a miss, never
-        # pays search cost); 'search' = on a miss, measure the candidate
-        # space at trainer build time and persist the winner (device
-        # jax:* envs only). `surreal_tpu tune <algo> <env>` runs the
-        # search standalone against the same cache.
-        autotune="off",
-        # searched scan-unroll knobs (tune/space.py declares the candidate
-        # values; every hot lax.scan states its decision explicitly —
-        # enforced by the test_import_hygiene unroll lint):
+        # scan-unroll knobs (every hot lax.scan states its decision
+        # explicitly — enforced by the test_import_hygiene unroll lint):
         rollout_unroll=1,  # device rollout scan over the horizon
-        gae_unroll=1,      # time recurrences: PPO's xla GAE scan,
-                           # IMPALA's V-trace scan, ops/returns estimators
+        gae_unroll=1,      # time recurrences: PPO's GAE scan, IMPALA's
+                           # V-trace scan, ops/returns estimators
         # precision policy (ops/precision.py) — ONE knob governing model
         # compute dtype, trajectory/SGD/replay staging dtype, and dynamic
-        # loss scaling, threaded through every learner and trainer (and a
-        # searched autotuner dimension, tune/space.py):
+        # loss scaling, threaded through every learner and trainer:
         #   'f32'      compute f32, staging f32 (numerics baseline)
         #   'mixed'    compute bf16, staging f32 (the pre-ISSUE-7 default
         #              — kept default so existing configs/checkpoints
@@ -50,8 +40,7 @@ BASE_LEARNER_CONFIG = Config(
         #   'bf16'     compute bf16 AND staging bf16 (obs-class arrays
         #              move half the bytes) + dynamic loss scaling
         #   'bf16_fp8' 'bf16' plus the experimental fp8 matmul path in
-        #              Dense layers — behind this knob only, never
-        #              auto-searched
+        #              Dense layers
         precision="mixed",
     ),
     model=Config(
@@ -391,13 +380,6 @@ BASE_SESSION_CONFIG = Config(
         ),
     ),
     total_env_steps=1_000_000,
-    # persistent JSON tuning cache (surreal_tpu/tune/cache.py): one entry
-    # per workload fingerprint holding the measured winner + its full
-    # trial record. Relative paths resolve
-    # under the session folder; None defaults to '<folder>/tuning_cache';
-    # an absolute path shares one cache across sessions (the pattern for
-    # `surreal_tpu tune` once + `algo.autotune='cache'` everywhere).
-    tuning_cache_dir=None,
     checkpoint=Config(
         every_n_iters=500,
         keep_last=3,

@@ -87,12 +87,6 @@ line, ``t`` = unix seconds):
      "shm_workers": N, "pickle_workers": M, "wire_bytes_per_step": B,
      ...}           (SEED drivers via SessionHooks.data_plane_event; the
                      last event reflects the settled negotiation)
-    {"type": "tune", "t": ..., "mode": "cache|search", "hit": ...,
-     "source": "...", "config": {...}, ["trials": [...], ...]}
-                    (autotuner decisions: trainers via
-                     SessionHooks.tune_event at build, the `surreal_tpu
-                     tune` CLI with full candidate timings; diag reports
-                     the last one plus hit/miss counts)
     {"type": "recovery", "t": ..., "kind": "interrupt|tripped|rollback|
      checkpoint_fallback|skipped_nonfinite_checkpoint|giveup", ...}
                     (the fault-tolerance layer: preemption sentinel stops,
@@ -268,7 +262,6 @@ EVENT_REGISTRY = {
     "device": "the platform/kind/count JAX resolved for the run "
               "(SessionHooks.begin_run)",
     "data_plane": "SEED drivers via SessionHooks.data_plane_event",
-    "tune": "autotuner decisions (tune/, launch/ via tune_event)",
     "recovery": "fault-tolerance layer (session/interrupt.py, "
                 "launch/recovery.py, session/checkpoint.py)",
     "fault": "chaos firings drained by SessionHooks (utils/faults.py)",
@@ -1028,8 +1021,6 @@ def diag_summary(folder: str) -> dict | None:
     perf_last: dict[str, float] = {}  # perf/*, replay/* gauges, last row
     hops = None                      # last 'hops' event's percentiles
     profiles: list[dict] = []        # 'profile' capture events
-    tune = None
-    tune_hits = tune_misses = 0
     recovery_counts: dict[str, int] = {}
     recovery_last = None
     fault_count = 0
@@ -1099,14 +1090,6 @@ def diag_summary(folder: str) -> dict | None:
             engine = {
                 k: v for k, v in ev.items() if k not in ("type", "t", "trace", "seq")
             }
-        elif ev.get("type") == "tune":
-            # the last event is the active decision; hit/miss counts
-            # accumulate over the session (trainer builds + CLI runs)
-            tune = {k: v for k, v in ev.items() if k not in ("type", "t", "trace", "seq")}
-            if ev.get("hit"):
-                tune_hits += 1
-            else:
-                tune_misses += 1
         elif ev.get("type") == "recovery":
             kind = str(ev.get("kind", "?"))
             recovery_counts[kind] = recovery_counts.get(kind, 0) + 1
@@ -1215,9 +1198,6 @@ def diag_summary(folder: str) -> dict | None:
         "serving": serving,
         "gateway": gateway,
         "engine": engine,
-        "tune": tune,
-        "tune_hits": tune_hits,
-        "tune_misses": tune_misses,
         "recovery": (
             {"counts": recovery_counts, "last": recovery_last}
             if recovery_counts else None
@@ -1305,35 +1285,6 @@ def diag_report(folder: str) -> str | None:
     gw_lines = _gateway_lines(s)
     if gw_lines:
         lines += ["", "Gateway"] + gw_lines
-    tn = s.get("tune")
-    if tn is not None:
-        cfg = tn.get("config") or {}
-        lines += [
-            "",
-            f"Autotuner — mode={tn.get('mode')} "
-            f"source={tn.get('source')} "
-            f"{'cache hit' if tn.get('hit') else 'cache miss'} "
-            f"({s.get('tune_hits', 0)} hits / {s.get('tune_misses', 0)} "
-            "misses this session)",
-            "  config: "
-            + (
-                ", ".join(f"{k}={cfg[k]}" for k in sorted(cfg))
-                if cfg else "(static defaults)"
-            ),
-        ]
-        trials = tn.get("trials")
-        if trials:
-            lines.append(
-                f"  {len(trials)} candidates measured "
-                f"(default {tn.get('default_ms', 0):.2f} ms -> chosen "
-                f"{tn.get('chosen_ms', 0):.2f} ms/iter):"
-            )
-            for t in trials[:16]:
-                lines.append(
-                    f"    {t.get('iter_ms', 0):>9.2f} ms  {t.get('config')}"
-                )
-            if len(trials) > 16:
-                lines.append(f"    ... {len(trials) - 16} more")
     perf_lines = _performance_lines(s)
     if perf_lines:
         lines += ["", "Performance"] + perf_lines

@@ -417,3 +417,87 @@ def test_fused_prioritized_iteration_is_the_sequence_run_by_hand(monkeypatch):
         np.testing.assert_allclose(
             float(fused_row[k]), float(by_hand_row[k]), rtol=1e-6, err_msg=k
         )
+
+
+# -- the unroll keys and the batched draw change the program, not its result --
+# (tolerances: conftest.py::assert_same_update)
+_FUSED = {}
+
+
+def _small_ddpg_config(tmp_path, replay=None, **algo_over):
+    """jax:pendulum, 8 envs x horizon 8, 4 updates of 16 rows an iteration:
+    batch, start and capacity divide by the 8-way dp mesh the trainer takes
+    on the suite's simulated devices."""
+    return Config(
+        learner_config=Config(
+            algo=Config(
+                name="ddpg", horizon=8, exploration=Config(warmup_steps=0),
+                updates_per_iter=4, **algo_over,
+            ),
+            replay=Config(batch_size=16, start_sample_size=16, **(replay or {})),
+        ),
+        env_config=Config(name="jax:pendulum", num_envs=8),
+        session_config=Config(folder=str(tmp_path)),
+    ).extend(base_config())
+
+
+def _fused_ddpg(tmp_path, **algo_over):
+    """Metrics and both networks' params after one fused DDPG iteration,
+    memoized per variant."""
+    tag = tuple(sorted(algo_over.items()))
+    if tag not in _FUSED:
+        t = OffPolicyTrainer(_small_ddpg_config(tmp_path, **algo_over))
+        key, ik, ek = jax.random.split(jax.random.key(3), 3)
+        state = t.learner.init(ik)
+        if t.mesh is not None and t.mesh.size > 1:
+            from surreal_tpu.parallel.mesh import replicate_state
+
+            state = replicate_state(t.mesh, state)
+        carry, replay_state = t.init_loop_state(ek)
+        state, _, _, metrics = t._train_iter(
+            state, replay_state, carry, jax.random.split(key)[1],
+            jnp.asarray(0.0, jnp.float32), jnp.asarray(False), jnp.asarray(True),
+        )
+        _FUSED[tag] = jax.device_get((
+            metrics,
+            {"actor": state.actor_params, "critic": state.critic_params},
+        ))
+    return _FUSED[tag]
+
+
+@pytest.mark.parametrize(
+    "variant",
+    [
+        {"rollout_unroll": 4},
+        {"update_unroll": 4},
+        pytest.param({"rollout_unroll": 2, "update_unroll": 2},
+                     marks=pytest.mark.slow),
+    ],
+    ids=["rollout", "update", "both"],
+)
+def test_ddpg_unrolled_program_matches_default(
+    tmp_path, variant, assert_same_update
+):
+    assert_same_update(_fused_ddpg(tmp_path), _fused_ddpg(tmp_path, **variant))
+
+
+def test_ddpg_batched_sampling_record_equivalence(tmp_path, assert_same_update):
+    """The uniform-replay fast path (one batched index draw + gather for
+    the whole update loop) must train on the IDENTICAL record as the
+    sequential path: same keys -> same indices -> same batches -> same
+    updates. Index/batch equality is bit-exact (tests/test_replay.py);
+    here the fused iteration's metrics and params must agree to float32
+    fusion-reordering tolerance."""
+    assert_same_update(
+        _fused_ddpg(tmp_path, batched_uniform_sampling=False),
+        _fused_ddpg(tmp_path, batched_uniform_sampling=True),
+    )
+
+
+def test_prioritized_replay_keeps_sequential_sampling(tmp_path):
+    """Prioritized replay must NOT take the batched path: priorities
+    change between updates, so draw k+1 depends on draw k's TD errors."""
+    t = OffPolicyTrainer(
+        _small_ddpg_config(tmp_path, replay={"kind": "prioritized"})
+    )
+    assert t.prioritized and not t._batched_sampling

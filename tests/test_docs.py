@@ -33,7 +33,6 @@ _FILE_EXTS = (".py", ".json", ".md", ".jsonl")
 # and the documents describe: not in the tree, by design
 _OUTPUT_PATHS = {
     "/root/TESTS_LAST_RUN.json",          # the driver's record of its test run
-    "tune.json",                          # `surreal_tpu tune --out` example
     "/tmp/campaign.json",                 # `surreal_tpu chaos --out` example
     # under a session's --folder
     "config.json", "checkpoints/", "checkpoints/run_meta.json", "extra/",

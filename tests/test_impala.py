@@ -337,3 +337,46 @@ def test_successor_values_under_dp_match_one_device():
     assert float(one_metrics["impala/boot_full"]) == 1.0
     assert float(dp_metrics["impala/boot_rows"]) == 13 * 2 / 8
     assert float(dp_metrics["impala/boot_full"]) == 0.0
+
+
+# -- the unroll keys change the program's shape, not its result --------------
+# (tolerances: conftest.py::assert_same_update)
+_FUSED = {}
+
+
+def _fused_impala(tmp_path, **algo_over):
+    """Metrics and params after one fused IMPALA iteration on jax:pendulum
+    (8 envs x horizon 8), memoized per variant."""
+    tag = tuple(sorted(algo_over.items()))
+    if tag not in _FUSED:
+        cfg = Config(
+            learner_config=Config(
+                algo=Config(name="impala", horizon=8, **algo_over)
+            ),
+            env_config=Config(name="jax:pendulum", num_envs=8),
+            session_config=Config(folder=str(tmp_path)),
+        ).extend(base_config())
+        t = Trainer(cfg)
+        key, ik, ek = jax.random.split(jax.random.key(3), 3)
+        state = t.learner.init(ik)
+        if t.mesh is not None and t.mesh.size > 1:
+            from surreal_tpu.parallel.mesh import replicate_state
+
+            state = replicate_state(t.mesh, state)
+        state, _, metrics = t._train_iter(
+            state, t.init_loop_state(ek), jax.random.split(key)[1]
+        )
+        _FUSED[tag] = jax.device_get((metrics, state.params))
+    return _FUSED[tag]
+
+
+@pytest.mark.parametrize(
+    "variant", [{"rollout_unroll": 4}, {"gae_unroll": 4}],
+    ids=["rollout", "vtrace"],
+)
+def test_impala_unrolled_program_matches_default(
+    tmp_path, variant, assert_same_update
+):
+    assert_same_update(
+        _fused_impala(tmp_path), _fused_impala(tmp_path, **variant)
+    )

@@ -209,11 +209,9 @@ def test_jitted_steps_declare_donation():
 
 
 _UNROLL_SCOPED_SOURCES = (
-    # hot-loop scan modules (the autotuner PR's invariant): every
-    # ``lax.scan`` here runs inside (or is traced into) a training hot
-    # loop whose unroll factor the autotuner searches
-    # (surreal_tpu/tune/space.py) — rollout scans, the SGD/update loops,
-    # the GAE/V-trace recurrences
+    # hot-loop scan modules: every ``lax.scan`` here runs inside (or is
+    # traced into) a training hot loop — rollout scans, the SGD/update
+    # loops, the GAE/V-trace recurrences
     "learners",
     "launch/rollout.py", "launch/trainer.py", "launch/offpolicy_trainer.py",
     "ops/returns.py", "ops/vtrace.py",
@@ -222,12 +220,12 @@ _UNROLL_SCOPED_SOURCES = (
 
 def test_hot_scans_declare_unroll():
     """Unroll-discipline lint (mirror of the donation lint above): a
-    ``lax.scan`` on a training hot path without an explicit ``unroll``
-    silently ships whatever jax defaults to, invisible to the autotuner
-    and to the next reader. Every call must state its decision — thread
-    the searched knob (``algo.rollout_unroll`` / ``sgd_unroll`` /
-    ``update_unroll`` / ``gae_unroll``), or pin ``unroll=1`` with the
-    reason the scan stays default."""
+    ``lax.scan`` on a training hot path without a declared ``unroll``
+    takes jax's default and no key reaches it, which the next reader
+    cannot see. Every call must state its decision — thread the key
+    (``algo.rollout_unroll`` / ``sgd_unroll`` / ``update_unroll`` /
+    ``gae_unroll``), or pin ``unroll=1`` with the reason the scan stays
+    default."""
     bad = []
     for entry in _UNROLL_SCOPED_SOURCES:
         root = _PKG_ROOT / entry

@@ -319,70 +319,135 @@ def test_vtrace_assoc_matches_scan():
     )
 
 
-def test_gae_pallas_matches_scan():
-    """The fused Pallas GAE kernel (interpret mode off-TPU) must match the
-    reverse-scan reference, including episode boundaries and non-multiple-
-    of-128 batch widths (padding path)."""
-    from surreal_tpu.ops.pallas_gae import gae_advantages_pallas
+# -- the two recurrences the learners run, each against a plain loop ----------
 
+def _ends(case: str):
+    """``(T, B, done, terminated, dtype)`` of one case: where episodes end in
+    a ``[T, B]`` rollout and how (a termination ends the episode AND the
+    bootstrap; a truncation ends the episode only)."""
+    T, B = (1, 5) if case == "one-step" else (8, 5)
+    done, terminated = np.zeros((T, B), bool), np.zeros((T, B), bool)
+    if case == "terminations":
+        for t, b in ((2, 0), (5, 0), (3, 3)):
+            done[t, b] = terminated[t, b] = True
+    elif case == "truncations":
+        for t, b in ((2, 1), (6, 4)):
+            done[t, b] = True
+    elif case in ("both-in-one-column", "bfloat16-in"):
+        done[2, 1] = True                        # truncated
+        done[5, 1] = terminated[5, 1] = True     # terminated
+        done[4, 3] = True
+    elif case == "end-on-last-step":
+        done[T - 1, 0] = terminated[T - 1, 0] = True
+        done[T - 1, 2] = True
+    elif case == "end-on-first-step":
+        done[0, 0] = terminated[0, 0] = True
+        done[0, 2] = True
+    elif case == "one-step":
+        done[0, 1] = terminated[0, 1] = True
+        done[0, 3] = True
+    elif case == "every-step-ends":              # nothing accumulates
+        done[:] = True
+        terminated[:, ::2] = True
+    else:
+        assert case == "no-end", case
+    dtype = jnp.bfloat16 if case == "bfloat16-in" else jnp.float32
+    return T, B, done, terminated, dtype
+
+
+RECURRENCE_CASES = [
+    "no-end", "terminations", "truncations", "both-in-one-column",
+    "end-on-last-step", "end-on-first-step", "one-step", "bfloat16-in",
+    "every-step-ends",
+]
+
+
+@pytest.mark.parametrize("case", RECURRENCE_CASES)
+def test_gae_scan_matches_a_numpy_loop(case):
+    """``PPOLearner._gae`` is the one GAE the learner runs: the bootstrap
+    discount is cut by ``terminated``, the accumulation by ``done``; any
+    input dtype in, float32 out."""
+    from surreal_tpu.envs.base import ArraySpec, EnvSpecs
+    from surreal_tpu.learners import build_learner
+    from surreal_tpu.session.config import Config
+
+    T, B, done, terminated, dtype = _ends(case)
+    learner = build_learner(
+        Config(algo=Config(name="ppo", gamma=0.99, lam=0.95)),
+        EnvSpecs(
+            obs=ArraySpec(shape=(3,), dtype=np.dtype(np.float32)),
+            action=ArraySpec(shape=(2,), dtype=np.dtype(np.float32)),
+        ),
+    )
     rng = np.random.default_rng(12)
-    for B in (128, 200):  # aligned and padded widths
-        T = 40
-        rewards = jnp.asarray(rng.normal(size=(T, B)), jnp.float32)
-        done = jnp.asarray(rng.random((T, B)) < 0.1)
-        discounts = 0.99 * (1.0 - done.astype(jnp.float32))
-        values = jnp.asarray(rng.normal(size=(T + 1, B)), jnp.float32)
-        adv_p, tgt_p = gae_advantages_pallas(
-            rewards, discounts, values, 0.95, interpret=True
-        )
-        adv, tgt = R.gae_advantages(rewards, discounts, values, 0.95)
-        np.testing.assert_allclose(np.asarray(adv_p), np.asarray(adv), rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(tgt_p), np.asarray(tgt), rtol=1e-5, atol=1e-5)
+    reward, values, v_next = (
+        jnp.asarray(rng.normal(size=(T, B)), dtype) for _ in range(3)
+    )
+    adv, targets = learner._gae(
+        {"reward": reward, "done": jnp.asarray(done),
+         "terminated": jnp.asarray(terminated)},
+        values, v_next,
+    )
+    assert adv.dtype == targets.dtype == jnp.float32
+    assert adv.shape == targets.shape == (T, B)
+
+    r, v, vn = (np.asarray(x, np.float64) for x in (reward, values, v_next))
+    want = np.zeros((T, B))
+    for b in range(B):
+        acc = 0.0
+        for t in reversed(range(T)):
+            delta = r[t, b] + 0.99 * (not terminated[t, b]) * vn[t, b] - v[t, b]
+            acc = delta + 0.99 * 0.95 * (not done[t, b]) * acc
+            want[t, b] = acc
+    np.testing.assert_allclose(np.asarray(adv), want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(targets), want + v, rtol=1e-5, atol=1e-5)
 
 
-def test_gae_pallas_masked_truncation_exact_and_f32_contract():
-    """The two-mask kernel entry (what `gae_impl=pallas` routes PPO
-    through) must reproduce the truncation-exact recurrence — bootstrap
-    discount uses (1-terminated), accumulation decay uses (1-done) — and
-    honor the documented dtype contract: any input dtype in, f32 out."""
-    from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
+@pytest.mark.parametrize("case", RECURRENCE_CASES)
+def test_vtrace_nextobs_matches_a_numpy_loop(case):
+    """``vtrace_nextobs`` is the one V-trace the learner runs: clipped
+    importance weights, the bootstrap cut by ``terminated``, the correction
+    cut by ``done``, and at a boundary the policy-gradient target falls back
+    to the successor's value; bfloat16 rewards and values in, float32 out."""
+    from surreal_tpu.ops.vtrace import vtrace_nextobs
 
+    T, B, done, terminated, dtype = _ends(case)
     rng = np.random.default_rng(13)
-    T, B = 32, 200  # padded width
-    gamma, lam = 0.99, 0.95
-    rewards = jnp.asarray(rng.normal(size=(T, B)), jnp.float32)
-    done = jnp.asarray(rng.random((T, B)) < 0.15)
-    # some dones are truncations (episode ends, no true termination)
-    terminated = done & jnp.asarray(rng.random((T, B)) < 0.5)
-    v_t = jnp.asarray(rng.normal(size=(T, B)), jnp.float32)
-    v_n = jnp.asarray(rng.normal(size=(T, B)), jnp.float32)
-    boot = gamma * (1.0 - terminated.astype(jnp.float32))
-    decay = gamma * lam * (1.0 - done.astype(jnp.float32))
+    blogp = jnp.asarray(rng.normal(scale=0.3, size=(T, B)), jnp.float32)
+    tlogp = blogp + jnp.asarray(rng.normal(scale=0.4, size=(T, B)), jnp.float32)
+    reward, values, v_next = (
+        jnp.asarray(rng.normal(size=(T, B)), dtype) for _ in range(3)
+    )
+    gamma, clip_rho, clip_c, clip_pg = 0.99, 1.0, 0.9, 1.1
+    out = vtrace_nextobs(
+        behaviour_logp=blogp, target_logp=tlogp, rewards=reward, values=values,
+        values_next=v_next, done=jnp.asarray(done),
+        terminated=jnp.asarray(terminated), gamma=gamma, clip_rho=clip_rho,
+        clip_c=clip_c, clip_pg_rho=clip_pg,
+    )
+    assert out.vs.dtype == out.pg_advantages.dtype == jnp.float32
+    assert out.vs.shape == out.pg_advantages.shape == (T, B)
 
-    adv_p, tgt_p = gae_advantages_pallas_masked(
-        rewards, boot, decay, v_t, v_n, interpret=True
-    )
-    # slow reverse-loop reference
-    adv_ref = np.zeros((T, B), np.float32)
-    acc = np.zeros(B, np.float32)
-    for t in reversed(range(T)):
-        delta = np.asarray(rewards[t] + boot[t] * v_n[t] - v_t[t])
-        acc = delta + np.asarray(decay[t]) * acc
-        adv_ref[t] = acc
-    np.testing.assert_allclose(np.asarray(adv_p), adv_ref, rtol=1e-5, atol=1e-5)
+    r, v, vn = (np.asarray(x, np.float64) for x in (reward, values, v_next))
+    rho = np.exp(np.asarray(tlogp, np.float64) - np.asarray(blogp, np.float64))
+    vs, pg = np.zeros((T, B)), np.zeros((T, B))
+    for b in range(B):
+        acc = 0.0
+        for t in reversed(range(T)):
+            boot = gamma * (not terminated[t, b])
+            delta = min(clip_rho, rho[t, b]) * (r[t, b] + boot * vn[t, b] - v[t, b])
+            acc = delta + gamma * (not done[t, b]) * min(clip_c, rho[t, b]) * acc
+            vs[t, b] = acc + v[t, b]
+            successor = (
+                vn[t, b] if done[t, b] or t == T - 1 else vs[t + 1, b]
+            )
+            pg[t, b] = min(clip_pg, rho[t, b]) * (
+                r[t, b] + boot * successor - v[t, b]
+            )
+    np.testing.assert_allclose(np.asarray(out.vs), vs, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(
-        np.asarray(tgt_p), adv_ref + np.asarray(v_t), rtol=1e-5, atol=1e-5
+        np.asarray(out.pg_advantages), pg, rtol=1e-5, atol=1e-5
     )
-    # dtype contract: bf16 inputs are cast in, outputs are f32
-    adv_bf, tgt_bf = gae_advantages_pallas_masked(
-        rewards.astype(jnp.bfloat16),
-        boot.astype(jnp.bfloat16),
-        decay.astype(jnp.bfloat16),
-        v_t.astype(jnp.bfloat16),
-        v_n.astype(jnp.bfloat16),
-        interpret=True,
-    )
-    assert adv_bf.dtype == jnp.float32 and tgt_bf.dtype == jnp.float32
 
 
 def test_ring_attention_matches_full_attention():

@@ -13,12 +13,8 @@ Documented tolerances (the numbers the assertions pin):
   bf16; bf16 only moves the f32->bf16 cast from per-minibatch-read to
   staging (the same rounding point) and adds exact power-of-two loss
   scaling.
-- Pallas recurrence kernels vs their XLA scans: <= 8 f32 ulps at unit
-  scale (atol 5e-6). The residual is XLA's FMA contraction inside the
-  compiled scan — the committed GAE kernel shows the identical delta on
-  this image; on-chip the round-3 measurement recorded exact equality.
-  The data-movement kernels (replay gather/scatter, discounted returns)
-  are bit-exact and asserted as such.
+- The Pallas kernels (replay gather/scatter, discounted returns) are
+  bit-exact against their XLA references and asserted as such.
 """
 
 from __future__ import annotations
@@ -278,26 +274,22 @@ def _tree_close(a, b, atol):
         # machinery (staging casts, loss scaling, the 'mixed'-vs-'bf16'
         # rounding-point identity) through the same fused-iteration
         # harness, and impala's distinct arithmetic (the v-trace
-        # recurrence) keeps its own tier-1 equivalence coverage in
-        # tests/test_tune.py — the impala arm rides the slow tier
-        # (ISSUE 19 suite-wall headroom pass, same precedent as the
-        # tuned-program sweeps)
+        # recurrence) keeps its own tier-1 coverage in tests/test_ops.py
+        # and tests/test_impala.py — the impala arm rides the slow tier
+        # (ISSUE 19 suite-wall headroom pass)
         "ppo",
         pytest.param("impala", marks=pytest.mark.slow),
     ],
 )
 def test_bf16_vs_f32_fused_iteration(algo):
-    # impala pins vtrace_impl so the cache key collides with the
-    # vtrace-equivalence test's xla arm (one compile, not two)
-    extra = {"vtrace_impl": "xla"} if algo == "impala" else {}
-    s32, m32 = _fused_iter(algo, "f32", **extra)
-    s16, m16 = _fused_iter(algo, "bf16", **extra)
+    s32, m32 = _fused_iter(algo, "f32")
+    s16, m16 = _fused_iter(algo, "bf16")
     for k in ("loss/pg", "loss/value", "policy/entropy"):
         np.testing.assert_allclose(m16[k], m32[k], rtol=5e-2, atol=5e-3)
     _tree_close(s16.params, s32.params, atol=5e-3)
     # and 'bf16' vs 'mixed' is tight: same compute dtype, staging cast at
     # the same rounding point, exact loss scaling
-    sm, mm = _fused_iter(algo, "mixed", **extra)
+    sm, mm = _fused_iter(algo, "mixed")
     for k in ("loss/pg", "loss/value"):
         np.testing.assert_allclose(m16[k], mm[k], rtol=1e-5, atol=1e-6)
     _tree_close(s16.params, sm.params, atol=1e-5)
@@ -391,60 +383,6 @@ def test_fp8_path_runs_and_stays_finite():
 # -- Pallas kernel validation (interpret mode) -------------------------------
 
 
-def _vtrace_inputs(T=16, B=37, seed=0):
-    rng = np.random.default_rng(seed)
-    f = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))
-    done = jnp.asarray(rng.random((T, B)) < 0.1)
-    return dict(
-        behaviour_logp=f(T, B) * 0.1 - 1.0,
-        target_logp=f(T, B) * 0.1 - 1.0,
-        rewards=f(T, B),
-        values=f(T, B),
-        values_next=f(T, B),
-        done=done,
-        terminated=done & jnp.asarray(rng.random((T, B)) < 0.5),
-    )
-
-
-def test_pallas_vtrace_nextobs_matches_xla():
-    from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
-    from surreal_tpu.ops.vtrace import vtrace_nextobs, vtrace_nextobs_assoc
-
-    kw = _vtrace_inputs()
-    ref = vtrace_nextobs(**kw, gamma=0.99)
-    pal = vtrace_nextobs_pallas(**kw, gamma=0.99, interpret=True)
-    # <= 8 f32 ulps at unit scale: the residual is XLA's FMA contraction
-    # inside the compiled scan (the committed GAE kernel shows the same
-    # delta on this image; on-chip the round-3 measurement was exact)
-    np.testing.assert_allclose(ref.vs, pal.vs, atol=5e-6, rtol=0)
-    np.testing.assert_allclose(
-        ref.pg_advantages, pal.pg_advantages, atol=5e-6, rtol=0
-    )
-    asc = vtrace_nextobs_assoc(**kw, gamma=0.99)
-    np.testing.assert_allclose(ref.vs, asc.vs, atol=1e-5, rtol=0)
-    np.testing.assert_allclose(
-        ref.pg_advantages, asc.pg_advantages, atol=1e-5, rtol=0
-    )
-
-
-def test_pallas_vtrace_simple_contract_matches_xla():
-    from surreal_tpu.ops.pallas_vtrace import vtrace_pallas
-    from surreal_tpu.ops.vtrace import vtrace
-
-    T, B = 12, 40
-    rng = np.random.default_rng(1)
-    f = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))
-    done = jnp.asarray(rng.random((T, B)) < 0.1)
-    disc = 0.99 * (1.0 - done.astype(jnp.float32))
-    args = (f(T, B) * 0.1, f(T, B) * 0.1, f(T, B), disc, f(T + 1, B))
-    ref = vtrace(*args)
-    pal = vtrace_pallas(*args, interpret=True)
-    np.testing.assert_allclose(ref.vs, pal.vs, atol=5e-6, rtol=0)
-    np.testing.assert_allclose(
-        ref.pg_advantages, pal.pg_advantages, atol=5e-6, rtol=0
-    )
-
-
 def test_pallas_discounted_returns_bit_exact():
     from surreal_tpu.ops.pallas_returns import discounted_returns_pallas
     from surreal_tpu.ops.returns import discounted_returns
@@ -511,20 +449,6 @@ def test_uniform_replay_pallas_gather_record_equivalent():
     np.testing.assert_array_equal(out["xla"][1], out["pallas"][1])
     for k in example:
         np.testing.assert_array_equal(out["xla"][0][k], out["pallas"][0][k])
-
-
-def test_impala_vtrace_impl_equivalence():
-    outs = {
-        impl: _fused_iter("impala", "mixed", vtrace_impl=impl)
-        for impl in ("xla", "assoc", "pallas")
-    }  # the xla arm is the memoized baseline from the bf16-vs-f32 test
-    ref = outs["xla"][1]
-    for impl in ("assoc", "pallas"):
-        for k in ("loss/pg", "loss/value"):
-            np.testing.assert_allclose(
-                outs[impl][1][k], ref[k], rtol=1e-4, atol=1e-5
-            )
-        _tree_close(outs[impl][0].params, outs["xla"][0].params, atol=1e-4)
 
 
 # -- checkpoint policy guard -------------------------------------------------

@@ -16,7 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 T, B = 256, 4096            # the fused PPO geometry (chip_smoke.py)
-VT, VB = 32, 1024           # IMPALA's V-trace geometry
+VT, VB = 32, 1024           # impala_pong_1k32's rollout geometry
 BATCH = 256                 # DDPG replay batch
 # leaves of the DDPG jax:lift replay example (OffPolicyTrainer._replay_example)
 LIFT_LEAVES = {"obs": (17,), "action": (4,), "reward": ()}
@@ -55,28 +55,11 @@ def _no_persistent_cache():
     reset_cache()
 
 
-def _gae(sds):
-    from surreal_tpu.ops.pallas_gae import gae_advantages_pallas_masked
-
-    x = sds((T, B), jnp.float32)
-    return gae_advantages_pallas_masked, (x, x, x, x, x)
-
-
 def _returns(sds):
     from surreal_tpu.ops.pallas_returns import discounted_returns_pallas
 
     x = sds((T, B), jnp.float32)
     return discounted_returns_pallas, (x, x, sds((B,), jnp.float32))
-
-
-def _vtrace(sds):
-    from functools import partial
-
-    from surreal_tpu.ops.pallas_vtrace import vtrace_nextobs_pallas
-
-    x = sds((VT, VB), jnp.float32)
-    m = sds((VT, VB), jnp.bool_)
-    return partial(vtrace_nextobs_pallas, gamma=0.99), (x, x, x, x, x, m, m)
 
 
 def _gather(capacity, leaf):
@@ -136,9 +119,7 @@ def _fused_step(sds, envs=B, learner=None, horizon=T, algo="ppo", env="jax:lift"
 
 
 CASES = [
-    pytest.param(_gae, True, id="gae-256x4096"),
     pytest.param(_returns, True, id="returns-256x4096"),
-    pytest.param(_vtrace, True, id="vtrace-32x1024"),
     *[
         pytest.param(_gather(cap, leaf), True, id=f"gather-{leaf}-{cap}")
         for cap in (200_000, 1_000_000)
@@ -266,7 +247,7 @@ def test_fused_impala_1024x32_convolves_the_frames_where_they_lie(sds):
     cell = Config(
         algo=Config(
             gamma=0.99, entropy_coeff=0.01, value_coeff=0.5, clip_rho=1.0,
-            clip_c=1.0, precision="mixed", vtrace_impl="xla",
+            clip_c=1.0, precision="mixed",
         ),
         model=Config(cnn=Config(enabled=True)),
     )
