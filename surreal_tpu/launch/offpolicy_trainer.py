@@ -96,7 +96,10 @@ class OffPolicyTrainer:
             gather_impl=algo.get("replay_gather", "xla")
         ).extend(self.learner.config.replay)
         # scan unrolls; `.get` keeps configs saved
-        # before the knobs existed loadable
+        # before the knobs existed loadable. rollout_unroll's default, 0,
+        # is "the collector chooses", and this 16-trip collector chooses 1
+        # (the clamp in _rollout): launch/rollout.py's rule was read on the
+        # chip for device_rollout's scan alone
         self._rollout_unroll = int(algo.get("rollout_unroll", 1))
         self._update_unroll = max(
             1, min(int(algo.get("update_unroll", 1)),

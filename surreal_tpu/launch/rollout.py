@@ -7,6 +7,9 @@ Two collectors, same batch contract (see learners/ppo.py docstring):
   ``lax.scan`` over the horizon, vmapped over B envs, inside the same jit
   as the learner step if the caller fuses them. This is the path where the
   reference needed 1000 actor processes and ZMQ; here it is one XLA loop.
+  Unless ``algo.rollout_unroll`` names a number, the scan chooses its own
+  unroll (:func:`rollout_unroll`): four env steps a trip for a memoryless
+  policy over vector observations, one otherwise.
 - :func:`host_rollout` — host envs (gym/dm_control/robosuite-class): the
   SEED-RL pattern, batched obs -> one jitted ``act`` -> batched env.step;
   per-step numpy dicts are aggregated (learners/aggregator.py) into one
@@ -58,6 +61,42 @@ def successor_and_termination(obs2, done, step_info):
     return next_obs, terminated
 
 
+def rollout_unroll(act_carry, obs: jax.Array, horizon: int, unroll: int = 0) -> int:
+    """The unroll of :func:`device_rollout`'s scan: env steps a trip.
+
+    A number the caller names (``unroll`` >= 1) wins. Otherwise the rule
+    reads two static facts of the scan's input, and no name:
+
+    - **4** where the policy acts without a carry (``act_carry`` is an empty
+      pytree) AND an env's observation is a vector (``obs`` is ``[B, D]``).
+      A step is then many thin ops over ``[B, .]`` rows and the count of
+      device ops binds it, not a peak. The fused PPO iteration at 65 536
+      envs x 256 steps, 17 -> 64 -> 64 -> 4, compiled for the v5e: 75 ops
+      an env step at unroll 1, of them 52 producing a ``[65536, .]`` array
+      (ten bare or fused ``dynamic-update-slice`` writes of the transition
+      among them); 68 / 30.5 at 2; **51.25 / 25 at 4**; 61.1 / 32.6 at 8:
+      neighbouring steps' writes fuse into their producers and one step's
+      broadcasts and concatenates into the next step's consumers. On the
+      chip (PR 49's traced runs, ``ppo_lift_long``) ``phase_collect_ms``
+      read 85.03 at 1, 64.29 at 2, **62.87 at 4**, and the iteration was
+      slower again at 8 (208.2 ms against 199.9): 4 is the minimum.
+    - **1** otherwise: a trajectory policy's step streams a gigabyte of
+      weights through a cache or a state at 94-97% of the HBM peak, and a
+      pixel policy's step is a few large convolutions (880 us a step, one
+      conv 323 of them); neither has thin ops to merge, and each copy of
+      the body is compile time. The chip read the pixel side too (PR 52,
+      ``impala_pong_1k32`` with 4 written out): 557 699 -> 477 203 steps/s,
+      ``collect`` 28.17 -> 34.83 ms and ``learn`` 28.32 -> 32.21, whose
+      convolutions then read a ``[8, 4, 1024, 84, 84, 4]`` rollout.
+
+    Clamped to the horizon.
+    """
+    if unroll < 1:
+        memoryless = not jax.tree_util.tree_leaves(act_carry)
+        unroll = 4 if memoryless and obs.ndim == 2 else 1
+    return max(1, min(unroll, horizon))
+
+
 def device_rollout(
     env: AutoReset,
     learner: Learner,
@@ -65,7 +104,7 @@ def device_rollout(
     carry: RolloutCarry,
     key: jax.Array,
     horizon: int,
-    unroll: int = 1,
+    unroll: int = 0,
 ):
     """Collect ``horizon`` steps across B batched on-device envs.
 
@@ -73,9 +112,9 @@ def device_rollout(
     ``ep_done`` for metrics, ``acting`` (the rows ``learner.act_rows`` reads
     off the acting carry). Pure; callers jit it (fused with ``learn``).
 
-    ``unroll`` is the rollout scan's unroll factor
-    (``algo.rollout_unroll``): program size for fewer sequential loop
-    iterations.
+    ``unroll`` is the rollout scan's unroll factor, program size for fewer
+    sequential loop iterations: ``algo.rollout_unroll`` where a user set
+    one, else 0, "the collector chooses" (:func:`rollout_unroll`).
     """
 
     def step(scan_carry, step_key):
@@ -119,9 +158,10 @@ def device_rollout(
     # memoryless learners get None, which scans as an empty pytree
     with phase("collect"):
         keys = jax.random.split(key, horizon)
+        act_carry = learner.act_init(carry.obs.shape[0])
         (new_carry, act_carry), batch = jax.lax.scan(
-            step, (carry, learner.act_init(carry.obs.shape[0])), keys,
-            unroll=max(1, min(int(unroll), horizon)),
+            step, (carry, act_carry), keys,
+            unroll=rollout_unroll(act_carry, carry.obs, horizon, int(unroll)),
         )
     return new_carry, dict(batch, acting=learner.act_rows(act_carry))
 

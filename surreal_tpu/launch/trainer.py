@@ -55,10 +55,11 @@ class Trainer:
         self.learner = build_learner(config.learner_config, self.env.specs)
         # the learner holds the fully-extended tree (algo defaults applied)
         self.horizon = self.learner.config.algo.horizon
-        # rollout-scan unroll; `.get` keeps configs saved before the knob
-        # existed loadable
+        # rollout-scan unroll: a user's number, or 0 = device_rollout
+        # chooses (launch/rollout.py::rollout_unroll); `.get` keeps configs
+        # saved before the knob existed loadable
         self._rollout_unroll = int(
-            self.learner.config.algo.get("rollout_unroll", 1)
+            self.learner.config.algo.get("rollout_unroll", 0)
         )
         self.num_envs = config.env_config.num_envs
         self.device_mode = is_jax_env(self.env)

@@ -26,7 +26,12 @@ BASE_LEARNER_CONFIG = Config(
         max_staleness=None,
         # scan-unroll knobs (every hot lax.scan states its decision
         # explicitly — enforced by the test_import_hygiene unroll lint):
-        rollout_unroll=1,  # device rollout scan over the horizon
+        # device rollout scan over the horizon. 0 = the collector chooses
+        # (launch/rollout.py::rollout_unroll: 4 for a memoryless policy
+        # over vector observations, 1 for a trajectory or pixel policy;
+        # DDPG's own collector in launch/offpolicy_trainer.py takes 1);
+        # a number >= 1 is the user's and wins
+        rollout_unroll=0,
         gae_unroll=1,      # time recurrences: PPO's GAE scan, IMPALA's
                            # V-trace scan, ops/returns estimators
         # precision policy (ops/precision.py) — ONE knob governing model

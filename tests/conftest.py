@@ -114,3 +114,46 @@ def assert_same_update():
         )
 
     return check
+
+
+@pytest.fixture
+def collect_scan_trips(tmp_path):
+    """``trips(algo, env, model=None, **algo_over)``: the trips of the rollout
+    scan's loop in the LOWERED fused iteration of a toy trainer (8 envs x
+    horizon 8): the bound of the ``while`` that ``lax.scan`` lowers to under
+    scope ``collect``. A scan of ``horizon`` steps at unroll u is a loop of
+    ``horizon // u`` trips over a body of u steps."""
+    import re
+
+    from surreal_tpu.launch.trainer import Trainer
+    from surreal_tpu.session.config import Config
+    from surreal_tpu.session.default_configs import base_config
+
+    def trips(algo, env, model=None, **algo_over):
+        learner = {"algo": Config(name=algo, horizon=8, **algo_over)}
+        if model is not None:
+            learner["model"] = model
+        trainer = Trainer(
+            Config(
+                learner_config=Config(**learner),
+                env_config=Config(name=env, num_envs=8),
+                session_config=Config(folder=str(tmp_path)),
+            ).extend(base_config())
+        )
+        key = jax.random.key(0)
+        text = jax.jit(trainer._device_train_iter).lower(
+            jax.eval_shape(trainer.learner.init, key),
+            jax.eval_shape(trainer.init_loop_state, key),
+            key,
+        ).as_text(debug_info=True)
+        (loc,) = re.findall(
+            r'(#loc\d+) = loc\("[^"]*/collect/while/cond/lt"', text
+        )
+        (bound,) = re.findall(
+            r"dense<(\d+)> : tensor<i32>[^\n]*\n\s*%\d+ = stablehlo\.compare\s+"
+            r"LT,[^\n]*loc\(" + loc + r"\)\n",
+            text,
+        )
+        return int(bound)
+
+    return trips

@@ -370,8 +370,11 @@ def _fused_impala(tmp_path, **algo_over):
     return _FUSED[tag]
 
 
+# the default's rollout scan runs four steps a trip on jax:pendulum (a
+# memoryless policy over vector observations: launch/rollout.py::
+# rollout_unroll), so the variant that differs from it is an explicit 1
 @pytest.mark.parametrize(
-    "variant", [{"rollout_unroll": 4}, {"gae_unroll": 4}],
+    "variant", [{"rollout_unroll": 1}, {"gae_unroll": 4}],
     ids=["rollout", "vtrace"],
 )
 def test_impala_unrolled_program_matches_default(
@@ -380,3 +383,19 @@ def test_impala_unrolled_program_matches_default(
     assert_same_update(
         _fused_impala(tmp_path), _fused_impala(tmp_path, **variant)
     )
+
+
+@pytest.mark.parametrize(
+    "env,model,trips",
+    [
+        ("jax:pong84", Config(cnn=Config(enabled=True)), 8),
+        ("jax:pendulum", None, 2),
+    ],
+    ids=["pixels", "vector"],
+)
+def test_fused_impala_collect_scan_trips(collect_scan_trips, env, model, trips):
+    """Toy IMPALA, horizon 8: over ``jax:pong84``'s frames the collect loop
+    of the lowered iteration keeps ``horizon`` trips; over a vector
+    observation it runs four steps a trip, as PPO's does (the rule reads the
+    scan's input, not the algorithm)."""
+    assert collect_scan_trips("impala", env, model) == trips

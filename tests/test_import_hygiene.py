@@ -225,7 +225,10 @@ def test_hot_scans_declare_unroll():
     cannot see. Every call must state its decision — thread the key
     (``algo.rollout_unroll`` / ``sgd_unroll`` / ``update_unroll`` /
     ``gae_unroll``), or pin ``unroll=1`` with the reason the scan stays
-    default."""
+    default. A key's default is 1, but for ``rollout_unroll``: its default
+    0 hands the decision to ``launch/rollout.py::rollout_unroll``, which
+    reads the scan's input (4 for a memoryless policy over vector
+    observations, 1 otherwise) and whose docstring holds the readings."""
     bad = []
     for entry in _UNROLL_SCOPED_SOURCES:
         root = _PKG_ROOT / entry
@@ -236,7 +239,8 @@ def test_hot_scans_declare_unroll():
                     bad.append(f"{path.relative_to(_REPO_ROOT)}:{line}")
     assert not bad, (
         "lax.scan calls in hot-loop modules without an explicit unroll "
-        "decision (thread the searched algo.*_unroll knob, or state "
+        "decision (thread an algo.*_unroll key, choose from the scan's "
+        "input as launch/rollout.py::rollout_unroll does, or state "
         "unroll=1 and why):\n" + "\n".join(bad)
     )
 

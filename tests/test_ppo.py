@@ -653,13 +653,16 @@ def _fused_ppo(tmp_path, **algo_over):
     return _FUSED[tag]
 
 
+# the default's rollout scan runs four steps a trip here (a memoryless
+# policy over jax:pendulum's vector observations: launch/rollout.py::
+# rollout_unroll), so the variant that differs from it is an explicit 1
 @pytest.mark.parametrize(
     "variant",
     [
-        {"rollout_unroll": 4},
+        {"rollout_unroll": 1},
         {"sgd_unroll": 2},
         {"gae_unroll": 4},
-        pytest.param({"rollout_unroll": 8, "sgd_unroll": 2, "gae_unroll": 2},
+        pytest.param({"rollout_unroll": 1, "sgd_unroll": 2, "gae_unroll": 2},
                      marks=pytest.mark.slow),
     ],
     ids=["rollout", "sgd", "gae", "all-unrolls"],
@@ -668,3 +671,83 @@ def test_ppo_unrolled_program_matches_default(
     tmp_path, variant, assert_same_update
 ):
     assert_same_update(_fused_ppo(tmp_path), _fused_ppo(tmp_path, **variant))
+
+
+# -- the rollout scan chooses its own unroll ---------------------------------
+_VECTOR_OBS = jax.ShapeDtypeStruct((8, 17), jnp.float32)
+_PIXEL_OBS = jax.ShapeDtypeStruct((8, 84, 84, 4), jnp.uint8)
+_NO_CARRY = None  # what a memoryless learner's act_init returns
+_A_CARRY = {"cache": jax.ShapeDtypeStruct((8, 16, 32), jnp.bfloat16), "t": 0}
+_TOY_TRAJECTORY = Config(
+    encoder=Config(
+        kind="trajectory", features=32, num_layers=1, num_heads=2, head_dim=8
+    )
+)
+
+
+@pytest.mark.parametrize(
+    "act_carry,obs,horizon,unroll,want",
+    [
+        (_NO_CARRY, _VECTOR_OBS, 256, 0, 4),
+        (_NO_CARRY, _VECTOR_OBS, 2, 0, 2),
+        ((), _VECTOR_OBS, 256, 0, 4),
+        (_A_CARRY, _VECTOR_OBS, 256, 0, 1),
+        (_NO_CARRY, _PIXEL_OBS, 256, 0, 1),
+        (_A_CARRY, _PIXEL_OBS, 256, 0, 1),
+        (_NO_CARRY, _VECTOR_OBS, 256, 1, 1),
+        (_NO_CARRY, _VECTOR_OBS, 256, 8, 8),
+        (_A_CARRY, _VECTOR_OBS, 256, 2, 2),
+        (_NO_CARRY, _PIXEL_OBS, 256, 4, 4),
+        (_NO_CARRY, _PIXEL_OBS, 2, 4, 2),
+    ],
+    ids=[
+        "memoryless-vector", "memoryless-vector-short-horizon",
+        "empty-tuple-carry", "acting-carry", "pixels", "acting-carry-pixels",
+        "explicit-1-over-memoryless-vector", "explicit-8-over-memoryless-vector",
+        "explicit-2-over-acting-carry", "explicit-4-over-pixels",
+        "explicit-clamped-to-horizon",
+    ],
+)
+def test_rollout_unroll_rule(act_carry, obs, horizon, unroll, want):
+    """``launch/rollout.py::rollout_unroll``: four env steps a trip where the
+    policy acts without a carry over vector observations, one where it
+    carries a cache or a state or sees pixels, a user's number over either,
+    all clamped to the horizon."""
+    from surreal_tpu.launch.rollout import rollout_unroll
+
+    assert rollout_unroll(act_carry, obs, horizon, unroll) == want
+
+
+def test_rollout_unroll_rule_reads_the_learner_s_own_carry():
+    """The two facts the rule reads, from real learners: a memoryless PPO's
+    ``act_init`` is an empty pytree, a trajectory policy's is not."""
+    from surreal_tpu.launch.rollout import rollout_unroll
+
+    specs = EnvSpecs(
+        obs=ArraySpec(shape=(17,), dtype=np.dtype(np.float32)),
+        action=ArraySpec(shape=(4,), dtype=np.dtype(np.float32)),
+    )
+    algo = Config(name="ppo", horizon=8, epochs=1, num_minibatches=1)
+    mlp = build_learner(Config(algo=algo), specs)
+    seq = build_learner(Config(algo=algo, model=_TOY_TRAJECTORY), specs)
+    assert not mlp.requires_act_carry and seq.requires_act_carry
+    assert rollout_unroll(mlp.act_init(8), _VECTOR_OBS, 8) == 4
+    assert rollout_unroll(seq.act_init(8), _VECTOR_OBS, 8) == 1
+
+
+@pytest.mark.parametrize(
+    "model,algo_over,trips",
+    [
+        (None, {}, 2),
+        (None, {"rollout_unroll": 1}, 8),
+        (_TOY_TRAJECTORY, {}, 8),
+    ],
+    ids=["memoryless", "memoryless-explicit-1", "trajectory"],
+)
+def test_fused_ppo_collect_scan_trips(collect_scan_trips, model, algo_over, trips):
+    """Toy PPO on ``jax:lift``, horizon 8: the collect loop of the lowered
+    iteration has ``horizon / 4`` trips for the MLP policy, ``horizon`` for a
+    trajectory policy, and what a user's ``algo.rollout_unroll`` says."""
+    assert trips == collect_scan_trips(
+        "ppo", "jax:lift", model, epochs=1, num_minibatches=1, **algo_over
+    )
