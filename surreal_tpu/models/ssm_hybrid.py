@@ -40,11 +40,13 @@ one position against a carry of three kinds side by side
 state and a ``[envs, taps - 1, C]`` conv tail, constant in size; per
 window layer a ring of ``sliding_window`` slots that forgets (slot =
 position mod window; a slot counts only if written in this segment, which
-the position says); for the full layer ``[envs, T, G, 64]`` keys and
-values that the cross layers read and never copy. The gated-memory and
-cross layers hold nothing. A wrap to a new segment zeroes the state-space
-leaves (:func:`reset_recurrent`) and only moves the position for the
-others, whose stale rows the masks hide.
+the position says); for the full layer the keys and values of ``T`` slots
+that the cross layers read and never copy. A slot's row is 128 lanes wide
+where the heads allow it (:func:`heads_per_row`): at the published 20
+heads of 64, ``[envs, T, 10, 128]`` with two neighbouring heads side by
+side. The gated-memory and cross layers hold nothing. A wrap to a new
+segment zeroes the state-space leaves (:func:`reset_recurrent`) and only
+moves the position for the others, whose stale rows the masks hide.
 
 The state spans episode ends inside a segment and is zero at the segment's
 start, exactly as attention's context here (``learners/ppo.py::_learn_seq``
@@ -95,6 +97,8 @@ DT_MIN, DT_MAX = 1e-3, 1e-1
 # is recomputed in the backward: an eighth of a v5e's memory
 REMAT_ABOVE_BYTES = 2 * 2**30
 QUERY_BLOCK = 256
+# the lanes of a TPU's vector register: the minor axis of an array's tiles
+LANES = 128
 
 # model.encoder keys this family reads beside the shared ones (kind, block,
 # num_heads, act_impl), with the values an unset (None) key takes:
@@ -115,10 +119,13 @@ KINDS = ("ssm", "window", "full", "gmu", "cross")
 # what a whole-segment apply sows, one scalar each: ``{sown name: (metrics
 # row, how the row reduces it over an iteration's minibatch steps)}``: the
 # largest entry of a state a segment ended with (a recurrence that blows up
-# shows before the loss does), and the keys a windowed query saw
+# shows before the loss does), the keys a windowed query saw, and how many
+# key-value heads share a row of the acting caches (static: which form of
+# the decode's attention this trunk runs, heads_per_row)
 COUNTERS = {
     "state_abs_max": ("ssm/state_abs_max", "max"),
     "window_keys_mean": ("attn/window_keys_mean", "mean"),
+    "cache_heads_per_row": ("attn/cache_heads_per_row", "max"),
 }
 
 
@@ -351,13 +358,35 @@ def attention_mixer(p, h, s, dt, window, kv=None):
         return jnp.einsum("bthe,hed->btd", out, p["o"].astype(dt)), kv, seen
 
 
+def heads_per_row(G: int, hd: int) -> int:
+    """How many neighbouring key-value heads one row of an acting cache
+    holds. A head narrower than the :data:`LANES` would leave the slot
+    axis the only one that fills them, and the compiler then lays the cache
+    out with the SLOTS on the lanes: a step's one-slot write touches every
+    tile of the array (72-76 us for 40 KB at 20 heads of 64). ``p`` heads
+    that fill the lanes exactly share a row, ``[envs, slots, G / p, 128]``;
+    any other geometry keeps a head a row (1)."""
+    p = LANES // hd
+    return p if p > 1 and p * hd == LANES and G % p == 0 else 1
+
+
 def _attend_one(q, k, v, valid):
-    """One query a row ``q [B, H, hd]`` over cached ``k, v [B, S, G, hd]``,
-    slots where ``valid [S]``: ``[B, H, hd]``; rounds as
-    ``blocked_attention`` does."""
+    """One query a row ``q [B, H, hd]`` over cached ``k, v [B, S, G / p,
+    p * hd]`` (``p`` key-value heads a row, :func:`heads_per_row`; the
+    width says which), slots where ``valid [S]``: ``[B, H, hd]``; rounds as
+    ``blocked_attention`` does. Where heads share a row the products
+    contract over the whole row: a query sits in its own head's lanes of a
+    zero row and keeps its own head's lanes of the result, so all the wider
+    products add to a head's sums are exact zeros."""
     B, H, hd = q.shape
-    G = k.shape[2]
-    q = q.reshape(B, G, H // G, hd)
+    J, p = k.shape[2], k.shape[3] // hd
+    q = q.reshape(B, J, H // J, hd)
+    if p > 1:
+        # head p*j + a of row j owns lanes a*hd .. (a+1)*hd
+        own = jnp.eye(p, dtype=bool)[:, None, :, None]
+        q = jnp.where(own, q.reshape(B, J, p, -1, 1, hd), 0).reshape(
+            B, J, H // J, p * hd
+        )
     scores = jnp.einsum(
         "bgrd,bkgd->bgrk", q, k, preferred_element_type=jnp.float32
     ) / jnp.sqrt(jnp.float32(hd))
@@ -366,20 +395,24 @@ def _attend_one(q, k, v, valid):
         "bgrk,bkgd->bgrd", prob.astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
+    if p > 1:
+        out = jnp.where(own, out.reshape(B, J, p, -1, p, hd), 0).sum(4)
     return out.astype(q.dtype).reshape(B, H, hd)
 
 
 def attention_step(p, h, cache, pos, dt, ring: bool):
     """One position of a window (``ring``) or full layer: writes its key
-    and value at slot ``pos mod S`` of ``cache {"k", "v"} [B, S, G, hd]``
-    and attends to the slots written in this segment that the query may
-    see. In a ring every such slot lies inside the window: the one
-    ``window`` positions back is the one just overwritten."""
+    and value at slot ``pos mod S`` of ``cache {"k", "v"} [B, S, G / p,
+    p * hd]`` (one row: the heads of a row are neighbours already) and
+    attends to the slots written in this segment that the query may see.
+    In a ring every such slot lies inside the window: the one ``window``
+    positions back is the one just overwritten."""
     with part("attn"):
-        S = cache["k"].shape[1]
+        B, S = cache["k"].shape[:2]
         slot = pos % S if ring else pos
         put = lambda c, w: jax.lax.dynamic_update_slice_in_dim(
-            c, _heads(w, h, dt)[:, None].astype(c.dtype), slot, axis=1
+            c, _heads(w, h, dt).reshape(B, 1, *c.shape[2:]).astype(c.dtype),
+            slot, axis=1,
         )
         cache = {"k": put(cache["k"], p["k"]), "v": put(cache["v"], p["v"])}
         return cross_step(p, h, cache, pos, dt), cache
@@ -464,6 +497,7 @@ def forward(params: dict, x, cfg: dict, dt, residual: int):
         # a trunk without a window layer (pairs_before 0) has no such query
         "window_keys_mean": jnp.stack(windows).mean() if windows
         else jnp.zeros((), jnp.float32),
+        "cache_heads_per_row": jnp.float32(heads_per_row(s["G"], s["hd"])),
     }
 
 
@@ -536,13 +570,15 @@ class SSMHybridTrunk(nn.Module):
 def acting_cache(cfg: dict, num_envs: int, horizon: int, dtype) -> dict:
     """The acting carry's cache, three kinds side by side: ``{"ssm":
     [{"state" [envs, N, C] float32, "conv" [envs, taps - 1, C]}, ...],
-    "ring": [{"k", "v" [envs, min(window, horizon), G, hd]}, ...],
-    "shared": {"k", "v" [envs, horizon, G, hd]}}``; keys, values and the
-    conv tail in the compute dtype."""
+    "ring": [{"k", "v" [envs, min(window, horizon), G / p, p * hd]}, ...],
+    "shared": {"k", "v" [envs, horizon, G / p, p * hd]}}``, ``p`` heads a
+    row (:func:`heads_per_row`); keys, values and the conv tail in the
+    compute dtype."""
     s = _sizes(cfg)
     kinds = [k for k, _ in layer_kinds(cfg)]
+    p = heads_per_row(s["G"], s["hd"])
     kv = lambda slots: {
-        name: jnp.zeros((num_envs, slots, s["G"], s["hd"]), dtype)
+        name: jnp.zeros((num_envs, slots, s["G"] // p, p * s["hd"]), dtype)
         for name in ("k", "v")
     }
     return {

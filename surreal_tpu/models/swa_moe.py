@@ -319,11 +319,11 @@ def attention_mixer(p, h, s, dt, kind: str):
 
 
 def attention_step(p, h, cache, pos, s, dt, kind: str):
-    """One position ``h [B, D]`` of a full or window layer: its key, turned
-    at ``pos``, and value go to slot ``pos`` (``pos mod S`` in a window
-    layer's ring) of ``cache {"k", "v"} [B, S, G, hd]``, and the query
-    attends to the slots written in this segment that it may see
-    (``models/ssm_hybrid.py::attention_step`` has the argument for a ring)."""
+    """One position ``h [B, D]`` of a full or window layer: its key, turned at
+    ``pos``, and value go to slot ``pos`` (``pos mod S`` in a window layer's
+    ring) of ``cache {"k", "v"} [B, S, G, hd]``, a head a row (``_attend_one``
+    reads that off the row's width); the query attends to the slots written in
+    this segment (``models/ssm_hybrid.py::attention_step`` argues the ring)."""
     with part(PART[kind]):
         S = cache["k"].shape[1]
         slot = pos % S if kind == "window" else pos

@@ -29,6 +29,13 @@ SPECS = EnvSpecs(
     obs=ArraySpec(shape=(5,), dtype=np.dtype(np.float32)),
     action=ArraySpec(shape=(2,), dtype=np.dtype(np.float32)),
 )
+# ``{case: (encoder overrides, key-value heads a cache row, a row's shape)}``:
+# TOY's heads of 8 keep a head a row; two heads of 64 fill a row's 128 lanes
+# and the acting caches pack them (ssm_hybrid.heads_per_row)
+ROWS = {
+    "a head a row": ({}, 1, (2, 8)),
+    "two heads a row": (dict(hidden_size=256), 2, (1, 128)),
+}
 CFG = ssm_hybrid.resolve(TOY)
 SIZES = ssm_hybrid._sizes(CFG)
 # the reference reads every width from its configuration's file: the three
@@ -124,50 +131,111 @@ def _act(learner, state, obs, carry=None):
     return jax.tree.map(lambda *x: jnp.stack(x, 1), *infos), carry
 
 
+@pytest.mark.parametrize("row", ROWS)
 @pytest.mark.parametrize("precision,tol", [("f32", 2e-5), ("mixed", 2e-2)])
 def test_decode_through_state_ring_and_shared_cache_equals_the_full_forward(
-    precision, tol
+    precision, tol, row
 ):
-    """At every one of 12 positions with a window of 4: what ``act_step``
-    produced through the constant-size state, the ring that forgets and the
-    shared cache is what one whole-segment apply recomputes."""
-    learner = _learner(T, precision)
+    """At every one of 12 positions with a window of 4 (the ring wraps
+    twice): what ``act_step`` produced through the constant-size state, the
+    ring that forgets and the shared cache is what one whole-segment apply
+    recomputes, with a head a cache row and with two heads sharing one."""
+    encoder, heads_a_row, kv_row = ROWS[row]
+    learner = _learner(T, precision, **encoder)
     state = learner.init(jax.random.key(0))
     obs = jax.random.normal(jax.random.key(1), (T, B, 5), jnp.float32)
     info, carry = _act(learner, state, obs)
-    out = learner.model.apply(
+    out, stats = learner._apply(
         state.params, learner._norm_obs(state.obs_stats, obs).swapaxes(0, 1)
     )
     np.testing.assert_allclose(info["mean"], out.mean, rtol=0, atol=tol)
     np.testing.assert_allclose(info["value"], out.value, rtol=0, atol=tol)
     assert float(jnp.abs(out.value).max()) > 0.1
     assert int(carry["pos"]) == T
+    # the counter says which row the decode ran on
+    assert float(stats["cache_heads_per_row"]) == heads_a_row
+    assert carry["cache"]["ring"][0]["v"].shape == (B, WINDOW, *kv_row)
     # and the window matters here: ignoring it moves the outputs
-    wide = _learner(T, precision, sliding_window=T)
+    wide = _learner(T, precision, sliding_window=T, **encoder)
     other = wide.model.apply(
         state.params, learner._norm_obs(state.obs_stats, obs).swapaxes(0, 1)
     )
     assert float(jnp.abs(other.value - out.value)[:, WINDOW:].max()) > 3 * tol
 
 
-def test_the_carry_holds_three_kinds_of_state_side_by_side():
-    learner = _learner(T, "mixed")
+def _attend_plain(q, k, v, valid):
+    """A head a row, ``k, v [B, S, G, hd]``: the decode's attention as it
+    was before rows were shared, kept here as the packed form's reference."""
+    B, H, hd = q.shape
+    G = k.shape[2]
+    q = q.reshape(B, G, H // G, hd)
+    scores = jnp.einsum(
+        "bgrd,bkgd->bgrk", q, k, preferred_element_type=jnp.float32
+    ) / jnp.sqrt(jnp.float32(hd))
+    prob = jax.nn.softmax(
+        jnp.where(valid, scores, ssm_hybrid._NEG_BIG), axis=-1
+    )
+    out = jnp.einsum(
+        "bgrk,bkgd->bgrd", prob.astype(v.dtype), v,
+        preferred_element_type=jnp.float32,
+    )
+    return out.astype(q.dtype).reshape(B, H, hd)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("G,hd,H,p", [
+    (2, 64, 4, 2), (6, 64, 12, 2), (4, 32, 8, 4),       # rows of 128 lanes
+    (3, 64, 6, 1), (2, 128, 4, 1), (2, 8, 4, 1),        # a head a row
+])
+def test_heads_sharing_a_row_attend_bit_for_bit_as_a_head_a_row(G, hd, H, p, dtype):
+    """The query in its own head's lanes of a zero row adds exact zeros to
+    the same sums: the packed read IS the unpacked one on the CPU, in both
+    dtypes; a geometry that does not fill 128 lanes keeps a head a row."""
+    assert ssm_hybrid.heads_per_row(G, hd) == p
+    S, keys = 24, jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(keys[0], (B, H, hd), dtype)
+    k, v = (jax.random.normal(key, (B, S, G, hd), dtype) for key in keys[1:])
+    valid = jnp.arange(S) <= 17
+    row = lambda x: x.reshape(B, S, G // p, p * hd)
+    got = jax.jit(ssm_hybrid._attend_one)(q, row(k), row(v), valid)
+    want = jax.jit(_attend_plain)(q, k, v, valid)
+    assert got.dtype == want.dtype and got.shape == (B, H, hd)
+    np.testing.assert_array_equal(
+        np.asarray(got, np.float32), np.asarray(want, np.float32)
+    )
+    assert float(jnp.abs(want.astype(jnp.float32)).max()) > 0.1
+    # a masked slot counts for nothing, whichever row holds it
+    moved = jax.jit(ssm_hybrid._attend_one)(
+        q, row(k.at[:, 18:].add(1)), row(v.at[:, 18:].add(1)), valid
+    )
+    np.testing.assert_array_equal(
+        np.asarray(moved, np.float32), np.asarray(got, np.float32)
+    )
+
+
+@pytest.mark.parametrize("row", ROWS)
+def test_the_carry_holds_three_kinds_of_state_side_by_side(row):
+    encoder, _, kv_row = ROWS[row]
+    width = {**TOY, **encoder}["hidden_size"]
+    learner = _learner(T, "mixed", **encoder)
     cache = learner.act_init(B)["cache"]
     shapes = jax.tree.map(lambda x: (x.shape, x.dtype.name), cache)
     kv = lambda slots: {
-        n: ((B, slots, 2, 8), "bfloat16") for n in ("k", "v")
+        n: ((B, slots, *kv_row), "bfloat16") for n in ("k", "v")
     }
     assert shapes == {
-        "ssm": [
-            {"state": ((B, 4, 64), "float32"), "conv": ((B, 3, 64), "bfloat16")}
-        ] * 2,
+        "ssm": [{
+            "state": ((B, 4, 2 * width), "float32"),
+            "conv": ((B, 3, 2 * width), "bfloat16"),
+        }] * 2,
         "ring": [kv(WINDOW)],
         "shared": kv(T),
     }
     # the ring is the window's size whatever the horizon; a horizon inside
     # the window needs no more slots than it has positions
-    assert _learner(64).act_init(B)["cache"]["ring"][0]["k"].shape[1] == WINDOW
-    assert _learner(2).act_init(B)["cache"]["ring"][0]["k"].shape[1] == 2
+    ring = lambda horizon: _learner(horizon, **encoder).act_init(B)["cache"]["ring"]
+    assert ring(64)[0]["k"].shape[1] == WINDOW
+    assert ring(2)[0]["k"].shape[1] == 2
 
 
 def test_a_wrap_zeroes_the_recurrent_leaves_and_leaves_the_rest():
@@ -260,14 +328,14 @@ def test_ppo_first_epoch_ratio_is_one(precision, tol):
     # (the obs filter is off the comparison: the state's statistics are
     # init's in both, as a first iteration's acting has them)
     assert float(jnp.abs(ratio - 1).max()) < tol
-    assert set(stats) == {"state_abs_max", "window_keys_mean"}
+    assert set(stats) == set(ssm_hybrid.COUNTERS)
     # the extended pass has T + 1 positions
     assert float(stats["window_keys_mean"]) == pytest.approx(
         ref.window_keys_mean(T + 1, WINDOW)
     )
 
 
-def test_learn_reports_the_two_counters():
+def test_learn_reports_the_three_counters():
     learner = _learner(T, "mixed")
     state = learner.init(jax.random.key(0))
     new, metrics = jax.jit(learner.learn)(
@@ -277,6 +345,7 @@ def test_learn_reports_the_two_counters():
         ref.window_keys_mean(T, WINDOW)
     )
     assert 0 < float(metrics["ssm/state_abs_max"]) < 1e3
+    assert float(metrics["attn/cache_heads_per_row"]) == 1.0    # heads of 8
     assert float(metrics["health/update_ratio"]) > 0
     assert "moe/overflow" not in metrics
 

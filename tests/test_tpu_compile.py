@@ -7,6 +7,7 @@ time. Nothing runs: a compile that passes says nothing about results.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -433,6 +434,19 @@ def test_an_acting_steps_routed_layer_reads_the_live_experts(
         assert defined.group(2) == "get-tuple-element", defined.group(0)
 
 
+def _minor_axes(text, shape):
+    """The minor-most logical axis of every bfloat16 array of ``shape`` (a
+    pattern) in a compiled program's text."""
+    return {int(a) for a in re.findall(rf"bf16\[{shape}\]\{{(\d)", text)}
+
+
+def _cache_writes(text, shape):
+    """The ``dynamic-update-slice`` ops whose result is such an array."""
+    return re.findall(
+        rf"= bf16\[{shape}\]\{{[^}}]*\}} dynamic-update-slice\(", text
+    )
+
+
 def test_phi4flash_iteration_fits_the_chip_with_each_layer_recomputed(sds):
     """The fused iteration of ``ppo_lift_phi4flash_16x1024`` (16 envs x
     1024, 2 x 2 minibatches of 8192 tokens, the family's published widths,
@@ -482,13 +496,63 @@ def test_phi4flash_iteration_fits_the_chip_with_each_layer_recomputed(sds):
     assert held < 15.0e9, held          # 14.05 GB when this was written
     text = compiled.as_text()
     assert not re.search(r"f32\[(1024|1025|1056),\d+,16,5120\]", text)
-    # the acting loop's carry: the state, the ring, the shared cache
+    # the acting loop's carry: the state, the ring, the shared cache, two
+    # heads of 64 a row (models/ssm_hybrid.py::heads_per_row)
     loops = [line.split(" while(")[0] for line in text.splitlines()
              if " while(" in line and "f32[16,16,5120]" in line]
     assert any(
-        "bf16[16,512,20,64]" in c and "bf16[16,1024,20,64]" in c
+        "bf16[16,512,10,128]" in c and "bf16[16,1024,10,128]" in c
         and "bf16[16,3,5120]" in c for c in loops
     )
+    # and a step's four cache writes are one row each: no cache anywhere in
+    # the program has its slots on the lanes
+    assert _minor_axes(text, r"16,(?:512|1024),10,128") == {3}
+    assert len(_cache_writes(text, r"16,(?:512|1024),10,128")) == 4
+
+
+@pytest.mark.parametrize("G,hd,H", [(20, 64, 40), (8, 128, 48)])
+def test_an_acting_cache_keeps_its_slots_off_the_lanes(sds, G, hd, H):
+    """A 1024-trip scan of ``ssm_hybrid.attention_step`` alone, its cache
+    donated, at phi4flash's heads (20 of 64) and at laguna's (8 of 128):
+    the compiler lays every ``[16, 1024, ., .]`` cache out with the slot
+    axis (logical axis 1) off the minor position, the two writes' results
+    included, so a one-slot write is one row and not a lane of every tile
+    (with a head of 64 a row it chose ``{1,3,2,0}``: 72-76 us a write in
+    ``ppo_lift_phi4flash_16x1024`` until PR 53)."""
+    from surreal_tpu.models import ssm_hybrid
+
+    envs, slots, dt = 16, 1024, jnp.bfloat16
+    cfg = ssm_hybrid.resolve(
+        dict(num_heads=H, num_kv_heads=G, hidden_size=H * hd)
+    )
+    like = lambda tree: jax.tree.map(lambda s: sds(s.shape, s.dtype), tree)
+    cache = like(jax.eval_shape(
+        lambda: ssm_hybrid.acting_cache(cfg, envs, slots, dt)["shared"]
+    ))
+    params = {
+        name: sds(shape, jnp.float32)
+        for name, shape, _ in ssm_hybrid.mixer_spec("full", cfg)
+    }
+
+    def decode(params, cache, hs):
+        def step(carry, h):
+            cache, pos = carry
+            out, cache = ssm_hybrid.attention_step(
+                params, h, cache, pos, dt, ring=False
+            )
+            return (cache, pos + 1), out
+
+        (cache, _), out = jax.lax.scan(step, (cache, jnp.int32(0)), hs)
+        return cache, out
+
+    text = (
+        jax.jit(decode, donate_argnums=1)
+        .lower(params, cache, sds((slots, envs, H * hd), dt))
+        .compile().as_text()
+    )
+    shape = rf"{envs},{slots},\d+,\d+"
+    assert 1 not in _minor_axes(text, shape), _minor_axes(text, shape)
+    assert len(_cache_writes(text, shape)) == 2
 
 
 def test_laguna_iteration_fits_the_chip_with_each_layer_recomputed(sds):
