@@ -1,11 +1,24 @@
-"""Every cell walks the whole measured path at toy sizes on the CPU, in a
-child process (the command is a process of its own; the four-chip cell
-needs four virtual devices where this suite forces eight), and prints the
-contract's last line. Without ``--rehearse`` and without a TPU the command
-exits non-zero and prints no result."""
+"""What a cell's rehearsal file runs. Every cell walks the whole measured
+path at toy sizes on the CPU, in a child process (the command is a process
+of its own; the four-chip cell needs four virtual devices where this suite
+forces eight), and prints the contract's last line.
+
+One test file a cell, ``test_benchmark_rehearse_<cell>.py``, which names its
+cell and takes everything else from here:
+
+    from benchmark_rehearsal import *  # noqa: F401,F403
+
+    CELL = "<cell>"
+
+so the driver's ``--dist loadfile`` spreads the cells over its workers, each
+file keeps a compile cache of its own module, and a PR that adds a cell adds
+one such file and edits none (``test_benchmark_command.py`` holds the files
+to the manifest's workloads, by name).
+"""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -13,9 +26,24 @@ import pytest
 
 from benchmarks.harness import manifest
 
+__all__ = [
+    "cell", "cache_dir", "rehearsed",
+    "test_cell_rehearses", "test_traced_line_has_only_what_the_sessions_gave",
+    "test_reference_check_follows_the_window",
+    "test_session_seed_is_listed_or_the_seed_itself",
+]
+
 M = manifest.load_manifest()
 CELLS = [w["name"] for w in M["workloads"]]
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+HERE = os.path.dirname(os.path.abspath(__file__))
+FILE_OF_CELL = re.compile(r"^test_benchmark_rehearse_(.+)\.py$")
+
+
+@pytest.fixture(scope="module")
+def cell(request):
+    """The cell the importing file names, which its own name repeats."""
+    return request.module.CELL
 
 
 @pytest.fixture(scope="module")
@@ -27,29 +55,44 @@ def cache_dir(tmp_path_factory):
 
 
 @pytest.fixture(scope="module")
-def rehearsed(cache_dir):
-    """``(cell, trace) ->`` the rehearsal's last line, each rehearsed once
-    in the module: ``test_cell_rehearses`` fills it and the tests after it
-    read what it left (a test run alone rehearses its own cell)."""
+def rehearsed(cell, cache_dir):
+    """``trace ->`` the rehearsal's last line, each rehearsed once in the
+    module: ``test_cell_rehearses`` fills it and the tests after it read
+    what it left (a test run alone rehearses its own trace flag)."""
     done: dict = {}
 
-    def get(cell: str, trace: int):
-        if (cell, trace) not in done:
+    def get(trace: int):
+        if trace not in done:
             proc = run_cell(cell, trace, "--rehearse", cache=cache_dir)
             assert proc.returncode == 3, proc.stderr[-2000:]
-            done[cell, trace] = dict(
+            done[trace] = dict(
                 last_line(proc), stderr_tail=proc.stderr.splitlines()[-60:]
             )
-        return done[cell, trace]
+        return done[trace]
 
     return get
+
+
+def rehearsal_files() -> dict:
+    """``cell -> file name`` for every rehearsal file beside this module."""
+    matches = (FILE_OF_CELL.match(name) for name in sorted(os.listdir(HERE)))
+    return {m.group(1): m.group(0) for m in matches if m}
+
+
+def worker_core() -> int:
+    """The core a child of this process pins itself to: under xdist the
+    worker's own (``gw3`` takes the fourth core this process may run on, so
+    six files that rehearse at once do not share one), without it the last."""
+    cores = sorted(os.sched_getaffinity(0))
+    worker = re.fullmatch(r"gw(\d+)", os.environ.get("PYTEST_XDIST_WORKER", ""))
+    return cores[int(worker.group(1)) % len(cores)] if worker else cores[-1]
 
 
 # The child pins itself to one core and then becomes the command: the suite
 # runs one worker per core and holds timing-sensitive tests, which a child
 # that spread XLA's thread pool over every core would slow.
 ON_ONE_CORE = (
-    "import os, sys; os.sched_setaffinity(0, {max(os.sched_getaffinity(0))}); "
+    "import os, sys; os.sched_setaffinity(0, {{{core}}}); "
     "os.execv(sys.executable, [sys.executable] + sys.argv[1:])"
 )
 
@@ -61,8 +104,8 @@ def run_cell(cell: str, trace: int, *extra: str, cache: str | None = None):
         env["JAX_ENABLE_COMPILATION_CACHE"] = "true"
         env["JAX_COMPILATION_CACHE_DIR"] = cache
     return subprocess.run(
-        [sys.executable, "-c", ON_ONE_CORE, *M["command"][1:],
-         "--workload", cell, "--seed", "7",
+        [sys.executable, "-c", ON_ONE_CORE.format(core=worker_core()),
+         *M["command"][1:], "--workload", cell, "--seed", "7",
          "--seconds", "1", "--trace", str(trace), *extra],
         cwd=manifest.ROOT, env=env, capture_output=True, text=True, timeout=300,
     )
@@ -88,12 +131,9 @@ def declared_and_required(cell: str, trace: int):
     }
 
 
-@pytest.mark.parametrize(
-    "cell,trace", [(c, t) for c in CELLS for t in (0, 1)],
-    ids=[f"{c}-trace{t}" for c in CELLS for t in (0, 1)],
-)
+@pytest.mark.parametrize("trace", (0, 1), ids=("trace0", "trace1"))
 def test_cell_rehearses(cell, trace, rehearsed):
-    line = rehearsed(cell, trace)
+    line = rehearsed(trace)
     assert LINE_KEYS <= set(line)
     assert line["correct"] is False
     chips = next(w["chips"] for w in M["workloads"] if w["name"] == cell)
@@ -119,25 +159,23 @@ def test_cell_rehearses(cell, trace, rehearsed):
         assert line["metrics"]["setup_s"]["value"] >= line["launch_marks_s"]["first_stamp"]
 
 
-@pytest.mark.parametrize("cell", CELLS)
 def test_traced_line_has_only_what_the_sessions_gave(cell, rehearsed):
     """A traced run reads its per-layer metrics from the measured session
     and the phase session and times nothing as a program of its own: the
     line has no ``standalone_s``, and its metrics are the cell's declared
     per-layer ones (all that need no chip, none from outside the list)."""
-    line = rehearsed(cell, 1)
+    line = rehearsed(1)
     assert "standalone_s" not in line
     declared, required = declared_and_required(cell, 1)
     assert required <= set(line["metrics"]) <= set(declared)
 
 
-@pytest.mark.parametrize("cell", CELLS)
 def test_reference_check_follows_the_window(cell, rehearsed):
     """The reference check is no part of ``setup_s`` and runs beside nothing
     of the measured session: the window closes, the session is freed, then
     the check runs; and the run's last lines on standard error are every
     number compared beside its limit, every check by name, and the verdict."""
-    line = rehearsed(cell, 0)
+    line = rehearsed(0)
     marks = line["launch_marks_s"]
     setup = line["metrics"]["setup_s"]["value"]
     assert marks["warm"] <= setup
@@ -154,7 +192,6 @@ def test_reference_check_follows_the_window(cell, rehearsed):
         assert f"check {name}: " in said
 
 
-@pytest.mark.parametrize("cell", CELLS)
 def test_session_seed_is_listed_or_the_seed_itself(cell):
     """A cell that lists ``session_seeds`` (and says why) launches from one
     of them whatever ``--seed`` is, the same one for the same seed; every
@@ -175,15 +212,3 @@ def test_session_seed_is_listed_or_the_seed_itself(cell):
         assert len(set(listed)) == len(listed) >= 12
         picked = {runner.session_seed(loaded, s) for s in range(len(listed))}
         assert picked == set(listed)
-
-
-def test_no_tpu_means_no_result():
-    proc = run_cell(CELLS[0], 0)
-    assert proc.returncode not in (0, 3)
-    assert proc.stdout.strip() == ""
-    assert "TPU" in proc.stderr
-
-
-def test_unknown_cell_is_an_error():
-    proc = run_cell("no_such_cell", 0, "--rehearse")
-    assert proc.returncode != 0 and proc.stdout.strip() == ""
