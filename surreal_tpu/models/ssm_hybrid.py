@@ -83,7 +83,9 @@ from surreal_tpu.models.attention import (
 )
 from surreal_tpu.ops import moe
 from surreal_tpu.ops.ring_attention import _NEG_BIG, blocked_attention
-from surreal_tpu.ops.selective_scan import selective_scan, selective_step
+from surreal_tpu.ops.selective_scan import (
+    scan_in_vmem, selective_scan, selective_step,
+)
 from surreal_tpu.utils.phases import part
 
 INIT_STD = 0.02
@@ -121,9 +123,13 @@ KINDS = ("ssm", "window", "full", "gmu", "cross")
 # largest entry of a state a segment ended with (a recurrence that blows up
 # shows before the loss does), the keys a windowed query saw, and how many
 # key-value heads share a row of the acting caches (static: which form of
-# the decode's attention this trunk runs, heads_per_row)
+# the decode's attention this trunk runs, heads_per_row), and which form of
+# the selective scan ran (1 the Pallas kernels that keep the state in VMEM, 0
+# the ``lax`` form: ops/selective_scan.py chooses from the device and the
+# shapes)
 COUNTERS = {
     "state_abs_max": ("ssm/state_abs_max", "max"),
+    "scan_in_vmem": ("ssm/scan_in_vmem", "mean"),
     "window_keys_mean": ("attn/window_keys_mean", "mean"),
     "cache_heads_per_row": ("attn/cache_heads_per_row", "max"),
 }
@@ -449,6 +455,8 @@ def _layer(kind: str, keeps: bool, s: dict, dt, p, x, kept):
     if kind == "ssm":
         out, y, state = ssm_mixer(p["mixer"], h, s, dt)
         stats["state_abs_max"] = jnp.abs(state).max()
+        # from the shapes the scan saw: y's are u's, A is A_log turned
+        stats["scan_in_vmem"] = scan_in_vmem(y, p["mixer"]["A_log"].T)
         if keeps:
             kept = dict(kept, m=y)
     elif kind in ("window", "full"):
@@ -490,10 +498,11 @@ def forward(params: dict, x, cfg: dict, dt, residual: int):
         layer = recomputed(layer, residual, REMAT_ABOVE_BYTES)
         x, kept, st = layer(params[f"layer{i}"], x, kept)
         stats.append(st)
-    states = [st["state_abs_max"] for st in stats if "state_abs_max" in st]
+    pick = lambda name: jnp.stack([st[name] for st in stats if name in st])  # noqa: E731
     windows = [st["window_keys"] for st in stats if "window_keys" in st]
     return x, {
-        "state_abs_max": jnp.stack(states).max(),
+        "state_abs_max": pick("state_abs_max").max(),
+        "scan_in_vmem": pick("scan_in_vmem").mean(),
         # a trunk without a window layer (pairs_before 0) has no such query
         "window_keys_mean": jnp.stack(windows).mean() if windows
         else jnp.zeros((), jnp.float32),

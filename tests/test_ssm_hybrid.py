@@ -346,6 +346,8 @@ def test_learn_reports_the_three_counters():
     )
     assert 0 < float(metrics["ssm/state_abs_max"]) < 1e3
     assert float(metrics["attn/cache_heads_per_row"]) == 1.0    # heads of 8
+    # the CPU runs the ``lax`` form of the scan at any width
+    assert float(metrics["ssm/scan_in_vmem"]) == 0.0
     assert float(metrics["health/update_ratio"]) > 0
     assert "moe/overflow" not in metrics
 
