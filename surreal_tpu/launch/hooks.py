@@ -270,11 +270,12 @@ class SessionHooks:
 
         # on-demand profiling (session/profile.py): trigger-file captures,
         # request() and the slow-iteration auto-trigger share one boundary
-        # tick; each capture is reduced to a digest with the op -> phase
-        # maps of the programs the cost accountant registered
+        # tick; each capture is reduced to a digest with the op -> phase,
+        # part, sub-scope and kernel maps of the programs the cost
+        # accountant registered
         self.profile = ProfileManager(
             cfg, cfg.folder, self.tracer, self.log,
-            op_phases=self.costs.op_phases, op_parts=self.costs.op_parts,
+            labels=self.costs.labels,
             # a digest's parse holds this thread too: not its tiers' silence
             on_hold=self.ops.excuse_pause,
         )

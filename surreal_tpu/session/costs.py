@@ -593,10 +593,10 @@ class CostAccountant:
         self._log = log
         self._programs: dict[str, dict] = {}
         # name -> zero-arg source of the compiled program's HLO text, and
-        # the {instruction: phase} maps parsed from them on first demand
+        # the {instruction: label} maps parsed from them on first demand
+        # (labels())
         self._hlo: dict[str, Any] = {}
-        self._op_phases: dict[str, dict[str, str]] | None = None
-        self._op_parts: dict[str, dict[str, str]] | None = None
+        self._labels: dict[str, dict[str, dict[str, str]]] | None = None
         self._failed: set[str] = set()  # don't re-lower every iteration
         # when a backend reports no cost model (record sites in host/SEED
         # loops call record_program once per iteration, idempotently)
@@ -647,7 +647,7 @@ class CostAccountant:
         )
         if hlo_text is not None:
             self._hlo[name] = hlo_text
-            self._op_phases = self._op_parts = None
+            self._labels = None
         if costs is None:
             self._failed.add(name)
             if self._log is not None:
@@ -680,45 +680,42 @@ class CostAccountant:
             self._on_event("program_cost", **rec, **self.peak.to_dict())
         return rec
 
-    def op_phases(self) -> dict[str, dict[str, str]]:
-        """``{HLO module name: {instruction name: phase}}`` of every
-        registered program, parsed from its compiled HLO text on first
-        demand (the profile digest's, off the loop's thread). A program
-        whose text cannot be had is left out."""
-        if self._op_phases is None:
-            from surreal_tpu.utils.phases import phase_of
+    def labels(self) -> dict[str, dict[str, dict[str, str]]]:
+        """The profile digest's label maps (``session/profile.py``
+        ``LABELS``), ``{"phases" | "parts" | "subphases" | "kernels": {HLO
+        module name: {instruction name: label}}}`` over every registered
+        program: an instruction's phase, model part (``utils/phases.py``
+        ``PARTS``; none in a program whose model scopes none) and sub-scope
+        of its phase (``SUBPHASES``: ``collect/act``), and a Pallas call's
+        kernel (``hlo_kernels``). All four come from one reading of each
+        program's compiled HLO text, on first demand (the digest's, off
+        the loop's thread). A program whose text cannot be had is left
+        out."""
+        if self._labels is not None:
+            return self._labels
+        from surreal_tpu.session.profile import LABELS, hlo_kernels, hlo_op_phases
+        from surreal_tpu.utils.phases import part_of, phase_of, subphase_of
 
-            self._op_phases = self._op_labels(phase_of, warn_empty=True)
-        return self._op_phases
-
-    def op_parts(self) -> dict[str, dict[str, str]]:
-        """The same maps by model part (``utils/phases.py`` ``PARTS``);
-        empty for a program whose model scopes none."""
-        if self._op_parts is None:
-            from surreal_tpu.utils.phases import part_of
-
-            self._op_parts = self._op_labels(part_of, warn_empty=False)
-        return self._op_parts
-
-    def _op_labels(self, label_of, warn_empty: bool) -> dict:
-        from surreal_tpu.session.profile import hlo_op_phases
-
-        maps: dict[str, dict[str, str]] = {}
+        maps: dict = {k: {} for k in LABELS}
         for name, hlo_text in list(self._hlo.items()):
             try:
-                module, ops = hlo_op_phases(hlo_text(), label_of)
+                text = hlo_text()
+                module, *found = hlo_op_phases(text, phase_of, part_of, subphase_of)
+                found.append(hlo_kernels(text)[1])
             except Exception as e:
                 if self._log is not None:
                     self._log.warning(
                         "no HLO text for program %r: %s", name, e
                     )
                 continue
-            maps[module] = ops
-            if warn_empty and not ops and self._log is not None:
+            for k, ops in zip(LABELS, found):
+                maps[k][module] = ops
+            if not found[0] and self._log is not None:
                 self._log.warning(
                     "program %r carries no phase name: the digest will "
                     "call its ops unattributed", name,
                 )
+        self._labels = maps
         return maps
 
     def gauges(self, window: dict | None) -> dict[str, float]:

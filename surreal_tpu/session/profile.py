@@ -43,16 +43,35 @@ announced as a ``profile`` telemetry event carrying the **digest**
   program's HLO text, which the cost accountant keeps the source of
   (``instruction name -> op_name``, looked up under the module the op ran
   in). A fusion takes the ``op_name`` XLA gave the fusion instruction,
-  which is its root's. Each phase lists its three largest ops;
+  which is its root's. Each phase lists its three largest ops, its
+  ``ops_per_iter`` (the device op events whose phase it is) and its
+  ``short_ops``: how many of them ran under :data:`SHORT_OP_NS` and the
+  time those own;
 - ``parts``: the same device time split a second way, by the model part
   of ``utils/phases.py`` (``PARTS``) each op's path names, read and owned
   by the same rules; ``unattributed`` holds what no part names (all of a
   program whose model scopes none), so parts too sum to ``busy_s``;
+- ``subphases``: for each phase that has one in the capture, its time by
+  sub-scope (``utils/phases.py`` ``SUBPHASES``: ``collect/act``) and
+  ``rest`` (its ops outside each of them), which sum to the phase;
+- ``parts_by_phase``: the joint of the two splits, ``{part: {phase: ms}}``:
+  a row sums to the part, a column (``unattributed`` with it) to the phase;
+- ``kernels``: every Pallas kernel (an HLO ``custom-call`` whose target is
+  ``tpu_custom_call``, read from the compiled program's text) under the
+  ``name=`` its ``pl.pallas_call`` was given, which is its instruction's
+  name less the ``.<n>`` of a call site: time and calls per iteration over
+  all ``sites``, its part, and its time by phase. XLA:TPU's own kernel for
+  ``jax.lax.ragged_dot`` has that target too and is listed as
+  ``ragged-dot-none`` / ``ragged-dot-metadata``;
 - ``idle_by_span``: every device idle gap charged to the innermost
   program span that covers it on the loop's thread (``metrics-sync``,
   ``engine.boundary``, ``engine.step``, ``iteration``, ...), or to
   ``none``. A span is the program's if the tracer has seen its name or
   the loop engine annotates it.
+
+Every op of the first device, not only a label's largest three, is written
+once beside the capture as ``<capture dir>/ops.json``: name and shape, phase,
+sub, part, kernel, calls and owned ms per iteration (they sum to ``busy_s``).
 
 A digest that fails writes ``digest_error`` and never stops training.
 The arithmetic takes plain tuples so that a test can hand-build a trace.
@@ -70,7 +89,7 @@ import time
 
 from surreal_tpu.session.telemetry import PROFILES_DIR, TELEMETRY_DIR
 from surreal_tpu.utils.phases import (
-    PARTS, PHASES, UNATTRIBUTED, part_of, phase_of,
+    PARTS, PHASES, REST, SUBPHASES, UNATTRIBUTED, part_of, phase_of,
 )
 
 TRIGGER_FILE = "profile.trigger"
@@ -79,6 +98,11 @@ TRIGGER_FILE = "profile.trigger"
 ENGINE_SPANS = ("iteration", "engine.step", "engine.boundary")
 DIGEST_WAIT_S = 120.0  # close() waits this long for a digest in flight
 TOP_OPS = 3
+SHORT_OP_NS = 1000  # a device op event shorter than this is a short op
+OPS_FILE = "ops.json"  # the whole op table, beside the capture
+OPS_COLUMNS = (
+    "op", "phase", "sub", "part", "kernel", "calls_per_iter", "ms_per_iter",
+)
 
 # EWMA shape for the slow-iteration detector: first _WARM_TICKS ticks only
 # seed the average (compiles + cache warmup dominate there), later ticks
@@ -170,15 +194,75 @@ def charge_gaps(gaps, spans) -> dict[str, int]:
     return out
 
 
-def _split(owned, labels, vocabulary, busy: int, per_iter_ms: float) -> dict:
-    """``{label: {ms_per_iter, share_of_busy, top_ops}}`` of the owned
-    pieces ``[(ns, op name), ...]`` under ``labels`` (one a piece), in the
+# the maps a digest labels its ops from (``CostAccountant.labels``), in the
+# order of an op event's fields after its name, each with what an op reads
+# that its map does not hold
+LABELS = {
+    "phases": UNATTRIBUTED, "parts": UNATTRIBUTED, "subphases": UNATTRIBUTED,
+    "kernels": None,
+}
+# what an op event's tuple may leave out after its phase: part, sub, kernel
+_NO_LABELS = tuple(LABELS.values())[1:]
+
+
+def op_table(events) -> dict:
+    """``{(name, phase, part, sub, kernel): [owned ns, events, short
+    events, their owned ns]}`` of one device's op events ``(start, end,
+    name, phase[, part[, sub[, kernel]]])``, in the order in which the ops
+    first own time: every table of the digest is a sum over these rows. A
+    key's sub is the last segment of the event's ``phase/sub``; one that
+    names another phase than the op's own (the maps are read one label at
+    a time) or none is ``rest``, so a phase's subs and rest sum to it."""
+    own = [0] * len(events)
+    first: list[int] = []
+    for i, a, b in owned_pieces(events):
+        if not own[i]:
+            first.append(i)
+        own[i] += b - a
+    first += [i for i, t in enumerate(own) if not t]  # wholly covered
+    table: dict[tuple, list[int]] = {}
+    rows: dict[tuple, list[int]] = {}  # an event's own labels -> its row
+    for i in first:
+        ev = events[i]
+        row = rows.get(ev[2:])
+        if row is None:
+            name, phase, part, sub, kernel = (
+                tuple(ev[2:]) + _NO_LABELS[len(ev) - 4:]
+            )
+            top, _, sub = sub.partition("/")
+            if top != phase or not sub:
+                sub = REST
+            row = rows[ev[2:]] = table.setdefault(
+                (name, phase, part, sub, kernel), [0, 0, 0, 0]
+            )
+        row[0] += own[i]
+        row[1] += 1
+        if ev[1] - ev[0] < SHORT_OP_NS:
+            row[2] += 1
+            row[3] += own[i]
+    return table
+
+
+def _sum_by(table: dict, *columns: int) -> dict:
+    """Owned ns of ``table`` by the key's ``columns``, first seen first;
+    a row that owns nothing (an op its children cover) is in no sum."""
+    out: dict = {}
+    for key, row in table.items():
+        if row[0]:
+            k = tuple(key[c] for c in columns)
+            out[k] = out.get(k, 0) + row[0]
+    return out
+
+
+def _split(table: dict, column: int, vocabulary, busy: int,
+           per_iter_ms: float) -> dict:
+    """``{label: {ms_per_iter, share_of_busy, top_ops}}`` of the op table
+    under the labels of key ``column`` (1 the phase, 2 the part), in the
     vocabulary's order with ``unattributed`` last and always present."""
-    by_label: dict[str, int] = {UNATTRIBUTED: 0}
-    by_op: dict[tuple[str, str], int] = {}
-    for (t, name), label in zip(owned, labels):
-        by_label[label] = by_label.get(label, 0) + t
-        by_op[label, name] = by_op.get((label, name), 0) + t
+    by_label = {UNATTRIBUTED: 0}
+    for (label,), t in _sum_by(table, column).items():
+        by_label[label] = t
+    by_op = _sum_by(table, column, 0)
     out = {}
     for label in (*vocabulary, UNATTRIBUTED):
         if label not in by_label:
@@ -195,34 +279,78 @@ def _split(owned, labels, vocabulary, busy: int, per_iter_ms: float) -> dict:
     return out
 
 
+def _subphases(table: dict, per_iter_ms: float) -> dict:
+    """``{phase: {sub | "rest": ms_per_iter}}`` for the phases with a sub
+    in the table, subs in the vocabulary's order."""
+    by_sub = _sum_by(table, 1, 3)
+    out = {}
+    for phase in sorted({p for p, sub in by_sub if sub != REST},
+                        key=PHASES.index):
+        out[phase] = {
+            sub: by_sub[phase, sub] * per_iter_ms
+            for sub in SUBPHASES[phase] if (phase, sub) in by_sub
+        }
+        out[phase][REST] = by_sub.get((phase, REST), 0) * per_iter_ms
+    return out
+
+
+def _kernels(table: dict, steps: int, per_iter_ms: float) -> dict:
+    """``{kernel: {ms_per_iter, calls_per_iter, sites, part, by_phase}}``
+    of the rows that are a Pallas call, every call site summed; ``part``
+    is the model part that most of its time is in."""
+    found: dict[str, dict] = {}
+    for (name, phase, part, _, kernel), row in table.items():
+        if kernel is None:
+            continue
+        k = found.setdefault(
+            kernel, {"ns": 0, "calls": 0, "sites": set(), "part": {}, "phase": {}}
+        )
+        k["ns"] += row[0]
+        k["calls"] += row[1]
+        k["sites"].add(name)
+        k["part"][part] = k["part"].get(part, 0) + row[0]
+        k["phase"][phase] = k["phase"].get(phase, 0) + row[0]
+    return {
+        kernel: {
+            "ms_per_iter": k["ns"] * per_iter_ms,
+            "calls_per_iter": k["calls"] / steps,
+            "sites": len(k["sites"]),
+            "part": max(k["part"], key=k["part"].get),
+            "by_phase": {p: t * per_iter_ms for p, t in k["phase"].items()},
+        }
+        for kernel, k in found.items()
+    }
+
+
 def reduce_digest(device_ops: dict, host_spans, steps: int) -> dict:
     """The digest's numbers from ``{device: [(start_ns, end_ns, name,
-    phase[, part]), ...]}``, the loop thread's program spans ``[(start_ns,
-    end_ns, name), ...]`` and the iterations the window holds. Phases,
-    parts and gaps are those of the first device by name; seconds are
-    floats, nothing is rounded."""
+    phase[, part[, sub[, kernel]]]), ...]}``, the loop thread's program
+    spans ``[(start_ns, end_ns, name), ...]`` and the iterations the window
+    holds. Every table is of the first device by name; seconds are floats,
+    nothing is rounded. ``ops`` is :data:`OPS_COLUMNS` of every op of that
+    device, largest first: :func:`digest_capture` moves it to ``ops.json``."""
     device_ops = {k: v for k, v in device_ops.items() if v}
     out = {"devices": len(device_ops), "steps": int(steps)}
     if not device_ops:
         return out
     ns = 1e-9
-    per_iter_ms = 1e-6 / max(int(steps), 1)
+    steps = max(int(steps), 1)
+    per_iter_ms = 1e-6 / steps
     events = device_ops[sorted(device_ops)[0]]
-    pieces = list(owned_pieces(events))
-    owned = [(b - a, events[i][2]) for i, a, b in pieces]
-    busy = sum(t for t, _ in owned)
+    table = op_table(events)
+    busy = sum(row[0] for row in table.values())
     window = max(ev[1] for ev in events) - min(ev[0] for ev in events)
-    phases = _split(
-        owned, [events[i][3] for i, _, _ in pieces], PHASES, busy, per_iter_ms
-    )
-    parts = _split(
-        owned,
-        [
-            events[i][4] if len(events[i]) > 4 else UNATTRIBUTED
-            for i, _, _ in pieces
-        ],
-        PARTS, busy, per_iter_ms,
-    )
+    phases = _split(table, 1, PHASES, busy, per_iter_ms)
+    for phase, entry in phases.items():
+        rows = [row for key, row in table.items() if key[1] == phase]
+        entry["ops_per_iter"] = sum(row[1] for row in rows) / steps
+        entry["short_ops"] = {
+            "per_iter": sum(row[2] for row in rows) / steps,
+            "ms_per_iter": sum(row[3] for row in rows) * per_iter_ms,
+        }
+    parts_by_phase: dict = {}
+    for (part, phase), t in _sum_by(table, 2, 1).items():
+        parts_by_phase.setdefault(part, {})[phase] = t * per_iter_ms
     out.update(
         window_s=window * ns,
         busy_s=busy * ns,
@@ -235,11 +363,20 @@ def reduce_digest(device_ops: dict, host_spans, steps: int) -> dict:
             for _, evs in sorted(device_ops.items())
         ],
         phases=phases,
-        parts=parts,
+        parts=_split(table, 2, PARTS, busy, per_iter_ms),
+        subphases=_subphases(table, per_iter_ms),
+        parts_by_phase=parts_by_phase,
+        kernels=_kernels(table, steps, per_iter_ms),
         idle_by_span={
             k: v * ns
             for k, v in charge_gaps(idle_gaps(events), list(host_spans)).items()
         },
+        ops=[
+            [name, phase, sub, part, kernel, row[1] / steps, row[0] * per_iter_ms]
+            for (name, phase, part, sub, kernel), row in sorted(
+                table.items(), key=lambda kv: -kv[1][0]
+            )
+        ],
     )
     return out
 
@@ -258,14 +395,18 @@ _HLO_CALLEES = re.compile(
 _HLO_REF = re.compile(r"%([\w.\-]+)")
 _HLO_OPCODE = re.compile(r"[\]})]\s([a-z][\w\-]*)\(")
 _HLO_RELAYOUT = ("copy", "copy-start", "copy-done")
+_HLO_KERNEL = 'custom_call_target="tpu_custom_call"'
+_HLO_SITE = re.compile(r"\.\d+$")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
 _SHAPE = re.compile(r"[a-z]+[0-9]*\[[0-9,]*\]")
 
 
-def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]]:
+def hlo_op_phases(hlo_text: str, *labels_of) -> tuple:
     """``(module name, {instruction name: phase})`` of a compiled
-    program's HLO text, for the instructions that have a phase (or, with
-    ``label_of=part_of``, a model part: the rules are one):
+    program's HLO text, for the instructions that have a phase. With
+    ``labels_of`` (``part_of``: a model part; ``subphase_of``: a sub-scope
+    of their phase), one map for each after the module's name, all from one
+    reading of the text; the rules are one:
 
     1. its own: the first vocabulary name in its ``op_name`` metadata;
     2. a fusion without one takes its fused computation's: the root's,
@@ -282,12 +423,12 @@ def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]
        outside.
     """
     head = _HLO_MODULE.match(hlo_text)
-    phases: dict[str, str] = {}
     computations: dict[str, list] = {}   # name -> [(instr, is_root)]
     order: list[tuple[str, list[str], str | None]] = []  # instr, refs, calls
     home: dict[str, str] = {}            # instr -> its computation
     caller: dict[str, str] = {}          # computation -> an instr calling it
     placed: set[str] = set()             # instrs with a path of their own
+    paths: dict[str, str] = {}           # instr -> its op_name
     current = here = None
     for line in hlo_text.splitlines():
         if line.startswith("}"):
@@ -315,13 +456,36 @@ def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]
             and not (opcode and opcode.group(1) in _HLO_RELAYOUT)
         ):
             placed.add(name)
-        if named and label_of(named.group(1)) != UNATTRIBUTED:
-            phases[name] = label_of(named.group(1))
+        if named:
+            paths[name] = named.group(1)
         calls = _HLO_CALLS.search(rest)
         order.append((
             name, _HLO_REF.findall(rest.split(", metadata=", 1)[0]),
             calls.group(1) if calls else None,
         ))
+    users: dict[str, list[str]] = {}
+    for name, refs, _ in order:
+        for ref in refs:
+            users.setdefault(ref, []).append(name)
+    return (head.group(1) if head else ""), *(
+        _hlo_labels(
+            label_of, paths, computations, order, home, caller, placed, users
+        )
+        for label_of in labels_of or (phase_of,)
+    )
+
+
+def _hlo_labels(label_of, paths, computations, order, home, caller, placed,
+                users) -> dict[str, str]:
+    """One label's map over a text :func:`hlo_op_phases` has read."""
+    of_path: dict[str, str] = {}  # many instructions share a path
+    phases: dict[str, str] = {}
+    for name, path in paths.items():
+        label = of_path.get(path)
+        if label is None:
+            label = of_path[path] = label_of(path)
+        if label != UNATTRIBUTED:
+            phases[name] = label
     for name, _, calls in order:
         body = computations.get(calls) if name not in phases else None
         if body:
@@ -338,10 +502,6 @@ def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]
             at = caller[home[at]]
             if at in own:
                 phases[name] = own[at]
-    users: dict[str, list[str]] = {}
-    for name, refs, _ in order:
-        for ref in refs:
-            users.setdefault(ref, []).append(name)
     for name, _, _ in reversed(order):       # users come later in the text
         if name not in phases and name not in placed:
             for user in users.get(name, ()):
@@ -354,7 +514,25 @@ def hlo_op_phases(hlo_text: str, label_of=phase_of) -> tuple[str, dict[str, str]
                 if ref in phases:
                     phases[name] = phases[ref]
                     break
-    return (head.group(1) if head else ""), phases
+    return phases
+
+
+def hlo_kernels(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """``(module name, {instruction name: kernel})`` of the Pallas calls
+    in a compiled program's HLO text: every ``custom-call`` whose target is
+    ``tpu_custom_call``, under its instruction's name less the ``.<n>`` XLA
+    gives a call site, which is the ``name=`` of its ``pl.pallas_call``
+    (a call without one is ``custom-call``). XLA:TPU lowers
+    ``jax.lax.ragged_dot`` to the same target under its own names
+    (``ragged-dot-none``, ``ragged-dot-metadata``): they are listed too."""
+    head = _HLO_MODULE.match(hlo_text)
+    kernels = {}
+    for line in hlo_text.splitlines():
+        if _HLO_KERNEL in line and " custom-call(" in line:
+            m = _HLO_OP.match(line)
+            if m:
+                kernels[m.group(2)] = _HLO_SITE.sub("", m.group(2))
+    return (head.group(1) if head else ""), kernels
 
 
 def _instruction(event_name: str) -> tuple[str, str]:
@@ -381,12 +559,12 @@ def _instruction(event_name: str) -> tuple[str, str]:
     return op, f"{op} {largest}".strip()
 
 
-def read_capture(path: str, op_phases: dict, span_names,
-                 op_parts: dict | None = None, on_parsed=None) -> tuple:
+def read_capture(path: str, labels: dict, span_names, on_parsed=None) -> tuple:
     """``(device_ops, loop_spans, host_span_counts)`` of one
     ``.xplane.pb``: per device plane the ``XLA Ops`` line as ``(start_ns,
-    end_ns, name, phase, part)``, each op's phase and part looked up under
-    the module (``XLA Modules`` line) it ran in; the program's spans on the loop's
+    end_ns, name, phase, part, sub, kernel)``, each op's labels looked up in
+    ``labels`` (``{one of LABELS: {HLO module: {instruction: label}}}``)
+    under the module (``XLA Modules`` line) it ran in; the program's spans on the loop's
     thread (the host line with the most ``engine.step``); and how often
     each program span appears on any host line. ``on_parsed(seconds)`` is
     told how long the file took to parse: the parser is one foreign call
@@ -396,7 +574,7 @@ def read_capture(path: str, op_phases: dict, span_names,
     from jax.profiler import ProfileData
 
     span_names = set(span_names) | set(ENGINE_SPANS)
-    op_parts = op_parts or {}
+    maps = [(labels.get(k, {}), absent) for k, absent in LABELS.items()]
     device_ops: dict[str, list] = {}
     lines: list[list] = []
     t0 = time.monotonic()
@@ -416,15 +594,22 @@ def read_capture(path: str, op_phases: dict, span_names,
                 )
             )
             starts = [s for s, _ in modules]
+            # a program's loop runs an instruction a thousand times: its
+            # name is taken apart and looked up once a module
+            seen: dict[tuple[str, str], tuple] = {}
             ops = []
             for ev in by_name["XLA Ops"].events:
                 s = int(ev.start_ns)
-                instr, shown = _instruction(ev.name)
                 i = bisect.bisect_right(starts, s) - 1
                 module = modules[i][1] if i >= 0 else ""
-                ph = op_phases.get(module, {}).get(instr, UNATTRIBUTED)
-                pt = op_parts.get(module, {}).get(instr, UNATTRIBUTED)
-                ops.append((s, s + int(ev.duration_ns), shown, ph, pt))
+                fields = seen.get((module, ev.name))
+                if fields is None:
+                    instr, shown = _instruction(ev.name)
+                    fields = seen[module, ev.name] = (
+                        shown,
+                        *(m.get(module, {}).get(instr, absent) for m, absent in maps),
+                    )
+                ops.append((s, s + int(ev.duration_ns), *fields))
             device_ops[plane.name] = ops
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -447,13 +632,13 @@ def read_capture(path: str, op_phases: dict, span_names,
     return device_ops, loop, counts
 
 
-def digest_capture(trace_dir: str, op_phases: dict, span_names,
-                   steps: int | None = None,
-                   op_parts: dict | None = None, on_parsed=None) -> dict:
-    """Reduce the one ``.xplane.pb`` a capture left under ``trace_dir``.
-    ``steps`` is the number of iterations the fenced window holds; a
-    capture cut short has none, and the ``iteration`` steps seen on the
-    host stand in."""
+def digest_capture(trace_dir: str, labels: dict, span_names,
+                   steps: int | None = None, on_parsed=None) -> dict:
+    """Reduce the one ``.xplane.pb`` a capture left under ``trace_dir``,
+    and write every op of its first device to ``trace_dir``'s
+    :data:`OPS_FILE`. ``steps`` is the number of iterations the fenced
+    window holds; a capture cut short has none, and the ``iteration`` steps
+    seen on the host stand in."""
     t0 = time.perf_counter()
     found = glob.glob(
         os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb")
@@ -463,7 +648,7 @@ def digest_capture(trace_dir: str, op_phases: dict, span_names,
             f"{len(found)} .xplane.pb files under {trace_dir}, expected 1"
         )
     device_ops, loop_spans, counts = read_capture(
-        found[0], op_phases, span_names, op_parts, on_parsed
+        found[0], labels, span_names, on_parsed
     )
     if steps is None:
         steps = counts.get("iteration", 0)
@@ -473,6 +658,13 @@ def digest_capture(trace_dir: str, op_phases: dict, span_names,
         os.path.getsize(os.path.join(root, f))
         for root, _, files in os.walk(trace_dir) for f in files
     )
+    if "ops" in out:  # the event keeps three names a label, the file all
+        with open(os.path.join(trace_dir, OPS_FILE), "w") as f:
+            json.dump({
+                "device": sorted(k for k, v in device_ops.items() if v)[0],
+                "steps": out["steps"], "columns": OPS_COLUMNS,
+                "ops": out.pop("ops"),
+            }, f)
     out["digest_s"] = time.perf_counter() - t0
     return out
 
@@ -482,15 +674,15 @@ class ProfileManager:
     in the steady state: one monotonic read, one EWMA update, and (at
     most once per second) one ``os.path.exists``."""
 
-    def __init__(self, session_cfg, folder: str, tracer, log, op_phases=None,
-                 op_parts=None, on_hold=None):
+    def __init__(self, session_cfg, folder: str, tracer, log, labels=None,
+                 on_hold=None):
         self._folder = folder
         self._tracer = tracer
         self._log = log
-        # zero-arg source of {HLO module: {instruction: phase}} for the
-        # digest (CostAccountant.op_phases); called off the loop's thread
-        self._op_phases = op_phases or dict
-        self._op_parts = op_parts or dict   # the same, by model part
+        # zero-arg source of the digest's label maps, {one of LABELS: {HLO
+        # module: {instruction: label}}} (CostAccountant.labels); called
+        # once a digest, off the loop's thread
+        self._labels = labels or dict
         # told the seconds for which a digest's parse kept every thread of
         # the process still (read_capture), from the digest's thread
         self._on_hold = on_hold
@@ -593,9 +785,9 @@ class ProfileManager:
         thread's body; a failure is recorded, never raised)."""
         try:
             fields["digest"] = digest_capture(
-                fields["dir"], self._op_phases(),
+                fields["dir"], self._labels(),
                 getattr(self._tracer, "span_names", ()), steps,
-                op_parts=self._op_parts(), on_parsed=self._on_hold,
+                on_parsed=self._on_hold,
             )
         except Exception as e:
             self._log.warning("profile digest failed: %s", e)

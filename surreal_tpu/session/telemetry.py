@@ -121,7 +121,13 @@ line, ``t`` = unix seconds):
      "end_iter": ..., "digest": {"devices": D, "steps": N, "window_s":
      ..., "busy_s": ..., "idle_s": ..., "phases": {"<phase>|unattributed":
      {"ms_per_iter": ..., "share_of_busy": ..., "top_ops": [[name, ms],
-     ...]}}, "parts": {"<part>|unattributed": {...the same}},
+     ...], "ops_per_iter": ..., "short_ops": {"per_iter": ...,
+     "ms_per_iter": ...}}}, "parts": {"<part>|unattributed":
+     {"ms_per_iter": ..., "share_of_busy": ..., "top_ops": [...]}},
+     "subphases": {"<phase>": {"<sub>|rest": ms}}, "parts_by_phase":
+     {"<part>|unattributed": {"<phase>|unattributed": ms}}, "kernels":
+     {"<pl.pallas_call name>": {"ms_per_iter": ..., "calls_per_iter":
+     ..., "sites": ..., "part": "...", "by_phase": {"<phase>": ms}}},
      "idle_by_span": {"<span>|none": seconds}, "host_spans":
      {"<span>": count}, "trace_bytes": ..., "digest_s": ...}}
                     (on-demand profiler captures, session/profile.py —
@@ -130,9 +136,13 @@ line, ``t`` = unix seconds):
                      time per phase of utils/phases.py on one device, per
                      iteration, and every device idle gap charged to the
                      innermost program span covering it on the loop's
-                     thread. ``digest_error`` replaces it when the
-                     reduction failed; diag's Performance section renders
-                     the newest digest)
+                     thread; a phase's time by its sub-scopes, a model
+                     part's by phase, each Pallas kernel's over its call
+                     sites, and a phase's count of op events with those
+                     under 1 us. Every op is in ``<dir>/ops.json``.
+                     ``digest_error`` replaces it when the reduction
+                     failed; diag's Performance section renders the
+                     newest digest)
     {"type": "param_fetch", "t": ..., "span": S, "version": V,
      "unchanged": ..., "bytes": B}
                     (parameter-service hop: span-tagged client fetches
@@ -1733,8 +1743,10 @@ def _performance_lines(s: dict) -> list[str]:
 
 def _digest_lines(profile: dict) -> list[str]:
     """The newest capture's digest (session/profile.py): device time per
-    phase per iteration with its share of busy, then device idle time by
-    the host span that covers it."""
+    phase per iteration with its share of busy, its count of ops and its
+    sub-scopes beneath it; the same by model part with a part's phases
+    beneath it; the Pallas kernels; then device idle time by the host span
+    that covers it."""
     d = profile["digest"]
     lines = [
         "  digest of iters {a}-{b}: {n} iteration(s) on {dev} device(s), "
@@ -1758,21 +1770,55 @@ def _digest_lines(profile: dict) -> list[str]:
             i=d["idle_s"] * per_iter,
         )
     )
-    splits = [("phase", phases)]
+    splits = [("phase", phases, d.get("subphases") or {})]
     parts = d.get("parts") or {}
     if len(parts) > 1:  # a model that scopes its parts (utils/phases.py)
-        splits.append(("model part", parts))
-    for title, split in splits:
+        splits.append(("model part", parts, {
+            # a part that runs in one phase has nothing to part
+            k: v for k, v in (d.get("parts_by_phase") or {}).items()
+            if sum(1 for ms in v.values() if ms) > 1
+        }))
+    for title, split, finer in splits:
         lines.append(
             f"    {title:<16} {'ms/iter':>10} {'% busy':>7}  largest ops (ms/iter)"
+            + ("; op events/iter, those under 1 us" if title == "phase" else "")
         )
         for name, ph in sorted(
             split.items(), key=lambda kv: -kv[1]["ms_per_iter"]
         ):
             ops = ", ".join(f"{n} {ms:.2f}" for n, ms in ph.get("top_ops", []))
+            short = ph.get("short_ops")
+            if short:
+                ops += (
+                    f"; {ph['ops_per_iter']:.1f} ops, {short['per_iter']:.1f} "
+                    f"under 1 us own {short['ms_per_iter']:.3f} ms"
+                )
             lines.append(
                 f"    {name:<16} {ph['ms_per_iter']:>10.3f} "
                 f"{100.0 * ph['share_of_busy']:>6.1f}%  {ops}"
+            )
+            for sub, ms in sorted(
+                finer.get(name, {}).items(), key=lambda kv: -kv[1]
+            ):
+                lines.append(f"      {sub:<14} {ms:>10.3f}")
+    kernels = d.get("kernels") or {}
+    if kernels:
+        lines.append(
+            f"    {'kernel':<24} {'ms/iter':>10} {'calls/iter':>10} "
+            f"{'sites':>5}  part, ms/iter by phase"
+        )
+        for name, k in sorted(
+            kernels.items(), key=lambda kv: -kv[1]["ms_per_iter"]
+        ):
+            by_phase = ", ".join(
+                f"{p} {ms:.3f}" for p, ms in sorted(
+                    k["by_phase"].items(), key=lambda kv: -kv[1]
+                )
+            )
+            lines.append(
+                f"    {name:<24} {k['ms_per_iter']:>10.3f} "
+                f"{k['calls_per_iter']:>10.1f} {k['sites']:>5}  "
+                f"{k['part']}: {by_phase}"
             )
     idle = d.get("idle_by_span") or {}
     if idle:

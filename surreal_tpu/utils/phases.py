@@ -31,6 +31,33 @@ code and no host clock or fence enters it.
 At most eight per algorithm, so a reader can hold a split in one line.
 An op outside every scope is ``unattributed``.
 
+A phase may name **sub-scopes** (:data:`SUBPHASES`), entered inside it as
+``phase("collect/act")``; an op of the phase outside every one of them is
+the phase's ``rest``, so a phase's subs and ``rest`` sum to the phase:
+
+    collect        act (the policy's forward and sampling), env (the env
+                   step), episodes (return and length bookkeeping),
+                   obs_stats (the running observation filter's update)
+    prepare        gae (the reverse advantage recurrence)
+    sgd, learn     psum (the gradients' cross-device sum)
+    replay_sample  mass (p^alpha summed a block where no caller carries
+                   the sums, and the blocks' cdf), search (the index draw),
+                   gather (the rows read)
+
+A sub's site has to sit inside its phase's scope: one that enters ``act``
+where the path holds no ``collect`` names no op of ``collect``. Two sites
+do not today, so no capture shows them and their ops read ``unattributed``
+(a missing row is not zero time; ROADMAP S6 (z) may move them, once for
+every cell, since a traced line that moves compiles every program anew):
+
+    collect/episodes     both of its sites (``launch/trainer.py``,
+                         ``launch/offpolicy_trainer.py``: after the learn
+                         step, outside ``collect``'s scope)
+    replay_sample/mass   ``replay/prioritized.py::block_mass``'s, called
+                         before the update scan; the site inside ``sample``
+                         (the blocks' cdf of each learn step) is in its
+                         phase and is what ``mass`` reads
+
 Phases say *when* in the iteration an op runs. A second, short
 vocabulary of **parts** says *which part of the model* it belongs to, for
 a trunk large enough that this is the question (``models/latent_moe.py``,
@@ -77,8 +104,18 @@ PARTS = (
     "ssm_scan", "ssm_proj", "gmu", "attn_window", "attn_full",
     "kda_scan", "kda_proj",
 )
+# the sub-scopes a phase may have: phase("collect/act")
+SUBPHASES = {
+    "collect": ("act", "env", "episodes", "obs_stats"),
+    "prepare": ("gae",),
+    "sgd": ("psum",),
+    "learn": ("psum",),
+    "replay_sample": ("mass", "search", "gather"),
+}
 UNATTRIBUTED = "unattributed"
+REST = "rest"  # of a phase: its ops outside every sub-scope of it
 _VOCABULARY = frozenset(PHASES)
+_SUBS = {top: frozenset(subs) for top, subs in SUBPHASES.items()}
 _PARTS = frozenset(PARTS)
 # transforms wrap a scope's name in the op_name path: jvp(sgd),
 # transpose(jvp(sgd)), vmap(collect). ``jit(...)`` names a function, never
@@ -88,15 +125,17 @@ _WRAPPERS = ("transpose(", "jvp(", "vmap(", "remat(", "checkpoint(")
 
 def phase(name: str):
     """The ``jax.named_scope`` of phase ``name``: ``"collect"`` for a
-    top-level phase, ``"collect/act"`` for a part of one. A part enters
-    only its last segment (the site sits inside its phase's scope, where
-    the path already reads ``collect/.../act``). A name whose top level is
-    outside :data:`PHASES` is refused: one vocabulary, kept here."""
-    top, _, sub = name.partition("/")
-    if top not in _VOCABULARY or "/" in sub:
+    top-level phase, ``"collect/act"`` for a sub-scope of one. A sub
+    enters only its last segment (the site sits inside its phase's scope,
+    where the path already reads ``collect/.../act``). A name whose top
+    level is outside :data:`PHASES`, or whose sub is outside the phase's
+    :data:`SUBPHASES`, is refused: one vocabulary, kept here."""
+    top, slash, sub = name.partition("/")
+    if top not in _VOCABULARY or (slash and sub not in _SUBS.get(top, ())):
         raise ValueError(
-            f"phase {name!r} is not in the vocabulary {PHASES} "
-            "(surreal_tpu/utils/phases.py): 'top' or 'top/part'"
+            f"phase {name!r} is not in the vocabulary {PHASES} with the "
+            f"sub-scopes {SUBPHASES} (surreal_tpu/utils/phases.py): "
+            "'top' or 'top/sub'"
         )
     import jax  # at trace time only: the digest and the CLI read names
 
@@ -116,10 +155,16 @@ def part(name: str):
     return jax.named_scope(name)
 
 
-def _first_segment_in(op_name: str | None, vocabulary: frozenset) -> str:
+def _segments(op_name: str | None):
+    """The segments of an ``op_name`` path, transforms peeled."""
     for segment in (op_name or "").split("/"):
         while segment.startswith(_WRAPPERS) and segment.endswith(")"):
             segment = segment[segment.index("(") + 1:-1]
+        yield segment
+
+
+def _first_segment_in(op_name: str | None, vocabulary: frozenset) -> str:
+    for segment in _segments(op_name):
         if segment in vocabulary:
             return segment
     return UNATTRIBUTED
@@ -137,3 +182,21 @@ def part_of(op_name: str | None) -> str:
     :data:`PARTS` (``jit(train_iter)/sgd/transpose(jvp(attn))/dot`` ->
     ``attn``)."""
     return _first_segment_in(op_name, _PARTS)
+
+
+def subphase_of(op_name: str | None) -> str:
+    """``"top/sub"`` of an op inside a phase: the first name of the
+    phase's :data:`SUBPHASES` among the segments after the phase's own
+    (``jit(train_iter)/collect/while/body/act/tanh`` -> ``collect/act``),
+    ``"top/rest"`` for an op of the phase outside each of its subs, and
+    :data:`UNATTRIBUTED` for an op outside every phase. The rest is a label
+    too, so that an op XLA made itself (``session/profile.py::
+    hlo_op_phases``, rule 4) takes a neighbour's as it takes its phase."""
+    top = subs = None
+    for segment in _segments(op_name):
+        if top is None:
+            if segment in _VOCABULARY:
+                top, subs = segment, _SUBS.get(segment, ())
+        elif segment in subs:
+            return f"{top}/{segment}"
+    return UNATTRIBUTED if top is None else f"{top}/{REST}"

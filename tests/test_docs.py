@@ -37,6 +37,7 @@ _OUTPUT_PATHS = {
     # under a session's --folder
     "config.json", "checkpoints/", "checkpoints/run_meta.json", "extra/",
     "tb/", "telemetry/events.jsonl", "exemplars.jsonl",
+    "ops.json",                           # beside a profiler capture
     ".jax_cache/",                        # the checkout's compile cache
 }
 _SKIP_DIRS = {

@@ -7,7 +7,8 @@ matrix states, the conv tails and the latent cache against the whole-segment
 forward, the wrap, the share test, the table of families and what the family
 refuses, PPO's rows, and its parts in the compiled program. (A session
 through ``main/launch.py``, ``select_trainer`` and ``Trainer.run`` is the
-cell's rehearsal: tests/benchmarks/test_benchmark_rehearse.py.)"""
+cell's rehearsal:
+tests/benchmarks/test_benchmark_rehearse_ppo_lift_kimilinear_16x1024.py.)"""
 
 import math
 

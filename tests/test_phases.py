@@ -3,6 +3,7 @@ metadata of the compiled fused programs, the program's spans and the loop
 engine's steps on a profile's host plane, and the digest of a triggered
 capture that ``diag`` renders."""
 
+import functools
 import glob
 import json
 import os
@@ -16,7 +17,9 @@ from surreal_tpu.session.config import Config
 from surreal_tpu.session.default_configs import base_config
 from surreal_tpu.session.profile import hlo_op_phases, write_trigger
 from surreal_tpu.session.telemetry import Tracer, diag_report, diag_summary
-from surreal_tpu.utils.phases import PHASES, UNATTRIBUTED, phase, phase_of
+from surreal_tpu.utils.phases import (
+    PHASES, SUBPHASES, UNATTRIBUTED, phase, phase_of, subphase_of,
+)
 
 PPO_PHASES = ("collect", "prepare", "shuffle", "sgd", "finalize")
 DDPG_PHASES = (
@@ -52,8 +55,10 @@ def _config(algo: str, folder: str, iters: int = 12) -> Config:
     ).extend(base_config())
 
 
+@functools.lru_cache(maxsize=None)
 def _compiled_text(algo: str) -> str:
-    """The fused iteration of ``algo`` at toy sizes, compiled: its HLO text."""
+    """The fused iteration of ``algo`` at toy sizes, compiled: its HLO text
+    (once a process: two tests read each)."""
     import jax.numpy as jnp
 
     key = jax.random.key(0)
@@ -74,7 +79,11 @@ def _compiled_text(algo: str) -> str:
     return trainer._train_iter.lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("name", ["warmup", "collect/act/inner", "", "Collect"])
+@pytest.mark.parametrize("name", [
+    "warmup", "collect/act/inner", "", "Collect",
+    # a sub-scope outside the phase's own (SUBPHASES) is refused as a phase is
+    "collect/nonsense", "sgd/act", "shuffle/psum", "collect/",
+])
 def test_a_name_outside_the_vocabulary_is_refused(name):
     with pytest.raises(ValueError, match="vocabulary"):
         phase(name)
@@ -105,6 +114,64 @@ def test_vocabulary_is_small_per_algorithm():
 ])
 def test_phase_of_takes_the_first_vocabulary_segment(op_name, expected):
     assert phase_of(op_name) == expected
+
+
+@pytest.mark.parametrize("op_name,expected", [
+    ("jit(train_iter)/collect/while/body/closed_call/act/tanh", "collect/act"),
+    ("jit(train_iter)/collect/while/body/env/add", "collect/env"),
+    ("jit(train_iter)/collect/while/body/add", "collect/rest"),
+    ("jit(f)/prepare/gae/while/body/mul", "prepare/gae"),
+    ("jit(f)/sgd/transpose(jvp(sgd))/psum/psum", "sgd/psum"),
+    ("jit(f)/vmap(replay_sample)/vmap(search)/while/body/lt", "replay_sample/search"),
+    ("jit(f)/replay_sample/mass/reduce_sum", "replay_sample/mass"),
+    # a sub of another phase's is none of this one's: the refresh after an
+    # update sums its blocks inside replay_priority
+    ("jit(f)/replay_priority/mass/reduce_sum", "replay_priority/rest"),
+    ("jit(f)/act/collect/add", "collect/rest"),      # after the phase only
+    ("jit(f)/collect/actor/add", "collect/rest"),    # whole segments only
+    ("jit(f)/collect/act/env/add", "collect/act"),   # the first sub
+    ("jit(f)/shuffle/gather", "shuffle/rest"),       # a phase without subs
+    ("jit(f)/mass/reduce_sum", UNATTRIBUTED),        # a sub outside every phase
+    ("", UNATTRIBUTED),
+    (None, UNATTRIBUTED),
+])
+def test_subphase_of_takes_the_first_sub_after_the_phase(op_name, expected):
+    assert subphase_of(op_name) == expected
+
+
+def test_every_sub_scope_site_is_in_the_vocabulary():
+    """The sites under ``surreal_tpu/`` and the table are one list."""
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sites = set()
+    for path in glob.glob(os.path.join(here, "surreal_tpu", "**", "*.py"),
+                          recursive=True):
+        sites |= set(re.findall(r'phase\("(\w+/\w+)"\)', open(path).read()))
+    assert sites == {
+        f"{top}/{sub}" for top, subs in SUBPHASES.items() for sub in subs
+    }
+    assert set(SUBPHASES) <= set(PHASES)
+
+
+@pytest.mark.parametrize("algo,subs", [
+    # (the episode sums run after ``learn``, outside ``collect``'s scope:
+    # their site enters ``episodes`` alone and the ops are unattributed)
+    ("ppo", {"collect/act", "collect/env", "prepare/gae"}),
+    ("ddpg", {"collect/act", "collect/env", "replay_sample/mass",
+              "replay_sample/search", "replay_sample/gather"}),
+    ("impala", {"collect/act", "collect/env"}),
+])
+def test_sub_scopes_name_ops_of_the_compiled_fused_program(algo, subs):
+    text = _compiled_text(algo)
+    phases = hlo_op_phases(text)[1]
+    found = hlo_op_phases(text, subphase_of)[1]
+    assert subs <= set(found.values()), subs - set(found.values())
+    # the gap utils/phases.py's docstring and ROADMAP S6 (z) record: the PR
+    # that moves the site inside ``collect`` drops this line and that note
+    assert "collect/episodes" not in found.values()
+    # an op with a phase has a sub of that phase or its rest, and no other
+    assert set(found) == set(phases)
+    agree = sum(found[k].split("/")[0] == phases[k] for k in phases)
+    assert agree >= 0.99 * len(phases), (agree, len(phases))
 
 
 @pytest.mark.parametrize(
@@ -214,6 +281,44 @@ def test_diag_renders_a_device_digest(tmp_path):
     assert re.search(r"sgd\s+0\.000\s+50\.0%\s+fusion\.1 f32\[8\]", report)
     assert re.search(r"unattributed\s+0\.000\s+25\.0%", report)
     assert "idle by span: metrics-sync" in report
+
+
+def test_diag_renders_the_finer_tables(tmp_path):
+    """Under a phase its sub-scopes and rest, beside it its count of op
+    events and those under 1 us; under a model part its phases where it has
+    more than one; and a table of the Pallas kernels."""
+    from surreal_tpu.session.profile import reduce_digest
+
+    U = UNATTRIBUTED
+    ops = [
+        (0, 10_000_000, "while.1", "collect", U, "collect/rest", None),
+        (1_000_000, 4_000_000, "fusion.2", "collect", "attn", "collect/act", None),
+        (4_000_000, 4_000_400, "fusion.3", "collect", U, "collect/env", None),
+        (5_000_000, 9_000_000, "held_experts_live.3", "collect", "moe_experts",
+         "collect/act", "held_experts_live"),
+        (11_000_000, 15_000_000, "held_experts_live.17", "sgd", "moe_experts",
+         "sgd/rest", "held_experts_live"),
+        (15_000_000, 16_000_000, "fusion.5", "sgd", "dense_ffn", "sgd/rest", None),
+    ]
+    digest = reduce_digest({"/device:TPU:0": ops}, [], steps=1)
+    digest.pop("ops")  # digest_capture moves the rows to ops.json
+    digest.update(host_spans={"iteration": 1}, trace_bytes=10, digest_s=0.1)
+    tracer = Tracer(str(tmp_path))
+    tracer.event("profile", dir="d", reason="trigger_file", start_iter=4,
+                 end_iter=5, digest=digest)
+    tracer.close()
+    report = diag_report(str(tmp_path))
+    assert re.search(r"collect\s+10\.000\s+66\.7%.*; 4\.0 ops, 1\.0 under 1 us "
+                     r"own 0\.000 ms", report)
+    assert re.search(r"\n\s+act\s+7\.000\n\s+rest\s+3\.000\n\s+env\s+0\.000\n",
+                     report)
+    # moe_experts runs in two phases and says so; dense_ffn in one and does not
+    assert re.search(r"moe_experts\s+8\.000\s+53\.3%[^\n]*\n\s+collect\s+4\.000"
+                     r"\n\s+sgd\s+4\.000\n", report)
+    assert re.search(r"dense_ffn\s+1\.000\s+6\.7%[^\n]*\n\s+kernel\s", report)
+    assert re.search(r"held_experts_live\s+8\.000\s+2\.0\s+2\s+moe_experts: "
+                     r"(collect 4\.000, sgd 4\.000|sgd 4\.000, collect 4\.000)",
+                     report)
 
 
 def test_a_digest_says_how_long_its_parse_held_the_process(captured, tmp_path):

@@ -337,11 +337,22 @@ def test_acting_scan_carries_the_latent_rows_and_nothing_per_head(chip):
         assert not re.search(r"\[128,128,32,\d+\]", carried), carried[:400]
 
 
-def test_routed_layer_compiles_to_the_ragged_kernel(chip):
+_COMPILED = {}  # what two tests of this file both read is compiled once
+
+
+def _once(key, build):
+    if key not in _COMPILED:
+        _COMPILED[key] = build()
+    return _COMPILED[key]
+
+
+def _routed_layer_grad(chip):
     """One routed layer at the published widths over a minibatch's 8192
-    tokens, forward and backward: XLA:TPU takes ``jax.lax.ragged_dot`` as
-    a kernel of its own (a ``ragged-dot`` custom call: three forward, six
-    backward), not as sixteen masked dense products."""
+    tokens, forward and backward, compiled for the described chip."""
+    return _once("routed_layer_grad", lambda: _compile_routed_layer_grad(chip))
+
+
+def _compile_routed_layer_grad(chip):
     from surreal_tpu.models import latent_moe
 
     cfg = latent_moe.resolve(dict(num_layers=5, num_heads=32))
@@ -357,7 +368,15 @@ def test_routed_layer_compiles_to_the_ragged_kernel(chip):
     def loss(p, x):
         return layer.apply({"params": p}, x)[0].astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    return jax.jit(jax.grad(loss)).lower(params, x).compile()
+
+
+def test_routed_layer_compiles_to_the_ragged_kernel(chip):
+    """One routed layer at the published widths over a minibatch's 8192
+    tokens, forward and backward: XLA:TPU takes ``jax.lax.ragged_dot`` as
+    a kernel of its own (a ``ragged-dot`` custom call: three forward, six
+    backward), not as sixteen masked dense products."""
+    compiled = _routed_layer_grad(chip)
     text = compiled.as_text()
     assert text.count("custom-call") >= 9 and "agged" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2**30
@@ -383,23 +402,16 @@ def _live_calls(computation: str) -> list:
     ]
 
 
-@pytest.mark.parametrize("family,tokens,kernel", [
-    pytest.param("kda_moe", 16, True, id="kimilinear-16-tokens-live-experts"),
-    pytest.param("mla_moe", 128, False, id="joyai-128-tokens-every-expert"),
-])
-def test_an_acting_steps_routed_layer_reads_the_live_experts(
-    chip, family, tokens, kernel
-):
-    """A routed layer's acting step inside a ``lax.scan`` at the published
-    widths. ``ppo_lift_kimilinear_16x1024``'s (``[16, 2304]``, 8 held of 256,
-    top-8: 0.39 of the held experts expected live) calls the live experts'
-    kernel, Mosaic takes it, and its weights are bfloat16 arrays that enter
-    the loop's body as its own operands: cast once outside and not a step
-    (float32 parameters cast inside would be three times the bytes).
-    ``ppo_lift_joyai_128x128``'s (``[128, 2048]``, 16 held, top-8: 0.98 live,
-    nothing to skip) keeps XLA's dense form and has no custom call."""
-    import re
+def _routed_acting_text(chip, family: str, tokens: int) -> str:
+    """A routed layer's acting step of ``family`` at the published widths
+    inside an eight-step ``lax.scan``, compiled for the described chip."""
+    return _once(
+        ("routed_acting", family, tokens),
+        lambda: _compile_routed_acting(chip, family, tokens),
+    )
 
+
+def _compile_routed_acting(chip, family: str, tokens: int) -> str:
     from surreal_tpu.models import kda_moe, latent_moe
 
     resolve = {"kda_moe": kda_moe.resolve, "mla_moe": latent_moe.resolve}[family]
@@ -420,7 +432,27 @@ def test_an_acting_steps_routed_layer_reads_the_live_experts(
             return x + y, read
         return jax.lax.scan(step, x, None, length=8)
 
-    text = jax.jit(acting).lower(on_chip(params), on_chip(x)).compile().as_text()
+    return jax.jit(acting).lower(on_chip(params), on_chip(x)).compile().as_text()
+
+
+@pytest.mark.parametrize("family,tokens,kernel", [
+    pytest.param("kda_moe", 16, True, id="kimilinear-16-tokens-live-experts"),
+    pytest.param("mla_moe", 128, False, id="joyai-128-tokens-every-expert"),
+])
+def test_an_acting_steps_routed_layer_reads_the_live_experts(
+    chip, family, tokens, kernel
+):
+    """A routed layer's acting step inside a ``lax.scan`` at the published
+    widths. ``ppo_lift_kimilinear_16x1024``'s (``[16, 2304]``, 8 held of 256,
+    top-8: 0.39 of the held experts expected live) calls the live experts'
+    kernel, Mosaic takes it, and its weights are bfloat16 arrays that enter
+    the loop's body as its own operands: cast once outside and not a step
+    (float32 parameters cast inside would be three times the bytes).
+    ``ppo_lift_joyai_128x128``'s (``[128, 2048]``, 16 held, top-8: 0.98 live,
+    nothing to skip) keeps XLA's dense form and has no custom call."""
+    import re
+
+    text = _routed_acting_text(chip, family, tokens)
     if not kernel:
         assert "tpu_custom_call" not in text
         return
@@ -710,6 +742,28 @@ def test_kimilinear_iteration_fits_the_chip_with_each_layer_recomputed(sds):
     assert len(resident) >= 2, len(resident)    # the parent's 2; 3 with the kernel
 
 
+def _selective_scan_grad_text(sds) -> str:
+    """``jax.grad`` of the selective scan at a learn pass's shapes,
+    compiled for the described chip."""
+    return _once("selective_scan_grad", lambda: _compile_selective_scan_grad(sds))
+
+
+def _compile_selective_scan_grad(sds) -> str:
+    from surreal_tpu.ops.selective_scan import selective_scan
+
+    def loss(u, delta, A, Bm, Cm, D, state):
+        y, final = selective_scan(u, delta, A, Bm, Cm, D, state)
+        return (y * y).sum() + (final * final).sum()
+
+    B, T, C, N = 8, 1024, 5120, 16
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    return jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        sds((B, T, C), bf16), sds((B, T, C), f32), sds((N, C), f32),
+        sds((B, T, N), bf16), sds((B, T, N), bf16), sds((C,), f32),
+        sds((B, N, C), f32),
+    ).compile().as_text()
+
+
 def test_selective_scan_gradient_walks_in_vmem(sds):
     """``jax.grad`` of the selective scan at a learn pass's shapes (8 rows of
     1024 positions, 5120 channels, 16 state indices, ``u, B, C`` in
@@ -719,19 +773,7 @@ def test_selective_scan_gradient_walks_in_vmem(sds):
     ``[8, 16, 5120]`` state through HBM and no array holds every state."""
     import re
 
-    from surreal_tpu.ops.selective_scan import selective_scan
-
-    def loss(u, delta, A, Bm, Cm, D, state):
-        y, final = selective_scan(u, delta, A, Bm, Cm, D, state)
-        return (y * y).sum() + (final * final).sum()
-
-    B, T, C, N = 8, 1024, 5120, 16
-    bf16, f32 = jnp.bfloat16, jnp.float32
-    text = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
-        sds((B, T, C), bf16), sds((B, T, C), f32), sds((N, C), f32),
-        sds((B, T, N), bf16), sds((B, T, N), bf16), sds((C,), f32),
-        sds((B, N, C), f32),
-    ).compile().as_text()
+    text = _selective_scan_grad_text(sds)
     calls = re.findall(
         r"%(selective_scan_(?:fwd|bwd))[.\d]* = [^\n]*tpu_custom_call", text
     )
@@ -739,6 +781,26 @@ def test_selective_scan_gradient_walks_in_vmem(sds):
     assert not [line for line in text.splitlines() if " while(" in line
                 and "f32[8,16,5120]" in line.split(" while(")[0]]
     assert not re.search(r"f32\[(1024|1025|1056|1088),8,16,5120\]", text)
+
+
+def _delta_rule_grad_text(sds) -> str:
+    """``jax.grad`` of the delta rule at a learn pass's shapes, compiled
+    for the described chip."""
+    return _once("delta_rule_grad", lambda: _compile_delta_rule_grad(sds))
+
+
+def _compile_delta_rule_grad(sds) -> str:
+    from surreal_tpu.ops.delta_rule import delta_rule
+
+    def loss(q, k, v, g, beta):
+        o, state = delta_rule(q, k, v, g, beta)
+        return (o * o).sum() + (state * state).sum()
+
+    wide = (8, 1024, 32, 128)
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        sds(wide, jnp.float32), sds(wide, jnp.float32), sds(wide, jnp.bfloat16),
+        sds(wide, jnp.float32), sds(wide[:3], jnp.float32),
+    ).compile().as_text()
 
 
 def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
@@ -750,21 +812,56 @@ def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
     left in the program."""
     import re
 
-    from surreal_tpu.ops.delta_rule import delta_rule
-
-    def loss(q, k, v, g, beta):
-        o, state = delta_rule(q, k, v, g, beta)
-        return (o * o).sum() + (state * state).sum()
-
-    wide = (8, 1024, 32, 128)
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        sds(wide, jnp.float32), sds(wide, jnp.float32), sds(wide, jnp.bfloat16),
-        sds(wide, jnp.float32), sds(wide[:3], jnp.float32),
-    ).compile().as_text()
+    text = _delta_rule_grad_text(sds)
     calls = re.findall(r"%(decayed_gram(?:_bwd)?)[.\d]* = [^\n]*tpu_custom_call", text)
     # the forward's, the backward's recomputed chunk's, and the cotangents'
     assert sorted(calls) == ["decayed_gram", "decayed_gram", "decayed_gram_bwd"]
     assert not re.search(r"f32\[8,32,4,16,16,128\]", text)
+
+
+@pytest.mark.parametrize("build,kernels", [
+    pytest.param(
+        lambda chip, sds: _selective_scan_grad_text(sds),
+        {"selective_scan_fwd": 1, "selective_scan_bwd": 1},
+        id="selective-scan-gradient",
+    ),
+    pytest.param(
+        lambda chip, sds: _delta_rule_grad_text(sds),
+        {"decayed_gram": 2, "decayed_gram_bwd": 1}, id="delta-rule-gradient",
+    ),
+    pytest.param(
+        lambda chip, sds: _routed_acting_text(chip, "kda_moe", 16),
+        {"held_experts_live": 1}, id="kimilinear-acting-step",
+    ),
+    pytest.param(
+        lambda chip, sds: _routed_acting_text(chip, "mla_moe", 128),
+        {}, id="joyai-acting-step-no-kernel",
+    ),
+    # XLA:TPU's own ragged product is a Mosaic call too, under XLA's name
+    pytest.param(
+        lambda chip, sds: _routed_layer_grad(chip).as_text(),
+        {"ragged-dot-none": 6, "ragged-dot-metadata": 2},
+        id="routed-layer-gradient-xlas-ragged-dot",
+    ),
+])
+def test_the_digests_kernel_map_names_each_pallas_call(chip, sds, build, kernels):
+    """``session/profile.py::hlo_kernels`` on the chip's own compiled text:
+    every Pallas call under the ``name=`` its ``pl.pallas_call`` was given
+    (what the digest's ``kernels`` table and the benchmark's ``kernel_*_ms``
+    readers key on), as many instructions as call sites, and no other
+    instruction of the program. The one ``tpu_custom_call`` that is not
+    ours, XLA's lowering of ``jax.lax.ragged_dot``, is listed under XLA's
+    own names."""
+    from surreal_tpu.session.profile import hlo_kernels
+
+    text = build(chip, sds)
+    module, found = hlo_kernels(text)
+    assert module.startswith("jit_")
+    sites = {k: sum(1 for v in found.values() if v == k) for k in set(found.values())}
+    assert sites == kernels
+    for instruction in found:
+        assert f"%{instruction} = " in text
+    assert text.count('custom_call_target="tpu_custom_call"') == len(found)
 
 
 @pytest.mark.parametrize("carry_is", [
