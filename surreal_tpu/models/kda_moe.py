@@ -81,7 +81,9 @@ from surreal_tpu.models.latent_moe import (
 # log-uniform in [1e-3, 1e-1], conv taps uniform in +-1/sqrt(taps)
 from surreal_tpu.models.ssm_hybrid import _conv_init, _dt_bias_init
 from surreal_tpu.ops import moe
-from surreal_tpu.ops.delta_rule import delta_rule, delta_step, gram_in_vmem
+from surreal_tpu.ops.delta_rule import (
+    delta_rule, delta_step, gram_in_vmem, walk_in_vmem,
+)
 from surreal_tpu.utils.phases import part
 
 BLOCK = "kda_moe"
@@ -125,14 +127,16 @@ FAMILY_DEFAULTS = dict(
 # largest entry of a matrix state a segment ended with (a rule that blows up
 # shows before the loss does), the mean decay a channel a step (1 forgets
 # nothing), the mean share of a key's content a step rewrites, and which form
-# of the rule's Gram matrices ran (1 the kernel that keeps a chunk's pairwise
-# decays in VMEM, 0 the ``lax`` form: ops/delta_rule.py chooses from the
-# device and the shapes)
+# of the rule's Gram matrices and of its walk over a segment's chunks ran (1
+# the kernels that keep a chunk's pairwise decays, and the matrix state from
+# chunk to chunk, in VMEM, 0 the ``lax`` forms: ops/delta_rule.py chooses from
+# the device and the shapes)
 COUNTERS = {
     "state_abs_max": ("kda/state_abs_max", "max"),
     "decay_mean": ("kda/decay_mean", "mean"),
     "beta_mean": ("kda/beta_mean", "mean"),
     "gram_in_vmem": ("kda/gram_in_vmem", "mean"),
+    "walk_in_vmem": ("kda/walk_in_vmem", "mean"),
 }
 
 
@@ -257,6 +261,7 @@ class DeltaAttention(nn.Module):
                 "state_abs_max": jnp.abs(state).max(),
                 "decay_mean": jnp.exp(g).mean(), "beta_mean": beta.mean(),
                 "gram_in_vmem": gram_in_vmem(q),
+                "walk_in_vmem": walk_in_vmem(q, v),
             }
         return self._out(o, gate), stats
 
@@ -398,7 +403,7 @@ class KDAMoETrunk(nn.Module):
                 stats.append(st)
         pick = lambda name: jnp.stack([st[name] for st in stats])  # noqa: E731
         self.sow(COUNTERS_COLLECTION, "state_abs_max", pick("state_abs_max").max())
-        for name in ("decay_mean", "beta_mean", "gram_in_vmem"):
+        for name in ("decay_mean", "beta_mean", "gram_in_vmem", "walk_in_vmem"):
             self.sow(COUNTERS_COLLECTION, name, pick(name).mean())
         return norm(x)
 

@@ -1,5 +1,5 @@
 """The gated delta rule with a decay a channel, in chunks, with its own
-backward.
+backward, the matrix state in VMEM where a TPU runs it.
 
 The recurrence of a Kimi Delta Attention layer (Kimi Linear, arXiv:2510.26692,
 section 3; the delta rule of Schlag et al. 2021, arXiv:2102.11174, gated a
@@ -63,9 +63,40 @@ process gets the kernels; :func:`gram_in_vmem` says which form a call takes.
 every state: ``[T, B, H, K, V]`` float32 is 17 GB at 8 x 1024 positions of
 32 heads of 128 x 128. The forward keeps the state each chunk started from
 (``T / CHUNK`` of them: 268 MB there) and the backward walks the chunks last
-to first, recomputes one chunk from its saved start (``jax.vjp`` of the
-chunk's own forward) and hands the state's cotangent on to the chunk before,
-as ``ops/selective_scan.py`` does.
+to first, forms one chunk again from its saved start and hands the state's
+cotangent on to the chunk before, as ``ops/selective_scan.py`` does.
+
+**Where the walks run.** As ``lax`` scans (:func:`_walk_lax`, a scan of
+:func:`_chunk`; :func:`_walk_back_lax`, a scan of its ``jax.vjp``) a chunk
+step passes the state, the solve and the five products' operands through
+HBM: 25 ops and 0.40 GB forward, 40 ops and 0.85 GB back at ``[8, 32, 64,
+128]`` where 0.05 GB go in and come out. Where the program is lowered for a
+TPU, a chunk is whole and the heads fill the lanes (``K`` and ``V`` multiples
+of 128), two Pallas kernels walk instead, a grid of (batch row, block of
+:data:`HEADS` heads, chunk) with a row's chunks in turn:
+``delta_chunk_fwd`` (:func:`_fwd_kernel`) keeps the heads' states ``[K, V]``
+float32 in VMEM scratch from a row's first chunk to its last and writes
+``o``, the state each chunk started from and the final state;
+``delta_chunk_bwd`` (:func:`_bwd_kernel`) takes the chunks last to first
+through its index maps with the states' cotangents in scratch, forms a
+chunk's ``u`` again from its saved start and writes ``dq, dk, dv, dG, dbeta``,
+the Gram pair's cotangent and, after the first chunk, ``dstate``. Both are
+:func:`_chunk`'s lines after ``decayed_gram``, place for place, and its
+precisions: the products' operands in ``v``'s dtype, the state rounded once
+as an operand and never as a carry, the triangular system in float32
+(:func:`_unit_lower_inverse`: row substitution inside blocks of :data:`SUB`,
+float32 products between blocks; with ``M`` the system and ``U = M^-1 R`` the
+backward is ``dR = M^-T dU``, ``dM = -tril(dR U^T, -1)``). The Gram kernels
+stay what they are: :func:`_walk` calls ``decayed_gram`` once over all of a
+walk's chunks ahead of it, :func:`_walk_back` forms the pairs again (134 MB a
+layer, a temporary: the residuals stay ``(xs, starts)``) and calls
+``decayed_gram_bwd`` once on the reverse walk's cotangent. No kernel asks for
+a VMEM limit of its own (``ops/selective_scan.py`` has the account of what
+that does to the program around it). One ``jax.custom_vjp`` carries the rule;
+each of its two walks is ``jax.lax.platform_dependent`` beside the ``lax``
+form of the same signature, which is what the CPU, a length under one chunk
+and heads of 8 channels run and what ``tests/test_delta_rule.py`` holds the
+kernels to; :func:`walk_in_vmem` says which form a call takes.
 
 A length that is no multiple of the chunk is padded at its end with ``g = 0,
 beta = 0``: the decay is then 1 and nothing is erased or written, so the
@@ -305,11 +336,17 @@ def _gram_pallas_bwd(q, k, G, d, cd, interpret=False):
     )
 
 
-def _kernel_takes(L: int, K: int) -> bool:
-    """Whether the kernels take chunks of ``L`` positions of ``K`` channels:
-    whole chunks (their four blocks of rows are unrolled) of heads that fill
-    the lanes."""
-    return L == CHUNK and K % 128 == 0
+def _kernel_takes(L: int, K: int, V: int | None = None) -> bool:
+    """Whether the kernels take chunks of ``L`` positions of ``K`` channels
+    (and, the walks, ``V`` values): whole chunks (their four blocks of rows
+    are unrolled) of heads that fill the lanes."""
+    return L == CHUNK and K % 128 == 0 and (V is None or V % 128 == 0)
+
+
+def _lowered_for_tpu():
+    return jax.lax.platform_dependent(
+        tpu=lambda: jnp.float32(1.0), default=lambda: jnp.float32(0.0)
+    )
 
 
 def gram_in_vmem(q):
@@ -318,9 +355,16 @@ def gram_in_vmem(q):
     scalar, settled when the program is lowered for its device."""
     if not _kernel_takes(_chunk_len(q.shape[1]), q.shape[3]):
         return jnp.float32(0.0)
-    return jax.lax.platform_dependent(
-        tpu=lambda: jnp.float32(1.0), default=lambda: jnp.float32(0.0)
-    )
+    return _lowered_for_tpu()
+
+
+def walk_in_vmem(q, v):
+    """1.0 where :func:`delta_rule` of ``q [B, T, H, K]``, ``v [B, T, H, V]``
+    walks its chunks in the kernels, the matrix state in VMEM, 0.0 where in
+    the ``lax`` scans: a float32 scalar, settled as :func:`gram_in_vmem`'s."""
+    if not _kernel_takes(_chunk_len(q.shape[1]), q.shape[3], v.shape[3]):
+        return jnp.float32(0.0)
+    return _lowered_for_tpu()
 
 
 def decayed_gram(q, k, G, cd):
@@ -394,6 +438,365 @@ def _chunk(state, xs):
     return state, o
 
 
+# -- a segment's chunks in turn: the ``lax`` form ------------------------------
+
+def _walk_lax(xs, state):
+    """The forward walk as a ``lax`` scan of :func:`_chunk`: ``xs``
+    chunk-major ``[N, B, H, L, .]`` from ``state [B, H, K, V]`` -> ``(o [N, B,
+    H, L, V], final state, the state each chunk started from [N, B, H, K,
+    V])``. What :func:`_walk` is tested against, and what runs where the
+    kernels do not."""
+    def outer(s, xs_n):
+        s_next, o_n = _chunk(s, xs_n)
+        return s_next, (o_n, s)
+
+    final, (o, starts) = jax.lax.scan(outer, state, xs)
+    return o, final, starts
+
+
+def _walk_back_lax(xs, starts, do, dfinal):
+    """The reverse walk as a ``lax`` scan, :func:`_walk_back`'s signature: a
+    chunk again from its saved start by ``jax.vjp`` of the chunk's own
+    forward -> ``(dxs, dstate)``."""
+    def outer(ds, inp):
+        xs_n, s_n, do_n = inp
+        _, vjp = jax.vjp(_chunk, s_n, xs_n)
+        return vjp((ds, do_n))
+
+    dstate, dxs = jax.lax.scan(outer, dfinal, (xs, starts, do), reverse=True)
+    return dxs, dstate
+
+
+# -- the same walks, the state in VMEM -----------------------------------------
+
+# heads a program of a walk: a chunk step is a chain of small products and
+# of row substitutions, and the chains of a program's heads are independent,
+# so the scheduler overlaps them (on the v5e at [8, 1024, 32, 128] a forward
+# walk is 8.0 ms and a reverse one 15.2 at four heads, 7.7 and 14.8 at eight;
+# the reverse walk's blocks and scratch of eight are 10 MB of the default 16
+# MiB)
+HEADS = 8
+
+
+def _mm(a, b, dims, cd):
+    """A product of the matrix unit, operands in ``cd``, float32 out."""
+    return jax.lax.dot_general(
+        a.astype(cd), b.astype(cd), (dims, ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _mm32(a, b, dims):
+    """A float32 product in float32's arithmetic (the solve's)."""
+    return jax.lax.dot_general(
+        a, b, (dims, ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+# contractions of two matrices: a b, a b^T, a^T b
+_AB, _ABT, _ATB = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _eye(n):
+    return (
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    ).astype(jnp.float32)
+
+
+def _strictly_lower(n):
+    return (
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+        > jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    )
+
+
+def _unit_lower_inverse(lower):
+    """``(I + lower)^-1`` of a strictly lower-triangular ``[L, L]`` float32
+    matrix, ``L`` a power of two of blocks of :data:`SUB` rows, in float32
+    throughout. The diagonal blocks by row substitution on the vector unit,
+    all of them at once (a row is final once the rows above it have been
+    taken off it: ``SUB - 1`` dependent steps); then the blocks under the
+    diagonal by ``[[A, 0], [C, B]]^-1 = [[A^-1, 0], [-B^-1 C A^-1, B^-1]]``
+    over pairs of blocks of twice the size each time, two float32 products
+    of ``L / 2`` rows a doubling (ten products of ``L`` rows, the inverse as
+    ``(I - N)(I + N^2) .. (I + N^32)``, were 5.0 ms of a walk's 11.1 on the
+    v5e where this is 2.5). Applied to a right-hand side, and transposed to
+    a cotangent, it is the solve."""
+    L = lower.shape[0]
+    index = lambda axis: jax.lax.broadcasted_iota(jnp.int32, (L, L), axis)  # noqa: E731
+    ours = jnp.concatenate([
+        lower[at:at + SUB, at:at + SUB] for at in range(0, L, SUB)
+    ])                                                          # [L, SUB]
+    inverse = _eye(L)
+    for s in range(SUB - 1):
+        final = jnp.concatenate([
+            jnp.broadcast_to(inverse[at + s:at + s + 1], (SUB, L))
+            for at in range(0, L, SUB)
+        ])
+        inverse = inverse - ours[:, s:s + 1] * final
+    size = SUB
+    while size < L:
+        pairs = range(0, L, 2 * size)
+        # a pair's second block gains -B^-1 C A^-1 under the first
+        second = jnp.concatenate(
+            [inverse[at + size:at + 2 * size] for at in pairs]
+        )
+        under = jnp.where(
+            index(1) // size == index(0) // size - 1, lower, 0.0
+        )
+        gain = _mm32(_mm32(second, under, _AB), inverse, _AB)
+        inverse = jnp.concatenate([
+            piece for i, at in enumerate(pairs) for piece in (
+                inverse[at:at + size],
+                inverse[at + size:at + 2 * size] - gain[i * size:(i + 1) * size],
+            )
+        ])
+        size *= 2
+    return inverse
+
+
+def _column(row):
+    """``[1, n]`` -> ``[n, 1]`` through the diagonal (a transpose of one
+    row is not worth the transpose unit's set-up)."""
+    return (_eye(row.shape[1]) * row).sum(1, keepdims=True)
+
+
+def _row(column):
+    """``[n, 1]`` -> ``[1, n]``."""
+    return (_eye(column.shape[0]) * column).sum(0, keepdims=True)
+
+
+def _chunk_again(q, k, G, v, beta, gram, state, cd):
+    """A head's chunk from the state it starts from, :func:`_chunk`'s lines
+    after ``decayed_gram`` on ``[L, .]`` tiles: what the forward writes and
+    the reverse walk forms again. ``beta [L, 1]``; ``rows [2 L, K]`` are ``q``
+    and ``k`` decayed since the chunk's start, the one operand of the two
+    products with the state."""
+    f32 = jnp.float32
+    L = q.shape[0]
+    since = jnp.exp(G)                      # decay since the chunk's start
+    to_end = jnp.exp(G[L - 1:L] - G)        # each position's decay to the end
+    rows = jnp.concatenate([q * since, k * since])
+    both = _mm(rows, state, _AB, cd)
+    from_state, held = both[:L], v.astype(f32) - both[L:]
+    k_on_k = jnp.where(_strictly_lower(L), gram[:, L:], 0.0)
+    inverse = _unit_lower_inverse(beta * k_on_k)
+    u = _mm32(inverse, beta * held, _AB)
+    return since, to_end, rows, from_state, held, k_on_k, inverse, u
+
+
+def _fwd_kernel(
+    q_ref, k_ref, G_ref, v_ref, beta_ref, gram_ref, s0_ref,
+    o_ref, starts_ref, final_ref, state, *, cd,
+):
+    """A block of heads' chunk: ``q, k, G [L, K]``, ``v [L, V]``, ``beta [1,
+    L]`` and the Gram pair ``[L, 2 L]`` a head -> ``o [L, V]`` and the state
+    the chunk started from; the states ``[K, V]`` stay in ``state`` from a
+    row's first chunk to its last."""
+    n = pl.program_id(2)
+    L = q_ref.shape[1]
+
+    @pl.when(n == 0)
+    def _():
+        state[...] = s0_ref[...]
+
+    starts_ref[...] = state[...]
+    for h in range(q_ref.shape[0]):
+        k, gram, start = k_ref[h], gram_ref[h], state[h]
+        since, to_end, _, from_state, _, _, _, u = _chunk_again(
+            q_ref[h], k, G_ref[h], v_ref[h], _column(beta_ref[h]), gram,
+            start, cd,
+        )
+        o_ref[h] = from_state + _mm(gram[:, :L], u, _AB, cd)
+        state[h] = _column(since[L - 1:L]) * start + _mm(
+            k * to_end, u, _ATB, cd
+        )
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        final_ref[...] = state[...]
+
+
+def _bwd_kernel(
+    q_ref, k_ref, G_ref, v_ref, beta_ref, gram_ref, starts_ref, do_ref,
+    dfinal_ref, dq_ref, dk_ref, dG_ref, dv_ref, dbeta_ref, dgram_ref,
+    dstate_ref, ds, *, cd,
+):
+    """A block of heads' chunk, the chunks coming last to first: a chunk's
+    ``u`` again from its saved start, then the five products' transposes and
+    the solve's (``d rhs = M^-T du``, ``dM = -tril(d rhs u^T, -1)``); the
+    states' cotangents ``[K, V]`` stay in ``ds`` from a row's last chunk to
+    its first."""
+    f32 = jnp.float32
+    n = pl.program_id(2)
+    L = q_ref.shape[1]
+    last_row = jax.lax.broadcasted_iota(jnp.int32, q_ref.shape[1:], 0) == L - 1
+
+    @pl.when(n == 0)
+    def _():
+        ds[...] = dfinal_ref[...]
+
+    for h in range(q_ref.shape[0]):
+        q, k, gram, start = q_ref[h], k_ref[h], gram_ref[h], starts_ref[h]
+        beta = _column(beta_ref[h])
+        since, to_end, rows, _, held, k_on_k, inverse, u = _chunk_again(
+            q, k, G_ref[h], v_ref[h], beta, gram, start, cd
+        )
+        d_next, do = ds[h], do_ref[h]
+        # the state's update and the output, back to u and their operands
+        d_k_to_end = _mm(u, d_next, _ABT, cd)
+        du = _mm(k * to_end, d_next, _AB, cd) + _mm(gram[:, :L], do, _ATB, cd)
+        d_q_on_k = _mm(do, u, _ABT, cd)
+        # the solve, transposed
+        d_rhs = _mm32(inverse, du, _ATB)
+        d_system = jnp.where(_strictly_lower(L), -_mm32(d_rhs, u, _ABT), 0.0)
+        d_held = beta * d_rhs
+        # the two products with the chunk's start, back
+        d_out = jnp.concatenate([do, -d_held])
+        d_rows = _mm(d_out, start, _ABT, cd)
+        d_start = _mm(rows, d_out, _ATB, cd)
+        moved = d_rows * rows               # what reaches G through `since`
+        left = d_k_to_end * k * to_end      # and through `to_end`
+        at_end = left.sum(0, keepdims=True) + since[L - 1:L] * _row(
+            (start * d_next).sum(1, keepdims=True)
+        )
+        dq_ref[h] = d_rows[:L] * since
+        dk_ref[h] = d_rows[L:] * since + d_k_to_end * to_end
+        dG_ref[h] = moved[:L] + moved[L:] - left + jnp.where(
+            last_row, at_end, 0.0
+        )
+        dv_ref[h] = d_held.astype(dv_ref.dtype)
+        dbeta_ref[h] = _row(
+            (d_system * k_on_k).sum(1, keepdims=True)
+            + (d_rhs * held).sum(1, keepdims=True)
+        )
+        dgram_ref[h] = jnp.concatenate([d_q_on_k, beta * d_system], axis=1)
+        ds[h] = _column(since[L - 1:L]) * d_next + d_start
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _():
+        dstate_ref[...] = ds[...]
+
+
+def _walk_call(kernel, name, cd, interpret, back, ins, rows, outs, row_outs):
+    """``kernel`` over the grid (batch row, block of heads, chunk) of
+    chunk-major ``[N, B, H, L, .]`` arrays ``ins`` and ``outs`` and of ``[B,
+    H, K, V]`` arrays ``rows`` and ``row_outs`` (a row's states and their
+    cotangents; one such scratch besides), a row's chunks in turn, last to
+    first where ``back``; ``name`` is what a device trace calls it."""
+    N, B, H = ins[0].shape[:3]
+    heads = next(h for h in (HEADS, 4, 2, 1) if H % h == 0)
+    at = (lambda n: N - 1 - n) if back else (lambda n: n)
+    chunk = lambda x: pl.BlockSpec(   # noqa: E731
+        (None, None, heads) + x.shape[3:], lambda b, h, n: (at(n), b, h, 0, 0)
+    )
+    row = lambda x: pl.BlockSpec(   # noqa: E731
+        (None, heads) + x.shape[2:], lambda b, h, n: (b, h, 0, 0)
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, cd=cd),
+        grid=(B, H // heads, N),
+        in_specs=[chunk(x) for x in ins] + [row(x) for x in rows],
+        out_specs=tuple(chunk(x) for x in outs) + tuple(row(x) for x in row_outs),
+        out_shape=tuple(outs) + tuple(row_outs),
+        scratch_shapes=[pltpu.VMEM((heads,) + rows[0].shape[2:], jnp.float32)],
+        # no vmem_limit_bytes: one on any Pallas call re-tiles the fusions of
+        # the whole program it sits in (ops/selective_scan.py has the account)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name=name,
+    )(*ins, *rows)
+
+
+def _running_sum(x, reverse=False):
+    """``x [..., L, K]`` float32 -> its running sum over ``L`` (from the end
+    where ``reverse``) as a float32 product with a triangle of ones: XLA's
+    ``cumsum`` is a window reduction there, 2.0 ms over a walk's ``[16, 8, 32,
+    64, 128]`` on the v5e where the array passes through HBM in 0.33."""
+    L = x.shape[-2]
+    ones = jnp.tril(jnp.ones((L, L), jnp.float32))
+    return jnp.einsum(
+        "st,...tk->...sk", ones.T if reverse else ones, x,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def _gram_inputs(xs):
+    """``xs`` -> ``(q, k, G, beta)`` float32, ``G`` the chunks' running sums
+    of ``g``, ``beta [N, B, H, 1, L]`` a row a head."""
+    q, k, g, beta = (xs[i].astype(jnp.float32) for i in (0, 1, 3, 4))
+    return q, k, _running_sum(g), beta[:, :, :, None]
+
+
+def _all_chunks(gram, *args, **static):
+    """A Gram kernel over every chunk of a walk at once: the chunks folded
+    into its grid's batch rows."""
+    fold = lambda x: x.reshape((-1,) + x.shape[2:])   # noqa: E731
+    out = gram(*(fold(x) for x in args), **static)
+    return jax.tree.map(lambda x: x.reshape(args[0].shape[:2] + x.shape[1:]), out)
+
+
+# jitted as the Gram kernels' entry points are, and for the same reason
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _walk(xs, state, interpret=False):
+    """The forward walk in VMEM, :func:`_walk_lax`'s signature: the Gram
+    pairs of all the chunks in one call of ``decayed_gram``, then
+    ``delta_chunk_fwd``."""
+    f32, cd = jnp.float32, xs[2].dtype
+    q, k, G, beta = _gram_inputs(xs)
+    gram = _all_chunks(_gram_pallas, q, k, G, cd=cd, interpret=interpret)
+    o = jax.ShapeDtypeStruct(xs[2].shape, f32)
+    starts = jax.ShapeDtypeStruct(q.shape[:1] + state.shape, f32)
+    final = jax.ShapeDtypeStruct(state.shape, f32)
+    o, starts, final = _walk_call(
+        _fwd_kernel, "delta_chunk_fwd", cd, interpret, False,
+        (q, k, G, xs[2], beta, gram), (state,), (o, starts), (final,),
+    )
+    return o, final, starts
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _walk_back(xs, starts, do, dfinal, interpret=False):
+    """The reverse walk in VMEM, :func:`_walk_back_lax`'s signature: the Gram
+    pairs again (a temporary: nothing of their size is a residual),
+    ``delta_chunk_bwd``, then ``decayed_gram_bwd`` once on the pairs'
+    cotangent."""
+    f32, cd = jnp.float32, xs[2].dtype
+    q, k, G, beta = _gram_inputs(xs)
+    gram = _all_chunks(_gram_pallas, q, k, G, cd=cd, interpret=interpret)
+    like = lambda x, dtype=f32: jax.ShapeDtypeStruct(x.shape, dtype)   # noqa: E731
+    dq, dk, dG, dv, dbeta, dgram, dstate = _walk_call(
+        _bwd_kernel, "delta_chunk_bwd", cd, interpret, True,
+        (q, k, G, xs[2], beta, gram, starts, do), (dfinal,),
+        (like(q), like(k), like(G), like(xs[2], cd), like(beta), like(gram)),
+        (like(dfinal),),
+    )
+    through = _all_chunks(
+        _gram_pallas_bwd, q, k, G, dgram, cd=cd, interpret=interpret
+    )
+    dq, dk, dG = (a + b for a, b in zip((dq, dk, dG), through))
+    # G is g's running sum, so g gathers what reaches G from its position on
+    dg = _running_sum(dG, reverse=True)
+    dxs = (dq, dk, dv, dg, dbeta[:, :, :, 0])
+    return tuple(d.astype(x.dtype) for d, x in zip(dxs, xs)), dstate
+
+
+# -- one rule, two forms -------------------------------------------------------
+
+def _where_lowered(kernel, lax_form, xs, *args):
+    """``kernel`` where the shapes ask for it and the program is lowered for
+    a TPU, else ``lax_form``."""
+    if not _kernel_takes(*xs[0].shape[3:], xs[2].shape[4]):
+        return lax_form(xs, *args)
+    return jax.lax.platform_dependent(
+        xs, *args, tpu=kernel, default=lax_form
+    )
+
+
 @jax.custom_vjp
 def _chunked(xs, state):
     """``xs`` chunk-major ``[N, B, H, L, .]`` -> ``(o [N, B, H, L, V], final
@@ -402,26 +805,12 @@ def _chunked(xs, state):
 
 
 def _chunked_fwd(xs, state):
-    def outer(s, xs_n):
-        s_next, o_n = _chunk(s, xs_n)
-        return s_next, (o_n, s)
-
-    final, (o, starts) = jax.lax.scan(outer, state, xs)
+    o, final, starts = _where_lowered(_walk, _walk_lax, xs, state)
     return (o, final), (xs, starts)
 
 
 def _chunked_bwd(res, cts):
-    xs, starts = res
-    do, dfinal = cts
-
-    def outer(ds, inp):
-        xs_n, s_n, do_n = inp
-        _, vjp = jax.vjp(_chunk, s_n, xs_n)
-        gs, gxs = vjp((ds, do_n))
-        return gs, gxs
-
-    dstate, dxs = jax.lax.scan(outer, dfinal, (xs, starts, do), reverse=True)
-    return dxs, dstate
+    return _where_lowered(_walk_back, _walk_back_lax, *res, *cts)
 
 
 _chunked.defvjp(_chunked_fwd, _chunked_bwd)

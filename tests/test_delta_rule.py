@@ -1,8 +1,9 @@
 """ops/delta_rule.py: the chunked gated delta rule against the recurrence a
 position at a time (values and, through the ``custom_vjp``, gradients), a
 length that is no multiple of the chunk, steps against a scan, and decays at
-both ends of (0, 1); the Gram kernels (interpreted) against the ``lax`` form
-they stand in for on the chip."""
+both ends of (0, 1); the Gram kernels and the two walks' kernels
+(interpreted) against the ``lax`` forms they stand in for on the chip, and the
+whole rule through them (the ``form`` fixture) against the recurrence."""
 
 import functools
 
@@ -27,6 +28,25 @@ def _inputs(seed, B, T, H, K, V, g_scale=1.0):
     return q, k, v, g, beta, state
 
 
+def _interpreted(kernel, lax_form, xs, *args):
+    """``_where_lowered`` with the kernel interpreted where a TPU would run
+    it."""
+    if not delta_rule_module._kernel_takes(*xs[0].shape[3:], xs[2].shape[4]):
+        return lax_form(xs, *args)
+    return kernel(xs, *args, interpret=True)
+
+
+@pytest.fixture
+def form(request, monkeypatch):
+    """``(K, V)`` of a form of the rule: ``"lax"`` at heads only the scans
+    take, ``"kernels"`` at heads that ask for the walks' kernels, with every
+    walk (and the Gram kernels inside it) interpreted."""
+    if request.param == "lax":
+        return 8, 6
+    monkeypatch.setattr(delta_rule_module, "_where_lowered", _interpreted)
+    return 128, 128
+
+
 def recurrence(q, k, v, g, beta, state):
     """The rule as its equation reads, a position at a time, written apart
     from ``delta_step``: ``S = (I - b k k^T) Diag(a) S + b k v^T``."""
@@ -44,15 +64,18 @@ def recurrence(q, k, v, g, beta, state):
     return jnp.moveaxis(o, 0, 1), S
 
 
-@pytest.mark.parametrize("T, chunk", [
-    (2 * CHUNK + 32, CHUNK), (37, 32), (5, CHUNK),
-])
-def test_chunked_rule_is_the_recurrence(T, chunk, monkeypatch):
+@pytest.mark.parametrize("form, T, chunk", [
+    ("lax", 2 * CHUNK + 32, CHUNK), ("lax", 37, 32), ("lax", 5, CHUNK),
+    # two whole chunks and a padded third through the kernels
+    ("kernels", 2 * CHUNK + 32, CHUNK),
+], indirect=["form"])
+def test_chunked_rule_is_the_recurrence(form, T, chunk, monkeypatch):
     monkeypatch.setattr(delta_rule_module, "CHUNK", chunk)
-    x = _inputs(0, 2, T, 3, 8, 6)
+    K, V = form
+    x = _inputs(0, 2, T, 3, K, V)
     o, S = jax.jit(lambda *a: delta_rule(*a))(*x)
     o_ref, S_ref = jax.jit(recurrence)(*x)
-    assert o.shape == (2, T, 3, 6) and S.shape == (2, 3, 8, 6)
+    assert o.shape == (2, T, 3, V) and S.shape == (2, 3, K, V)
     np.testing.assert_allclose(o, o_ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(S, S_ref, rtol=2e-5, atol=2e-5)
 
@@ -64,15 +87,18 @@ def test_zero_start_is_the_default():
     np.testing.assert_array_equal(a[0], b[0])
 
 
-@pytest.mark.parametrize("T, chunk", [(80, 32), (21, 32)])
-def test_gradients_are_autodiffs_of_the_recurrence(T, chunk, monkeypatch):
+@pytest.mark.parametrize("form, T, chunk", [
+    ("lax", 80, 32), ("lax", 21, 32), ("kernels", CHUNK + 16, CHUNK),
+], indirect=["form"])
+def test_gradients_are_autodiffs_of_the_recurrence(form, T, chunk, monkeypatch):
     """Every input's gradient through the custom backward (chunks recomputed
     from their saved starts) against autodiff of the plain recurrence, with a
     cotangent on the outputs and on the final state."""
     monkeypatch.setattr(delta_rule_module, "CHUNK", chunk)
-    x = _inputs(2, 2, T, 2, 8, 8)
-    w_o = jax.random.normal(jax.random.key(7), (2, T, 2, 8))
-    w_s = jax.random.normal(jax.random.key(8), (2, 2, 8, 8))
+    K, V = form
+    x = _inputs(2, 2, T, 2, K, V)
+    w_o = jax.random.normal(jax.random.key(7), (2, T, 2, V))
+    w_s = jax.random.normal(jax.random.key(8), (2, 2, K, V))
 
     def loss(fn):
         def f(*a):
@@ -220,6 +246,104 @@ def test_gram_kernel_rounds_the_products_between_blocks_only():
         assert float(jnp.abs(a - b).max()) < 1e-2 * float(jnp.abs(b).max())
 
 
+# -- the walks: the kernels against the ``lax`` scans ---------------------------
+
+def _walk_inputs(log_decay, with_state, dtype=jnp.float32, K=128, chunks=2, heads=3):
+    """``xs`` chunk-major ``[chunks, 1, heads, CHUNK, .]``, a starting state
+    and cotangents for ``o`` and the final state."""
+    q, k, v, g, beta, state = _inputs(21, 1, chunks * CHUNK, heads, K, K)
+    if log_decay is not None:
+        g = jnp.full_like(g, log_decay)
+    xs = tuple(
+        jnp.moveaxis(x.reshape(1, chunks, CHUNK, *x.shape[2:]), (1, 3), (0, 2))
+        for x in (q, k, v.astype(dtype), g, beta)
+    )
+    do = jax.random.normal(jax.random.key(22), xs[2].shape)
+    dfinal = jax.random.normal(jax.random.key(23), state.shape)
+    return xs, state if with_state else jnp.zeros_like(state), do, dfinal
+
+
+@functools.cache
+def _walk_forms():
+    """``(kernels, lax form)``, each ``xs, state, do, dfinal -> ((o, final,
+    starts), ((dq, dk, dv, dg, dbeta), dstate))``, jitted once for every
+    case."""
+    def both(walk, walk_back, **kw):
+        def run(xs, state, do, dfinal):
+            o, final, starts = walk(xs, state, **kw)
+            return (o, final, starts), walk_back(xs, starts, do, dfinal, **kw)
+        return jax.jit(run)
+
+    m = delta_rule_module
+    return (
+        both(m._walk, m._walk_back, interpret=True),
+        both(m._walk_lax, m._walk_back_lax),
+    )
+
+
+WALK_NAMES = ("o", "final", "starts", "dq", "dk", "dv", "dg", "dbeta", "dstate")
+
+
+@pytest.mark.parametrize("with_state", [True, False])
+@pytest.mark.parametrize("log_decay", [None, -100.0, 0.0])
+def test_walk_kernels_are_the_lax_walks(log_decay, with_state):
+    """``delta_chunk_fwd`` and ``delta_chunk_bwd`` (interpreted) against the
+    scans of ``_chunk`` and of its ``jax.vjp`` at ``K = V = 128``, two chunks
+    of three heads: ``o``, the final state, the kept starts and all six
+    gradients, from a state and from none, at a layer's decays, at ``e^-100``
+    a step (the state never outlives a position) and at none."""
+    x = _walk_inputs(log_decay, with_state)
+    kernels, lax_form = _walk_forms()
+    got, want = jax.tree.leaves(kernels(*x)), jax.tree.leaves(lax_form(*x))
+    for name, a, b in zip(WALK_NAMES, got, want, strict=True):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        assert bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(
+            a, b, rtol=2e-5, atol=2e-5 * max(float(jnp.abs(b).max()), 0.1),
+            err_msg=name,
+        )
+
+
+def test_walk_kernels_round_the_products_operands_only():
+    """With ``v`` in bfloat16 the walks' products take bfloat16 operands in
+    the kernels as in the scans and the states stay float32: the forward
+    agrees to float32's precision (the same roundings), ``dv`` comes back in
+    bfloat16, and the gradients agree at bfloat16's (the scans' autodiff
+    rounds each operand's cotangent, the kernels add them in float32)."""
+    x = _walk_inputs(None, True, jnp.bfloat16, heads=1)
+    kernels, lax_form = _walk_forms()
+    got, want = jax.tree.leaves(kernels(*x)), jax.tree.leaves(lax_form(*x))
+    for name, a, b in zip(WALK_NAMES, got, want, strict=True):
+        assert a.dtype == b.dtype, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        tol = 1e-5 if name in ("o", "final", "starts") else 1e-2
+        assert float(jnp.abs(a - b).max()) <= tol * float(jnp.abs(b).max()), name
+    assert got[1].dtype == jnp.float32 and got[5].dtype == jnp.bfloat16
+
+
+@pytest.mark.parametrize("log_decay", [None, 0.0])
+def test_the_kernels_solve_is_triangular_solve(log_decay):
+    """The inverse the kernels apply (``(I - N)(I + N^2) .. (I + N^32)`` in
+    float32 products) against ``triangular_solve`` in float32 on a chunk's
+    own system, a right-hand side and a transposed one, at a layer's decays
+    and at none (the largest entries a system of unit keys can hold)."""
+    q, k, G, _ = _gram_inputs(log_decay, chunks=1, heads=1)
+    beta = jax.nn.sigmoid(jax.random.normal(jax.random.key(31), (CHUNK, 1)))
+    k_on_k = delta_rule_module._gram_lax(q, k, G, jnp.float32)[0, 0, :, CHUNK:]
+    lower = beta * jnp.tril(k_on_k, -1)
+    rhs = jax.random.normal(jax.random.key(32), (CHUNK, 128))
+    inverse = jax.jit(delta_rule_module._unit_lower_inverse)(lower)
+    for transpose in (False, True):
+        want = jax.lax.linalg.triangular_solve(
+            jnp.eye(CHUNK) + lower, rhs, left_side=True, lower=True,
+            unit_diagonal=True, transpose_a=transpose,
+        )
+        got = jnp.matmul(
+            inverse.T if transpose else inverse, rhs, precision="highest"
+        )
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("K, asks", [(8, False), (128, True)])
 def test_the_shapes_choose_the_form(K, asks):
     """Heads of 8 channels never ask for the kernel; heads of 128 ask where
@@ -229,6 +353,7 @@ def test_the_shapes_choose_the_form(K, asks):
     text = str(jax.make_jaxpr(delta_rule)(q, k, v, g, beta, state))
     assert ("pallas_call" in text) == asks
     assert float(jax.jit(delta_rule_module.gram_in_vmem)(q)) == 0.0
+    assert float(jax.jit(delta_rule_module.walk_in_vmem)(q, v)) == 0.0
     if not asks:    # every other test of this file runs heads of 8 or 16
         return
     o, S = jax.jit(delta_rule)(q, k, v, g, beta, state)

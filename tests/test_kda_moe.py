@@ -312,6 +312,7 @@ def test_the_table_has_the_family_and_the_selectors_read_it():
     assert family.reset_recurrent is kda_moe.reset_recurrent
     assert set(family.counters) == {
         "state_abs_max", "decay_mean", "beta_mean", "gram_in_vmem",
+        "walk_in_vmem",
     }
     assert family.moe_stats is latent_moe.moe_stats
     assert family.update_router_bias is latent_moe.update_router_bias
@@ -501,7 +502,7 @@ def test_learn_reports_the_rows_moves_the_bias_and_no_router_and_the_ratio_is_on
     assert float(jnp.abs(ratio - 1).max()) < 1e-4
     assert set(stats) == {
         "load", "overflow", "state_abs_max", "decay_mean", "beta_mean",
-        "gram_in_vmem",
+        "gram_in_vmem", "walk_in_vmem",
     }
     assert stats["load"].shape == (4, 8)
     metrics = {k: float(v) for k, v in metrics.items()}
@@ -511,8 +512,10 @@ def test_learn_reports_the_rows_moves_the_bias_and_no_router_and_the_ratio_is_on
     assert 0.0 < metrics["kda/state_abs_max"] < 10.0
     assert 0.5 < metrics["kda/decay_mean"] < 1.0
     assert metrics["kda/beta_mean"] == pytest.approx(0.5, abs=0.1)
-    # heads of 8 channels on the CPU: the lax form of the Gram matrices
+    # heads of 8 channels on the CPU: the lax form of the Gram matrices and
+    # of the walk over the chunks
     assert metrics["kda/gram_in_vmem"] == 0.0
+    assert metrics["kda/walk_in_vmem"] == 0.0
     assert math.isfinite(metrics["loss/pg"]) and metrics["health/update_ratio"] > 0
     before, after = state.params["params"]["trunk"], new.params["params"]["trunk"]
     for i in range(1, 5):

@@ -807,16 +807,28 @@ def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
     """``jax.grad`` of the delta rule at a learn pass's shapes (8 rows of
     1024 positions, 32 heads of 128 channels, ``v`` in bfloat16), compiled
     for the v5e in this CPU process: the lowering takes the two Gram kernels
-    (ops/delta_rule.py chooses from the device it lowers for and the shapes),
-    Mosaic accepts both, and no array of a chunk step's pairwise decays is
-    left in the program."""
+    and the two walks' (ops/delta_rule.py chooses from the device it lowers
+    for and the shapes), Mosaic accepts all four, no array of a chunk step's
+    pairwise decays is left in the program, no loop carries the ``[8, 32,
+    128, 128]`` states through HBM and no triangular solve is left outside
+    the kernels."""
     import re
 
     text = _delta_rule_grad_text(sds)
-    calls = re.findall(r"%(decayed_gram(?:_bwd)?)[.\d]* = [^\n]*tpu_custom_call", text)
-    # the forward's, the backward's recomputed chunk's, and the cotangents'
-    assert sorted(calls) == ["decayed_gram", "decayed_gram", "decayed_gram_bwd"]
+    calls = re.findall(
+        r"%(decayed_gram(?:_bwd)?|delta_chunk_(?:fwd|bwd))[.\d]* = "
+        r"[^\n]*tpu_custom_call", text,
+    )
+    # the Gram pairs of all the chunks ahead of each walk, the two walks, and
+    # the pairs' cotangent after the reverse one
+    assert sorted(calls) == [
+        "decayed_gram", "decayed_gram", "decayed_gram_bwd",
+        "delta_chunk_bwd", "delta_chunk_fwd",
+    ]
     assert not re.search(r"f32\[8,32,4,16,16,128\]", text)
+    assert not [line for line in text.splitlines() if " while(" in line
+                and "f32[8,32,128,128]" in line.split(" while(")[0]]
+    assert "triangular-solve" not in text
 
 
 @pytest.mark.parametrize("build,kernels", [
@@ -827,7 +839,8 @@ def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
     ),
     pytest.param(
         lambda chip, sds: _delta_rule_grad_text(sds),
-        {"decayed_gram": 2, "decayed_gram_bwd": 1}, id="delta-rule-gradient",
+        {"decayed_gram": 2, "decayed_gram_bwd": 1, "delta_chunk_fwd": 1,
+         "delta_chunk_bwd": 1}, id="delta-rule-gradient",
     ),
     pytest.param(
         lambda chip, sds: _routed_acting_text(chip, "kda_moe", 16),
