@@ -43,7 +43,10 @@ experts' part alone added.
 
 **Two paths compute it**, from one parameter tree and the same functions.
 The learn pass runs whole segments, ``QUERY_BLOCK`` queries at a time
-(``ops/ring_attention.py::blocked_attention`` with a keep-mask a block):
+(``ops/ring_attention.py::blocked_attention`` with a keep-mask a block:
+on a TPU at a 128-wide head in bfloat16 its two Pallas kernels, the mask
+one byte a pair and no score in HBM, anywhere else its ``lax`` form; the
+row ``attn/scores_in_vmem`` says which):
 a block whose last query has no more than ``index_topk`` keys selects
 nothing, by its static shape; any other scores its queries against the
 keys up to its end and searches each query's k-th value. An acting step
@@ -86,7 +89,7 @@ from surreal_tpu.models.swa_moe import (
     _normal, _ONES, moe_stats, rms_norm, rotate, routed_ffn, routing_of,
 )
 from surreal_tpu.ops import moe
-from surreal_tpu.ops.ring_attention import blocked_attention
+from surreal_tpu.ops.ring_attention import blocked_attention, scores_in_vmem
 from surreal_tpu.ops.sparse_select import (
     index_scores, keep_mask, weighted_heads,
 )
@@ -118,11 +121,13 @@ FAMILY_DEFAULTS = dict(
     num_held=16,
 )
 # what a whole-segment apply sows, one scalar each: kept keys over causal
-# keys (1 while no query has more than index_topk), and the share of the
-# queries that have more
+# keys (1 while no query has more than index_topk), the share of the
+# queries that have more, and whether the attention's scores stayed in VMEM
+# (1 in the Pallas kernels, 0 in the ``lax`` form)
 COUNTERS = {
     "kept_share": ("attn/kept_share", "mean"),
     "selecting_share": ("attn/selecting_share", "mean"),
+    "scores_in_vmem": ("attn/scores_in_vmem", "mean"),
 }
 
 
@@ -375,6 +380,7 @@ def forward(params: dict, x, cfg: dict, dt, residual: int):
     return x, {
         "kept_share": jnp.stack(shares).mean(),
         "selecting_share": jnp.float32(max(T - s["topk"], 0) / T),
+        "scores_in_vmem": scores_in_vmem(jax.ShapeDtypeStruct((s["hd"],), dt)),
         "routed": routed, "kept": kept,
     }
 

@@ -309,9 +309,15 @@ def attention_mixer(p, h, s, dt, kind: str):
         cos, sin = rope_table(kind, s["hd"], jnp.arange(h.shape[1]))
         q = rotate(_heads(p["q"], h, dt), cos, sin)
         k = rotate(_heads(p["k"], h, dt), cos, sin)
+        # kernels=False: this family's blocks keep the ``lax`` form, and its
+        # program is the one it had, until ppo_lift_laguna_16x1024's check can
+        # tell a rounding from a fault: it compares learn rows through the
+        # value clip's kinks, and a change of the learn pass's rounding draws
+        # its listed seeds again (PERF.md section 7, first item; ROADMAP S6 y)
         out, seen = blocked_attention(
             q, k, _heads(p["v"], h, dt),
             window=s["W"] if kind == "window" else None, block=QUERY_BLOCK,
+            kernels=False,
         )
         g = _head_gates(p, h, dt)
         out = (out.astype(jnp.float32) * g[..., None]).astype(dt)

@@ -188,6 +188,7 @@ def test_with_topk_at_or_past_the_length_the_layer_is_attention_without_selectio
     counters = {k: float(v[-1]) for k, v in sown["counters"]["trunk"].items()}
     assert counters == {
         "kept_share": pytest.approx(1.0), "selecting_share": 0.0,
+        "scores_in_vmem": 0.0,
     }
 
 
@@ -436,6 +437,7 @@ def test_learn_reports_the_rows_and_moves_neither_indexer_nor_router():
     kept, selecting = ref.kept_share_of(T, TOPK)
     assert metrics["attn/kept_share"] == pytest.approx(kept, abs=1e-6)
     assert metrics["attn/selecting_share"] == pytest.approx(selecting)
+    assert metrics["attn/scores_in_vmem"] == 0.0    # toy widths, and the CPU
     assert math.isfinite(metrics["loss/pg"]) and metrics["health/update_ratio"] > 0
     before, after = state.params["params"]["trunk"], new.params["params"]["trunk"]
     for i in range(3):
@@ -472,7 +474,10 @@ def test_ppo_first_epoch_ratio_is_one():
     logp = D.diag_gauss_logp(out.mean, out.log_std, batch["action"].swapaxes(0, 1))
     ratio = jnp.exp(logp - batch["behavior_logp"].swapaxes(0, 1))
     assert float(jnp.abs(ratio - 1).max()) < 1e-4
-    assert set(stats) == {"load", "overflow", "kept_share", "selecting_share"}
+    assert set(stats) == {
+        "load", "overflow", "kept_share", "selecting_share", "scores_in_vmem",
+    }
+    assert float(stats["scores_in_vmem"]) == 0.0    # the CPU's form
     assert stats["load"].shape == (3, 8)
 
 

@@ -831,6 +831,73 @@ def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
     assert "triangular-solve" not in text
 
 
+# blocked_attention's learn shapes: B, T, H, G, window, block, a keep-mask on
+# the blocks past the first half
+ATTENTION = {
+    "keye": (2, 4096, 32, 4, None, 512, True),
+    "laguna-window": (4, 1024, 72, 8, 512, 256, False),
+}
+
+
+def _blocked_attention_grad_text(sds, which: str) -> str:
+    """``jax.grad`` of ``blocked_attention`` at a learn pass's shapes,
+    compiled for the described chip."""
+    return _once(
+        f"blocked_attention_grad_{which}",
+        lambda: _compile_blocked_attention_grad(sds, *ATTENTION[which]),
+    )
+
+
+def _compile_blocked_attention_grad(sds, B, T, H, G, window, block, masked) -> str:
+    from surreal_tpu.ops.ring_attention import blocked_attention
+
+    def loss(q, k, v, *kept):
+        keep = None
+        if masked:
+            def keep(lo, hi, first):
+                return kept[0][:, lo:hi, first:hi] if hi > T // 2 else None
+        out, _ = blocked_attention(q, k, v, window=window, block=block, keep=keep)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    bf16 = jnp.bfloat16
+    args = [sds((B, T, H, 128), bf16)] + [sds((B, T, G, 128), bf16)] * 2
+    if masked:
+        args.append(sds((B, T, T), jnp.bool_))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("which,scores", [
+    ("keye", "f32[2,4,8,512,"), ("laguna-window", "f32[4,8,9,256,"),
+])
+def test_blocked_attention_gradient_keeps_its_scores_in_vmem(sds, which, scores):
+    """``jax.grad`` of ``blocked_attention`` at ``ppo_lift_keye_4x4096``'s
+    learn shapes (2 rows of 4096 positions, 32 heads of 128 over 4 key-value
+    heads, blocks of 512, a keep-mask on half of them) and at
+    ``ppo_lift_laguna_16x1024``'s window layer's (4 x 1024, 72 over 8, a
+    window of 512, blocks of 256), bfloat16, compiled for the v5e in this CPU
+    process: the lowering takes the two kernels a block
+    (ops/ring_attention.py chooses from the device it lowers for, the head's
+    width and the dtype), Mosaic accepts both with no ``vmem_limit_bytes``,
+    and no float32 buffer of ``block x keys`` a head is left in the
+    program."""
+    import inspect
+
+    from surreal_tpu.ops import ring_attention
+
+    text = _blocked_attention_grad_text(sds, which)
+    calls = re.findall(
+        r"%(blocked_attention_(?:fwd|bwd))[.\d]* = [^\n]*tpu_custom_call", text
+    )
+    blocks = -(-ATTENTION[which][1] // ATTENTION[which][5])
+    assert sorted(calls) == (
+        ["blocked_attention_bwd"] * blocks + ["blocked_attention_fwd"] * blocks
+    )
+    # a block's dq [.., block, 128] and log-sum-exp [.., block, 1] are there
+    assert not re.search(re.escape(scores) + r"(?!128\]|1\])", text)
+    assert "vmem_limit_bytes" not in text
+    assert "vmem_limit_bytes=" not in inspect.getsource(ring_attention)
+
+
 @pytest.mark.parametrize("build,kernels", [
     pytest.param(
         lambda chip, sds: _selective_scan_grad_text(sds),
@@ -841,6 +908,11 @@ def test_delta_rule_gradient_forms_the_gram_matrices_in_vmem(sds):
         lambda chip, sds: _delta_rule_grad_text(sds),
         {"decayed_gram": 2, "decayed_gram_bwd": 1, "delta_chunk_fwd": 1,
          "delta_chunk_bwd": 1}, id="delta-rule-gradient",
+    ),
+    pytest.param(
+        lambda chip, sds: _blocked_attention_grad_text(sds, "keye"),
+        {"blocked_attention_fwd": 8, "blocked_attention_bwd": 8},
+        id="blocked-attention-gradient",
     ),
     pytest.param(
         lambda chip, sds: _routed_acting_text(chip, "kda_moe", 16),
