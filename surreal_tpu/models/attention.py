@@ -270,6 +270,7 @@ FAMILY_MODULES = {
     "swa_moe": "swa_moe",
     "kda_moe": "kda_moe",
     "dsa_moe": "dsa_moe",
+    "gdn_moe": "gdn_moe",
 }
 BLOCK_FAMILIES = ("preln", *FAMILY_MODULES)
 
@@ -288,7 +289,7 @@ def block_family(encoder_cfg) -> str:
 
 def recomputed(layer, residual_bytes: int, above_bytes: int):
     """The wide families' shape-driven recomputation rule, in one place
-    (``ssm_hybrid``, ``swa_moe``, ``kda_moe`` and ``dsa_moe`` read it, each with its own
+    (``ssm_hybrid``, ``swa_moe``, ``kda_moe``, ``dsa_moe`` and ``gdn_moe`` read it, each with its own
     estimate and threshold): ``layer`` (a function of arrays, or a flax module class) as it is while
     the residuals a differentiated pass would keep (the family's own
     estimate, from the pass's shapes; no key) stay within ``above_bytes``,
@@ -341,7 +342,9 @@ def acting_cache(cfg, num_envs: int, horizon: int, dtype):
     state, a ring that forgets and one shared cache for 'ssm_hybrid'; full
     caches and rings of rotated keys for 'swa_moe'; a matrix state and conv
     tails a delta-rule layer beside latent rows for 'kda_moe'; rotated keys,
-    values and an indexer's own key rows a layer for 'dsa_moe').
+    values and an indexer's own key rows a layer for 'dsa_moe'; a matrix
+    state and one conv tail a delta-rule layer beside rotated keys and
+    values for 'gdn_moe').
     In the compute dtype, the attention math's own, so decode and the
     full-segment recompute round alike (precision policy,
     ops/precision.py); a recurrent state is float32."""

@@ -27,7 +27,9 @@ SiLU(conv(W_v h))``, ``conv`` causal and depthwise over the last
 (float32), ``q`` times ``K^-1/2``; the log-decay a channel ``g = -exp(A_log[h])
 softplus(W_fb (W_fa h) + dt_bias)`` (float32; the pair's inner size is ``K``);
 ``beta = sigmoid(W_b h)``, one a head; the recurrence of ``ops/delta_rule.py``
-gives ``o``; out ``W_o (RMSNorm_head(o) * sigmoid(W_gb (W_ga h)))``.
+in its channel-wise case (``g`` of ``q``'s rank; ``models/gdn_moe.py`` runs the
+head-wise one) gives ``o``; out ``W_o (RMSNorm_head(o) * sigmoid(W_gb (W_ga
+h)))``.
 
 **Latent attention** is ``models/latent_moe.py::LatentAttention`` with no
 low-rank query pair and no rotary turn; **the feed-forward**, the bias rule

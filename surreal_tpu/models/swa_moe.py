@@ -351,7 +351,10 @@ def attention_step(p, h, cache, pos, s, dt, kind: str):
 
 def routed_ffn(p, shared, x, s):
     """A routed layer over ``x [N, D]`` (``shared`` None: no shared expert,
-    ``models/dsa_moe.py``): ``(y, {"load" [E], "overflow",
+    ``models/dsa_moe.py``; with a leaf ``"token_gate" [D]`` the shared
+    expert's output is times ``sigmoid(x . token_gate)`` a token, float32,
+    and the gate's mean is in the statistics as ``"shared_gate"``:
+    ``models/gdn_moe.py``): ``(y, {"load" [E], "overflow",
     "experts" [N, K], "inputs" [N, D], "read": the share of the held
     experts whose weights the pass read})``."""
     E, K, held, first = s["E"], s["K"], s["held"], s["first"]
@@ -387,7 +390,15 @@ def routed_ffn(p, shared, x, s):
                 x, token, weight, valid, sizes, p["gate"], p["up"], p["down"]
             ), jnp.float32(1.0)
         if shared is not None:
-            y = y + moe.swiglu(x, shared["gate"], shared["up"], shared["down"])
+            extra = moe.swiglu(x, shared["gate"], shared["up"], shared["down"])
+            if "token_gate" in shared:
+                open_ = jax.nn.sigmoid(jnp.dot(
+                    x, shared["token_gate"].astype(x.dtype),
+                    preferred_element_type=jnp.float32,
+                ))
+                stats["shared_gate"] = open_.mean()
+                extra = (extra.astype(jnp.float32) * open_[:, None]).astype(extra.dtype)
+            y = y + extra
     return y, stats
 
 

@@ -1,5 +1,5 @@
-"""The gated delta rule with a decay a channel, in chunks, with its own
-backward, the matrix state in VMEM where a TPU runs it.
+"""The gated delta rule with a decay a channel or a head, in chunks, with its
+own backward, the matrix state in VMEM where a TPU runs it.
 
 The recurrence of a Kimi Delta Attention layer (Kimi Linear, arXiv:2510.26692,
 section 3; the delta rule of Schlag et al. 2021, arXiv:2102.11174, gated a
@@ -12,6 +12,18 @@ so a step first decays every row of the state by its own channel's
 ``alpha``, then erases what the decayed state holds along the key it is
 about to write (``beta`` of it) and writes the new value there. The state is
 float32 throughout.
+
+**The rank of ``g`` says which decay.** ``g`` of ``q``'s rank (``[B, T, H,
+K]``; ``[B, H, K]`` a step) is a decay a channel, ``models/kda_moe.py``'s;
+``g`` one rank lower (``[B, T, H]``; ``[B, H]`` a step) is one decay a head,
+``Diag(alpha_t)`` a multiple of the identity: the Gated DeltaNet rule
+(Yang et al. 2024, arXiv:2412.06464), ``models/gdn_moe.py``'s. No key or
+flag: :func:`delta_rule` spreads a head's decay over the head's channels and
+runs the forms below as they are, which is exact (every channel's running
+sum is then the head's); :func:`delta_step` scales the whole state. A
+head-wide decay does not need the pairwise ``[SUB, SUB, K]`` decays it pays
+for so (a chunk's Gram pair is then ``(Q K^T) * exp(G_t - G_i)`` under the
+triangle, an ``[L, L]`` table): that lean form is not written (ROADMAP S16).
 
 **In chunks.** With ``u_t = beta_t (v_t - (Diag(alpha_t) S_{t-1})^T k_t)``,
 the value a step really writes, ``S_t = Diag(alpha_t) S_{t-1} + k_t u_t^T``.
@@ -135,11 +147,14 @@ def _chunk_len(T: int) -> int:
 
 
 def delta_step(q_t, k_t, v_t, g_t, beta_t, state):
-    """One position: ``q_t, k_t, g_t [B, H, K]``, ``v_t [B, H, V]``,
-    ``beta_t [B, H]``, ``state [B, H, K, V]`` float32 -> ``(o_t [B, H, V]
-    float32, new state)``."""
+    """One position: ``q_t, k_t [B, H, K]``, ``g_t [B, H, K]`` (a decay a
+    channel) or ``[B, H]`` (one a head), ``v_t [B, H, V]``, ``beta_t [B,
+    H]``, ``state [B, H, K, V]`` float32 -> ``(o_t [B, H, V] float32, new
+    state)``."""
     f32 = jnp.float32
     q_t, k_t, v_t = q_t.astype(f32), k_t.astype(f32), v_t.astype(f32)
+    if g_t.ndim == q_t.ndim - 1:
+        g_t = g_t[..., None]
     state = jnp.exp(g_t.astype(f32))[..., None] * state
     held = (k_t[..., None] * state).sum(-2)
     u = beta_t.astype(f32)[..., None] * (v_t - held)
@@ -817,12 +832,14 @@ _chunked.defvjp(_chunked_fwd, _chunked_bwd)
 
 
 def delta_rule(q, k, v, g, beta, state=None):
-    """``q, k, g [B, T, H, K]`` (``g`` the log-decay, <= 0), ``v [B, T, H,
-    V]``, ``beta [B, T, H]``, ``state [B, H, K, V]`` float32 (zeros when
-    ``None``) -> ``(o [B, T, H, V] float32, final state [B, H, K, V]
-    float32)``."""
+    """``q, k [B, T, H, K]``, ``g`` the log-decay, <= 0, ``[B, T, H, K]`` a
+    channel or ``[B, T, H]`` a head (module docstring), ``v [B, T, H, V]``,
+    ``beta [B, T, H]``, ``state [B, H, K, V]`` float32 (zeros when ``None``)
+    -> ``(o [B, T, H, V] float32, final state [B, H, K, V] float32)``."""
     B, T, H, K = q.shape
     V = v.shape[-1]
+    if g.ndim == q.ndim - 1:
+        g = jnp.broadcast_to(g[..., None], q.shape)
     if CHUNK % SUB:
         raise ValueError(f"CHUNK={CHUNK} must be a multiple of {SUB}")
     if state is None:

@@ -106,6 +106,22 @@ BASE_LEARNER_CONFIG = Config(
             #   n_routed_experts, num_experts_per_tok, rms_norm_eps,
             #   first_held, num_held and its own three keys below.
             #   Keye-VL-2.0-30B-A3B's widths where a key is None.
+            # 'gdn_moe' (models/gdn_moe.py): zero-centred RMSNorm (1 + w),
+            #   Gated DeltaNet layers (the delta rule with one decay a head
+            #   over a matrix state a value head, linear_num_key_heads key
+            #   heads under linear_num_value_heads value heads, one conv
+            #   over q | k | v, an output norm gated by SiLU) three to one
+            #   gated attention layer (a norm a head on q and k, rotary on
+            #   partial_rotary_factor of the head, a sigmoid gate a channel
+            #   before the output product), every layer routed by a softmax
+            #   beside a shared expert with a sigmoid gate a token; matrix
+            #   states and conv tails beside rotated keys and values to act
+            #   from. Reads hidden_size, num_kv_heads, attn_head_dim,
+            #   rope_theta, short_conv_kernel_size, moe_intermediate_size,
+            #   shared_intermediate_size, n_routed_experts,
+            #   num_experts_per_tok, rms_norm_eps, first_held, num_held and
+            #   its own four keys below. Qwen3-Next-80B-A3B-Instruct's widths
+            #   where a key is None.
             # All read kind, block, num_heads, act_impl, and num_layers
             # ('ssm_hybrid': pairs_before, pairs_after instead).
             block="preln",
@@ -173,6 +189,13 @@ BASE_LEARNER_CONFIG = Config(
             index_n_heads=None,            # the indexer's query heads
             index_head_dim=None,           # their size, and the one key head's
             index_topk=None,               # keys a query keeps
+            # -- 'gdn_moe' only (None = the published
+            # Qwen3-Next-80B-A3B-Instruct value, FAMILY_DEFAULTS in
+            # models/gdn_moe.py) ---------------------------------------------
+            linear_num_key_heads=None,     # a delta-rule layer's key heads
+            linear_num_value_heads=None,   # its value heads, a multiple of them
+            linear_head_dim=None,          # a key's and a value's size alike
+            partial_rotary_factor=None,    # the share of a head that turns
         ),
         cnn=Config(
             enabled=False,          # pixel observations -> Nature-CNN stem

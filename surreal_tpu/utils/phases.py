@@ -62,7 +62,7 @@ Phases say *when* in the iteration an op runs. A second, short
 vocabulary of **parts** says *which part of the model* it belongs to, for
 a trunk large enough that this is the question (``models/latent_moe.py``,
 ``models/ssm_hybrid.py``, ``models/swa_moe.py``, ``models/kda_moe.py``,
-``models/dsa_moe.py``).
+``models/dsa_moe.py``, ``models/gdn_moe.py``).
 A part's scope sits inside whatever phase runs the model, so an op has
 one phase and at most one part, and the digest sums each on its own:
 
@@ -97,6 +97,13 @@ one phase and at most one part, and the digest sums each on its own:
                  scores, the k-th-value search and the keep-mask, in the
                  learn pass and against the acting step's index cache; the
                  attention over the kept keys is ``attn``
+    gdn_scan     a Gated DeltaNet layer's (``models/gdn_moe.py``) conv over
+                 the concatenated q | k | v, SiLU, L2 norms, the head's
+                 decay, ``beta``, the delta rule over a segment
+                 (``ops/delta_rule.py``) or one acting step of it, the
+                 output norm and its SiLU gate
+    gdn_proj     its products: the one to q, k, v and the gate, the one to
+                 ``beta`` and the decay, the output projection
 """
 
 from __future__ import annotations
@@ -109,7 +116,7 @@ PHASES = (
 PARTS = (
     "attn", "moe_route", "moe_experts", "dense_ffn", "optimizer",
     "ssm_scan", "ssm_proj", "gmu", "attn_window", "attn_full",
-    "kda_scan", "kda_proj", "attn_index",
+    "kda_scan", "kda_proj", "attn_index", "gdn_scan", "gdn_proj",
 )
 # the sub-scopes a phase may have: phase("collect/act")
 SUBPHASES = {

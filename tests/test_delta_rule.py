@@ -360,3 +360,71 @@ def test_the_shapes_choose_the_form(K, asks):
     o_ref, S_ref = jax.jit(recurrence)(q, k, v, g, beta, state)
     np.testing.assert_allclose(o, o_ref, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(S, S_ref, rtol=2e-5, atol=2e-5)
+
+
+# -- one decay a head ----------------------------------------------------------------
+
+@pytest.mark.parametrize("form, T", [
+    ("lax", CHUNK + 21), ("kernels", CHUNK + 16),
+], indirect=["form"])
+def test_a_heads_decay_is_the_same_decay_a_channel(form, T):
+    """``g [B, T, H]`` (one decay a head: the Gated DeltaNet rule) against
+    the same decay spread over the head's channels by the caller: outputs,
+    final state and every gradient, in the ``lax`` form and through the
+    kernels, and both against the recurrence with a scalar decay."""
+    K, V = form
+    q, k, v, g, beta, state = _inputs(5, 2, T, 2, K, V)
+    g_head = g[..., 0]
+    g_wide = jnp.broadcast_to(g_head[..., None], g.shape)
+    w_o = jax.random.normal(jax.random.key(7), (2, T, 2, V))
+    w_s = jax.random.normal(jax.random.key(8), (2, 2, K, V))
+
+    def run(fn, g):
+        def f(q, k, v, g, beta, state):
+            o, S = fn(q, k, v, g, beta, state)
+            return (o * w_o).sum() + (S * w_s).sum(), (o, S)
+        return jax.jit(jax.value_and_grad(f, argnums=tuple(range(6)), has_aux=True))(
+            q, k, v, g, beta, state
+        )
+
+    (_, (o, S)), grads = run(delta_rule, g_head)
+    (_, (o_w, S_w)), grads_w = run(delta_rule, g_wide)
+    np.testing.assert_array_equal(o, o_w)
+    np.testing.assert_array_equal(S, S_w)
+    assert grads[3].shape == g_head.shape
+    for name, a, b in zip(("q", "k", "v", "g", "beta", "state"), grads, grads_w):
+        if name == "g":
+            b = b.sum(-1)       # the spread's transpose
+        np.testing.assert_allclose(
+            a, b, rtol=1e-5, atol=1e-5 * float(jnp.abs(b).max()), err_msg=name
+        )
+    (_, (o_r, S_r)), grads_r = run(recurrence, g_wide)
+    np.testing.assert_allclose(o, o_r, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(S, S_r, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        grads[3], grads_r[3].sum(-1), rtol=1e-4,
+        atol=1e-4 * float(jnp.abs(grads_r[3].sum(-1)).max()),
+    )
+
+
+def test_steps_with_a_heads_decay_equal_the_rule():
+    """``k`` calls of ``delta_step`` with ``g_t [B, H]`` against a carried
+    state are the rule over ``k`` positions with ``g [B, T, H]``, and the
+    steps with the same decay a channel, to the bit."""
+    q, k, v, g, beta, state = _inputs(6, 2, 19, 3, 8, 6)
+    g_head = g[..., 0]
+    o_rule, S_rule = delta_rule(q, k, v, g_head, beta, state)
+    S = S_wide = state
+    outs = []
+    for t in range(q.shape[1]):
+        o_t, S = delta_step(q[:, t], k[:, t], v[:, t], g_head[:, t], beta[:, t], S)
+        o_w, S_wide = delta_step(
+            q[:, t], k[:, t], v[:, t],
+            jnp.broadcast_to(g_head[:, t, :, None], q[:, t].shape), beta[:, t],
+            S_wide,
+        )
+        np.testing.assert_array_equal(o_t, o_w)
+        outs.append(o_t)
+    np.testing.assert_array_equal(S, S_wide)
+    np.testing.assert_allclose(jnp.stack(outs, 1), o_rule, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(S, S_rule, rtol=2e-5, atol=2e-5)

@@ -351,6 +351,7 @@ def test_the_table_has_one_entry_a_family_and_the_selectors_read_it():
 
     assert attention.BLOCK_FAMILIES == (
         "preln", "mla_moe", "ssm_hybrid", "swa_moe", "kda_moe", "dsa_moe",
+        "gdn_moe",
     )
     assert attention.family_of({"block": "preln"}) is None
     assert attention.family_of({}) is None
